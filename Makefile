@@ -75,7 +75,7 @@ distributed:
 # repo-specific analyzers (thread safety, JAX trace purity,
 # metric/config drift). See docs/static-analysis.md.
 lint:
-	python -m compileall -q retina_tpu tests tools bench.py __graft_entry__.py
+	python -m compileall -q retina_tpu tests tools bench.py chip_smoke.py __graft_entry__.py
 	python tools/lint.py
 
 # Device-program analysis (RT300 family): AOT-lowers every registered

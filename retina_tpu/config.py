@@ -786,33 +786,88 @@ def load_config(
     return cfg
 
 
-# Where the deploy manifests point compilation_cache_dir on a node.
+# The value the deploy manifests write into their configmap's
+# compilation_cache_dir. Nothing in the tree opens it on its own.
 DEFAULT_CACHE_DIR = "/var/cache/retina-tpu/xla"
 
+# Where bench.py and chip_smoke.py keep compiled programs when the
+# caller has not placed the cache with JAX_COMPILATION_CACHE_DIR: one
+# fixed, git-ignored directory of the checkout. The path is part of the
+# persistent cache's key, so it must never move between runs.
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".retina_cache",
+)
 
-def enable_compilation_cache(cache_dir: str) -> bool:
-    """Point JAX's persistent compilation cache at ``cache_dir``.
 
-    Returns True if enabled. Failure (unwritable dir, old jax) is
-    non-fatal but logged: the agent still boots, restarts just pay the
-    full compile again. JAX's default min-compile-time/size thresholds
-    are kept — the target is the ~100 s fused-step compile, and the
-    thresholds stop trivial compiles from growing the dir unboundedly.
-    """
-    if not cache_dir:
-        return False
+def harness_cache_dirs() -> tuple[str, str]:
+    """``(xla_dir, aot_dir)`` for bench.py and chip_smoke.py.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, places both: JAX's
+    persistent cache at that directory and the AOT executable cache
+    (``cfg.aot_cache_dir``) in its ``aot`` subdirectory. Unset, both
+    live under :data:`CHECKOUT_CACHE_DIR`."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR", "")
+    if env:
+        return env, os.path.join(env, "aot")
+    return (
+        os.path.join(CHECKOUT_CACHE_DIR, "xla"),
+        os.path.join(CHECKOUT_CACHE_DIR, "aot"),
+    )
+
+
+def enable_harness_caches() -> tuple[str, str]:
+    """Turn on both caches for bench.py / chip_smoke.py and return
+    ``(xla_dir, aot_dir)`` as used. An unusable directory is an error
+    here, not a warning."""
+    xla_dir, aot_dir = harness_cache_dirs()
+    xla_dir = enable_compilation_cache(xla_dir, strict=True)
     try:
-        os.makedirs(cache_dir, exist_ok=True)
-        import jax
+        os.makedirs(aot_dir, exist_ok=True)
+    except OSError as e:
+        raise RuntimeError(f"AOT cache at {aot_dir} unusable: {e}") from e
+    return xla_dir, aot_dir
 
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        return True
-    except Exception as e:  # noqa: BLE001 — cache is an optimization only
+
+def enable_compilation_cache(cache_dir: str, strict: bool = False) -> str:
+    """Turn on JAX's persistent compilation cache; returns the directory
+    in use ("" = off).
+
+    ``JAX_COMPILATION_CACHE_DIR`` wins: when it is set JAX has already
+    read it and ``cache_dir`` is ignored — no code path then calls
+    ``jax.config.update``. Otherwise the cache is pointed at
+    ``cache_dir`` ("" leaves it off). JAX's default min-compile-time
+    threshold is kept: the target is the minutes-long fused-step
+    compile, and the threshold stops trivial compiles from growing the
+    directory unboundedly.
+
+    A directory that cannot be created or written is a logged warning
+    for the agent (it still boots; restarts pay the full compile) and,
+    with ``strict``, an error: a harness that reports warm-boot seconds
+    must not run without the cache it reports on."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR", "")
+    target = env or cache_dir
+    if not target:
+        return ""
+    try:
+        os.makedirs(target, exist_ok=True)
+        if not os.access(target, os.W_OK | os.X_OK):
+            raise PermissionError(f"{target} is not writable")
+    except OSError as e:
+        if strict:
+            raise RuntimeError(
+                f"compilation cache at {target} unusable: {e}"
+            ) from e
         from retina_tpu.log import logger
 
         logger("config").warning(
             "compilation cache at %s unavailable (%s: %s); "
             "restarts will pay full XLA compile",
-            cache_dir, type(e).__name__, e,
+            target, type(e).__name__, e,
         )
-        return False
+        return ""
+    if not env:
+        import jax
+
+        jax.config.update("jax_compilation_cache_dir", target)
+    return target

@@ -37,9 +37,9 @@ class Daemon:
             jax.config.update("jax_platforms", cfg.device_platform)
             self.log.info("device platform forced: %s",
                           cfg.device_platform)
-        if enable_compilation_cache(cfg.compilation_cache_dir):
-            self.log.info("XLA compilation cache at %s",
-                          cfg.compilation_cache_dir)
+        cache_dir = enable_compilation_cache(cfg.compilation_cache_dir)
+        if cache_dir:
+            self.log.info("XLA compilation cache at %s", cache_dir)
         if cfg.fault_spec:
             # Deterministic fault injection (chaos testing): armed only
             # when explicitly configured (RETINA_FAULT_SPEC / config).

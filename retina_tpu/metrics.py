@@ -128,9 +128,9 @@ class Metrics:
         self.events_sampled = c(mn.EVENTS_SAMPLED, [])
         self.events_shed = c(mn.EVENTS_SHED, [mn.L_STAGE])
         self.accuracy_debt = c(mn.ACCURACY_DEBT, [])
-        # Device->host bytes (snapshot readbacks): on a serialized
-        # tunnel link they share the same pipe as transfer_bytes, so
-        # link-utilization math must sum both directions.
+        # Device->host bytes (snapshot readbacks), beside
+        # transfer_bytes for the other direction: link-utilization
+        # math sums both.
         self.readback_bytes = c(mn.READBACK_BYTES, [])
         # Fleet rollup tier (fleet/; see metric_names for semantics).
         # Node-side shipper:
@@ -269,7 +269,8 @@ class Metrics:
         # engine; docs/observability.md).
         self.build_info = g(
             mn.RETINA_BUILD_INFO,
-            ["version", "jax", "backend", "devices", "config"],
+            ["version", "jax", "backend", "device_kind", "devices",
+             "config"],
         )
         self.uptime_seconds = g(mn.TPU_UPTIME_SECONDS, [])
 

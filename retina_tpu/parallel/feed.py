@@ -67,7 +67,12 @@ class TransferQueue:
             ) -> bool:
         """Enqueue, waiting for a free slot. Returns False (item NOT
         enqueued) once ``alive`` goes falsy — the consumer died and the
-        caller must drop + count instead of wedging forever."""
+        caller must drop + count instead of wedging forever. Liveness
+        is checked before the first append too: a queue with free
+        slots in front of a dead consumer would otherwise swallow
+        ``depth`` items that nobody ever counts."""
+        if alive is not None and not alive():
+            return False
         t0 = None
         while len(self.q) >= self.depth:
             if alive is not None and not alive():
@@ -110,6 +115,16 @@ class TransferMux:
     def put_ctl(self, item: Any) -> None:
         self._ctl.append(item)
         self._data.set()
+
+    def drain_unconsumed(self) -> list:
+        """Items still queued after the consumer has exited (it died
+        before the sentinel reached it). Only safe once the consumer
+        thread is gone; the caller drops + counts them."""
+        out = []
+        for tq in self._qs:
+            while tq.q:
+                out.append(tq.q.popleft())
+        return out
 
     def get(self, timeout: float | None = None) -> Any:
         deadline = None if timeout is None else time.monotonic() + timeout

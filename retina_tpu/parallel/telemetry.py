@@ -21,6 +21,7 @@ hand, XLA inserts the collectives from the shardings.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 import os
 import pickle
@@ -38,21 +39,6 @@ from retina_tpu.models.identity import IdentityMap
 from retina_tpu.models.pipeline import PipelineConfig, PipelineState, TelemetryPipeline
 from retina_tpu.ops.invertible import decode_verified
 from retina_tpu.ops.topk import TopKTable
-
-# jax >= 0.5 promotes shard_map to the top-level namespace and renames
-# the replication checker kwarg check_rep -> check_vma; 0.4.x keeps both
-# the experimental home and the old name. Resolve once so every _build_*
-# site stays version agnostic.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def _shard_map(f, **kw):
-        if "check_vma" in kw:
-            kw["check_rep"] = kw.pop("check_vma")
-        return _exp_shard_map(f, **kw)  # noqa: RT305 — version shim, not a program site; callers carry @device_entry
-
 
 # On-disk AOT executable cache accounting (ROADMAP item 5: compile cost
 # swings 2.1s->96.1s and bucket-grid warm is 214s PER PROCESS — a disk
@@ -94,32 +80,66 @@ def _aot_disk_bump(field: str, tag: str = "") -> None:
 # grid is the bulk of the 214s r05 warm, so it must ride the same disk
 # cache as the step programs for a warm boot to land under 10s.
 
+def _exec_devices(mesh: Mesh | None) -> list:
+    """The devices one cached executable runs on: the mesh's, or the
+    single default device for the mesh-less query programs
+    (timetravel/fold.py), which compile from concrete uncommitted
+    arguments."""
+    if mesh is not None:
+        return list(mesh.devices.ravel())
+    return [jax.local_devices()[0]]
+
+
+@functools.lru_cache(maxsize=1)
+def _source_fingerprint() -> str:
+    """Hash of the package's own source. The disk cache is consulted
+    BEFORE a program is traced, so its key cannot see the program: a
+    cache directory that outlives a code change (a node's hostPath, a
+    CI machine's kept cache) would otherwise serve the old executable
+    for new code whenever the config happens to be unchanged."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    h = hashlib.sha256()
+    for dirpath, dirnames, filenames in os.walk(root):
+        dirnames.sort()
+        for name in sorted(filenames):
+            if name.endswith(".py"):
+                path = os.path.join(dirpath, name)
+                h.update(os.path.relpath(path, root).encode())
+                with open(path, "rb") as f:
+                    h.update(f.read())
+    return h.hexdigest()[:16]
+
+
 def aot_disk_path(
     cache_dir: str, mesh: Mesh | None, tag: str, config_sig: str, key
 ) -> str:
     """Cache-file path for one (program tag, input-signature) pair,
-    keyed by jax version + backend topology + config signature so a
-    stale entry can never load into a mismatched process. ``mesh=None``
-    keys on the full default device set — the mesh-less query programs
-    (timetravel/fold.py) compile against it."""
-    devs = (
-        mesh.devices.ravel() if mesh is not None
-        else np.asarray(jax.devices())
-    )
+    keyed by jax version + package source + the executable's own device
+    set + config signature so a stale entry can never load into a
+    mismatched process."""
+    devs = _exec_devices(mesh)
     topo = "{}:{}:{}".format(
-        jax.default_backend(), len(devs),
-        getattr(devs[0], "device_kind", "?"),
+        jax.default_backend(), ",".join(str(d.id) for d in devs),
+        devs[0].device_kind,
     )
-    raw = "|".join((jax.__version__, topo, tag, config_sig, repr(key)))
+    raw = "|".join((
+        jax.__version__, _source_fingerprint(), topo, tag, config_sig,
+        repr(key),
+    ))
     h = hashlib.sha256(raw.encode()).hexdigest()[:32]
     return os.path.join(cache_dir, f"{tag}-{h}.aotx")
 
 
-def aot_disk_load(path: str, tag: str = ""):
-    """Deserialize a cached executable, or None (best-effort: stale jax,
-    corrupt/truncated file, incompatible executable all fall back to a
-    fresh compile). ``tag`` feeds the per-program counters and the
-    hit/miss log line."""
+def aot_disk_load(path: str, mesh: Mesh | None, tag: str = ""):
+    """Deserialize a cached executable onto ``mesh``'s devices, or None
+    (best-effort: corrupt/truncated file or incompatible executable
+    fall back to a fresh compile, counted under ``errors``). ``tag``
+    feeds the per-program counters and the hit/miss log line.
+
+    ``execution_devices`` is always passed: left out,
+    ``deserialize_and_load`` assumes every device of the backend, and an
+    executable compiled for fewer loads without complaint and then
+    fails at its first call."""
     if not os.path.exists(path):
         return None
     try:
@@ -128,7 +148,8 @@ def aot_disk_load(path: str, tag: str = ""):
         with open(path, "rb") as f:
             payload = pickle.load(f)
         ex = se.deserialize_and_load(
-            payload["exe"], payload["in_tree"], payload["out_tree"]
+            payload["exe"], payload["in_tree"], payload["out_tree"],
+            execution_devices=_exec_devices(mesh),
         )
         _aot_disk_bump("hits", tag)
         if tag:
@@ -136,6 +157,10 @@ def aot_disk_load(path: str, tag: str = ""):
         return ex
     except Exception:
         _aot_disk_bump("errors", tag)
+        _aot_log().warning(
+            "aot disk load failed tag=%s path=%s", tag, path,
+            exc_info=True,
+        )
         return None
 
 
@@ -162,6 +187,10 @@ def aot_disk_save(path: str, ex, tag: str = "") -> None:
             )
     except Exception:
         _aot_disk_bump("errors", tag)
+        _aot_log().warning(
+            "aot disk save failed tag=%s path=%s", tag, path,
+            exc_info=True,
+        )
 
 
 def _aot_log():
@@ -190,11 +219,11 @@ class AotProgram:
 
     When ``cache_dir`` is set, each compiled executable is additionally
     persisted to disk via ``jax.experimental.serialize_executable``,
-    keyed by (jax version, backend topology, ``config_sig``, program
+    keyed by (jax version, the mesh's devices, ``config_sig``, program
     tag, input signature) — a later process with the same key skips XLA
     compilation entirely. Every disk interaction is best-effort: any
-    failure (old jax without the API, unpicklable trees, corrupt file,
-    read-only dir) falls back to a fresh in-process compile.
+    failure (unpicklable trees, corrupt file, read-only dir) falls back
+    to a fresh in-process compile and is counted under ``errors``.
     """
 
     def __init__(self, jitted, mesh: Mesh, sharded_spec,
@@ -227,7 +256,7 @@ class AotProgram:
         )
 
     def _disk_load(self, path: str):
-        return aot_disk_load(path, tag=self._tag)
+        return aot_disk_load(path, self._mesh, tag=self._tag)
 
     def _disk_save(self, path: str, ex) -> None:
         aot_disk_save(path, ex, tag=self._tag)
@@ -352,7 +381,7 @@ class ShardedTelemetry:
             return new, out
 
         sh = self._sharded_spec
-        fn = _shard_map(
+        fn = jax.shard_map(
             local_step,
             mesh=self.mesh,
             in_specs=(sh, sh, sh, P(), P(), P(), P(), P(), P()),
@@ -465,7 +494,7 @@ class ShardedTelemetry:
             return new, {"entropy_bits": h, "anomaly": flags, "zscore": z}
 
         sh = self._sharded_spec
-        fn = _shard_map(
+        fn = jax.shard_map(
             local_end,
             mesh=self.mesh,
             in_specs=(sh, P()),
@@ -533,7 +562,7 @@ class ShardedTelemetry:
                 "active_conns": psum(s.conntrack.active_connections(now_s)),
             }
 
-        fn = _shard_map(
+        fn = jax.shard_map(
             local_snap,
             mesh=self.mesh,
             in_specs=(self._sharded_spec, P()),
@@ -605,7 +634,7 @@ class ShardedTelemetry:
                 out["inv_hi_weights"] = psum(s.inv_hi.weights)
             return out
 
-        fn = _shard_map(
+        fn = jax.shard_map(
             local_fx,
             mesh=self.mesh,
             in_specs=(self._sharded_spec,),
@@ -683,7 +712,7 @@ class ShardedTelemetry:
                 "tier": jnp.concatenate([f_tier, h_tier]),
             }
 
-        fn = _shard_map(
+        fn = jax.shard_map(
             local_dec,
             mesh=self.mesh,
             in_specs=(self._sharded_spec, P()),

@@ -80,7 +80,7 @@ def _disk_compiled(tag: str, jitted, args: tuple):  # may-block: AOT disk-cache 
     ex = _AOT_EXEC_CACHE.get(ck)
     if ex is None:
         path = aot_disk_path(_AOT_CACHE_DIR, None, tag, "", key)
-        ex = aot_disk_load(path, tag=tag)
+        ex = aot_disk_load(path, None, tag=tag)
         if ex is None:
             ex = jitted.lower(*args).compile()
             aot_disk_save(path, ex, tag=tag)

@@ -249,6 +249,29 @@ def test_grafana_dashboards_reference_real_metrics():
         reset_metrics()
 
 
+def test_shipped_agent_config_names_registered_plugins(tmp_path):
+    """The configmap's config.yaml, loaded the way `retina-tpu agent
+    --config` loads it, must construct a PluginManager: an unknown
+    plugin name is fatal at boot (registry.get raises KeyError). The
+    helm chart's default plugin list is held to the same registry."""
+    import retina_tpu.plugins  # noqa: F401 — self-registration
+    from retina_tpu.config import load_config
+    from retina_tpu.managers.pluginmanager import PluginManager
+    from retina_tpu.plugins import registry
+
+    cm = next(d for d in load_all() if d["kind"] == "ConfigMap")
+    path = tmp_path / "config.yaml"
+    path.write_text(cm["data"]["config.yaml"])
+    cfg = load_config(str(path), env={})
+    assert set(cfg.enabled_plugins) <= set(registry.names())
+    pm = PluginManager(cfg)
+    assert set(cfg.enabled_plugins) <= set(pm.plugins)
+    values = os.path.join(DEPLOY, "..", "helm", "retina-tpu", "values.yaml")
+    with open(values) as fh:
+        helm = yaml.safe_load(fh)
+    assert set(helm["agent"]["enabledPlugins"]) <= set(registry.names())
+
+
 def test_ci_workflow_coherent():
     """CI workflow (reference .github/workflows/test.yaml analog) parses
     and references files/commands that exist in the repo."""

@@ -30,24 +30,59 @@ def test_config_defaults_valid():
     assert "packetparser" in cfg.enabled_plugins
 
 
-def test_compilation_cache_enable(tmp_path):
-    """Persistent XLA cache knob points jax at the dir (restart SLA:
-    warm full-shape compile drops ~100s -> ~2s on TPU)."""
+def test_compilation_cache_enable(tmp_path, monkeypatch):
+    """Persistent XLA cache knob points jax at the dir (a restart then
+    skips the minutes-long fused-step compile); an unusable dir is a
+    warning for the agent and an error for a harness."""
     import jax
 
     from retina_tpu.config import enable_compilation_cache
 
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     prev = jax.config.jax_compilation_cache_dir
     try:
         d = str(tmp_path / "xla-cache")
-        assert enable_compilation_cache(d)
+        assert enable_compilation_cache(d) == d
         assert jax.config.jax_compilation_cache_dir == d
         assert os.path.isdir(d)
-        assert enable_compilation_cache("") is False
+        assert enable_compilation_cache("") == ""
         # Off by default: bare Config must not touch global host state.
         assert Config().compilation_cache_dir == ""
+        blocked = tmp_path / "a-file"
+        blocked.write_text("")
+        bad = str(blocked / "cache")
+        assert enable_compilation_cache(bad) == ""
+        with pytest.raises(RuntimeError, match="unusable"):
+            enable_compilation_cache(bad, strict=True)
+        assert jax.config.jax_compilation_cache_dir == d
     finally:
         jax.config.update("jax_compilation_cache_dir", prev)
+
+
+def test_compilation_cache_placed_from_outside(tmp_path, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR, when the caller sets it, places every
+    cache and no code touches jax's own setting; unset, the harnesses
+    use one fixed directory of the checkout."""
+    import jax
+
+    from retina_tpu import config
+
+    prev = jax.config.jax_compilation_cache_dir
+    env_dir = str(tmp_path / "placed")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    assert config.enable_compilation_cache(str(tmp_path / "other")) == env_dir
+    assert config.enable_compilation_cache("", strict=True) == env_dir
+    assert jax.config.jax_compilation_cache_dir == prev
+    assert not (tmp_path / "other").exists()
+    assert config.harness_cache_dirs() == (
+        env_dir, os.path.join(env_dir, "aot")
+    )
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert config.harness_cache_dirs() == (
+        os.path.join(repo, ".retina_cache", "xla"),
+        os.path.join(repo, ".retina_cache", "aot"),
+    )
 
 
 def test_config_yaml_env_layering(tmp_path):

@@ -495,6 +495,65 @@ class TestAotDiskCacheWarm:
                     "snapshot_flat"):
             assert s2["by_tag"][tag]["hits"] >= 1, (tag, s2)
 
+    def test_subset_mesh_executable_reloads_and_runs(self, tmp_path):
+        """The agent's restart path on a host with more devices than
+        the mesh uses (``mesh_devices`` < local devices): the cached
+        executable must come back bound to the mesh's own devices and
+        RUN. Loaded without ``execution_devices`` it is bound to every
+        device of the backend, deserializes without complaint and
+        fails at its first call."""
+        import jax
+
+        from retina_tpu.models.pipeline import PipelineConfig
+        from retina_tpu.parallel import ShardedTelemetry, make_mesh
+        from retina_tpu.parallel.telemetry import aot_disk_cache_stats
+
+        assert len(jax.devices()) >= 4
+        cfg = PipelineConfig(
+            n_pods=1 << 4, cms_width=1 << 6, topk_slots=1 << 4,
+            hll_precision=4, hll_pod_precision=4,
+            entropy_buckets=1 << 6, conntrack_slots=1 << 6,
+            latency_slots=1 << 4,
+        )
+        mesh = make_mesh(jax.devices()[:2])
+
+        def boot():
+            st = ShardedTelemetry(cfg, mesh, aot_cache_dir=str(tmp_path))
+            state = st.init_state()
+            state, win = st.end_window(state)
+            return np.asarray(win["entropy_bits"])
+
+        s0 = aot_disk_cache_stats()
+        first = boot()
+        second = boot()  # deserialized: must execute, not just load
+        s2 = aot_disk_cache_stats()
+        assert s2["hits"] - s0["hits"] >= 1, (s0, s2)
+        assert s2["errors"] == s0["errors"], (s0, s2)
+        np.testing.assert_array_equal(first, second)
+
+    def test_disk_key_names_devices_and_source(self, monkeypatch):
+        """Same program, different device set or different package
+        source => different cache file: an executable is never served
+        to devices it was not compiled for, nor to code it was not
+        compiled from."""
+        import jax
+
+        from retina_tpu.parallel import make_mesh, telemetry
+
+        devs = jax.devices()
+        path = lambda mesh: telemetry.aot_disk_path(
+            "/c", mesh, "step", "sig", ("k",)
+        )
+        a = path(make_mesh(devs[:2]))
+        assert a == path(make_mesh(devs[:2]))
+        assert a != path(make_mesh(devs[1:3]))
+        assert a != path(make_mesh(devs[:1]))
+        assert path(None) == path(make_mesh(jax.local_devices()[:1]))
+        monkeypatch.setattr(
+            telemetry, "_source_fingerprint", lambda: "another-tree"
+        )
+        assert a != path(make_mesh(devs[:2]))
+
     def test_second_fold_warm_all_hits(self, tmp_path):
         """Same contract for the timetravel query programs (fold /
         extract), which live outside AotProgram."""

@@ -252,7 +252,7 @@ TRANSFER = {
     "concatenate": None,  # handled inline (n-ary hull)
 }
 
-_CALL_PRIMS = {"pjit", "closed_call", "custom_jvp_call", "custom_vjp_call"}
+_CALL_PRIMS = {"jit", "closed_call", "custom_jvp_call", "custom_vjp_call"}
 
 
 def _literal_interval(val) -> Interval:
@@ -272,7 +272,7 @@ def analyze_jaxpr(
 
     ``in_intervals`` gives (lo, hi) per flattened input; returns the
     output intervals plus every potentially-wrapping op and every
-    unmodeled primitive encountered (including inside pjit calls).
+    unmodeled primitive encountered (including inside nested jit calls).
     """
     jaxpr = getattr(closed_or_open, "jaxpr", closed_or_open)
     consts = list(getattr(closed_or_open, "consts", ()))

@@ -48,7 +48,7 @@ import ast
 from tools.analyze.core import FileCtx, Reporter
 
 JIT_NAMES = {"jit"}
-SHARD_NAMES = {"shard_map", "_shard_map"}
+SHARD_NAMES = {"shard_map"}
 PARTIAL_NAMES = {"partial", "_partial"}
 
 STATIC_ATTRS = {"shape", "ndim", "dtype", "size", "sharding", "aval",

@@ -48,7 +48,7 @@ from tools.analyze.core import FileCtx, Reporter
 # ---------------------------------------------------------------------
 # RT305 — registry exhaustiveness (pure AST, default lint)
 
-_SHARD_MAP_NAMES = {"shard_map", "_shard_map", "_exp_shard_map"}
+_SHARD_MAP_NAMES = {"shard_map"}
 
 
 def _is_jit_expr(node: ast.expr) -> bool:
