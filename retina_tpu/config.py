@@ -432,10 +432,6 @@ class Config:
     # (docs/observability.md). Off only for A/B overhead measurement —
     # the recorder is the instrument every perf PR reads.
     trace_enabled: bool = True
-    # Record 1 span in this many per thread (hot-path sampling gate).
-    # Spans are per-flush/per-window cadence, so 1 (record everything)
-    # is affordable; raise on very hot deployments.
-    trace_sample_every: int = 1
     # Per-thread span ring capacity (preallocated slots).
     trace_ring_spans: int = 4096
     # POST /debug/profile: jax.profiler session + all-thread stack
@@ -687,8 +683,7 @@ class Config:
                 f"soak_fd_generations_per_phase must be >= 1, "
                 f"got {self.soak_fd_generations_per_phase}"
             )
-        for f in ("trace_sample_every", "trace_ring_spans",
-                  "profile_max_artifacts"):
+        for f in ("trace_ring_spans", "profile_max_artifacts"):
             if getattr(self, f) < 1:
                 raise ValueError(
                     f"{f} must be >= 1, got {getattr(self, f)}"
@@ -866,8 +861,16 @@ def enable_compilation_cache(cache_dir: str, strict: bool = False) -> str:
             target, type(e).__name__, e,
         )
         return ""
-    if not env:
-        import jax
+    import jax
 
+    # The cache's key leaves out an operation's metadata by default, so
+    # a program compiled by another tree is a hit whose executable
+    # carries THAT tree's op_name metadata: the operator scopes of this
+    # one's device programs (models/pipeline.STEP_SCOPES) would be
+    # missing from every profile and from op_scopes.json (seen on the
+    # chip, PR 26: a step cached by the parent commit had no scope).
+    # With the metadata in the key a hit is this tree's own program.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    if not env:
         jax.config.update("jax_compilation_cache_dir", target)
     return target

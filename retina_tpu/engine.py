@@ -39,7 +39,7 @@ from retina_tpu.log import logger, rate_limited
 from retina_tpu.metrics import get_metrics
 from retina_tpu.models.identity import HostIdentityTable, IdentityMap
 from retina_tpu.models.pipeline import PipelineConfig
-from retina_tpu.obs.recorder import initialize_recorder
+from retina_tpu.obs.recorder import NULL_SPAN, initialize_recorder
 from retina_tpu.parallel.combine import combine_blocks
 from retina_tpu.parallel.feed import (
     FeedWorkerPool, TransferMux, TransferQueue,
@@ -48,7 +48,10 @@ from retina_tpu.parallel.flowdict import flow_dict_stats, make_flow_dict
 from retina_tpu.parallel.partition import (
     ShardedBatch, _next_bucket, partition_events,
 )
-from retina_tpu.parallel.telemetry import ShardedTelemetry, topk_from_snapshot
+from retina_tpu.parallel.telemetry import (
+    SCOPE_INGEST_UNPACK, ShardedTelemetry, note_op_scopes,
+    topk_from_snapshot,
+)
 from retina_tpu.plugins.api import QueueSink
 from retina_tpu.runtime import faults
 from retina_tpu.runtime.overload import OverloadController
@@ -57,7 +60,7 @@ from retina_tpu.runtime.supervisor import (
 )
 from retina_tpu.utils import metric_names as mnames
 from retina_tpu.utils.device_proxy import (
-    fence, fetch_on_device, run_on_device, submit_on_device,
+    fence, fetch_on_device, on_ready, run_on_device, submit_on_device,
 )
 
 
@@ -381,6 +384,11 @@ class SketchEngine:
         self._steps = 0
         self._events_in = 0
         self._closed_events_in = 0
+        # Events the sink accepted that were dropped (and counted under
+        # lost_events) before any step held them: the publish watermark
+        # must not read them as lag (_count_unheld, publish_lag_s).
+        self._events_unheld = 0
+        self._unheld_lock = threading.Lock()
         # Crash-only recovery (runtime/supervisor.py wiring): while
         # _degraded is set, async dispatches drop-and-count (stage
         # "degraded") instead of touching device state mid-rebuild;
@@ -400,12 +408,11 @@ class SketchEngine:
         )
         # Flight recorder (obs/recorder.py): rebuild the process
         # singleton from config so every span site — here, the feed
-        # workers, the fleet shipper/aggregator — shares the same rings
-        # and sampling policy. Sites outside the engine fetch it via
+        # workers, the device proxy, the fleet shipper/aggregator —
+        # shares the same rings. Sites outside the engine fetch it via
         # get_recorder() per call, so the rebuild is visible everywhere.
         self._recorder = initialize_recorder(
             capacity=cfg.trace_ring_spans,
-            sample_every=cfg.trace_sample_every,
             enabled=cfg.trace_enabled,
         )
         self._start_monotonic = time.monotonic()
@@ -584,7 +591,7 @@ class SketchEngine:
             return resumed
 
         hb.park()  # rebuild may recompile init_state on a cold cache
-        resumed = run_on_device(rebuild)
+        resumed = run_on_device(rebuild, kind=mnames.KIND_OTHER)
         hb.beat()
         self._last_resume_src = (
             f"resumed from {path}" if resumed else "cold start"
@@ -660,7 +667,7 @@ class SketchEngine:
             with self._ident_lock:
                 self.ident = dev
 
-        run_on_device(apply_ident)
+        run_on_device(apply_ident, kind=mnames.KIND_TABLE)
 
     def update_filter_ips(self, ips: set[int]) -> None:
         # Build the cuckoo table on the CALLING thread (pure numpy, O(n)
@@ -696,7 +703,7 @@ class SketchEngine:
             with self._ident_lock:
                 self.filter_map = fmap
 
-        run_on_device(apply_filter)
+        run_on_device(apply_filter, kind=mnames.KIND_TABLE)
 
     def set_apiserver_ips(self, ips: list[int]) -> None:
         self.apiserver_ip = ips[0] if ips else 0
@@ -934,7 +941,7 @@ class SketchEngine:
                     # proxy for 30-100s — parked, not stalled.
                     hb.park()
                     try:
-                        run_on_device(fn, *args)
+                        run_on_device(fn, *args, kind=mnames.KIND_OTHER)
                         n_warmed += 1
                     except Exception:
                         ok = False
@@ -1044,6 +1051,7 @@ class SketchEngine:
             if ex is not None:
                 return ex
         ex = lower().compile()
+        note_op_scopes(ex)
         if path is not None:
             aot_disk_save(path, ex, tag=tag)
         return ex
@@ -1090,22 +1098,27 @@ class SketchEngine:
             # (RT302; found by the device-program donation audit).
             @_partial(jax.jit, out_shardings=out_sh, donate_argnums=(0,))
             def ingest(small, meta):
-                if packed:
-                    small = unpack_records_device(small, meta[0], meta[1])
-                nv = meta[5:].astype(jnp.int32)
-                wins, nvs = [], []
-                for w in range(n_win):
-                    lo = w * cap
-                    hi = min(lo + cap, bucket)
-                    c = small[:, lo:hi]
-                    if hi - lo < cap:
-                        c = jnp.pad(
-                            c, ((0, 0), (0, cap - (hi - lo)), (0, 0))
+                with jax.named_scope(SCOPE_INGEST_UNPACK):
+                    if packed:
+                        small = unpack_records_device(
+                            small, meta[0], meta[1]
                         )
-                    wins.append(c)
-                    nvs.append(
-                        jnp.clip(nv - lo, 0, hi - lo).astype(jnp.uint32)
-                    )
+                    nv = meta[5:].astype(jnp.int32)
+                    wins, nvs = [], []
+                    for w in range(n_win):
+                        lo = w * cap
+                        hi = min(lo + cap, bucket)
+                        c = small[:, lo:hi]
+                        if hi - lo < cap:
+                            c = jnp.pad(
+                                c, ((0, 0), (0, cap - (hi - lo)), (0, 0))
+                            )
+                        wins.append(c)
+                        nvs.append(
+                            jnp.clip(nv - lo, 0, hi - lo).astype(
+                                jnp.uint32
+                            )
+                        )
                 return tuple(wins), tuple(nvs), meta[2], meta[3]
 
             # AOT-compile from shape specs: warming a bucket key moves
@@ -1240,15 +1253,16 @@ class SketchEngine:
                 jax.jit, out_shardings=out_sh, donate_argnums=(0, 2)
             )
             def ingest(wire, meta, table):
-                ids = wire[..., 0]
-                lanes = wire[..., 1:]
-                d_idx = jnp.arange(lanes.shape[0])[:, None]
-                table = table.at[d_idx, ids].set(lanes)
-                full = unpack_records_device(lanes, meta[0], meta[1])
-                nv = meta[5:].astype(jnp.int32)
-                wins, nvs = SketchEngine._slice_windows(
-                    full, nv, bucket, cap
-                )
+                with jax.named_scope(SCOPE_INGEST_UNPACK):
+                    ids = wire[..., 0]
+                    lanes = wire[..., 1:]
+                    d_idx = jnp.arange(lanes.shape[0])[:, None]
+                    table = table.at[d_idx, ids].set(lanes)
+                    full = unpack_records_device(lanes, meta[0], meta[1])
+                    nv = meta[5:].astype(jnp.int32)
+                    wins, nvs = SketchEngine._slice_windows(
+                        full, nv, bucket, cap
+                    )
                 return wins, nvs, meta[2], meta[3], table
 
             fn = self._compile_cached("ingest_new", key, lambda: ingest.lower(
@@ -1327,26 +1341,27 @@ class SketchEngine:
             # every subsequent known-flow flush.
             @_partial(jax.jit, out_shardings=out_sh, donate_argnums=(0,))
             def ingest(wire, meta, table):
-                if dense:
-                    ids, pk, by = dense_known_unpack_device(
-                        wire, bucket, self._fd_id_bits
+                with jax.named_scope(SCOPE_INGEST_UNPACK):
+                    if dense:
+                        ids, pk, by = dense_known_unpack_device(
+                            wire, bucket, self._fd_id_bits
+                        )
+                    else:
+                        ids = wire[..., 0] & id_mask
+                        pk = wire[..., 0] >> id_bits
+                        by = wire[..., 1]
+                    d_idx = jnp.arange(ids.shape[0])[:, None]
+                    desc = table[d_idx, ids]  # (D, bucket, 12)
+                    desc = desc.at[..., 6].set(pk)  # PACKETS
+                    desc = desc.at[..., 5].set(by)  # BYTES
+                    desc = desc.at[..., 0].set(
+                        jnp.broadcast_to(meta[4], ids.shape)  # TS_REL
                     )
-                else:
-                    ids = wire[..., 0] & id_mask
-                    pk = wire[..., 0] >> id_bits
-                    by = wire[..., 1]
-                d_idx = jnp.arange(ids.shape[0])[:, None]
-                desc = table[d_idx, ids]  # (D, bucket, 12)
-                desc = desc.at[..., 6].set(pk)  # PACKETS
-                desc = desc.at[..., 5].set(by)  # BYTES
-                desc = desc.at[..., 0].set(
-                    jnp.broadcast_to(meta[4], ids.shape)  # TS_REL
-                )
-                full = unpack_records_device(desc, meta[0], meta[1])
-                nv = meta[5:].astype(jnp.int32)
-                wins, nvs = SketchEngine._slice_windows(
-                    full, nv, bucket, cap
-                )
+                    full = unpack_records_device(desc, meta[0], meta[1])
+                    nv = meta[5:].astype(jnp.int32)
+                    wins, nvs = SketchEngine._slice_windows(
+                        full, nv, bucket, cap
+                    )
                 return wins, nvs, meta[2], meta[3]
 
             wire_shape = (
@@ -1425,6 +1440,8 @@ class SketchEngine:
         )
 
         t_d0 = time.monotonic()
+        tid = fleet_epoch(self.cfg.window_seconds)
+        sp_build = self._recorder.span(mnames.STAGE_WIRE_BUILD, tid)
         m = get_metrics()
         lost = sb.lost
         D = self.n_devices
@@ -1579,6 +1596,7 @@ class SketchEngine:
                         m.lost_events.labels(
                             stage="dispatch", plugin="engine"
                         ).inc(n_events)
+                        self._count_unheld(n_raw)
                     self.log.warning(
                         "dropping in-flight flow-dict batch from "
                         "pre-resync epoch"
@@ -1614,6 +1632,9 @@ class SketchEngine:
                 )
                 m.flow_dict_entries.set(fd_entries)
                 m.flow_dict_generation.set(fd_generation)
+            sp_x = self._step_span(
+                mnames.STAGE_TRANSFER_ENQUEUE, tid, record_metrics
+            )
             t_x0 = time.perf_counter()
             # ONE batched device_put for everything this flush moves:
             # separate puts each pay a client round-trip.
@@ -1651,6 +1672,10 @@ class SketchEngine:
                     Bk
                 )(known_dev, mk_dev, table)
                 sides.append((wins, nvs, now_dev, lost_dev))
+            sp_x.end()
+            sp_s = self._step_span(
+                mnames.STAGE_DEVICE_STEP, tid, record_metrics
+            )
             t0 = time.perf_counter()
             n_steps = 0
             with self._state_lock:
@@ -1658,7 +1683,7 @@ class SketchEngine:
                 first = True
                 for wins, nvs, now_dev, lost_dev in sides:
                     for w in range(len(wins)):
-                        st, _ = self.sharded.step(
+                        st, summary = self.sharded.step(
                             st, wins[w], nvs[w], now_dev, ident,
                             self._api_dev, filter_map=fmap,
                             # meta_known carries lost=0, so folding on
@@ -1673,16 +1698,9 @@ class SketchEngine:
             if record_metrics:
                 t_end = time.perf_counter()
                 m.transfer_seconds.observe(t0 - t_x0)
-                m.device_step_seconds.observe(t_end - t0)
-                tid = fleet_epoch(self.cfg.window_seconds)
-                self._recorder.record(
-                    mnames.STAGE_TRANSFER, t_x0, tid, t1=t0
-                )
-                self._recorder.record(
-                    mnames.STAGE_DEVICE_STEP, t0, tid, t1=t_end
-                )
-                # Overload signal: EWMA of transfer+step wall time
-                # (proxy thread only — no lock needed).
+                self._watch_steps(sp_s, summary["events"], t0, n_steps)
+                # Overload signal: EWMA of the enqueue wall time of
+                # transfer+step (proxy thread only — no lock needed).
                 self._dispatch_lat_ewma = (
                     0.8 * self._dispatch_lat_ewma + 0.2 * (t_end - t_x0)
                 )
@@ -1695,10 +1713,16 @@ class SketchEngine:
                 self._events_in += n_raw
 
         if not (have_new or have_known):
+            sp_build.end()
+            if record_metrics:
+                self._count_unheld(n_raw)
             return  # nothing valid (pure padding batch)
 
         if sync:
-            run_on_device(xfer_and_step)
+            sp_build.end()
+            run_on_device(
+                xfer_and_step, kind=mnames.KIND_STEP, parent=sp_build.id
+            )
             return
 
         def safe_xfer_and_step():
@@ -1710,6 +1734,7 @@ class SketchEngine:
                 get_metrics().lost_events.labels(
                     stage="device", plugin="engine"
                 ).inc(n_events)
+                self._count_unheld(n_raw)
                 # The donated table may be gone and the host dict no
                 # longer matches it — resync by rebuilding both (one
                 # re-upload burst, no wrong data); queued batches from
@@ -1723,14 +1748,13 @@ class SketchEngine:
                 self._inflight.release()
 
         t_d1 = time.monotonic()
-        self._recorder.record(
-            mnames.STAGE_WIRE_BUILD, t_d0,
-            fleet_epoch(self.cfg.window_seconds), t1=t_d1,
-        )
+        sp_build.end()
         self._inflight.acquire()
         with self._busy_lock:
             self._inflight_busy += 1
-        submit_on_device(safe_xfer_and_step)
+        submit_on_device(
+            safe_xfer_and_step, kind=mnames.KIND_STEP, parent=sp_build.id
+        )
         if self._feed_trace:
             self.log.info(
                 "dispatch trace: build %.0fms inflight-wait %.0fms "
@@ -1766,6 +1790,7 @@ class SketchEngine:
                 get_metrics().lost_events.labels(
                     stage="degraded", plugin="engine"
                 ).inc(int(sb.events) + int(sb.lost))
+                self._count_unheld(n_raw)
             return
         # The dictionary pays off per ROW saved; a tiny flush (idle
         # agent, interval flush) is cheaper as one plain transfer than
@@ -1791,6 +1816,7 @@ class SketchEngine:
                     get_metrics().lost_events.labels(
                         stage="dispatch", plugin="engine"
                     ).inc(int(sb.events) + int(sb.lost))
+                    self._count_unheld(n_raw)
                     if self._count_error("flowdict_dispatch"):
                         self.log.exception("flow-dict dispatch failed")
                     return
@@ -1799,7 +1825,10 @@ class SketchEngine:
         m = get_metrics()
         if sb.lost and record_metrics:
             m.lost_events.labels(stage="partition", plugin="engine").inc(sb.lost)
-        t_w0 = time.monotonic()
+        tid = fleet_epoch(self.cfg.window_seconds)
+        sp_build = self._step_span(
+            mnames.STAGE_WIRE_BUILD, tid, record_metrics
+        )
         if self.cfg.transfer_packed:
             from retina_tpu.parallel.wire import pack_records
 
@@ -1824,12 +1853,7 @@ class SketchEngine:
         n_valid_total = int(sb.n_valid.sum())
         n_events = int(sb.events)
         samp_k = int(sb.sample_k)
-        if record_metrics:
-            self._recorder.record(
-                mnames.STAGE_WIRE_BUILD, t_w0,
-                fleet_epoch(self.cfg.window_seconds),
-                t1=time.monotonic(),
-            )
+        sp_build.end()
 
         def xfer_and_step():
             faults.inject("transfer")
@@ -1839,6 +1863,9 @@ class SketchEngine:
             with self._ident_lock:
                 ident = self.ident
                 fmap = self.filter_map
+            sp_x = self._step_span(
+                mnames.STAGE_TRANSFER_ENQUEUE, tid, record_metrics
+            )
             t_x0 = time.perf_counter()
             # One batched put (wire + meta): separate puts each pay a
             # client round-trip.
@@ -1848,11 +1875,15 @@ class SketchEngine:
             wins, nvs, now_dev, lost_dev = self._ingest_fn(
                 bucket, packed
             )(wire_dev, meta_dev)
+            sp_x.end()
+            sp_s = self._step_span(
+                mnames.STAGE_DEVICE_STEP, tid, record_metrics
+            )
             t0 = time.perf_counter()
             with self._state_lock:
                 st = self.state
                 for w in range(len(wins)):
-                    st, _ = self.sharded.step(
+                    st, summary = self.sharded.step(
                         st, wins[w], nvs[w], now_dev, ident,
                         self._api_dev, filter_map=fmap,
                         # Host-partition losses are folded into the
@@ -1868,16 +1899,9 @@ class SketchEngine:
                 # with a synthetic zero batch.
                 t_end = time.perf_counter()
                 m.transfer_seconds.observe(t0 - t_x0)
-                m.device_step_seconds.observe(t_end - t0)
-                tid = fleet_epoch(self.cfg.window_seconds)
-                self._recorder.record(
-                    mnames.STAGE_TRANSFER, t_x0, tid, t1=t0
-                )
-                self._recorder.record(
-                    mnames.STAGE_DEVICE_STEP, t0, tid, t1=t_end
-                )
-                # Overload signal: EWMA of transfer+step wall time
-                # (proxy thread only — no lock needed).
+                self._watch_steps(sp_s, summary["events"], t0, len(wins))
+                # Overload signal: EWMA of the enqueue wall time of
+                # transfer+step (proxy thread only — no lock needed).
                 self._dispatch_lat_ewma = (
                     0.8 * self._dispatch_lat_ewma + 0.2 * (t_end - t_x0)
                 )
@@ -1900,7 +1924,9 @@ class SketchEngine:
                 self._events_in += n_raw
 
         if sync:
-            run_on_device(xfer_and_step)
+            run_on_device(
+                xfer_and_step, kind=mnames.KIND_STEP, parent=sp_build.id
+            )
             return
 
         def safe_xfer_and_step():
@@ -1912,6 +1938,7 @@ class SketchEngine:
                 get_metrics().lost_events.labels(
                     stage="device", plugin="engine"
                 ).inc(n_events)
+                self._count_unheld(n_raw)
                 if self._fatal_device_error(e):
                     self._request_recovery(repr(e))
             finally:
@@ -1922,7 +1949,35 @@ class SketchEngine:
         self._inflight.acquire()
         with self._busy_lock:
             self._inflight_busy += 1
-        submit_on_device(safe_xfer_and_step)
+        submit_on_device(
+            safe_xfer_and_step, kind=mnames.KIND_STEP, parent=sp_build.id
+        )
+
+    def _step_span(self, stage: str, tid: int, record_metrics: bool):
+        """A span of the dispatch path, or the null span for a warm-up
+        dispatch (``compile()``): a one-shot 30-100 s cold compile
+        would sit in the stage histogram's p99 forever."""
+        if not record_metrics:
+            return NULL_SPAN
+        return self._recorder.span(stage, tid)
+
+    def _watch_steps(self, span, out, t0: float, n_steps: int) -> None:
+        """(proxy thread) Hand a dispatched group of steps to the
+        completion thread: ``out`` is the last step's ``events`` output
+        (not donated, unlike the state), ready when the whole group has
+        run. The ``device_step`` span, open since the first dispatch at
+        ``t0``, is closed there, and ``tpu_step_seconds`` observes
+        completed seconds per step — what the device took (plus, on a
+        backlogged device, the wait behind earlier groups), not what
+        the enqueue took. The proxy thread does not wait."""
+        hist = get_metrics().device_step_seconds
+
+        def done(err: BaseException | None) -> None:
+            args = {"error": type(err).__name__} if err else {}
+            dt = span.end(n_steps=n_steps, **args)
+            hist.observe((dt or time.perf_counter() - t0) / n_steps)
+
+        on_ready(out, done)
 
     def _win_stack(self, win):
         """(proxy thread) Stack the 3 per-dimension window outputs into
@@ -2071,20 +2126,18 @@ class SketchEngine:
                     # of utils/device_proxy.py), but the queue-wait
                     # happens here, off-proxy.
                     tid = fleet_epoch(self.cfg.window_seconds)
-                    t_h0 = time.perf_counter()
-                    host = fetch_on_device(stacked)
-                    self._recorder.record(
-                        mnames.STAGE_HARVEST, t_h0, tid
-                    )
-                    t_p0 = time.perf_counter()
-                    self._publish_window({
-                        "entropy_bits": host[0],
-                        "anomaly": host[1],
-                        "zscore": host[2],
-                    }, meta)
-                    self._recorder.record(
-                        mnames.STAGE_PUBLISH, t_p0, tid
-                    )
+                    timing: dict = {}
+                    with self._recorder.span(
+                        mnames.STAGE_HARVEST, tid
+                    ) as sp:
+                        host = fetch_on_device(stacked, timing=timing)
+                        sp.set(**timing)
+                    with self._recorder.span(mnames.STAGE_PUBLISH, tid):
+                        self._publish_window({
+                            "entropy_bits": host[0],
+                            "anomaly": host[1],
+                            "zscore": host[2],
+                        }, meta)
                     inv_dec = meta.pop("inv_decode", None)
                     if inv_dec is not None:
                         self._harvest_invertible(inv_dec)
@@ -2162,7 +2215,7 @@ class SketchEngine:
         """End the entropy/anomaly window (self-proxying: the body —
         including the harvest's device_get — always executes on the
         device-proxy thread, whatever thread calls this)."""
-        run_on_device(self._close_window_impl)
+        run_on_device(self._close_window_impl, kind=mnames.KIND_CLOSE)
 
     def _close_window_impl(self) -> None:  # hot-path: close
         """(proxy thread) End the entropy/anomaly window. Runs as a
@@ -2225,7 +2278,10 @@ class SketchEngine:
         meta["events"] = ingested - self._closed_events_in
 
         def close():
-            t_c0 = time.perf_counter()
+            sp_close = self._recorder.span(
+                mnames.STAGE_WINDOW_CLOSE,
+                fleet_epoch(self.cfg.window_seconds),
+            )
             self._device_consts()
             with self._state_lock:
                 if (self._fleet_shipper is not None
@@ -2272,14 +2328,10 @@ class SketchEngine:
                 self.state, win = self.sharded.end_window(
                     self.state, self._zthresh
                 )
-            self._recorder.record(
-                mnames.STAGE_WINDOW_CLOSE, t_c0,
-                fleet_epoch(self.cfg.window_seconds),
-                t1=time.perf_counter(),
-            )
+            sp_close.end()
             return self._win_stack(win), inv
 
-        stacked, inv_dec = run_on_device(close)
+        stacked, inv_dec = run_on_device(close, kind=mnames.KIND_CLOSE)
         # Advance only after a SUCCESSFUL dispatch: if end_window
         # raised, the next tick must retry this window, not skip it
         # forever.
@@ -2314,7 +2366,7 @@ class SketchEngine:
         if not self._close_inflight.acquire(blocking=False):
             get_metrics().windows_deferred.inc()
             return
-        submit_on_device(safe_close)
+        submit_on_device(safe_close, kind=mnames.KIND_CLOSE)
 
     def _resolve_feed_workers(self) -> int:
         """Feed-worker count: config value, or auto-size to the machine
@@ -2415,20 +2467,18 @@ class SketchEngine:
         coal_per_dev = self.cfg.batch_capacity * max(
             1, self.cfg.feed_coalesce_windows
         )
-        t_cb0 = self._recorder.begin()
-        if self.cfg.host_combine:
-            all_rec = combine_blocks(blocks)
-            get_metrics().combine_ratio.set(
-                n_raw / max(len(all_rec), 1)
-            )
-        elif len(blocks) == 1:
-            all_rec = blocks[0]
-        else:
-            all_rec = np.concatenate(blocks, axis=0)
-        self._recorder.record(
-            mnames.STAGE_COMBINE, t_cb0,
-            fleet_epoch(self.cfg.window_seconds),
-        )
+        with self._recorder.span(
+            mnames.STAGE_COMBINE, fleet_epoch(self.cfg.window_seconds)
+        ):
+            if self.cfg.host_combine:
+                all_rec = combine_blocks(blocks)
+                get_metrics().combine_ratio.set(
+                    n_raw / max(len(all_rec), 1)
+                )
+            elif len(blocks) == 1:
+                all_rec = blocks[0]
+            else:
+                all_rec = np.concatenate(blocks, axis=0)
         if self.record_hook is not None:
             try:
                 self.record_hook(all_rec, now_s)
@@ -2501,6 +2551,10 @@ class SketchEngine:
                 except Exception:
                     if self._count_error("dispatch"):
                         self.log.exception("%s dispatch failed", kind)
+                    if kind == "step":
+                        # Raised before the batch reached the proxy
+                        # (the sites past that point count their own).
+                        self._count_unheld(n_raw)
         finally:
             self._deregister_hb("engine-dispatch")
 
@@ -2564,6 +2618,7 @@ class SketchEngine:
                 get_metrics().lost_events.labels(
                     stage="dispatch", plugin="engine"
                 ).inc(int(item[1].events) + int(item[1].lost))
+                self._count_unheld(item[3])
 
         def submit(item):
             if q is not None:
@@ -2686,11 +2741,13 @@ class SketchEngine:
                 self._overload.tick()
                 blocks = self.sink.drain(max_blocks=64)
                 shed_dns = self._overload.shed_active("dns")
-                # Span covers the emit handoff: generator blocks leave
-                # the sink and are dealt into the feed (observers +
-                # staging) — begin() only when there IS a drain, so an
-                # idle spin never burns sampling ticks.
-                t_g0 = self._recorder.begin() if blocks else 0.0
+                # Span covers the deal: blocks leave the sink and are
+                # dealt into the feed (observers + staging) — only when
+                # there IS a drain, so an idle spin writes no span.
+                sp_deal = self._recorder.span(
+                    mnames.STAGE_DISTRIBUTOR_DEAL,
+                    fleet_epoch(self.cfg.window_seconds),
+                ) if blocks else NULL_SPAN
                 for rec, plugin in blocks:
                     for obs, oname in self._observers:
                         if shed_dns and oname == "dns":
@@ -2714,6 +2771,7 @@ class SketchEngine:
                         # loss site).
                         if not pool.stage(rec):
                             pool.count_drop(len(rec))
+                            self._count_unheld(len(rec))
                             m.lost_events.labels(
                                 stage="handoff", plugin="engine"
                             ).inc(int(rec[:, F.PACKETS].sum()))
@@ -2726,11 +2784,7 @@ class SketchEngine:
                     # quantum plus a block's worth of overshoot.
                     if n_pending >= quantum:
                         flush()
-                if blocks:
-                    self._recorder.record(
-                        mnames.STAGE_GENERATOR_EMIT, t_g0,
-                        fleet_epoch(self.cfg.window_seconds),
-                    )
+                sp_deal.end()
                 now = time.monotonic()
                 if n_pending and now - last_flush >= self.cfg.flush_interval_s:
                     # Interval flushes serve LATENCY and only make sense
@@ -2869,21 +2923,71 @@ class SketchEngine:
                 # traffic never parks the step pipeline — while every
                 # actual JAX call still rides the proxy (one-thread rule
                 # of utils/device_proxy.py).
+                # _steps/_events_in advance on this thread in FIFO
+                # order: read here they are exactly what the snapshot
+                # holds (the publish watermark rests on that).
                 with self._state_lock:
                     return self.sharded.snapshot_flat_dispatch(
                         self.state, int(time.time())
-                    )
+                    ), self._steps, self._events_in, self._events_unheld
 
-            flat_dev = run_on_device(snap_dispatch)
-            flat_host = fetch_on_device(flat_dev)
-            host = self.sharded.snapshot_flat_finish(flat_host)
+            # shared=True: a snapshot may be taken on a thread that
+            # lives for one request (a query handler).
+            rec = self._recorder
+            tid = fleet_epoch(self.cfg.window_seconds)
+            timing: dict = {}
+            with rec.span(mnames.STAGE_SNAPSHOT, tid, shared=True):
+                with rec.span(
+                    mnames.STAGE_SNAPSHOT_DISPATCH, tid, shared=True
+                ):
+                    flat_dev, steps, events_in, unheld = run_on_device(
+                        snap_dispatch, kind=mnames.KIND_SNAPSHOT
+                    )
+                with rec.span(
+                    mnames.STAGE_SNAPSHOT_FETCH, tid, shared=True
+                ) as sp:
+                    flat_host = fetch_on_device(flat_dev, timing=timing)
+                    sp.set(**timing)
+                with rec.span(
+                    mnames.STAGE_SNAPSHOT_FINISH, tid, shared=True
+                ):
+                    host = self.sharded.snapshot_flat_finish(flat_host)
             get_metrics().readback_bytes.inc(int(flat_host.nbytes))
-            host["steps"] = self._steps
-            host["events_in"] = self._events_in
+            host["steps"] = steps
+            host["events_in"] = events_in
+            host["events_unheld"] = unheld
             with self._snap_lock:
                 self._snap_cache = host
                 self._snap_time = time.monotonic()
             return host
+
+    def _count_unheld(self, n_raw: int) -> None:
+        """``n_raw`` events the sink accepted were dropped before a
+        step held them (every site also counts them, packet-weighted,
+        under ``lost_events``). Called from the feed, dispatch and
+        proxy threads, hence the lock; drops are rare."""
+        if n_raw:
+            with self._unheld_lock:
+                self._events_unheld += n_raw
+
+    def publish_lag_s(self, snap: dict[str, Any]) -> tuple[float, int]:
+        """(seconds, events the snapshot holds): now minus the accept
+        time of the oldest event the sink accepted that ``snap``
+        neither holds nor ever will; 0 when none is. The sink's count
+        of accepts and ``_events_in`` are both cumulative, so events
+        dropped between the sink and the device would otherwise read
+        as lag for the life of the process: ``snap`` carries their
+        count as of its dispatch and they are passed over. They are
+        passed over as a count, not by position, so between a drop and
+        the landing of the blocks accepted before it the lag reads low
+        by at most the time in which the dropped events were accepted;
+        ``lost_events`` says that there was a drop."""
+        included = int(snap.get("events_in", 0))
+        t = self.sink.oldest_unheld(
+            included + int(snap.get("events_unheld", 0))
+        )
+        return (max(0.0, time.monotonic() - t) if t is not None else 0.0,
+                included)
 
     def top_flows(self, k: int = 20) -> tuple[np.ndarray, np.ndarray]:
         return topk_from_snapshot(self.snapshot(), "flow_hh", k)
@@ -2929,7 +3033,7 @@ class SketchEngine:
                 state = self.state
             save_state(path, state, self.pcfg)
 
-        run_on_device(save)
+        run_on_device(save, kind=mnames.KIND_OTHER)
 
     def load_snapshot_state(self, path: str) -> bool:
         """Restore sketch state from ``path``. Crash-only: a missing or
@@ -2943,4 +3047,4 @@ class SketchEngine:
                 self.state = state
             return resumed
 
-        return run_on_device(load)
+        return run_on_device(load, kind=mnames.KIND_OTHER)

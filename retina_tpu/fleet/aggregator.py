@@ -372,11 +372,13 @@ class FleetAggregator:
     ) -> None:
         t0 = time.monotonic()
         m = get_metrics()
-        rec = get_recorder()
-        span_t0 = rec.begin()
         snaps = sorted(bucket.snaps.values(), key=lambda s: s.node)
         if not snaps:
             return
+        # The shipped trace id is the window epoch by construction
+        # (below); the span opens under the epoch and takes the shipped
+        # id, should it ever differ, when it closes.
+        span = get_recorder().span(mn.STAGE_AGG_MERGE, int(epoch))
         # Mid-rotation an epoch can hold frames from more than one seed
         # generation. Cross-generation sketches don't merge, so take the
         # dominant generation (ties break toward the NEWER one — the
@@ -453,7 +455,8 @@ class FleetAggregator:
         rollup["seed_gen"] = gen
         rollup["merge_seconds"] = time.monotonic() - t0
         self._publish(rollup)
-        rec.record(mn.STAGE_AGG_MERGE, span_t0, trace_id)
+        span.trace_id = trace_id
+        span.end()
         m.fleet_windows_merged.inc()
         if straggled:
             m.fleet_windows_stragglers.inc()

@@ -208,14 +208,13 @@ class SnapshotShipper:
         seed_gen: int = 0,
     ) -> None:
         rec = get_recorder()
-        t0 = rec.begin()
         host: dict[str, np.ndarray] = {}
-        for name, arr in arrays.items():
-            if isinstance(arr, np.ndarray):
-                host[name] = arr
-            else:
-                host[name] = fetch_on_device(arr)
-        rec.record(mn.STAGE_SHIP_READBACK, t0, int(epoch))
+        with rec.span(mn.STAGE_SHIP_READBACK, int(epoch)):
+            for name, arr in arrays.items():
+                if isinstance(arr, np.ndarray):
+                    host[name] = arr
+                else:
+                    host[name] = fetch_on_device(arr)
         with self._lock:
             seq = self._seq
             self._seq += 1
@@ -230,12 +229,10 @@ class SnapshotShipper:
             seed_gen=int(seed_gen),
             tier=int(self.tier),
         )
-        t0 = rec.begin()
-        frame = encode_snapshot(snap)
-        rec.record(mn.STAGE_SHIP_ENCODE, t0, int(epoch))
-        t0 = rec.begin()
-        self._deliver(frame)
-        rec.record(mn.STAGE_SHIP_SEND, t0, int(epoch))
+        with rec.span(mn.STAGE_SHIP_ENCODE, int(epoch)):
+            frame = encode_snapshot(snap)
+        with rec.span(mn.STAGE_SHIP_SEND, int(epoch)):
+            self._deliver(frame)
 
     # -- delivery: circuit + spool + backoff ---------------------------
     def _deliver(self, frame: bytes) -> None:

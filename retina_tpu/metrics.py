@@ -88,6 +88,8 @@ class Metrics:
             mn.DEVICE_STEP_SECONDS,
             [],
             buckets=[1e-4, 3e-4, 1e-3, 3e-3, 1e-2, 3e-2, 0.1, 0.3, 1.0],
+            help_="completed seconds per fused step: first dispatch of a "
+            "group to its last output ready on the device, over its steps",
         )
         self.device_batch_fill = g(mn.DEVICE_BATCH_FILL, [])
         self.windows_closed = c(mn.WINDOWS_CLOSED, [])
@@ -107,6 +109,9 @@ class Metrics:
             mn.TRANSFER_SECONDS,
             [],
             buckets=[1e-3, 3e-3, 1e-2, 3e-2, 0.1, 0.3, 1.0, 3.0],
+            help_="seconds to stage and enqueue one dispatch's device_put "
+            "and ingest programs on the proxy thread (the enqueue, not "
+            "the copy)",
         )
         self.transfer_bytes = c(mn.TRANSFER_BYTES, [])
         # Supervised-runtime robustness series (runtime/supervisor.py;
@@ -263,7 +268,25 @@ class Metrics:
             mn.TPU_STAGE_SECONDS,
             [mn.L_STAGE],
             buckets=[1e-5, 3e-5, 1e-4, 3e-4, 1e-3, 3e-3, 1e-2, 3e-2,
-                     0.1, 0.3, 1.0, 3.0],
+                     0.1, 0.3, 1.0, 3.0, 10.0, 30.0],
+        )
+        # Device proxy (utils/device_proxy.py): wait in its FIFO and
+        # run time per call, by kind (the FIXED registry
+        # mn.PROXY_KINDS); queue depth; calls. publish_lag is observed
+        # once per publish cycle by the metrics module.
+        proxy_buckets = [1e-5, 1e-4, 1e-3, 3e-3, 1e-2, 3e-2, 0.1, 0.3,
+                         1.0, 3.0, 10.0, 30.0]
+        self.proxy_wait_seconds = ex.new_histogram(
+            mn.TPU_PROXY_WAIT_SECONDS, [mn.L_KIND], buckets=proxy_buckets
+        )
+        self.proxy_run_seconds = ex.new_histogram(
+            mn.TPU_PROXY_RUN_SECONDS, [mn.L_KIND], buckets=proxy_buckets
+        )
+        self.proxy_queue_depth = g(mn.TPU_PROXY_QUEUE_DEPTH, [])
+        self.publish_lag_seconds = ex.new_histogram(
+            mn.TPU_PUBLISH_LAG_SECONDS,
+            [],
+            buckets=[0.1, 0.3, 1.0, 2.0, 3.0, 5.0, 10.0, 30.0],
         )
         # Build identity + process uptime (set once / ticked by the
         # engine; docs/observability.md).

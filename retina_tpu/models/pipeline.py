@@ -272,6 +272,20 @@ class PipelineState:
         return cls(**dict(zip(aux, children)))
 
 
+# Operator scopes of the fused step (``jax.named_scope``): metadata on
+# the lowered operations, so a device profile can say which operator
+# owns a fusion. The optimised program is the same with or without
+# them (tests/test_obs.py compares it opcode for opcode). ``decode``
+# and ``totals`` are the column extraction and the scalar sums round
+# the operators proper.
+STEP_SCOPES = (
+    "decode", "rescale", "identity_join", "filter", "conntrack",
+    "pod_counters", "cms_flow_hh", "cms_svc_hh", "cms_dns_hh",
+    "invertible", "hll", "entropy", "latency_match", "totals",
+)
+SCOPE_END_WINDOW = "end_window"
+
+
 class TelemetryPipeline:
     """Builds zero state and the jitted step for a PipelineConfig."""
 
@@ -337,15 +351,16 @@ class TelemetryPipeline:
         """Process one batch. Pure; jit via TelemetryPipeline.jitted_step."""
         c = self.config
         b = records.shape[0]
-        col = lambda i: records[:, i]
-        mask = jnp.arange(b, dtype=jnp.uint32) < n_valid
+        with jax.named_scope("decode"):
+            col = lambda i: records[:, i]
+            mask = jnp.arange(b, dtype=jnp.uint32) < n_valid
 
-        src_ip, dst_ip = col(F.SRC_IP), col(F.DST_IP)
-        ports, meta = col(F.PORTS), col(F.META)
-        proto = meta >> 24
-        tcp_flags = (meta >> 16) & np.uint32(0xFF)
-        direction = (meta >> 4) & np.uint32(0xF)
-        bytes_, packets = col(F.BYTES), col(F.PACKETS)
+            src_ip, dst_ip = col(F.SRC_IP), col(F.DST_IP)
+            ports, meta = col(F.PORTS), col(F.META)
+            proto = meta >> 24
+            tcp_flags = (meta >> 16) & np.uint32(0xFF)
+            direction = (meta >> 4) & np.uint32(0xF)
+            bytes_, packets = col(F.BYTES), col(F.PACKETS)
 
         # ---- overload-sampling rescale (Horvitz-Thompson) ----
         # When the host fed a 1-in-k sampled batch (ShardedBatch.
@@ -361,69 +376,75 @@ class TelemetryPipeline:
         # Priority-class rows (the overload lattice's (tenant, service)
         # tier) are exempt on the host and therefore never rescaled
         # here; they also route to the dedicated invertible region.
-        is_priority = priority_class(
-            src_ip, dst_ip, c.priority_ip_mask, c.priority_ip_match
-        )
-        if c.sample_exempt_packets > 0:
-            exempt = sample_exempt(
-                packets, col(F.TSVAL), col(F.TSECR), is_priority,
-                c.sample_exempt_packets,
+        with jax.named_scope("rescale"):
+            is_priority = priority_class(
+                src_ip, dst_ip, c.priority_ip_mask, c.priority_ip_match
             )
-            packets, bytes_ = ht_rescale(
-                packets, bytes_, exempt, sample_k
-            )
-        verdict = col(F.VERDICT)
-        reason = jnp.minimum(col(F.DROP_REASON), np.uint32(c.n_drop_reasons - 1))
-        ev_type = col(F.EVENT_TYPE)
+            if c.sample_exempt_packets > 0:
+                exempt = sample_exempt(
+                    packets, col(F.TSVAL), col(F.TSECR), is_priority,
+                    c.sample_exempt_packets,
+                )
+                packets, bytes_ = ht_rescale(
+                    packets, bytes_, exempt, sample_k
+                )
+        with jax.named_scope("decode"):
+            verdict = col(F.VERDICT)
+            reason = jnp.minimum(col(F.DROP_REASON), np.uint32(c.n_drop_reasons - 1))
+            ev_type = col(F.EVENT_TYPE)
 
-        is_fwd = mask & (verdict == VERDICT_FORWARDED)
-        is_drop = mask & (verdict == VERDICT_DROPPED)
-        is_dns_req = mask & (ev_type == EV_DNS_REQ)
-        is_dns_resp = mask & (ev_type == EV_DNS_RESP)
-        is_retrans = mask & (ev_type == EV_TCP_RETRANS)
-        is_ingress = direction == DIR_INGRESS
+            is_fwd = mask & (verdict == VERDICT_FORWARDED)
+            is_drop = mask & (verdict == VERDICT_DROPPED)
+            is_dns_req = mask & (ev_type == EV_DNS_REQ)
+            is_dns_resp = mask & (ev_type == EV_DNS_RESP)
+            is_retrans = mask & (ev_type == EV_TCP_RETRANS)
+            is_ingress = direction == DIR_INGRESS
 
         # ---- enrichment join: IP -> pod index (one gather each) ----
-        src_pod = jnp.where(mask, ident.lookup(src_ip), 0)
-        dst_pod = jnp.where(mask, ident.lookup(dst_ip), 0)
+        with jax.named_scope("identity_join"):
+            src_pod = jnp.where(mask, ident.lookup(src_ip), 0)
+            dst_pod = jnp.where(mask, ident.lookup(dst_ip), 0)
 
         # ---- IPs-of-interest filter (retina_filter.c lookup() analog) ----
-        if not c.bypass_filter:
-            if c.identity_implies_interest:
-                interest = (src_pod > 0) | (dst_pod > 0)
-            else:
-                interest = jnp.zeros((b,), bool)
-            if filter_map is not None:
-                interest |= (filter_map.lookup(src_ip) > 0) | (
-                    filter_map.lookup(dst_ip) > 0
-                )
-            mask &= interest
-            is_fwd &= interest
-            is_drop &= interest
-            is_dns_req &= interest
-            is_dns_resp &= interest
-            is_retrans &= interest
+        with jax.named_scope("filter"):
+            if not c.bypass_filter:
+                if c.identity_implies_interest:
+                    interest = (src_pod > 0) | (dst_pod > 0)
+                else:
+                    interest = jnp.zeros((b,), bool)
+                if filter_map is not None:
+                    interest |= (filter_map.lookup(src_ip) > 0) | (
+                        filter_map.lookup(dst_ip) > 0
+                    )
+                mask &= interest
+                is_fwd &= interest
+                is_drop &= interest
+                is_dns_req &= interest
+                is_dns_resp &= interest
+                is_retrans &= interest
         # The "local pod" of an event: dst for ingress, src for egress
         # (reference forward.go:107-160 local-context basis).
-        local_pod = jnp.where(is_ingress, dst_pod, src_pod)
-        dir_idx = jnp.where(is_ingress, 0, 1).astype(jnp.uint32)
+        with jax.named_scope("pod_counters"):
+            local_pod = jnp.where(is_ingress, dst_pod, src_pod)
+            dir_idx = jnp.where(is_ingress, 0, 1).astype(jnp.uint32)
 
-        w_pkts = jnp.where(is_fwd, packets, 0)
-        w_bytes = jnp.where(is_fwd, bytes_, 0)
+            w_pkts = jnp.where(is_fwd, packets, 0)
+            w_bytes = jnp.where(is_fwd, bytes_, 0)
 
         # ---- conntrack sampling (before the sketches: low aggregation
         # gates sketch updates on the report decisions) ----
-        ct = state.conntrack
-        n_reports = np.uint32(0)
-        report = jnp.zeros((b,), bool)
-        rep_pkts = jnp.zeros((b,), jnp.uint32)
-        rep_bytes = jnp.zeros((b,), jnp.uint32)
-        if c.enable_conntrack:
-            ct, report, _, rep_pkts, rep_bytes = ct.process(
-                src_ip, dst_ip, ports, proto, tcp_flags, now_s, bytes_, mask,
-                packets_=packets,
-            )
-            n_reports = jnp.sum(report).astype(jnp.uint32)
+        with jax.named_scope("conntrack"):
+            ct = state.conntrack
+            n_reports = np.uint32(0)
+            report = jnp.zeros((b,), bool)
+            rep_pkts = jnp.zeros((b,), jnp.uint32)
+            rep_bytes = jnp.zeros((b,), jnp.uint32)
+            if c.enable_conntrack:
+                ct, report, _, rep_pkts, rep_bytes = ct.process(
+                    src_ip, dst_ip, ports, proto, tcp_flags, now_s, bytes_, mask,
+                    packets_=packets,
+                )
+                n_reports = jnp.sum(report).astype(jnp.uint32)
 
         # ---- dense rectangles ----
         # Every rectangle updates through ONE row-scatter with the counter
@@ -432,82 +453,83 @@ class TelemetryPipeline:
         # and the pass count (the measured TPU cost driver) drops from 17
         # scatters to 4.
         P = c.n_pods
-        local_pod_c = jnp.minimum(local_pod, np.uint32(P - 1))
-        pf = (
-            state.pod_forward.reshape(P * 2, 2)
-            .at[local_pod_c * 2 + dir_idx]
-            .add(jnp.stack([w_pkts, w_bytes], axis=1), mode="drop")
-            .reshape(P, 2, 2)
-        )
-
-        R = c.n_drop_reasons
-        drop_idx = jnp.where(is_drop, local_pod_c * R + reason, np.uint32(P * R))
-        pd = (
-            state.pod_drop.reshape(P * R, 2)
-            .at[drop_idx]
-            .add(
-                jnp.stack(
-                    [
-                        jnp.where(is_drop, packets, 0),
-                        jnp.where(is_drop, bytes_, 0),
-                    ],
-                    axis=1,
-                ),
-                mode="drop",
+        with jax.named_scope("pod_counters"):
+            local_pod_c = jnp.minimum(local_pod, np.uint32(P - 1))
+            pf = (
+                state.pod_forward.reshape(P * 2, 2)
+                .at[local_pod_c * 2 + dir_idx]
+                .add(jnp.stack([w_pkts, w_bytes], axis=1), mode="drop")
+                .reshape(P, 2, 2)
             )
-            .reshape(P, R, 2)
-        )
 
-        # tcp flags: one (B, 8) row-scatter; non-TCP rows route OOB.
-        is_tcp = mask & (proto == PROTO_TCP)
-        flag_rows = jnp.stack(
-            [
-                jnp.where(((tcp_flags >> bit) & 1).astype(bool), packets, 0)
-                for bit in range(8)
-            ],
-            axis=1,
-        )
-        ptf = state.pod_tcpflags.at[
-            jnp.where(is_tcp, local_pod_c, np.uint32(P))
-        ].add(flag_rows, mode="drop")
-
-        Q = c.n_dns_qtypes
-        qtype = jnp.minimum(col(F.DNS) >> 16, np.uint32(Q - 1))
-        is_dns = is_dns_req | is_dns_resp
-        dns_idx = jnp.where(is_dns, local_pod_c * Q + qtype, np.uint32(P * Q))
-        # Every count below weights by F.PACKETS (1 for per-packet events,
-        # N for combined/pre-aggregated rows) so host-side RLE combining
-        # (parallel/combine.py) is exactly lossless.
-        w_dns_req = jnp.where(is_dns_req, packets, 0)
-        w_dns_resp = jnp.where(is_dns_resp, packets, 0)
-        w_retrans = jnp.where(is_retrans, packets, 0)
-        pdns = (
-            state.pod_dns.reshape(P * Q, 2)
-            .at[dns_idx]
-            .add(
-                jnp.stack([w_dns_req, w_dns_resp], axis=1),
-                mode="drop",
+            R = c.n_drop_reasons
+            drop_idx = jnp.where(is_drop, local_pod_c * R + reason, np.uint32(P * R))
+            pd = (
+                state.pod_drop.reshape(P * R, 2)
+                .at[drop_idx]
+                .add(
+                    jnp.stack(
+                        [
+                            jnp.where(is_drop, packets, 0),
+                            jnp.where(is_drop, bytes_, 0),
+                        ],
+                        axis=1,
+                    ),
+                    mode="drop",
+                )
+                .reshape(P, R, 2)
             )
-            .reshape(P, Q, 2)
-        )
 
-        pret = state.pod_retrans.at[
-            jnp.where(is_retrans, local_pod_c, np.uint32(P))
-        ].add(w_retrans, mode="drop")
+            # tcp flags: one (B, 8) row-scatter; non-TCP rows route OOB.
+            is_tcp = mask & (proto == PROTO_TCP)
+            flag_rows = jnp.stack(
+                [
+                    jnp.where(((tcp_flags >> bit) & 1).astype(bool), packets, 0)
+                    for bit in range(8)
+                ],
+                axis=1,
+            )
+            ptf = state.pod_tcpflags.at[
+                jnp.where(is_tcp, local_pod_c, np.uint32(P))
+            ].add(flag_rows, mode="drop")
 
-        # Node counters are plain masked reductions (no scatter needed):
-        # each masked forward event contributes to exactly one (dir) cell.
-        ing = is_ingress.astype(jnp.uint32)
-        nc = state.node_counters + jnp.stack(
-            [
-                jnp.stack(
-                    [jnp.sum(w_pkts * ing), jnp.sum(w_bytes * ing)]
-                ),
-                jnp.stack(
-                    [jnp.sum(w_pkts * (1 - ing)), jnp.sum(w_bytes * (1 - ing))]
-                ),
-            ]
-        ).astype(jnp.uint32)
+            Q = c.n_dns_qtypes
+            qtype = jnp.minimum(col(F.DNS) >> 16, np.uint32(Q - 1))
+            is_dns = is_dns_req | is_dns_resp
+            dns_idx = jnp.where(is_dns, local_pod_c * Q + qtype, np.uint32(P * Q))
+            # Every count below weights by F.PACKETS (1 for per-packet events,
+            # N for combined/pre-aggregated rows) so host-side RLE combining
+            # (parallel/combine.py) is exactly lossless.
+            w_dns_req = jnp.where(is_dns_req, packets, 0)
+            w_dns_resp = jnp.where(is_dns_resp, packets, 0)
+            w_retrans = jnp.where(is_retrans, packets, 0)
+            pdns = (
+                state.pod_dns.reshape(P * Q, 2)
+                .at[dns_idx]
+                .add(
+                    jnp.stack([w_dns_req, w_dns_resp], axis=1),
+                    mode="drop",
+                )
+                .reshape(P, Q, 2)
+            )
+
+            pret = state.pod_retrans.at[
+                jnp.where(is_retrans, local_pod_c, np.uint32(P))
+            ].add(w_retrans, mode="drop")
+
+            # Node counters are plain masked reductions (no scatter needed):
+            # each masked forward event contributes to exactly one (dir) cell.
+            ing = is_ingress.astype(jnp.uint32)
+            nc = state.node_counters + jnp.stack(
+                [
+                    jnp.stack(
+                        [jnp.sum(w_pkts * ing), jnp.sum(w_bytes * ing)]
+                    ),
+                    jnp.stack(
+                        [jnp.sum(w_pkts * (1 - ing)), jnp.sum(w_bytes * (1 - ing))]
+                    ),
+                ]
+            ).astype(jnp.uint32)
 
         # ---- sketches ----
         # At low aggregation, sketch updates ride the conntrack reports:
@@ -516,8 +538,9 @@ class TelemetryPipeline:
         # instead of one per packet — the documented low-mode semantics.
         low = c.data_aggregation_level == "low"
         five = [src_ip, dst_ip, ports, proto]
-        flow_w = rep_pkts if low else jnp.where(is_fwd, packets, 0)
-        flow_hh = state.flow_hh.update(five, flow_w)
+        with jax.named_scope("cms_flow_hh"):
+            flow_w = rep_pkts if low else jnp.where(is_fwd, packets, 0)
+            flow_hh = state.flow_hh.update(five, flow_w)
         # Invertible key-recovery sketches ride the SAME keys and
         # weights as flow_hh, so decode verification against its CMS is
         # apples-to-apples. Priority rows go ONLY to the hi region:
@@ -525,108 +548,115 @@ class TelemetryPipeline:
         # exact whatever the overload state (background noise can't
         # even dilute its buckets).
         inv_flow, inv_hi = state.inv_flow, state.inv_hi
-        if c.enable_invertible:
-            inv_flow = inv_flow.update(
-                five, jnp.where(is_priority, 0, flow_w)
+        with jax.named_scope("invertible"):
+            if c.enable_invertible:
+                inv_flow = inv_flow.update(
+                    five, jnp.where(is_priority, 0, flow_w)
+                )
+                inv_hi = inv_hi.update(
+                    five, jnp.where(is_priority, flow_w, 0)
+                )
+        with jax.named_scope("cms_svc_hh"):
+            pods_known = (src_pod > 0) & (dst_pod > 0)
+            svc_w = jnp.where(
+                pods_known, rep_pkts if low else jnp.where(is_fwd, packets, 0), 0
             )
-            inv_hi = inv_hi.update(
-                five, jnp.where(is_priority, flow_w, 0)
+            svc_hh = state.svc_hh.update([src_pod, dst_pod], svc_w)
+        with jax.named_scope("cms_dns_hh"):
+            dns_hh = state.dns_hh.update([col(F.DNS_QHASH)], w_dns_req)
+
+        with jax.named_scope("hll"):
+            sk_mask = report if low else mask
+            hll_flows = state.hll_flows.update(
+                five, jnp.zeros_like(src_ip), sk_mask
             )
-        pods_known = (src_pod > 0) & (dst_pod > 0)
-        svc_w = jnp.where(
-            pods_known, rep_pkts if low else jnp.where(is_fwd, packets, 0), 0
-        )
-        svc_hh = state.svc_hh.update([src_pod, dst_pod], svc_w)
-        dns_hh = state.dns_hh.update([col(F.DNS_QHASH)], w_dns_req)
+            hll_reason = state.hll_src_per_reason.update([src_ip], reason, is_drop)
+            hll_pod = state.hll_src_per_pod.update(
+                [src_ip],
+                jnp.minimum(dst_pod, np.uint32(c.n_pods - 1)),
+                is_ingress & sk_mask,
+            )
 
-        sk_mask = report if low else mask
-        hll_flows = state.hll_flows.update(
-            five, jnp.zeros_like(src_ip), sk_mask
-        )
-        hll_reason = state.hll_src_per_reason.update([src_ip], reason, is_drop)
-        hll_pod = state.hll_src_per_pod.update(
-            [src_ip],
-            jnp.minimum(dst_pod, np.uint32(c.n_pods - 1)),
-            is_ingress & sk_mask,
-        )
-
-        ones = (
-            rep_pkts.astype(jnp.float32)
-            if low
-            else jnp.where(mask, packets, 0).astype(jnp.float32)
-        )
-        ent = state.entropy
-        ent = ent.update([src_ip], jnp.zeros_like(src_ip), ones)
-        ent = ent.update([dst_ip], jnp.ones_like(src_ip), ones)
-        ent = ent.update(
-            [ports & np.uint32(0xFFFF)], jnp.full_like(src_ip, 2), ones
-        )
+        with jax.named_scope("entropy"):
+            ones = (
+                rep_pkts.astype(jnp.float32)
+                if low
+                else jnp.where(mask, packets, 0).astype(jnp.float32)
+            )
+            ent = state.entropy
+            ent = ent.update([src_ip], jnp.zeros_like(src_ip), ones)
+            ent = ent.update([dst_ip], jnp.ones_like(src_ip), ones)
+            ent = ent.update(
+                [ports & np.uint32(0xFFFF)], jnp.full_like(src_ip, 2), ones
+            )
 
         # ---- apiserver latency (reference latency.go:286-301: match
         # TSval of outgoing apiserver packets to TSecr of replies) ----
         lat_key, lat_ts, lat_hist = state.lat_key, state.lat_ts, state.lat_hist
-        if c.enable_latency:
-            L = c.latency_slots
-            from retina_tpu.ops.hashing import hash_cols, reduce_range
+        with jax.named_scope("latency_match"):
+            if c.enable_latency:
+                L = c.latency_slots
+                from retina_tpu.ops.hashing import hash_cols, reduce_range
 
-            ts_ms = (col(F.TS_HI) << 12) | (col(F.TS_LO) >> 20)  # ns >> 20 ~ ms
-            out_to_api = mask & (dst_ip == apiserver_ip) & (col(F.TSVAL) > 0)
-            in_from_api = mask & (src_ip == apiserver_ip) & (col(F.TSECR) > 0)
-            k_out = hash_cols([dst_ip, col(F.TSVAL)], 0x1A7)
-            k_in = hash_cols([src_ip, col(F.TSECR)], 0x1A7)
-            slot_out = jnp.where(out_to_api, reduce_range(k_out, L), L)
-            lat_key = lat_key.at[slot_out].set(k_out, mode="drop")
-            lat_ts = lat_ts.at[slot_out].set(ts_ms, mode="drop")
-            slot_in = reduce_range(k_in, L).astype(jnp.int32)
-            hit = in_from_api & (lat_key[slot_in] == k_in)
-            rtt = jnp.where(hit, ts_ms - lat_ts[slot_in], 0)
-            # Invalidate matched slots: later segments echoing the same
-            # TSecr (normal TCP) must not re-record the sample, and a
-            # recycled TSval hours later must not match a stale entry.
-            lat_key = lat_key.at[jnp.where(hit, slot_in, L)].set(
-                np.uint32(0), mode="drop"
+                ts_ms = (col(F.TS_HI) << 12) | (col(F.TS_LO) >> 20)  # ns >> 20 ~ ms
+                out_to_api = mask & (dst_ip == apiserver_ip) & (col(F.TSVAL) > 0)
+                in_from_api = mask & (src_ip == apiserver_ip) & (col(F.TSECR) > 0)
+                k_out = hash_cols([dst_ip, col(F.TSVAL)], 0x1A7)
+                k_in = hash_cols([src_ip, col(F.TSECR)], 0x1A7)
+                slot_out = jnp.where(out_to_api, reduce_range(k_out, L), L)
+                lat_key = lat_key.at[slot_out].set(k_out, mode="drop")
+                lat_ts = lat_ts.at[slot_out].set(ts_ms, mode="drop")
+                slot_in = reduce_range(k_in, L).astype(jnp.int32)
+                hit = in_from_api & (lat_key[slot_in] == k_in)
+                rtt = jnp.where(hit, ts_ms - lat_ts[slot_in], 0)
+                # Invalidate matched slots: later segments echoing the same
+                # TSecr (normal TCP) must not re-record the sample, and a
+                # recycled TSval hours later must not match a stale entry.
+                lat_key = lat_key.at[jnp.where(hit, slot_in, L)].set(
+                    np.uint32(0), mode="drop"
+                )
+                # exponential buckets: bucket = floor(log2(rtt_ms + 1)).
+                bug = jnp.floor(
+                    jnp.log2(rtt.astype(jnp.float32) + 1.0)
+                ).astype(jnp.uint32)
+                bug = jnp.minimum(bug, np.uint32(c.latency_buckets - 1))
+                lat_hist = lat_hist.at[jnp.where(hit, bug, c.latency_buckets)].add(
+                    jnp.where(hit, 1, 0).astype(jnp.uint32), mode="drop"
+                )
+
+        with jax.named_scope("totals"):
+            # 64-bit (two-limb) accumulation of reported packets/bytes; exact
+            # byte-plane sums — per-connection report accumulators are full
+            # u32, so a plain batch sum could wrap before the carry applies.
+            rp_lo, rp_hi = _sum64(rep_pkts)
+            rb_lo, rb_hi = _sum64(rep_bytes)
+            ctt = state.ct_totals
+            lo_p = ctt[0] + rp_lo
+            lo_b = ctt[2] + rb_lo
+            ct_totals = jnp.stack(
+                [
+                    lo_p,
+                    ctt[1] + rp_hi + (lo_p < rp_lo).astype(jnp.uint32),
+                    lo_b,
+                    ctt[3] + rb_hi + (lo_b < rb_lo).astype(jnp.uint32),
+                ]
             )
-            # exponential buckets: bucket = floor(log2(rtt_ms + 1)).
-            bug = jnp.floor(
-                jnp.log2(rtt.astype(jnp.float32) + 1.0)
-            ).astype(jnp.uint32)
-            bug = jnp.minimum(bug, np.uint32(c.latency_buckets - 1))
-            lat_hist = lat_hist.at[jnp.where(hit, bug, c.latency_buckets)].add(
-                jnp.where(hit, 1, 0).astype(jnp.uint32), mode="drop"
+
+            # totals[0] counts EVENTS REPRESENTED (sum of packet weights), not
+            # rows: a combined row stands for F.PACKETS underlying events.
+            n_events = jnp.sum(jnp.where(mask, packets, 0)).astype(jnp.uint32)
+            totals = state.totals + jnp.stack(
+                [
+                    n_events,
+                    jnp.sum(w_pkts).astype(jnp.uint32),
+                    jnp.sum(jnp.where(is_drop, packets, 0)).astype(jnp.uint32),
+                    jnp.sum(w_dns_req).astype(jnp.uint32),
+                    jnp.sum(w_dns_resp).astype(jnp.uint32),
+                    jnp.sum(w_retrans).astype(jnp.uint32),
+                    n_reports,
+                    np.uint32(0),
+                ]
             )
-
-        # 64-bit (two-limb) accumulation of reported packets/bytes; exact
-        # byte-plane sums — per-connection report accumulators are full
-        # u32, so a plain batch sum could wrap before the carry applies.
-        rp_lo, rp_hi = _sum64(rep_pkts)
-        rb_lo, rb_hi = _sum64(rep_bytes)
-        ctt = state.ct_totals
-        lo_p = ctt[0] + rp_lo
-        lo_b = ctt[2] + rb_lo
-        ct_totals = jnp.stack(
-            [
-                lo_p,
-                ctt[1] + rp_hi + (lo_p < rp_lo).astype(jnp.uint32),
-                lo_b,
-                ctt[3] + rb_hi + (lo_b < rb_lo).astype(jnp.uint32),
-            ]
-        )
-
-        # totals[0] counts EVENTS REPRESENTED (sum of packet weights), not
-        # rows: a combined row stands for F.PACKETS underlying events.
-        n_events = jnp.sum(jnp.where(mask, packets, 0)).astype(jnp.uint32)
-        totals = state.totals + jnp.stack(
-            [
-                n_events,
-                jnp.sum(w_pkts).astype(jnp.uint32),
-                jnp.sum(jnp.where(is_drop, packets, 0)).astype(jnp.uint32),
-                jnp.sum(w_dns_req).astype(jnp.uint32),
-                jnp.sum(w_dns_resp).astype(jnp.uint32),
-                jnp.sum(w_retrans).astype(jnp.uint32),
-                n_reports,
-                np.uint32(0),
-            ]
-        )
 
         new_state = PipelineState(
             pod_forward=pf,
@@ -668,14 +698,15 @@ class TelemetryPipeline:
         EWMA, reset the window histograms. Called once per window (1s).
         Idle windows (no traffic) do not touch the baseline — see
         AnomalyEWMA.observe."""
-        h = state.entropy.entropy_bits()
-        active = state.entropy.counts.sum(axis=-1) > 0
-        anomaly, flags, z = state.anomaly.observe(
-            h, z_thresh=z_thresh, active=active
-        )
-        new = dataclasses.replace(
-            state, entropy=state.entropy.reset(), anomaly=anomaly
-        )
+        with jax.named_scope(SCOPE_END_WINDOW):
+            h = state.entropy.entropy_bits()
+            active = state.entropy.counts.sum(axis=-1) > 0
+            anomaly, flags, z = state.anomaly.observe(
+                h, z_thresh=z_thresh, active=active
+            )
+            new = dataclasses.replace(
+                state, entropy=state.entropy.reset(), anomaly=anomaly
+            )
         return new, {"entropy_bits": h, "anomaly": flags, "zscore": z}
 
     # ------------------------------------------------------------------

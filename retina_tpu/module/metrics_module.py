@@ -32,8 +32,12 @@ from retina_tpu.controllers.cache import Cache
 from retina_tpu.crd.types import MetricsConfiguration, MetricsSpec
 from retina_tpu.events.schema import ip_to_u32
 from retina_tpu.exporter import Exporter, get_exporter
+from retina_tpu.fleet.shipper import window_epoch
 from retina_tpu.log import logger
 from retina_tpu.managers.filtermanager import FilterManager
+from retina_tpu.metrics import get_metrics
+from retina_tpu.obs.recorder import get_recorder
+from retina_tpu.utils import metric_names as mn
 from retina_tpu.module.metric_objects import (
     METRIC_CONSTRUCTORS,
     AdvMetricBase,
@@ -149,7 +153,25 @@ class MetricsModule:
             spec = self._spec
         if not metrics:
             return
-        snap = self.engine.snapshot()
+        rec = get_recorder()
+        with rec.span(
+            mn.STAGE_POD_PUBLISH,
+            window_epoch(self.cfg.window_seconds),
+        ) as span:
+            snap = self.engine.snapshot()
+            with rec.span(mn.STAGE_SERIES_PUBLISH, span.trace_id):
+                self._publish_series(metrics, spec, snap)
+            # The watermark (engine.publish_lag_s): how far behind the
+            # sink's accepts this cycle's series are, now that they are
+            # out. An engine without a sink (test doubles) has none.
+            lag = getattr(self.engine, "publish_lag_s", None)
+            if lag is not None:
+                lag_s, included = lag(snap)
+                get_metrics().publish_lag_seconds.observe(lag_s)
+                span.set(events_included=included,
+                         lag_ms=round(lag_s * 1e3, 1))
+
+    def _publish_series(self, metrics, spec, snap) -> None:
         shed = getattr(self.engine, "shed_active", None)
         labeler: dict = {}
         if shed is not None and shed("labels"):

@@ -2,7 +2,8 @@
 
 - ``GET /debug/trace?last=N`` — dump the recorder's retained spans as
   Chrome trace-event JSON (load the body straight into Perfetto or
-  chrome://tracing).
+  chrome://tracing). ``&epoch=E`` keeps the spans of one window epoch
+  (the trace id) and adds that epoch's ``stageReport``.
 - ``POST /debug/profile?seconds=S`` — on-demand deep profiling: one
   single-flight ``jax.profiler`` trace session plus an all-thread
   Python stack dump, written to a bounded artifact directory. Safe
@@ -76,10 +77,17 @@ class DebugObservability:
             last = None
             if "last" in q:
                 last = max(0, int(q["last"][0]))
+            epoch = int(q["epoch"][0]) if "epoch" in q else None
         except (ValueError, IndexError):
-            return _reply(400, {"error": "last must be an integer"})
-        doc = self.recorder.chrome_trace(last)
-        return 200, json.dumps(doc).encode(), _JSON
+            return _reply(
+                400, {"error": "last and epoch must be integers"}
+            )
+        doc = self.recorder.chrome_trace(last, trace_id=epoch)
+        if epoch is not None:
+            doc["stageReport"] = self.recorder.stage_report(
+                last, trace_id=epoch
+            )
+        return 200, json.dumps(doc, default=str).encode(), _JSON
 
     # -- POST /debug/profile (handler threads; single-flight) ----------
     def handle_profile(self, q: dict) -> tuple[int, bytes, str]:
@@ -124,9 +132,14 @@ class DebugObservability:
         try:
             import jax
 
+            from retina_tpu.parallel.telemetry import write_op_scopes
+
             jax.profiler.start_trace(outdir)
             time.sleep(seconds)
             jax.profiler.stop_trace()
+            # Device events carry no operator scope: the map from each
+            # program's instructions to scopes goes beside the trace.
+            write_op_scopes(os.path.join(outdir, "op_scopes.json"))
         except Exception as e:
             # The stack dump below still lands: a host-side hang is
             # diagnosable even when the device profiler is unavailable.

@@ -300,15 +300,13 @@ class FeedWorker(threading.Thread):
         from retina_tpu.utils import metric_names as mn
 
         rec = get_recorder()
-        t0 = rec.begin()
-        items = self.pool.build_steps(blocks, n_raw, int(time.time()))
-        rec.record(mn.STAGE_FEED_FILL, t0)
-        t0 = rec.begin()
-        for it in items:
-            if not self.outq.put(it, alive=self.pool.alive):
-                self.handoff_dropped += 1
-                self.pool.drop(it)
-        rec.record(mn.STAGE_STAGING_HANDOFF, t0)
+        with rec.span(mn.STAGE_FEED_FILL):
+            items = self.pool.build_steps(blocks, n_raw, int(time.time()))
+        with rec.span(mn.STAGE_STAGING_HANDOFF):
+            for it in items:
+                if not self.outq.put(it, alive=self.pool.alive):
+                    self.handoff_dropped += 1
+                    self.pool.drop(it)
         self.batches += 1
         self._publish_metrics()
 

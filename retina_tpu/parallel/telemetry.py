@@ -23,8 +23,10 @@ from __future__ import annotations
 import dataclasses
 import functools
 import hashlib
+import json
 import os
 import pickle
+import re
 import threading
 from functools import partial
 from typing import Any
@@ -36,9 +38,86 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from retina_tpu.devprog import device_entry
 from retina_tpu.models.identity import IdentityMap
-from retina_tpu.models.pipeline import PipelineConfig, PipelineState, TelemetryPipeline
+from retina_tpu.models.pipeline import (
+    SCOPE_END_WINDOW, STEP_SCOPES, PipelineConfig, PipelineState,
+    TelemetryPipeline,
+)
 from retina_tpu.ops.invertible import decode_verified
 from retina_tpu.ops.topk import TopKTable
+
+# Operator scopes (``jax.named_scope``) of every device program of the
+# agent. The profiler's device events carry an operation's HLO line but
+# not its ``op_name`` metadata (read on the attached v5e: the stats of
+# an ``XLA Ops`` event are its offset, its duration and a time scale),
+# so each program's map from instruction name to scope is taken from
+# the executable's own text where the executable is obtained (compiled
+# or loaded from the AOT cache: one way for both), kept here, read
+# in-process through ``op_scope_map()`` and written beside the trace of
+# a ``/debug/profile`` session (``op_scopes.json``). A fusion's
+# metadata is its root's, so a fusion belongs to the scope of its root.
+SCOPE_SNAPSHOT_MERGE = "snapshot_merge"
+SCOPE_SNAPSHOT_CONCAT = "snapshot_concat"
+SCOPE_INGEST_UNPACK = "ingest_unpack"
+OP_SCOPES = frozenset(STEP_SCOPES + (
+    SCOPE_END_WINDOW, SCOPE_SNAPSHOT_MERGE, SCOPE_SNAPSHOT_CONCAT,
+    SCOPE_INGEST_UNPACK,
+))
+_OP_SCOPE_LOCK = threading.Lock()
+_OP_SCOPE_MAP: dict[str, dict[str, str]] = {}  # module -> {instr: scope}
+_HLO_MODULE = re.compile(r"^HloModule ([\w.\-]+)", re.M)
+_HLO_INSTR = re.compile(
+    r'^\s*(?:ROOT )?%?([\w.\-]+) = [^\n]*?op_name="([^"]*)"', re.M
+)
+
+
+def scopes_of_text(hlo_text: str) -> tuple[str, dict[str, str]]:
+    """(module name, {instruction name: scope}) of one compiled
+    program's text: for every instruction whose ``op_name`` passes
+    through a registered scope, the outermost such scope."""
+    m = _HLO_MODULE.search(hlo_text)
+    out: dict[str, str] = {}
+    for instr, op_name in _HLO_INSTR.findall(hlo_text):
+        for part in op_name.split("/"):
+            if part in OP_SCOPES:
+                out[instr] = part
+                break
+    return (m.group(1) if m else ""), out
+
+
+def note_op_scopes(ex) -> None:
+    """Keep the scope map of one executable, compiled or loaded.
+    Best-effort: a runtime that cannot print an executable leaves that
+    program's operations unscoped."""
+    try:
+        module, table = scopes_of_text(ex.as_text())
+    except Exception:  # noqa: RT101 — diagnostics only
+        return
+    if module and table:
+        with _OP_SCOPE_LOCK:
+            _OP_SCOPE_MAP.setdefault(module, {}).update(table)
+
+
+def op_scope_map() -> dict[str, dict[str, str]]:
+    """``{module: {instruction: scope}}`` of every program this process
+    has obtained so far (a copy)."""
+    with _OP_SCOPE_LOCK:
+        return {m: dict(t) for m, t in _OP_SCOPE_MAP.items()}
+
+
+def write_op_scopes(path: str) -> None:
+    """:func:`op_scope_map` as JSON at ``path``, a place the caller
+    chose (atomic; best-effort)."""
+    doc = op_scope_map()
+    try:
+        os.makedirs(os.path.dirname(path), exist_ok=True)
+        tmp = f"{path}.tmp.{os.getpid()}"
+        with open(tmp, "w") as f:
+            json.dump(doc, f)
+        os.replace(tmp, path)
+    except OSError:
+        _aot_log().warning("op scope map not written to %s", path,
+                           exc_info=True)
+
 
 # On-disk AOT executable cache accounting (ROADMAP item 5: compile cost
 # swings 2.1s->96.1s and bucket-grid warm is 214s PER PROCESS — a disk
@@ -154,6 +233,7 @@ def aot_disk_load(path: str, mesh: Mesh | None, tag: str = ""):
         _aot_disk_bump("hits", tag)
         if tag:
             _aot_log().debug("aot disk HIT tag=%s path=%s", tag, path)
+        note_op_scopes(ex)
         return ex
     except Exception:
         _aot_disk_bump("errors", tag)
@@ -258,9 +338,6 @@ class AotProgram:
     def _disk_load(self, path: str):
         return aot_disk_load(path, self._mesh, tag=self._tag)
 
-    def _disk_save(self, path: str, ex) -> None:
-        aot_disk_save(path, ex, tag=self._tag)
-
     def _lower(self, args, key=None):
         if self._cache_dir and key is not None:
             path = self._disk_path(key)
@@ -284,8 +361,9 @@ class AotProgram:
             for i, arg in enumerate(args)
         )
         ex = self._jitted.lower(*specs).compile()
+        note_op_scopes(ex)
         if self._cache_dir and key is not None:
-            self._disk_save(self._disk_path(key), ex)
+            aot_disk_save(self._disk_path(key), ex, tag=self._tag)
         return ex
 
     def __call__(self, *args):
@@ -472,25 +550,27 @@ class ShardedTelemetry:
     @device_entry("sharded.end_window", kind="shard_map")
     def _build_end_window(self):
         def local_end(state, z_thresh):
-            s = jax.tree.map(lambda x: x[0], state)
-            # Merge window histograms first so every device computes the
-            # entropy of the UNION stream, then updates its (replicated)
-            # anomaly EWMA identically.
-            merged_ent = dataclasses.replace(
-                s.entropy, counts=jax.lax.psum(s.entropy.counts, self.axes)
-            )
-            h = merged_ent.entropy_bits()
-            # Idle windows (including the engine's compile() warm-up)
-            # must not seed/poison the EWMA baseline — same contract as
-            # the single-chip end_window (models/pipeline.py).
-            active = merged_ent.counts.sum(axis=-1) > 0
-            anomaly, flags, z = s.anomaly.observe(
-                h, z_thresh=z_thresh, active=active
-            )
-            new = dataclasses.replace(
-                s, entropy=s.entropy.reset(), anomaly=anomaly
-            )
-            new = jax.tree.map(lambda x: x[None], new)
+            with jax.named_scope(SCOPE_END_WINDOW):
+                s = jax.tree.map(lambda x: x[0], state)
+                # Merge window histograms first so every device computes
+                # the entropy of the UNION stream, then updates its
+                # (replicated) anomaly EWMA identically.
+                merged_ent = dataclasses.replace(
+                    s.entropy,
+                    counts=jax.lax.psum(s.entropy.counts, self.axes),
+                )
+                h = merged_ent.entropy_bits()
+                # Idle windows (including the engine's compile() warm-up)
+                # must not seed/poison the EWMA baseline — same contract
+                # as the single-chip end_window (models/pipeline.py).
+                active = merged_ent.counts.sum(axis=-1) > 0
+                anomaly, flags, z = s.anomaly.observe(
+                    h, z_thresh=z_thresh, active=active
+                )
+                new = dataclasses.replace(
+                    s, entropy=s.entropy.reset(), anomaly=anomaly
+                )
+                new = jax.tree.map(lambda x: x[None], new)
             return new, {"entropy_bits": h, "anomaly": flags, "zscore": z}
 
         sh = self._sharded_spec
@@ -747,20 +827,22 @@ class ShardedTelemetry:
         leaves, treedef = jax.tree_util.tree_flatten(shapes)
 
         def flat_fn(st, now_s):
-            d = base(st, now_s)
-            out = []
-            for leaf in jax.tree_util.tree_leaves(d):
-                if leaf.dtype != jnp.uint32:
-                    leaf = jax.lax.bitcast_convert_type(
-                        leaf.astype(
-                            jnp.float32
-                            if jnp.issubdtype(leaf.dtype, jnp.floating)
-                            else jnp.uint32
-                        ),
-                        jnp.uint32,
-                    )
-                out.append(leaf.reshape(-1))
-            return jnp.concatenate(out)
+            with jax.named_scope(SCOPE_SNAPSHOT_MERGE):
+                d = base(st, now_s)
+            with jax.named_scope(SCOPE_SNAPSHOT_CONCAT):
+                out = []
+                for leaf in jax.tree_util.tree_leaves(d):
+                    if leaf.dtype != jnp.uint32:
+                        leaf = jax.lax.bitcast_convert_type(
+                            leaf.astype(
+                                jnp.float32
+                                if jnp.issubdtype(leaf.dtype, jnp.floating)
+                                else jnp.uint32
+                            ),
+                            jnp.uint32,
+                        )
+                    out.append(leaf.reshape(-1))
+                return jnp.concatenate(out)
 
         prog = AotProgram(
             jax.jit(flat_fn), self.mesh, self._sharded_spec, (0,),

@@ -28,8 +28,11 @@ record blocks, not per-event calls: batches are the unit the device wants.
 from __future__ import annotations
 
 import abc
+import bisect
+import collections
 import queue as queue_mod
 import threading
+import time
 from typing import Optional, Protocol
 
 import numpy as np
@@ -119,22 +122,58 @@ class Plugin(abc.ABC):
         get_metrics().lost_events.labels(stage=stage, plugin=self.name).inc(n)
 
 
+def oldest_unheld(
+    accepts: list[tuple[float, int]], events_held: int
+) -> float | None:
+    """The watermark arithmetic of :meth:`QueueSink.oldest_unheld` on a
+    plain list of (accept time, cumulative events), oldest first."""
+    i = bisect.bisect_right(accepts, events_held, key=lambda a: a[1])
+    return accepts[i][0] if i < len(accepts) else None
+
+
 class QueueSink:
     """Bounded sink over a queue of record blocks — the userspace record
     channel analog (10k-deep, drop-on-full; packetparser types_linux.go:38,
     packetparser_linux.go:692-697). The batcher drains it."""
 
+    # Accepts remembered for the publish watermark: at 122 blocks a
+    # second (packetparser's 8,192-row blocks at 1M events/s) half a
+    # minute, far past any publish lag worth a number.
+    ACCEPT_RING = 4096
+
     def __init__(self, max_blocks: int = 1024):
         self.q: queue_mod.Queue[tuple[np.ndarray, str]] = queue_mod.Queue(
             maxsize=max_blocks
         )
+        # (accept time on time.monotonic, events accepted up to and
+        # including that block), oldest first; one entry per block, so
+        # a lock per write is cheap.
+        self._accepts: collections.deque[tuple[float, int]] = (
+            collections.deque(maxlen=self.ACCEPT_RING)
+        )
+        self._accepted = 0
+        self._accept_lock = threading.Lock()
 
     def write_records(self, records: np.ndarray, plugin: str) -> int:
-        try:
-            self.q.put_nowait((records, plugin))
-            return len(records)
-        except queue_mod.Full:
-            return 0
+        # Stamped under the lock that orders the put, so the ring's
+        # counts run in the order the blocks leave the queue.
+        with self._accept_lock:
+            try:
+                self.q.put_nowait((records, plugin))
+            except queue_mod.Full:
+                return 0
+            self._accepted += len(records)
+            self._accepts.append((time.monotonic(), self._accepted))
+        return len(records)
+
+    def oldest_unheld(self, events_held: int) -> float | None:
+        """Accept time of the oldest accepted block that a reader
+        holding the first ``events_held`` accepted events does not hold
+        in full; None when it holds them all. Where the ring has
+        forgotten that block, the oldest accept it still knows."""
+        with self._accept_lock:
+            accepts = list(self._accepts)
+        return oldest_unheld(accepts, events_held)
 
     def drain(self, max_blocks: int = 64) -> list[tuple[np.ndarray, str]]:
         out = []

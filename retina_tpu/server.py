@@ -1,21 +1,19 @@
-"""Agent HTTP server: /metrics, /healthz, /readyz, /debug/pprof.
+"""Agent HTTP server: /metrics, /healthz, /readyz, /debug/pprof/heap.
 
 Reference analog: pkg/server/server.go — a chi mux serving promhttp over
 the combined gatherer (:61-63), pprof handlers (:46-56), and health
 endpoints wired by the daemon (cmd/standard/daemon.go:217-222) so kubelet
 can restart an unhealthy agent.
 
-Python analog: a ThreadingHTTPServer. /debug/pprof/profile runs cProfile
-for ``seconds=N`` and returns pstats text; /debug/pprof/heap returns a
+Python analog: a ThreadingHTTPServer. /debug/pprof/heap returns a
 tracemalloc snapshot if tracing is on; /debug/vars dumps runtime counters.
+The CPU-profile analog of the reference's pprof is ``POST /debug/profile``
+(obs/debug.py), which traces every thread and the device.
 """
 
 from __future__ import annotations
 
-import cProfile
-import io
 import json
-import pstats
 import threading
 import time
 import tracemalloc
@@ -25,7 +23,9 @@ from urllib.parse import parse_qs, urlparse
 
 from retina_tpu.exporter import Exporter, get_exporter
 from retina_tpu.log import logger
+from retina_tpu.obs.recorder import get_recorder
 from retina_tpu.utils import buildinfo
+from retina_tpu.utils import metric_names as mn
 
 _log = logger("server")
 
@@ -88,8 +88,15 @@ class Server:
         # starts the clock).
         self._stale_since: float | None = None
 
+    def _timed_gather(self) -> bytes:
+        """One render of the exposition, as a ``render`` span. It runs
+        on the render thread, or on a handler thread that lives for one
+        request (first render, or no cache): hence the shared ring."""
+        with get_recorder().span(mn.STAGE_RENDER, shared=True):
+            return self._gather()
+
     def _render(self) -> bytes:
-        body = self._gather()
+        body = self._timed_gather()
         with self._cache_lock:
             self._cache_body = body
             self._cache_time = time.monotonic()
@@ -116,7 +123,7 @@ class Server:
 
     def _metrics_body(self) -> bytes:
         if self._cache_ttl <= 0:
-            return self._gather()
+            return self._timed_gather()
         with self._cache_lock:
             body = self._cache_body
             age = time.monotonic() - self._cache_time
@@ -224,18 +231,6 @@ class Server:
                         doc = {k: f() for k, f in srv._vars.items()}
                         self._send(200, json.dumps(doc, default=str).encode(),
                                    "application/json")
-                    elif route == "/debug/pprof/profile":
-                        q = parse_qs(url.query)
-                        seconds = min(float(q.get("seconds", ["1"])[0]), 30.0)
-                        prof = cProfile.Profile()
-                        prof.enable()
-                        time.sleep(seconds)
-                        prof.disable()
-                        out = io.StringIO()
-                        pstats.Stats(prof, stream=out).sort_stats(
-                            "cumulative"
-                        ).print_stats(50)
-                        self._send(200, out.getvalue().encode(), "text/plain")
                     elif route == "/debug/pprof/heap":
                         if not tracemalloc.is_tracing():
                             tracemalloc.start()

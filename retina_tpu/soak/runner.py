@@ -90,8 +90,7 @@ def _span_cost_probe_us(n: int = 2000) -> float:
     rec = get_recorder()
     t0 = time.perf_counter()
     for _ in range(n):
-        b = rec.begin()
-        rec.record(mn.STAGE_PUBLISH, b)
+        rec.span(mn.STAGE_PUBLISH).end()
     return (time.perf_counter() - t0) / n * 1e6
 
 

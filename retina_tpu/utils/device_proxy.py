@@ -19,7 +19,21 @@ overhead is a queue round-trip (~tens of µs) against device operations
 that are ms-scale.
 
 Re-entrant calls (a proxied closure calling run_on_device) execute
-directly on the proxy thread.
+directly on the proxy thread, as part of the call that made them.
+
+The queue is observed, not changed. Every call carries a ``kind`` from
+the fixed registry ``utils/metric_names.PROXY_KINDS``. Per call the
+proxy records the wait (enqueue -> start) and the run (start -> end):
+``tpu_proxy_wait_seconds{kind}``, ``tpu_proxy_run_seconds{kind}`` (its
+``_count`` is the number of calls), and ``tpu_proxy_queue_depth``
+(calls queued, set after every put and every take). Through the
+flight recorder each call is a ``retina:proxy_run`` annotation with its
+kind and the blocking ``q.get()`` a ``retina:proxy_idle`` one, so a
+profile shows at every instant whether the proxy thread was idle or
+which kind of call it ran; every kind but ``poll`` also writes a ring
+span (``proxy_run``, with ``kind``, ``wait_s`` and the enqueuing span
+as parent). A readiness poll runs a hundred times a second and would
+wrap a ring in a run: polls are counted and annotated only.
 """
 
 from __future__ import annotations
@@ -31,23 +45,113 @@ from typing import Any, Callable, TypeVar
 
 import numpy as np
 
+from retina_tpu.obs.recorder import NULL_SPAN, annotate, get_recorder
+from retina_tpu.utils import metric_names as mn
+
 T = TypeVar("T")
 
 _lock = threading.Lock()
 _q: queue.Queue | None = None
 _thread: threading.Thread | None = None
+# kind -> (wait histogram child, run histogram child) of the registry
+# in _children_of; filled on the proxy thread only.
+_children: dict[str, tuple] = {}
+_children_of: Any = None
+
+
+def _set_depth(q: queue.Queue) -> None:
+    """The queue's depth to the gauge, where it changes: after a put
+    and after the proxy took a call. Like ``_observe`` it must never
+    take down its caller."""
+    try:
+        from retina_tpu.metrics import get_metrics
+
+        get_metrics().proxy_queue_depth.set(q.qsize())
+    except Exception:  # noqa: RT101 — see docstring
+        pass
+
+
+def _observe(kind: str, wait_s: float, run_s: float) -> None:
+    """(proxy thread) One call's numbers to the exposition. The
+    observer must never take down the proxy: a registry that cannot be
+    reached drops the numbers and keeps the call."""
+    global _children_of
+    try:
+        from retina_tpu.metrics import get_metrics
+
+        m = get_metrics()
+        if m is not _children_of:  # a new registry (tests reset it)
+            _children.clear()
+            _children_of = m
+        ch = _children.get(kind)
+        if ch is None:
+            ch = _children[kind] = (
+                m.proxy_wait_seconds.labels(kind=kind),
+                m.proxy_run_seconds.labels(kind=kind),
+            )
+        ch[0].observe(wait_s)
+        ch[1].observe(run_s)
+    except Exception:  # noqa: RT101 — see docstring
+        pass
+
+
+# The idle wait is annotated in slices: an annotation that is open when
+# a profiler session starts or stops is not in the session, so one long
+# wait would leave the head and the tail of a trace unlabelled.
+_IDLE_SLICE_S = 0.05
+
+
+def _mark(make: Callable[[], Any]) -> Any:
+    """(proxy thread) Open the span or annotation ``make()`` gives.
+    Like ``_observe`` it must never take down the proxy, the one thread
+    every ``run_on_device`` waits for: where the recorder or the
+    profiler raises, the call runs unmarked."""
+    try:
+        span = make()
+        span.__enter__()
+        return span
+    except Exception:  # noqa: RT101 — see docstring
+        return NULL_SPAN
+
+
+def _unmark(span: Any) -> None:
+    try:
+        span.__exit__(None, None, None)
+    except Exception:  # noqa: RT101 — see _mark
+        pass
 
 
 def _loop(q: queue.Queue) -> None:
     while True:
-        fn, args, kwargs, box, done = q.get()
+        item = None
+        while item is None:
+            idle = _mark(lambda: annotate("proxy_idle"))
+            try:
+                item = q.get(timeout=_IDLE_SLICE_S)
+            except queue.Empty:  # noqa: RT101 — an idle slice ended; wait on
+                pass
+            finally:
+                _unmark(idle)
+        fn, args, kwargs, box, done, kind, t_enq, parent = item
+        t_start = time.perf_counter()
+        _set_depth(q)
+        span = _mark(
+            (lambda: annotate("proxy_run", kind=kind))
+            if kind == mn.KIND_POLL
+            else (lambda: get_recorder().span(
+                mn.STAGE_PROXY_RUN, parent=parent, kind=kind,
+                wait_s=t_start - t_enq))
+        )
         try:
             box.append(fn(*args, **kwargs))
         except BaseException as e:  # noqa: BLE001 — delivered to caller
             box.append(e)
             box.append(True)
         finally:
+            t_end = time.perf_counter()
             done.set()
+            _unmark(span)
+            _observe(kind, t_start - t_enq, t_end - t_start)
 
 
 def _ensure_thread() -> queue.Queue:
@@ -67,22 +171,38 @@ def _ensure_thread() -> queue.Queue:
         return _q
 
 
-def run_on_device(fn: Callable[..., T], *args: Any, **kwargs: Any) -> T:
+def _enqueue(q: queue.Queue, fn, args, kwargs, box, done, kind,
+             parent=None) -> None:
+    if parent is None:
+        parent = get_recorder().current_id()
+    q.put((fn, args, kwargs, box, done, kind, time.perf_counter(), parent))
+    _set_depth(q)
+
+
+def run_on_device(
+    fn: Callable[..., T], *args: Any, kind: str = mn.KIND_OTHER,
+    parent: int | None = None, **kwargs: Any,
+) -> T:
     """Execute ``fn(*args, **kwargs)`` on the device proxy thread and
-    return (or re-raise) its result."""
+    return (or re-raise) its result. ``kind`` names the call for the
+    proxy's own metrics (module docstring); ``parent`` is the span that
+    caused it, by default the one open on the calling thread."""
     if threading.current_thread() is _thread:
         return fn(*args, **kwargs)
     q = _ensure_thread()
     box: list = []
     done = threading.Event()
-    q.put((fn, args, kwargs, box, done))
+    _enqueue(q, fn, args, kwargs, box, done, kind, parent)
     done.wait()
     if len(box) == 2:
         raise box[0]
     return box[0]
 
 
-def submit_on_device(fn: Callable[..., Any], *args: Any, **kwargs: Any) -> None:
+def submit_on_device(
+    fn: Callable[..., Any], *args: Any, kind: str = mn.KIND_OTHER,
+    parent: int | None = None, **kwargs: Any,
+) -> None:
     """Fire-and-forget: enqueue ``fn`` on the proxy thread and return
     immediately.
 
@@ -102,10 +222,12 @@ def submit_on_device(fn: Callable[..., Any], *args: Any, **kwargs: Any) -> None:
             pass
         return
     q = _ensure_thread()
-    q.put((fn, args, kwargs, [], threading.Event()))
+    _enqueue(q, fn, args, kwargs, [], threading.Event(), kind, parent)
 
 
-def fetch_on_device(arr: Any, poll_s: float = 0.01) -> Any:
+def fetch_on_device(
+    arr: Any, poll_s: float = 0.01, timing: dict | None = None
+) -> Any:
     """Device->host readback that blocks only the CALLER.
 
     A plain ``np.asarray(arr)`` on the proxy thread parks it for the
@@ -118,12 +240,71 @@ def fetch_on_device(arr: Any, poll_s: float = 0.01) -> Any:
     sleeping off-proxy between polls, and only when the computation has
     finished does the proxy run the asarray — which then costs just the
     D2H bytes, not the queue wait. Every JAX touch stays on the proxy
-    thread."""
+    thread.
+
+    ``timing``, when given, receives the two halves in seconds:
+    ``ready_wait_s`` (until ``is_ready`` said yes) and ``copy_s`` (the
+    proxied asarray, its own queue wait included)."""
+    t0 = time.perf_counter()
     check = getattr(arr, "is_ready", None)
     if check is not None:
-        while not run_on_device(check):
+        while not run_on_device(check, kind=mn.KIND_POLL):
             time.sleep(poll_s)
-    return run_on_device(np.asarray, arr)
+    t1 = time.perf_counter()
+    host = run_on_device(np.asarray, arr, kind=mn.KIND_FETCH)
+    if timing is not None:
+        timing["ready_wait_s"] = t1 - t0
+        timing["copy_s"] = time.perf_counter() - t1
+    return host
+
+
+# -- completion: waiting for the device off the proxy thread -----------
+_ready_q: queue.SimpleQueue | None = None
+_ready_thread: threading.Thread | None = None
+
+
+def _ready_loop(q: queue.SimpleQueue) -> None:
+    while True:
+        arr, fn = q.get()
+        err = None
+        try:
+            arr.block_until_ready()
+        except BaseException as e:  # noqa: BLE001 — handed to fn
+            err = e
+        try:
+            fn(err)
+        except BaseException:  # noqa: BLE001, RT101 — contract: fn self-handles errors
+            pass
+
+
+def on_ready(arr: Any, fn: Callable[[BaseException | None], Any]) -> None:
+    """Call ``fn(error_or_None)`` on the completion thread once ``arr``
+    is ready on the device. Returns at once; the proxy thread, where
+    the engine calls this from, never waits. Hand-overs complete in
+    order, as the device runs them.
+
+    This is the one place where a thread other than the proxy touches
+    a JAX array: ``block_until_ready`` waits, and neither dispatches nor
+    copies. The direct wait was tried on one attached v5e chip (PR 26:
+    a probe of 300 waits beside a dispatching thread, then every run of
+    the benchmark) and is kept. On a four-chip mesh, where the awaited
+    output is sharded over the devices, a probe of 300 waits was clean
+    too, but the engine itself has not booted there with it (ROADMAP
+    D11): the next four-chip bring-up call decides it, and the fallback
+    is to poll ``is_ready`` through the proxy as ``fetch_on_device``
+    does.
+    Callers bound the number of outstanding hand-overs (the engine
+    hands over one per dispatch, inside its in-flight semaphore)."""
+    global _ready_q, _ready_thread
+    with _lock:
+        if _ready_q is None:
+            _ready_q = queue.SimpleQueue()  # noqa: RT102 — bounded upstream: one hand-over per dispatch, inside the engine's in-flight semaphore
+            _ready_thread = threading.Thread(
+                target=_ready_loop, args=(_ready_q,),
+                name="device-completion", daemon=True,
+            )
+            _ready_thread.start()
+    _ready_q.put((arr, fn))
 
 
 def fence(timeout: float | None = None) -> bool:
@@ -137,5 +318,5 @@ def fence(timeout: float | None = None) -> bool:
         return True
     q = _ensure_thread()
     done = threading.Event()
-    q.put((lambda: None, (), {}, [], done))
+    _enqueue(q, lambda: None, (), {}, [], done, mn.KIND_OTHER)
     return done.wait(timeout)

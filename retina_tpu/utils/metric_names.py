@@ -272,8 +272,8 @@ TPU_SOAK_SENTINEL_FAILURES = TPU_SOAK_PREFIX + "sentinel_failures_counter"
 TPU_SOAK_RECOVERY_SECONDS = TPU_SOAK_PREFIX + "last_recovery_seconds"
 
 # Flight recorder (retina_tpu/obs/): per-window stage-latency
-# breakdown. tpu_stage_seconds{stage} is observed once per SAMPLED span
-# by the recorder; build_info is a constant-1 gauge whose labels
+# breakdown. tpu_stage_seconds{stage} is observed once per span by the
+# recorder; build_info is a constant-1 gauge whose labels
 # identify the running build (version/jax/backend/devices/config
 # signature — the scrape-side answer to "what exactly is running?");
 # uptime_seconds is seconds since engine start.
@@ -281,22 +281,41 @@ TPU_STAGE_SECONDS = PREFIX + "tpu_stage_seconds"
 RETINA_BUILD_INFO = PREFIX + "retina_build_info"
 TPU_UPTIME_SECONDS = PREFIX + "tpu_uptime_seconds"
 
+# Device proxy (utils/device_proxy.py): per proxied call, by kind, how
+# long it waited in the FIFO (enqueue -> start) and how long it ran
+# (start -> end; its _count is the number of calls, readiness polls
+# included); the queue's depth. publish_lag_seconds is the program's own
+# reading of freshness: at the end of a publish cycle, now minus the
+# accept time of the oldest accepted event its snapshot does not hold.
+TPU_PROXY_WAIT_SECONDS = PREFIX + "tpu_proxy_wait_seconds"
+TPU_PROXY_RUN_SECONDS = PREFIX + "tpu_proxy_run_seconds"
+TPU_PROXY_QUEUE_DEPTH = PREFIX + "tpu_proxy_queue_depth"
+TPU_PUBLISH_LAG_SECONDS = PREFIX + "tpu_publish_lag_seconds"
+
 # Pipeline stage-name registry (the ONLY legal values of the
 # tpu_stage_seconds `stage` label and of every recorder span). The
 # RT226 analyzer machine-checks three-way agreement between these
 # constants, the span names actually emitted through the recorder, and
 # the stage table in docs/observability.md — add the constant, the
 # emission site and the doc row together.
-STAGE_GENERATOR_EMIT = "generator_emit"
+STAGE_DISTRIBUTOR_DEAL = "distributor_deal"
 STAGE_COMBINE = "combine"
 STAGE_FEED_FILL = "feed_fill"
 STAGE_STAGING_HANDOFF = "staging_handoff"
 STAGE_WIRE_BUILD = "wire_build"
-STAGE_TRANSFER = "transfer"
+STAGE_TRANSFER_ENQUEUE = "transfer_enqueue"
 STAGE_DEVICE_STEP = "device_step"
+STAGE_PROXY_RUN = "proxy_run"
 STAGE_WINDOW_CLOSE = "window_close"
 STAGE_HARVEST = "harvest"
 STAGE_PUBLISH = "publish"
+STAGE_POD_PUBLISH = "pod_publish"
+STAGE_SNAPSHOT = "snapshot"
+STAGE_SNAPSHOT_DISPATCH = "snapshot_dispatch"
+STAGE_SNAPSHOT_FETCH = "snapshot_fetch"
+STAGE_SNAPSHOT_FINISH = "snapshot_finish"
+STAGE_SERIES_PUBLISH = "series_publish"
+STAGE_RENDER = "render"
 STAGE_SHIP_READBACK = "ship_readback"
 STAGE_SHIP_ENCODE = "ship_encode"
 STAGE_SHIP_SEND = "ship_send"
@@ -305,20 +324,51 @@ STAGE_AGG_MERGE = "aggregator_merge"
 # Ordered registry (pipeline order); drives the fixed label space of
 # tpu_stage_seconds and the bench critical-path report.
 STAGES = (
-    STAGE_GENERATOR_EMIT,
+    STAGE_DISTRIBUTOR_DEAL,
     STAGE_COMBINE,
     STAGE_FEED_FILL,
     STAGE_STAGING_HANDOFF,
     STAGE_WIRE_BUILD,
-    STAGE_TRANSFER,
+    STAGE_TRANSFER_ENQUEUE,
     STAGE_DEVICE_STEP,
+    STAGE_PROXY_RUN,
     STAGE_WINDOW_CLOSE,
     STAGE_HARVEST,
     STAGE_PUBLISH,
+    STAGE_POD_PUBLISH,
+    STAGE_SNAPSHOT,
+    STAGE_SNAPSHOT_DISPATCH,
+    STAGE_SNAPSHOT_FETCH,
+    STAGE_SNAPSHOT_FINISH,
+    STAGE_SERIES_PUBLISH,
+    STAGE_RENDER,
     STAGE_SHIP_READBACK,
     STAGE_SHIP_ENCODE,
     STAGE_SHIP_SEND,
     STAGE_AGG_MERGE,
+)
+
+# Device-proxy call-kind registry (the ONLY legal values of the `kind`
+# label of tpu_proxy_* and of the `kind` argument of every proxied
+# call). RT226 holds these constants, the kinds passed at the call
+# sites and the kind table in docs/observability.md together, as it
+# does the stages: a kind is added with its first call site.
+KIND_STEP = "step"
+KIND_CLOSE = "close"
+KIND_SNAPSHOT = "snapshot"
+KIND_FETCH = "fetch"
+KIND_POLL = "poll"
+KIND_TABLE = "table"
+KIND_OTHER = "other"
+
+PROXY_KINDS = (
+    KIND_STEP,
+    KIND_CLOSE,
+    KIND_SNAPSHOT,
+    KIND_FETCH,
+    KIND_POLL,
+    KIND_TABLE,
+    KIND_OTHER,
 )
 
 # Label keys (reference pkg/utils/metric_names.go label constants).

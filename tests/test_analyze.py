@@ -560,16 +560,107 @@ def test_rt226_clean(tmp_path):
         usage_src="""
             from retina_tpu.utils import metric_names as mn
 
-            def work(rec, t0):
-                rec.record(mn.STAGE_ALPHA, t0)
-                rec.record(mn.STAGE_BETA, t0)
-                ring.span()  # unrelated .span method: out of scope
+            def work(rec, m):
+                with rec.span(mn.STAGE_ALPHA, trace_id=3):
+                    sp = rec.span(mn.STAGE_BETA)
+                sp.end()
+                m.span(1)  # unrelated .span method: out of scope
         """,
         doc_obs=STAGE_TABLE_OK,
     )
     rep = Reporter()
     rt226.check_program(ctxs, rep, tmp_path)
     assert rep.findings == []
+
+
+KIND_DECLS = STAGE_DECLS + """
+    KIND_STEP = "step"
+    KIND_POLL = "poll"
+
+    PROXY_KINDS = (
+        KIND_STEP,
+        KIND_POLL,
+    )
+"""
+
+KIND_TABLE_OK = STAGE_TABLE_OK + """
+<!-- kind-table-begin -->
+| Kind | What |
+|---|---|
+| `step` | a dispatch |
+| `poll` | a readiness poll |
+<!-- kind-table-end -->
+"""
+
+KIND_USAGE = """
+    from retina_tpu.utils import metric_names as mn
+    from retina_tpu.utils.device_proxy import run_on_device
+
+    def work(rec, proxy):
+        with rec.span(mn.STAGE_ALPHA):
+            rec._step_span(mn.STAGE_BETA, 3, True).end()
+        run_on_device(len, (), kind=mn.KIND_STEP)
+        proxy.submit_on_device(len, (), kind=mn.KIND_POLL, parent=4)
+        run_on_device(len, ())  # no kind: the default
+"""
+
+
+def test_rt226_kinds_clean(tmp_path):
+    ctxs = _rt226_repo(tmp_path, metrics_src=KIND_DECLS,
+                       usage_src=KIND_USAGE, doc_obs=KIND_TABLE_OK)
+    rep = Reporter()
+    rt226.check_program(ctxs, rep, tmp_path)
+    assert rep.findings == []
+
+
+def test_rt226_kind_drift_every_direction(tmp_path):
+    ctxs = _rt226_repo(
+        tmp_path,
+        metrics_src=STAGE_DECLS + """
+    KIND_STEP = "step"
+    KIND_ORPHAN = "orphan"
+
+    PROXY_KINDS = (
+        KIND_STEP,
+    )
+""",
+        usage_src="""
+            from retina_tpu.utils import metric_names as mn
+            from retina_tpu.utils.device_proxy import run_on_device
+
+            def work(rec):
+                rec.span(mn.STAGE_ALPHA).end()
+                rec.span(mn.STAGE_BETA).end()
+                run_on_device(len, (), kind=mn.KIND_STEP)
+                run_on_device(len, (), kind="fetch")        # literal
+                run_on_device(len, (), kind=mn.KIND_GHOST)  # undeclared
+        """,
+        doc_obs=STAGE_TABLE_OK + """
+            <!-- kind-table-begin -->
+            | `step` | a dispatch |
+            | `phantom` | not a kind |
+            <!-- kind-table-end -->
+        """,
+    )
+    rep = Reporter()
+    rt226.check_program(ctxs, rep, tmp_path)
+    keys = {f.key for f in rep.findings}
+    assert keys == {
+        "RT226:kind-tuple:KIND_ORPHAN",
+        "RT226:kind-unused:KIND_ORPHAN",
+        "RT226:retina_tpu/app.py:kind:fetch",
+        "RT226:retina_tpu/app.py:KIND_GHOST",
+        "RT226:kind-doc-missing:orphan",
+        "RT226:kind-doc-unknown:phantom",
+    }
+
+
+def test_rt226_missing_kind_table(tmp_path):
+    ctxs = _rt226_repo(tmp_path, metrics_src=KIND_DECLS,
+                       usage_src=KIND_USAGE, doc_obs=STAGE_TABLE_OK)
+    rep = Reporter()
+    rt226.check_program(ctxs, rep, tmp_path)
+    assert [f.key for f in rep.findings] == ["RT226:kind-doc:no-table"]
 
 
 def test_rt226_drift_every_direction(tmp_path):
@@ -588,10 +679,10 @@ def test_rt226_drift_every_direction(tmp_path):
         usage_src="""
             from retina_tpu.utils import metric_names as mn
 
-            def work(rec, t0):
-                rec.record(mn.STAGE_ALPHA, t0)
-                rec.record("beta", t0)          # literal
-                rec.record(mn.STAGE_GHOST, t0)  # undeclared
+            def work(rec):
+                rec.span(mn.STAGE_ALPHA).end()
+                rec.span("beta").end()          # literal
+                rec.span(mn.STAGE_GHOST).end()  # undeclared
         """,
         doc_obs="""\
             <!-- stage-table-begin -->
@@ -623,9 +714,9 @@ def test_rt226_missing_stage_table(tmp_path):
         usage_src="""
             from retina_tpu.utils import metric_names as mn
 
-            def work(rec, t0):
-                rec.record(mn.STAGE_ALPHA, t0)
-                rec.record(mn.STAGE_BETA, t0)
+            def work(rec):
+                rec.span(mn.STAGE_ALPHA).end()
+                rec.span(mn.STAGE_BETA).end()
         """,
         doc_obs="no markers here\n",
     )

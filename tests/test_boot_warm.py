@@ -54,12 +54,16 @@ def test_compile_warms_only_steady_state_keys():
     assert len(keys) <= 3, sorted(keys, key=str)
 
 
-def test_background_warm_covers_every_reachable_bucket():
+def test_background_warm_covers_every_reachable_bucket(tmp_path):
     """After bucket_warm_done, any bucket the feed can produce — every
     _wire_bucket(n) for n in [0, coal_cap] — must already be compiled:
     no mid-feed cold compile at any reachable bucket. Live dispatches
-    interleave with the warm (FIFO proxy queue)."""
-    eng = SketchEngine(small_cfg(feed_coalesce_windows=2))
+    interleave with the warm (FIFO proxy queue). A boot writes nothing
+    under ``profile_artifact_dir`` (its default is a fixed path in
+    /tmp): only a ``/debug/profile`` session does."""
+    prof = tmp_path / "profile"
+    eng = SketchEngine(small_cfg(feed_coalesce_windows=2,
+                                 profile_artifact_dir=str(prof)))
     eng.compile()
     t = eng.start_background_warm()
     # Feed while the warm runs: dispatches must interleave, not wedge.
@@ -76,6 +80,11 @@ def test_background_warm_covers_every_reachable_bucket():
         assert ("known", wb) in eng._pad_cache, (n, wb)
     snap = eng.snapshot(max_age_s=0)
     assert int(np.asarray(snap["totals"]).sum()) > 0
+    assert not prof.exists()
+    # The operator scopes are kept in the process, for a reader there.
+    from retina_tpu.parallel.telemetry import op_scope_map
+
+    assert "cms_flow_hh" in op_scope_map()["jit_local_step"].values()
 
 
 def test_background_warm_plain_mode_covers_coalesced_buckets():

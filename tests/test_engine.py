@@ -585,3 +585,101 @@ def test_harvest_thread_retires_and_stays_retired():
     # Straggler after shutdown: must not spawn a fresh thread.
     eng._ensure_harvest_thread()
     assert eng._harvest_thread is old or not eng._harvest_thread.is_alive()
+
+
+def test_step_timer_ends_at_completion_and_snapshot_carries_the_watermark():
+    """The `device_step` span and `tpu_step_seconds` come from the
+    completion thread (dispatch to ready, per step), the stage that
+    times the puts says enqueue, and a snapshot's `events_in` is read
+    where it is dispatched — what the publish watermark rests on."""
+    from retina_tpu.metrics import get_metrics
+    from retina_tpu.utils import metric_names as mn
+
+    cfg = small_cfg()
+    eng = SketchEngine(cfg)
+    eng.update_identities({POD_NET + i: i for i in range(1, 50)})
+    eng.compile()
+    rec = eng._recorder
+    assert not [s for s in rec.spans()
+                if s["stage"] == mn.STAGE_DEVICE_STEP]  # warm-up: none
+    hist = get_metrics().device_step_seconds
+    n0 = sum(b.get() for b in hist._buckets)
+    for _ in range(3):
+        eng.step_records(mk_records(100, src_pods=np.arange(100) % 49 + 1,
+                                    dst_pods=np.full(100, 7)))
+    snap = eng.snapshot(max_age_s=0)
+    assert snap["events_in"] == 300 and snap["steps"] == 3
+    deadline = time.monotonic() + 10.0
+    while time.monotonic() < deadline:
+        steps = [s for s in rec.spans()
+                 if s["stage"] == mn.STAGE_DEVICE_STEP]
+        if len(steps) == 3:
+            break
+        time.sleep(0.01)
+    assert len(steps) == 3
+    assert all(s["thread"] == "device-completion" for s in steps)
+    assert all(s["args"]["n_steps"] == 1 for s in steps)
+    assert sum(b.get() for b in hist._buckets) - n0 == 3
+    by = {}
+    for s in rec.spans():
+        by.setdefault(s["stage"], []).append(s)
+    assert mn.STAGE_TRANSFER_ENQUEUE in by and "transfer" not in by
+    # Each step's spans hang under the proxied call that ran them,
+    # and that call under the dispatch's wire_build.
+    runs = {s["id"]: s for s in by[mn.STAGE_PROXY_RUN]}
+    builds = {s["id"] for s in by[mn.STAGE_WIRE_BUILD]}
+    for s in steps + by[mn.STAGE_TRANSFER_ENQUEUE]:
+        run = runs[s["parent"]]
+        assert run["args"]["kind"] == mn.KIND_STEP
+        assert run["parent"] in builds
+    # The snapshot's own tree: dispatch, fetch (both halves), finish.
+    (snap_span,) = by[mn.STAGE_SNAPSHOT]
+    for stage in (mn.STAGE_SNAPSHOT_DISPATCH, mn.STAGE_SNAPSHOT_FETCH,
+                  mn.STAGE_SNAPSHOT_FINISH):
+        (child,) = by[stage]
+        assert child["parent"] == snap_span["id"]
+    fetch = by[mn.STAGE_SNAPSHOT_FETCH][0]["args"]
+    assert fetch["ready_wait_s"] >= 0 and fetch["copy_s"] > 0
+    # Direct step_records bypass the sink: the snapshot holds more
+    # than the sink ever accepted, so nothing accepted is unheld.
+    assert eng.publish_lag_s(snap) == (0.0, 300)
+    eng.sink.write_records(mk_records(10, [1] * 10, [2] * 10), "t")
+    time.sleep(0.02)
+    lag, held = eng.publish_lag_s({"events_in": 5})
+    assert held == 5 and 0.02 <= lag < 5.0
+
+
+def test_a_dropped_block_is_not_publish_lag_for_ever():
+    """The sink's accepts and `_events_in` are cumulative: a block
+    dropped between the two must be passed over by the watermark, or
+    every later publish reads it as lag."""
+    from retina_tpu.parallel.partition import partition_events
+
+    cfg = small_cfg()
+    eng = SketchEngine(cfg)
+    eng.update_identities({POD_NET + i: i for i in range(1, 50)})
+    eng.compile()
+    blocks = [mk_records(10, [1] * 10, [2] * 10) for _ in range(3)]
+    for b in blocks:
+        eng.sink.write_records(b, "t")
+    time.sleep(0.02)
+    eng.step_records(blocks[0])
+    # The second block is dropped on its way, as the feed's dispatch
+    # drops one while a recovery rebuilds the device state.
+    eng._degraded.set()
+    sb = partition_events(blocks[1], eng.n_devices, cfg.batch_capacity,
+                          min_bucket=cfg.transfer_min_bucket)
+    eng._dispatch_sharded(sb, int(time.time()), len(blocks[1]), sync=False)
+    eng._degraded.clear()
+    # Before the third lands it is the oldest the snapshot lacks ...
+    snap = eng.snapshot(max_age_s=0)
+    assert (snap["events_in"], snap["events_unheld"]) == (10, 10)
+    lag, held = eng.publish_lag_s(snap)
+    assert held == 10 and 0.02 <= lag < 5.0
+    # ... and once it has, nothing accepted is waited for.
+    eng.step_records(blocks[2])
+    snap = eng.snapshot(max_age_s=0)
+    assert (snap["events_in"], snap["events_unheld"]) == (20, 10)
+    assert eng.publish_lag_s(snap) == (0.0, 20)
+    # The counts alone would trail by the dropped block for ever.
+    assert eng.publish_lag_s({"events_in": 20})[0] >= 0.02

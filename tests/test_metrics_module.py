@@ -225,3 +225,58 @@ def test_dirty_pod_sync_to_filtermanager():
     assert done.wait(2.0)
     assert fm.has_ip(ip_to_u32("10.1.2.3"))
     ps.shutdown()
+
+
+def test_publish_cycle_is_a_span_tree_and_observes_its_lag():
+    """One publish_once: pod_publish with series_publish under it, the
+    watermark's lag observed once and events_included on the span."""
+    from retina_tpu.metrics import get_metrics
+    from retina_tpu.obs.recorder import get_recorder, initialize_recorder
+    from retina_tpu.utils import metric_names as mn
+
+    eng = FakeEngine()
+    eng.snap["events_in"] = 1234
+    seen = []
+
+    def publish_lag_s(snap):
+        seen.append(snap["events_in"])
+        return 2.5, snap["events_in"]
+
+    eng.publish_lag_s = publish_lag_s
+    mm, _ = build_module(eng)
+    hist = get_metrics().publish_lag_seconds
+    s0 = hist._sum.get()
+    old = get_recorder()
+    rec = initialize_recorder(capacity=64)
+    try:
+        mm.publish_once()
+        spans = {s["stage"]: s for s in rec.spans()}
+    finally:
+        initialize_recorder(capacity=old.capacity, enabled=old.enabled)
+    assert seen == [1234]
+    assert hist._sum.get() - s0 == 2.5
+    pub = spans[mn.STAGE_POD_PUBLISH]
+    assert pub["args"] == {"events_included": 1234, "lag_ms": 2500.0}
+    assert spans[mn.STAGE_SERIES_PUBLISH]["parent"] == pub["id"]
+    assert pub["trace_id"] == spans[mn.STAGE_SERIES_PUBLISH]["trace_id"] > 0
+
+
+def test_publish_without_a_watermark_still_publishes():
+    """An engine with no sink (a test double) has no lag to observe:
+    the cycle publishes and its span carries no watermark."""
+    from retina_tpu.obs.recorder import get_recorder, initialize_recorder
+    from retina_tpu.utils import metric_names as mn
+
+    eng = FakeEngine()
+    mm, cache = build_module(eng)
+    eng.snap["pod_forward"][cache.get_index("default/web-0"), 0] = (3, 30)
+    old = get_recorder()
+    rec = initialize_recorder(capacity=64)
+    try:
+        mm.publish_once()
+        (pub,) = [s for s in rec.spans()
+                  if s["stage"] == mn.STAGE_POD_PUBLISH]
+    finally:
+        initialize_recorder(capacity=old.capacity, enabled=old.enabled)
+    assert pub["args"] == {}
+    assert 'podname="web-0"' in adv_text()

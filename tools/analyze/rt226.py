@@ -1,24 +1,32 @@
-"""RT226 — recorder span-name drift (whole-program).
+"""RT226 — recorder span-name and proxy-kind drift (whole-program).
 
 The contract (the RT220 analog for the flight recorder): the
 ``STAGE_*`` constants in ``utils/metric_names.py`` are the single
-registry of pipeline stage names; every span emitted through
-``FlightRecorder.record`` resolves to a registry constant; the stage
+registry of pipeline stage names; every span opened through
+``FlightRecorder.span`` resolves to a registry constant; the stage
 table in ``docs/observability.md`` (between the ``stage-table-begin``/
 ``stage-table-end`` markers) lists every stage and mentions no stage
-that does not exist. Drift in any direction is a finding:
+that does not exist. The device proxy's call kinds are held the same
+way: ``KIND_*`` constants, the ``PROXY_KINDS`` tuple, the ``kind=`` of
+every ``run_on_device``/``submit_on_device`` call, and the kind table
+(``kind-table-begin``/``kind-table-end``). Drift in any direction is a
+finding:
 
-  RT226 span recorded under a stage not declared in the registry
+  RT226 span opened under a stage not declared in the registry
         (string literal, unknown STAGE_* reference, or a registry
         constant missing from the STAGES tuple);
-        a registry stage never emitted through any recorder; or
-        the docs/observability.md stage table out of sync with the
-        registry (either direction).
+        a registry stage never emitted through any recorder;
+        a proxied call whose kind is a literal or an undeclared
+        KIND_* reference, a KIND_* constant missing from
+        PROXY_KINDS, or a registry kind no proxied call names (a
+        kind is added with its first call site); or
+        a docs/observability.md table out of sync with its registry
+        (either direction).
 
-Scope: ``record(...)`` calls under ``retina_tpu/`` whose first
-argument is a string literal or a ``STAGE_``-prefixed name — other
-``.record(...)`` methods (different first-arg shapes) are out of
-scope by construction.
+Scope: calls under ``retina_tpu/`` whose first argument is a
+``STAGE_``-prefixed name (``rec.span(...)`` and helpers that forward
+to it), ``.span("literal", ...)`` calls, and the ``kind=`` keyword of
+the two proxy entry points.
 """
 
 from __future__ import annotations
@@ -33,28 +41,31 @@ METRIC_NAMES_REL = "retina_tpu/utils/metric_names.py"
 DOC_REL = "docs/observability.md"
 TABLE_BEGIN = "<!-- stage-table-begin -->"
 TABLE_END = "<!-- stage-table-end -->"
+KIND_TABLE_BEGIN = "<!-- kind-table-begin -->"
+KIND_TABLE_END = "<!-- kind-table-end -->"
 DOC_STAGE_RE = re.compile(r"`([a-z0-9_]+)`")
+PROXY_FUNCS = {"run_on_device", "submit_on_device"}
 
 
-def _stage_registry(ctx: FileCtx) -> dict[str, tuple[str, int]]:
-    """STAGE_* const name -> (stage string, decl lineno)."""
+def _registry(ctx: FileCtx, prefix: str) -> dict[str, tuple[str, int]]:
+    """``prefix``* const name -> (string value, decl lineno)."""
     out: dict[str, tuple[str, int]] = {}
     for stmt in ctx.tree.body:
         if (isinstance(stmt, ast.Assign) and len(stmt.targets) == 1
                 and isinstance(stmt.targets[0], ast.Name)
-                and stmt.targets[0].id.startswith("STAGE_")
+                and stmt.targets[0].id.startswith(prefix)
                 and isinstance(stmt.value, ast.Constant)
                 and isinstance(stmt.value.value, str)):
             out[stmt.targets[0].id] = (stmt.value.value, stmt.lineno)
     return out
 
 
-def _stages_tuple(ctx: FileCtx) -> set[str]:
-    """Constant names listed in the ordered STAGES tuple."""
+def _names_tuple(ctx: FileCtx, name: str) -> set[str]:
+    """Constant names listed in the ordered tuple ``name``."""
     for stmt in ctx.tree.body:
         if (isinstance(stmt, ast.Assign) and len(stmt.targets) == 1
                 and isinstance(stmt.targets[0], ast.Name)
-                and stmt.targets[0].id == "STAGES"
+                and stmt.targets[0].id == name
                 and isinstance(stmt.value, ast.Tuple)):
             return {
                 e.id for e in stmt.value.elts if isinstance(e, ast.Name)
@@ -62,14 +73,30 @@ def _stages_tuple(ctx: FileCtx) -> set[str]:
     return set()
 
 
+def _const_name(node: ast.AST) -> str | None:
+    if isinstance(node, ast.Attribute):
+        return node.attr
+    if isinstance(node, ast.Name):
+        return node.id
+    return None
+
+
 def check_program(ctxs: list[FileCtx], rep: Reporter, root: Path) -> None:
     by_rel = {c.rel: c for c in ctxs}
     mn_ctx = by_rel.get(METRIC_NAMES_REL)
     if mn_ctx is None:
         return
-    registry = _stage_registry(mn_ctx)  # const name -> (value, lineno)
+    registry = _registry(mn_ctx, "STAGE_")  # const -> (value, lineno)
     values = {v for v, _ in registry.values()}
-    in_tuple = _stages_tuple(mn_ctx)
+    in_tuple = _names_tuple(mn_ctx, "STAGES")
+    kinds = _registry(mn_ctx, "KIND_")
+    kinds_in_tuple = _names_tuple(mn_ctx, "PROXY_KINDS")
+    for name, (value, lineno) in sorted(kinds.items()):
+        if name not in kinds_in_tuple:
+            rep.add(mn_ctx, lineno, "RT226",
+                    f"proxy kind constant {name} (\"{value}\") is "
+                    "missing from the PROXY_KINDS tuple",
+                    key=f"RT226:kind-tuple:{name}")
 
     # A declared constant absent from the ordered STAGES tuple never
     # gets its histogram child pre-ordered in stage_report — drift.
@@ -80,34 +107,34 @@ def check_program(ctxs: list[FileCtx], rep: Reporter, root: Path) -> None:
                     "from the STAGES tuple",
                     key=f"RT226:tuple:{name}")
 
-    # --- emission sites: record(<stage>, ...) under retina_tpu/ ------
+    # --- emission sites under retina_tpu/: span(<stage>, ...) and the
+    # kind= of proxied calls -------------------------------------------
     emitted: set[str] = set()
+    kinds_used: set[str] = set()
     prod = [
         c for c in ctxs
         if c.rel.startswith("retina_tpu/") and c.rel != METRIC_NAMES_REL
     ]
     for ctx in prod:
         for node in ast.walk(ctx.tree):
-            if not (isinstance(node, ast.Call) and node.args):
+            if not isinstance(node, ast.Call):
                 continue
             func = node.func
-            if not (isinstance(func, ast.Attribute)
-                    and func.attr == "record"):
+            if _const_name(func) in PROXY_FUNCS:
+                _check_kind(ctx, node, kinds, kinds_used, rep)
+            if not node.args:
                 continue
             arg = node.args[0]
             if isinstance(arg, ast.Constant) and isinstance(arg.value, str):
-                rep.add(ctx, node.lineno, "RT226",
-                        f'span "{arg.value}" recorded from a literal — '
-                        "use the utils.metric_names STAGE_ constant",
-                        key=f"RT226:{ctx.rel}:{arg.value}")
+                if isinstance(func, ast.Attribute) and func.attr == "span":
+                    rep.add(ctx, node.lineno, "RT226",
+                            f'span "{arg.value}" opened from a literal — '
+                            "use the utils.metric_names STAGE_ constant",
+                            key=f"RT226:{ctx.rel}:{arg.value}")
                 continue
-            const = None
-            if isinstance(arg, ast.Attribute):
-                const = arg.attr
-            elif isinstance(arg, ast.Name):
-                const = arg.id
+            const = _const_name(arg)
             if const is None or not const.startswith("STAGE_"):
-                continue  # some other .record() method — out of scope
+                continue  # not a span site — out of scope
             if const not in registry:
                 rep.add(ctx, node.lineno, "RT226",
                         f"span constant {const} is not declared in "
@@ -123,8 +150,15 @@ def check_program(ctxs: list[FileCtx], rep: Reporter, root: Path) -> None:
                     f"stage constant {name} (\"{value}\") is never "
                     "emitted through a recorder span",
                     key=f"RT226:unused:{name}")
+    for name, (value, lineno) in sorted(kinds.items()):
+        if name not in kinds_used:
+            rep.add(mn_ctx, lineno, "RT226",
+                    f"proxy kind constant {name} (\"{value}\") is "
+                    "named by no proxied call — add a kind with its "
+                    "first call site",
+                    key=f"RT226:kind-unused:{name}")
 
-    # --- docs/observability.md stage table, two-way ------------------
+    # --- docs/observability.md stage and kind tables, two-way --------
     doc_path = root / DOC_REL
     doc_lines = (
         doc_path.read_text().splitlines() if doc_path.exists() else []
@@ -137,13 +171,51 @@ def check_program(ctxs: list[FileCtx], rep: Reporter, root: Path) -> None:
     doc_ctx.tree = None
     doc_ctx.syntax_error = None
 
-    table: dict[str, int] = {}  # stage token -> doc lineno
+    _check_table(doc_ctx, rep, "stage", values, TABLE_BEGIN, TABLE_END,
+                 "doc")
+    if kinds:
+        _check_table(doc_ctx, rep, "proxy kind",
+                     {v for v, _ in kinds.values()}, KIND_TABLE_BEGIN,
+                     KIND_TABLE_END, "kind-doc")
+
+
+def _check_kind(ctx: FileCtx, node: ast.Call, kinds: dict,
+                used: set[str], rep: Reporter) -> None:
+    """The ``kind=`` of one run_on_device/submit_on_device call; the
+    registry kinds it names go into ``used``."""
+    for kw in node.keywords:
+        if kw.arg != "kind":
+            continue
+        if (isinstance(kw.value, ast.Constant)
+                and isinstance(kw.value.value, str)):
+            rep.add(ctx, node.lineno, "RT226",
+                    f'proxied call of kind "{kw.value.value}" from a '
+                    "literal — use the utils.metric_names KIND_ constant",
+                    key=f"RT226:{ctx.rel}:kind:{kw.value.value}")
+            continue
+        const = _const_name(kw.value)
+        if const is None or not const.startswith("KIND_"):
+            continue
+        if const in kinds:
+            used.add(const)
+        else:
+            rep.add(ctx, node.lineno, "RT226",
+                    f"proxy kind constant {const} is not declared in "
+                    "utils/metric_names.py",
+                    key=f"RT226:{ctx.rel}:{const}")
+
+
+def _check_table(doc_ctx: FileCtx, rep: Reporter, what: str,
+                 values: set[str], begin: str, end: str,
+                 key: str) -> None:
+    """One registry against its table in the doc, both directions."""
+    table: dict[str, int] = {}  # token -> doc lineno
     inside = False
-    for i, line in enumerate(doc_lines, start=1):
-        if TABLE_BEGIN in line:
+    for i, line in enumerate(doc_ctx.lines, start=1):
+        if begin in line:
             inside = True
             continue
-        if TABLE_END in line:
+        if end in line:
             inside = False
             continue
         if inside:
@@ -153,19 +225,19 @@ def check_program(ctxs: list[FileCtx], rep: Reporter, root: Path) -> None:
 
     if not table:
         rep.add(doc_ctx, 1, "RT226",
-                f"{DOC_REL} has no stage table between the "
-                f"{TABLE_BEGIN} / {TABLE_END} markers",
-                key="RT226:doc:no-table")
+                f"{DOC_REL} has no {what} table between the "
+                f"{begin} / {end} markers",
+                key=f"RT226:{key}:no-table")
         return
     for value in sorted(values):
         if value not in table:
             rep.add(doc_ctx, 1, "RT226",
-                    f'stage "{value}" has no row in the {DOC_REL} '
-                    "stage table",
-                    key=f"RT226:doc-missing:{value}")
+                    f'{what} "{value}" has no row in the {DOC_REL} '
+                    f"{what} table",
+                    key=f"RT226:{key}-missing:{value}")
     for tok, lineno in sorted(table.items()):
         if tok not in values:
             rep.add(doc_ctx, lineno, "RT226",
-                    f'{DOC_REL} stage table mentions "{tok}" which is '
+                    f'{DOC_REL} {what} table mentions "{tok}" which is '
                     "not declared in utils/metric_names.py",
-                    key=f"RT226:doc-unknown:{tok}")
+                    key=f"RT226:{key}-unknown:{tok}")

@@ -104,8 +104,8 @@ STRUCTURAL_ROOTS = (
      "close"),
     ("retina_tpu/parallel/feed.py", "FeedWorker", "_loop", "event"),
     ("retina_tpu/parallel/feed.py", "FeedWorker", "push", "event"),
-    ("retina_tpu/obs/recorder.py", "FlightRecorder", "begin", "event"),
-    ("retina_tpu/obs/recorder.py", "FlightRecorder", "record", "event"),
+    ("retina_tpu/obs/recorder.py", "FlightRecorder", "span", "event"),
+    ("retina_tpu/obs/recorder.py", "Span", "end", "event"),
     ("retina_tpu/fleet/shipper.py", "SnapshotShipper", "offer", "close"),
     ("retina_tpu/timetravel/ring.py", "SnapshotRing", "offer", "close"),
     ("retina_tpu/fleet/aggregator.py", "FleetAggregator", "ingest",
