@@ -14,11 +14,12 @@ the HTTP server re-register its handler like the reference does.
 from __future__ import annotations
 
 import threading
-from typing import Callable, Iterable
+from typing import Callable
 
 from prometheus_client import CollectorRegistry, Counter, Gauge, Histogram
 
 from retina_tpu.log import logger
+from retina_tpu.utils import metric_names as mn
 
 _log = logger("exporter")
 
@@ -135,12 +136,37 @@ class Exporter:
         self.hubble_registry = CollectorRegistry()
         self._reset_cbs: list[Callable[[], None]] = []
         self._lock = threading.Lock()
+        # The advanced registry is written in bursts (a publish cycle of
+        # the metrics module every 1-5 s, a reconcile) and gathered far
+        # more often, at ~0.5 s of Python for 35k pod-level series.
+        # Every change to it advances the generation; a publisher that
+        # has finished a cycle also declares the generation published,
+        # and any other change takes that word back.
+        # gather() keeps the bytes it rendered from a published
+        # generation and reuses them for as long as that generation
+        # stands. Until a publisher speaks (tests and doubles that set
+        # gauges directly, basic mode, the operator) every gather
+        # renders, so a direct write is read back at once.
+        self._adv_gen = 0
+        self._adv_published = False
+        self._adv_rendered: tuple[int, bytes] = (-1, b"")  # none kept
+        gathers = self.new_counter(
+            mn.TPU_EXPOSITION_GATHERS, [mn.L_ADVANCED],
+            "gathers of the combined exposition, by what they did with "
+            "the advanced (pod-level) registry",
+        )
+        self._gathers = {
+            how: gathers.labels(advanced=how)
+            for how in (mn.ADVANCED_RENDERED, mn.ADVANCED_REUSED)
+        }
 
     # -- reset (prometheusexporter.go:35-40) --
     def reset_advanced(self) -> None:
         """Replace the advanced registry (CRD reconcile changed metrics)."""
         with self._lock:
             self.advanced_registry = CollectorRegistry()
+            self._advanced_changed()
+            self._adv_rendered = (-1, b"")
             cbs = list(self._reset_cbs)
         _log.info("advanced metrics registry reset")
         for cb in cbs:
@@ -150,9 +176,26 @@ class Exporter:
         with self._lock:
             self._reset_cbs.append(cb)
 
+    def _advanced_changed(self) -> None:
+        """Caller holds ``_lock``."""
+        self._adv_gen += 1
+        self._adv_published = False
+
+    def advanced_published(self) -> None:
+        """A publisher finished a cycle of writes to the advanced
+        registry: the next gather renders it, and the gathers after
+        that reuse those bytes until the next change. Called where the
+        writes END: declared before them, a gather could keep half of
+        them under the new generation."""
+        with self._lock:
+            self._adv_gen += 1
+            self._adv_published = True
+
     # -- combined gatherer (prometheusexporter.go:17-33) --
-    def gather_text(self) -> bytes:
-        """Prometheus text exposition of both registries.
+    def gather(self) -> tuple[bytes, str]:
+        """Prometheus text exposition of both registries, and what was
+        done with the advanced one: ``mn.ADVANCED_RENDERED`` or
+        ``mn.ADVANCED_REUSED``.
 
         Rendered by :func:`render_exposition`, not prometheus_client's
         generate_latest: at production cardinality (~30k pod-level
@@ -160,13 +203,36 @@ class Exporter:
         ~1.1s per render on one core — over half the agent's CPU under
         scrape load. The fast path emits the same text format ~10x
         cheaper; a round-trip test pins it byte-compatible.
+
+        The default registry (the agent's own counters, histograms and
+        health series, which change all the time) is rendered on every
+        call. The advanced registry is rendered unless bytes are kept
+        for its generation. The generation is read BEFORE the render
+        and kept with the bytes, and only a published generation that
+        still stands when the render ends is kept: a publish that lands
+        mid-render costs one more render, never a lost update, and a
+        render that overlapped a half-written cycle is replaced by the
+        one after that cycle's declaration.
         """
         with self._lock:
-            regs: Iterable[CollectorRegistry] = (
-                self.default_registry,
-                self.advanced_registry,
-            )
-        return b"".join(render_exposition(r) for r in regs)
+            default, advanced = self.default_registry, self.advanced_registry
+            gen, published = self._adv_gen, self._adv_published
+            kept_gen, adv_bytes = self._adv_rendered
+        how = mn.ADVANCED_REUSED if kept_gen == gen else mn.ADVANCED_RENDERED
+        # Counted before the default registry is rendered: a gather's
+        # own count is in its bytes.
+        self._gathers[how].inc()
+        if how == mn.ADVANCED_RENDERED:
+            adv_bytes = render_exposition(advanced)
+            if published:
+                with self._lock:
+                    if self._adv_gen == gen:
+                        self._adv_rendered = (gen, adv_bytes)
+        return render_exposition(default) + adv_bytes, how
+
+    def gather_text(self) -> bytes:
+        """The exposition alone (see :meth:`gather`)."""
+        return self.gather()[0]
 
     # -- constructor helpers (prometheusexporter.go:46-88) --
     def new_gauge(self, name: str, labels: list[str], help_: str = "") -> Gauge:
@@ -203,17 +269,22 @@ class Exporter:
             name, help_ or name, labels, registry=self.hubble_registry
         )
 
+    # A new family is a change too (its HELP/TYPE lines appear):
+    # registered and the generation advanced under the one lock
+    # gather() reads registry and generation under.
     def new_adv_gauge(self, name: str, labels: list[str], help_: str = "") -> Gauge:
         with self._lock:
-            reg = self.advanced_registry
-        return Gauge(name, help_ or name, labels, registry=reg)
+            self._advanced_changed()
+            return Gauge(name, help_ or name, labels,
+                         registry=self.advanced_registry)
 
     def new_adv_counter(
         self, name: str, labels: list[str], help_: str = ""
     ) -> Counter:
         with self._lock:
-            reg = self.advanced_registry
-        return Counter(name, help_ or name, labels, registry=reg)
+            self._advanced_changed()
+            return Counter(name, help_ or name, labels,
+                           registry=self.advanced_registry)
 
 
 _singleton: Exporter | None = None

@@ -159,8 +159,15 @@ class MetricsModule:
             window_epoch(self.cfg.window_seconds),
         ) as span:
             snap = self.engine.snapshot()
-            with rec.span(mn.STAGE_SERIES_PUBLISH, span.trace_id):
-                self._publish_series(metrics, spec, snap)
+            try:
+                with rec.span(mn.STAGE_SERIES_PUBLISH, span.trace_id):
+                    self._publish_series(metrics, spec, snap)
+            finally:
+                # The one place the advanced registry's values are
+                # written ends here, whatever a metric object raised:
+                # until now the exporter serves the previous cycle's
+                # bytes, from now the first gather renders this one's.
+                self.exporter.advanced_published()
             # The watermark (engine.publish_lag_s): how far behind the
             # sink's accepts this cycle's series are, now that they are
             # out. An engine without a sink (test doubles) has none.

@@ -45,7 +45,8 @@ class Server:
         host, _, port = addr.rpartition(":")
         self._host, self._port = host or "127.0.0.1", int(port)
         self._exporter = exporter or get_exporter()
-        self._gather = gather or self._exporter.gather_text
+        # None: the exporter's combined gatherer.
+        self._gather = gather
         self._ready = ready_check or (lambda: True)
         self._healthy = healthy_check or (lambda: True)
         self._vars: dict[str, Callable[[], object]] = {}
@@ -65,13 +66,17 @@ class Server:
         self._httpd: ThreadingHTTPServer | None = None
         self._thread: threading.Thread | None = None
         # Rendering ~50k pod-level series is Python-heavy (~0.5s at 2k
-        # pods); gauges only change at the metrics module's >=1s publish
-        # cadence, so a render cache is lossless. On TTL expiry the
-        # scrape serves the STALE body and kicks a background re-render:
-        # scrape latency never includes a render (measured p99 3.7s when
-        # it did — VERDICT r3 weak #2) — a scrape sees series at most one
-        # scrape interval plus one render older than live. 0 disables
-        # (render inline, uncached).
+        # pods), and they change only when the metrics module finishes
+        # a publish cycle: the exporter keeps those bytes per publish
+        # (Exporter.gather), so most renders here cost the default
+        # registry and a join. This cache holds the whole body for a
+        # TTL, which bounds how often the default registry is rendered
+        # and how soon a finished publish is picked up. On TTL expiry
+        # the scrape serves the STALE body and kicks a background
+        # re-render: scrape latency never includes a render (measured
+        # p99 3.7s when it did — VERDICT r3 weak #2) — a scrape sees
+        # series at most one scrape interval plus one render older than
+        # live. 0 disables (render inline, uncached).
         self._cache_ttl = metrics_cache_ttl_s
         self._cache_lock = threading.Lock()
         self._cache_body: bytes = b""
@@ -89,11 +94,18 @@ class Server:
         self._stale_since: float | None = None
 
     def _timed_gather(self) -> bytes:
-        """One render of the exposition, as a ``render`` span. It runs
-        on the render thread, or on a handler thread that lives for one
-        request (first render, or no cache): hence the shared ring."""
-        with get_recorder().span(mn.STAGE_RENDER, shared=True):
-            return self._gather()
+        """One render of the exposition, as a ``render`` span whose
+        ``advanced`` argument says whether the exporter rendered the
+        pod-level bytes or reused them (a gatherer of the caller's own
+        has nothing to say). It runs on the render thread, or on a
+        handler thread that lives for one request (first render, or no
+        cache): hence the shared ring."""
+        with get_recorder().span(mn.STAGE_RENDER, shared=True) as span:
+            if self._gather is not None:
+                return self._gather()
+            body, how = self._exporter.gather()
+            span.set(advanced=how)
+            return body
 
     def _render(self) -> bytes:
         body = self._timed_gather()

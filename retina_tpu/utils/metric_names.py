@@ -291,6 +291,16 @@ TPU_PROXY_WAIT_SECONDS = PREFIX + "tpu_proxy_wait_seconds"
 TPU_PROXY_RUN_SECONDS = PREFIX + "tpu_proxy_run_seconds"
 TPU_PROXY_QUEUE_DEPTH = PREFIX + "tpu_proxy_queue_depth"
 TPU_PUBLISH_LAG_SECONDS = PREFIX + "tpu_publish_lag_seconds"
+# Gathers of the combined exposition (exporter.Exporter.gather) by what
+# they did with the advanced registry: "rendered" once after every
+# publish cycle and on every gather while no publisher has declared the
+# registry's state complete, "reused" when the bytes kept from that one
+# render were joined to the default registry's fresh ones. The `render`
+# span carries the same word under the same key.
+TPU_EXPOSITION_GATHERS = PREFIX + "tpu_exposition_gathers_counter"
+L_ADVANCED = "advanced"
+ADVANCED_RENDERED = "rendered"
+ADVANCED_REUSED = "reused"
 
 # Pipeline stage-name registry (the ONLY legal values of the
 # tpu_stage_seconds `stage` label and of every recorder span). The

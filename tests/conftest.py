@@ -28,6 +28,7 @@ if "xla_force_host_platform_device_count" not in _flags:
 # the reset is cheap and global state bleed between tests is never wanted.
 import pytest  # noqa: E402
 
+from retina_tpu.exporter import render_exposition as _real_render  # noqa: E402
 from retina_tpu.exporter import reset_for_tests as _reset_exporter  # noqa: E402
 from retina_tpu.metrics import reset_for_tests as _reset_metrics  # noqa: E402
 
@@ -37,3 +38,41 @@ def _fresh_metric_singletons():
     _reset_exporter()
     _reset_metrics()
     yield
+
+
+class CountingRender:
+    """Stand-in for ``exporter.render_exposition``: the real bytes,
+    counted by registry."""
+
+    def __init__(self, real):
+        self._real = real
+        self.calls: list = []
+
+    def __call__(self, registry) -> bytes:
+        self.calls.append(registry)
+        return self._real(registry)
+
+    def count(self, registry) -> int:
+        return sum(1 for r in self.calls if r is registry)
+
+
+@pytest.fixture
+def counting_render(monkeypatch):
+    """Counts the exporter's renders by registry from here on."""
+    import retina_tpu.exporter as exporter_mod
+
+    render = CountingRender(_real_render)
+    monkeypatch.setattr(exporter_mod, "render_exposition", render)
+    return render
+
+
+@pytest.fixture
+def fresh_exposition():
+    """What rendering both registries of an exporter afresh gives (by
+    the real renderer, uncounted)."""
+
+    def fresh(ex) -> bytes:
+        return (_real_render(ex.default_registry)
+                + _real_render(ex.advanced_registry))
+
+    return fresh

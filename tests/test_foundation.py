@@ -165,6 +165,284 @@ def test_exporter_registries_and_reset():
     assert fired == [1]
 
 
+# ---------------------------------- pod-level bytes, once per publish
+def _gather_counts(ex: Exporter) -> dict[str, float]:
+    from retina_tpu.utils import metric_names as mn
+
+    return {
+        how: ex.default_registry.get_sample_value(
+            mn.TPU_EXPOSITION_GATHERS + "_total", {mn.L_ADVANCED: how})
+        for how in (mn.ADVANCED_REUSED, mn.ADVANCED_RENDERED)
+    }
+
+
+def test_published_generation_is_rendered_once(
+        counting_render, fresh_exposition):
+    """(a) With a publisher-declared generation, gathers render the
+    advanced registry once and the default registry every time, and
+    every body is what rendering both registries gives."""
+    ex = Exporter()
+    adv = ex.new_adv_gauge("gen_adv_gauge", ["pod"])
+    for i in range(40):
+        adv.labels(pod=f"p{i}").set(i)
+    ex.advanced_published()
+    render = counting_render
+    bodies = [ex.gather() for _ in range(4)]
+    assert [how for _, how in bodies] == [
+        "rendered", "reused", "reused", "reused"]
+    assert render.count(ex.advanced_registry) == 1
+    assert render.count(ex.default_registry) == 4
+    # Identical but for the gather counter's own two lines.
+    own = b"tpu_exposition_gathers_counter_total{"
+    reused = [[ln for ln in body.splitlines() if own not in ln]
+              for body, _ in bodies[1:]]
+    assert reused[0] == reused[1] == reused[2]
+    assert ex.gather_text() == fresh_exposition(ex)
+    assert render.count(ex.advanced_registry) == 1
+
+
+def test_publish_that_changes_one_series_shows_in_next_gather(
+        counting_render):
+    """(b) One series changed and the generation declared: the next
+    gather carries it, at the cost of one render."""
+    ex = Exporter()
+    adv = ex.new_adv_gauge("pub_adv_gauge", ["pod"])
+    adv.labels(pod="a").set(1)
+    adv.labels(pod="b").set(1)
+    ex.advanced_published()
+    render = counting_render
+    assert b'pub_adv_gauge{pod="a"} 1.0' in ex.gather_text()
+    assert b'pub_adv_gauge{pod="a"} 1.0' in ex.gather_text()
+    adv.labels(pod="a").set(2)
+    # Mid-cycle: the previous complete publish is what is served.
+    assert b'pub_adv_gauge{pod="a"} 1.0' in ex.gather_text()
+    ex.advanced_published()
+    for _ in range(3):
+        body = ex.gather_text()
+        assert b'pub_adv_gauge{pod="a"} 2.0' in body
+        assert b'pub_adv_gauge{pod="b"} 1.0' in body
+    assert render.count(ex.advanced_registry) == 2
+
+
+@pytest.mark.parametrize("change", ["reset", "new_gauge", "new_counter"])
+def test_reset_and_new_family_invalidate_kept_bytes(
+        change, fresh_exposition):
+    """(c) A reset drops the kept bytes at once, and so does a newly
+    registered family; neither is a publisher's declaration, so every
+    gather renders until the next one."""
+    ex = Exporter()
+    ex.new_adv_gauge("inv_adv_gauge", []).set(7)
+    ex.advanced_published()
+    assert ex.gather()[1] == "rendered"
+    assert ex.gather()[1] == "reused"
+    if change == "reset":
+        ex.reset_advanced()
+        assert ex._adv_rendered == (-1, b"")
+        body, how = ex.gather()
+        assert b"inv_adv_gauge" not in body
+    elif change == "new_gauge":
+        ex.new_adv_gauge("inv_new_gauge", []).set(1)
+        body, how = ex.gather()
+        assert b"inv_new_gauge 1.0" in body and b"inv_adv_gauge 7.0" in body
+    else:
+        ex.new_adv_counter("inv_new_counter", []).inc(3)
+        body, how = ex.gather()
+        assert b"inv_new_counter_total 3.0" in body
+    assert how == "rendered"
+    assert ex.gather()[1] == "rendered"  # nobody declared this state
+    assert ex.gather_text() == fresh_exposition(ex)
+    ex.advanced_published()
+    assert [ex.gather()[1] for _ in range(2)] == ["rendered", "reused"]
+    assert ex.gather_text() == fresh_exposition(ex)
+
+
+def test_publish_landing_during_a_render_is_rendered_next(monkeypatch):
+    """(d) The generation is read BEFORE the render and kept with the
+    bytes: a publish that ends while a render is in flight (held here
+    on an event) makes the next gather render again instead of being
+    lost under the bytes the first render would have kept."""
+    import retina_tpu.exporter as exporter_mod
+
+    ex = Exporter()
+    adv = ex.new_adv_gauge("race_adv_gauge", [])
+    adv.set(1)
+    ex.advanced_published()
+    real = exporter_mod.render_exposition
+    in_render, release = threading.Event(), threading.Event()
+    renders = []
+
+    def held(registry) -> bytes:
+        body = real(registry)
+        if registry is ex.advanced_registry:
+            renders.append(body)
+            if len(renders) == 1:
+                in_render.set()
+                assert release.wait(10.0)
+        return body
+
+    got = []
+    monkeypatch.setattr(exporter_mod, "render_exposition", held)
+    try:
+        t = threading.Thread(target=lambda: got.append(ex.gather()))
+        t.start()
+        assert in_render.wait(10.0)
+        adv.set(2)  # the publisher's cycle lands mid-render
+        ex.advanced_published()
+        release.set()
+        t.join(10.0)
+        assert not t.is_alive()
+        assert b"race_adv_gauge 1.0" in got[0][0]
+        assert got[0][1] == "rendered"
+        second, third = ex.gather(), ex.gather()
+    finally:
+        release.set()
+    assert b"race_adv_gauge 2.0" in second[0] and second[1] == "rendered"
+    assert b"race_adv_gauge 2.0" in third[0] and third[1] == "reused"
+    assert len(renders) == 2
+
+
+def test_exporter_nobody_publishes_into_reads_back_direct_writes(
+        counting_render):
+    """(e) Reuse engages only for a generation a publisher declared:
+    without one, a gauge set directly is in the very next gather."""
+    ex = Exporter()
+    adv = ex.new_adv_gauge("direct_adv_gauge", [])
+    render = counting_render
+    for v in range(4):
+        adv.set(v)
+        body, how = ex.gather()
+        assert f"direct_adv_gauge {float(v)}".encode() in body
+        assert how == "rendered"
+    assert render.count(ex.advanced_registry) == 4
+    assert _gather_counts(ex) == {"reused": 0.0, "rendered": 4.0}
+
+
+def test_default_registry_is_live_while_advanced_bytes_are_reused():
+    """(f) The agent's own series change between publishes and show in
+    every gather."""
+    ex = Exporter()
+    ex.new_adv_gauge("live_adv_gauge", []).set(5)
+    live = ex.new_gauge("live_default_gauge", [])
+    ex.advanced_published()
+    for i in range(4):
+        live.set(i)
+        body, how = ex.gather()
+        assert how == ("reused" if i else "rendered")
+        assert f"live_default_gauge {float(i)}".encode() in body
+        assert b"live_adv_gauge 5.0" in body
+    # A gather counts itself before it renders: its body holds the count.
+    assert (b'tpu_exposition_gathers_counter_total{advanced="reused"} 4.0'
+            in ex.gather_text())
+
+
+@pytest.mark.parametrize("ttl", [0, 60.0])
+def test_render_span_and_counter_say_reused_or_rendered(ttl):
+    """(g) The server's ``render`` span carries ``advanced`` and the
+    counter counts both, inline (TTL 0) and through the render cache; a
+    server with a gatherer of its own has nothing to say."""
+    from retina_tpu.obs.recorder import get_recorder, initialize_recorder
+    from retina_tpu.utils import metric_names as mn
+
+    ex = Exporter()
+    adv = ex.new_adv_gauge("span_adv_gauge", [])
+    adv.set(1)
+    old = get_recorder()
+    rec = initialize_recorder(capacity=64)
+    try:
+        srv = Server("127.0.0.1:0", exporter=ex, metrics_cache_ttl_s=ttl)
+
+        def scrape() -> bytes:
+            srv._cache_time = 0.0  # expire the body cache, if any
+            with srv._cache_lock:
+                srv._cache_body = b""
+            return srv._metrics_body()
+
+        assert b"span_adv_gauge 1.0" in scrape()  # nobody published yet
+        ex.advanced_published()
+        for _ in range(3):
+            assert b"span_adv_gauge 1.0" in scrape()
+        adv.set(2)
+        ex.advanced_published()
+        assert b"span_adv_gauge 2.0" in scrape()
+        own = Server("127.0.0.1:0", gather=lambda: b"up 1\n",
+                     metrics_cache_ttl_s=ttl)
+        assert own._metrics_body() == b"up 1\n"
+        args = [s["args"] for s in rec.spans()
+                if s["stage"] == mn.STAGE_RENDER]
+    finally:
+        initialize_recorder(capacity=old.capacity, enabled=old.enabled)
+    assert args == [
+        {"advanced": "rendered"}, {"advanced": "rendered"},
+        {"advanced": "reused"}, {"advanced": "reused"},
+        {"advanced": "rendered"}, {},
+    ]
+    assert _gather_counts(ex) == {"reused": 2.0, "rendered": 3.0}
+
+
+def test_concurrent_gathers_never_keep_stale_bytes_under_a_new_generation():
+    """A publisher writes every series and declares the cycle, cycle
+    after cycle, while more gatherers than cores gather: whatever a
+    gatherer reads once cycle v has been declared holds no value older
+    than v (a render that overlapped the writes may hold newer ones).
+    Bytes kept under a generation they do not belong to would break
+    it."""
+    import sys
+
+    ex = Exporter()
+    g = ex.new_adv_gauge("stress_adv_gauge", ["pod"])
+    n_series, cycles = 300, 60
+    children = [g.labels(pod=f"p{i}") for i in range(n_series)]
+    for c in children:
+        c.set(0)
+    ex.advanced_published()
+    declared = [0]
+    stop = threading.Event()
+    failures: list[str] = []
+
+    def values(body: bytes) -> list[float]:
+        return [float(line.rsplit(b" ", 1)[1])
+                for line in body.splitlines()
+                if line.startswith(b"stress_adv_gauge{")]
+
+    def gatherer() -> None:
+        while not stop.is_set():
+            floor = declared[0]
+            got = values(ex.gather_text())
+            if len(got) != n_series or min(got) < floor:
+                failures.append(f"floor {floor}, read {sorted(set(got))}")
+                return
+
+    def publisher() -> None:
+        for v in range(1, cycles + 1):
+            for c in children:
+                c.set(v)
+            ex.advanced_published()
+            declared[0] = v
+            time.sleep(0.002)  # let gathers start between cycles
+
+    old_interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    threads = [threading.Thread(target=gatherer)
+               for _ in range((os.cpu_count() or 4) + 2)]
+    pub = threading.Thread(target=publisher)
+    try:
+        for t in threads:
+            t.start()
+        pub.start()
+        pub.join(60.0)
+        assert not pub.is_alive()
+    finally:
+        stop.set()
+        for t in threads:
+            t.join(30.0)
+        sys.setswitchinterval(old_interval)
+    assert not any(t.is_alive() for t in threads)
+    assert failures == []
+    assert set(values(ex.gather_text())) == {float(cycles)}
+    counts = _gather_counts(ex)
+    assert counts["rendered"] >= 1 and counts["reused"] >= 1
+
+
 def test_fast_renderer_matches_generate_latest():
     """render_exposition must emit BYTE-identical text to
     prometheus_client.generate_latest — it replaces the library on the

@@ -280,3 +280,180 @@ def test_publish_without_a_watermark_still_publishes():
         initialize_recorder(capacity=old.capacity, enabled=old.enabled)
     assert pub["args"] == {}
     assert 'podname="web-0"' in adv_text()
+
+
+# --------------------------- the publisher declares its cycle complete
+def test_publish_once_declares_once_per_cycle_after_series_publish():
+    """(h) One cycle, one declaration, made when ``series_publish`` has
+    closed and ``pod_publish`` is still open; the values it covers are
+    in the registry by then."""
+    from retina_tpu.obs.recorder import get_recorder, initialize_recorder
+    from retina_tpu.utils import metric_names as mn
+
+    eng = FakeEngine()
+    mm, cache = build_module(eng)
+    ex = get_exporter()
+    eng.snap["pod_forward"][cache.get_index("default/web-0"), 0] = (3, 30)
+    declared = []
+    real = ex.advanced_published
+    old = get_recorder()
+    rec = initialize_recorder(capacity=64)
+
+    def spy() -> None:
+        declared.append((
+            [s["stage"] for s in rec.spans()],
+            'workload_kind="web"} 3.0' in adv_text(),
+        ))
+        real()
+
+    ex.advanced_published = spy
+    try:
+        gen0 = ex._adv_gen
+        mm.publish_once()
+        assert ex._adv_gen == gen0 + 1 and ex._adv_published
+        mm.publish_once()
+        stages = [s["stage"] for s in rec.spans()]
+    finally:
+        initialize_recorder(capacity=old.capacity, enabled=old.enabled)
+    assert len(declared) == 2
+    closed, written = declared[0]
+    assert written
+    assert mn.STAGE_SERIES_PUBLISH in closed
+    assert mn.STAGE_POD_PUBLISH not in closed  # still open
+    assert stages.count(mn.STAGE_POD_PUBLISH) == 2
+
+
+def test_gathers_between_two_publishes_render_pod_level_once(
+        counting_render):
+    """N gathers between two publish cycles: the advanced registry is
+    rendered once, the default registry N times; the next publish brings
+    one more render, and its values."""
+    eng = FakeEngine()
+    mm, cache = build_module(eng)
+    i_web = cache.get_index("default/web-0")
+    ex = get_exporter()
+    n = 6
+    for value in (100, 101):
+        eng.snap["pod_forward"][i_web, 0] = (value, 50 * value)
+        mm.publish_once()
+        bodies = [ex.gather_text() for _ in range(n)]
+        want = f'podname="web-0",workload_kind="web"}} {value}.0'.encode()
+        assert all(want in b for b in bodies)
+    assert counting_render.count(ex.advanced_registry) == 2
+    assert counting_render.count(ex.default_registry) == 2 * n
+
+
+@pytest.mark.parametrize("change", [
+    "publish", "publish_new_pod", "reconcile", "reset_advanced",
+])
+def test_gather_equals_fresh_render_after_every_change(
+        change, fresh_exposition):
+    """After every publish cycle, a reconcile and a bare reset,
+    gather_text() is byte for byte what rendering both registries
+    afresh gives, on the gather that renders and on those that reuse;
+    a reconcile invalidates at once."""
+    eng = FakeEngine()
+    mm, cache = build_module(eng)
+    ex = get_exporter()
+    i_web = cache.get_index("default/web-0")
+    for cycle in range(3):
+        eng.snap["pod_forward"][i_web, 0] = (cycle + 1, 10 * cycle)
+        eng.snap["pod_drop"][i_web, 1, 0] = cycle
+        mm.publish_once()
+        for _ in range(2):
+            assert ex.gather_text() == fresh_exposition(ex)
+    assert ex.gather()[1] == "reused"
+    if change == "publish":
+        eng.snap["hll_flows"] = np.array([77.0])
+        mm.publish_once()
+        assert b"sketch_distinct_flows 77.0" in ex.gather_text()
+    elif change == "publish_new_pod":
+        cache.update_endpoint(RetinaEndpoint(
+            name="db-0", namespace="default", ips=("10.0.0.3",)))
+        eng.snap["pod_forward"][
+            cache.get_index("default/db-0"), 1] = (9, 900)
+        mm.publish_once()
+        assert b'podname="db-0"' in ex.gather_text()
+    elif change == "reconcile":
+        mm.reconcile(MetricsConfiguration(spec=MetricsSpec(
+            context_options=[MetricsContextOptions("drop")])))
+        assert b"adv_forward_count" not in ex.gather_text()
+        mm.publish_once()
+    else:
+        ex.reset_advanced()
+        assert b"adv_forward_count" not in ex.gather_text()
+    for _ in range(3):
+        assert ex.gather_text() == fresh_exposition(ex)
+
+
+def test_metric_object_that_raises_still_declares_the_cycle(
+        fresh_exposition):
+    """(h) A metric object that raises in ``publish`` must not hold its
+    siblings' new values back: the declaration is in a ``finally``,
+    which also covers what the per-object guard does not catch."""
+    eng = FakeEngine()
+    mm, cache = build_module(eng)
+    ex = get_exporter()
+    i_web = cache.get_index("default/web-0")
+    eng.snap["pod_forward"][i_web, 0] = (5, 50)
+    mm.publish_once()
+    assert b'workload_kind="web"} 5.0' in ex.gather_text()
+    assert ex.gather()[1] == "reused"
+
+    def boom(snap, ctx):
+        raise RuntimeError("drop object broke")
+
+    mm._metrics["drop"].publish = boom
+    eng.snap["pod_forward"][i_web, 0] = (6, 60)
+    gen = ex._adv_gen
+    mm.publish_once()
+    assert ex._adv_gen == gen + 1
+    assert b'workload_kind="web"} 6.0' in ex.gather_text()
+    assert ex.gather_text() == fresh_exposition(ex)
+
+    def worse(snap, ctx):
+        raise KeyboardInterrupt
+
+    mm._metrics["drop"].publish = worse
+    order = list(mm._metrics)
+    assert order.index("forward") < order.index("drop")
+    eng.snap["pod_forward"][i_web, 0] = (7, 70)
+    with pytest.raises(KeyboardInterrupt):
+        mm.publish_once()
+    assert ex._adv_gen == gen + 2
+    assert b'workload_kind="web"} 7.0' in ex.gather_text()
+
+
+def test_only_a_publish_cycle_writes_the_advanced_registry():
+    """The invariant the exporter's kept bytes rest on, held on the
+    tree: under retina_tpu/ the advanced registry's families are made
+    by module/metric_objects.py alone, every ``publish`` of a metric
+    object is called from MetricsModule._publish_series, and
+    publish_once is its only caller."""
+    import ast
+    import pathlib
+
+    root = pathlib.Path(__file__).resolve().parent.parent / "retina_tpu"
+    makers, publishers, callers = set(), set(), set()
+    for path in root.rglob("*.py"):
+        rel = path.relative_to(root).as_posix()
+        for fn in ast.walk(ast.parse(path.read_text())):
+            if not isinstance(fn, (ast.FunctionDef, ast.AsyncFunctionDef)):
+                continue
+            for node in ast.walk(fn):
+                if not (isinstance(node, ast.Call)
+                        and isinstance(node.func, ast.Attribute)):
+                    continue
+                attr = node.func.attr
+                if attr in ("new_adv_gauge", "new_adv_counter"):
+                    makers.add(rel)
+                elif attr == "_publish_series":
+                    callers.add((rel, fn.name))
+                elif attr == "publish":
+                    first = node.args[0] if node.args else None
+                    if not (isinstance(first, ast.Name)
+                            and "TOPIC" in first.id):  # not pubsub
+                        publishers.add((rel, fn.name))
+    assert makers == {"module/metric_objects.py"}
+    assert publishers == {("module/metrics_module.py", "_publish_series")}
+    assert callers == {("module/metrics_module.py", "publish_once")}

@@ -97,6 +97,68 @@ def test_scrape_p99_bounded_with_slow_render(srv_factory):
     assert p99 < 0.25, f"scrape p99 {p99:.3f}s; render leaked onto scrape path"
 
 
+def _scrape_until(port, want: bytes, timeout: float = 5.0) -> bytes:
+    deadline = time.monotonic() + timeout
+    while True:
+        code, body = _get(port, "/metrics")
+        assert code == 200
+        if want in body or time.monotonic() > deadline:
+            return body
+        time.sleep(0.01)
+
+
+@pytest.mark.parametrize("ttl", [0, 0.05])
+def test_scrape_carries_a_finished_publish_within_one_render(
+        srv_factory, counting_render, ttl):
+    """Over HTTP, with the exporter's own gatherer: scrapes between two
+    publishes render the pod-level registry once however many TTLs
+    expire, a finished publish shows one kick and one render later, and
+    the agent's own series stay live throughout. TTL 0 renders inline
+    and takes the same path."""
+    from retina_tpu.exporter import Exporter
+
+    ex = Exporter()
+    adv = ex.new_adv_gauge("srv_adv_gauge", ["pod"])
+    live = ex.new_gauge("srv_live_gauge", [])
+    for i in range(20):
+        adv.labels(pod=f"p{i}").set(1)
+    ex.advanced_published()
+    srv = srv_factory(exporter=ex, metrics_cache_ttl_s=ttl)
+    for v in range(1, 4):
+        live.set(v)
+        body = _scrape_until(srv.port, f"srv_live_gauge {v}.0".encode())
+        assert f"srv_live_gauge {v}.0".encode() in body
+        assert b'srv_adv_gauge{pod="p7"} 1.0' in body
+        time.sleep(2 * ttl)  # let the body cache expire
+    assert counting_render.count(ex.advanced_registry) == 1
+    assert counting_render.count(ex.default_registry) >= 3
+    adv.labels(pod="p7").set(2)  # a publish cycle: writes, then the word
+    ex.advanced_published()
+    body = _scrape_until(srv.port, b'srv_adv_gauge{pod="p7"} 2.0')
+    assert b'srv_adv_gauge{pod="p7"} 2.0' in body
+    assert b'srv_adv_gauge{pod="p6"} 1.0' in body
+    _scrape_until(srv.port, b"never there", timeout=3 * ttl)
+    assert counting_render.count(ex.advanced_registry) == 2
+
+
+def test_reconcile_reset_invalidates_the_served_body_at_once(srv_factory):
+    """A reset of the advanced registry (CRD reconcile) must not leave
+    the old pod-level bytes on the scrape: inline, the very next scrape
+    is without them."""
+    from retina_tpu.exporter import Exporter
+
+    ex = Exporter()
+    ex.new_adv_gauge("srv_reset_gauge", []).set(1)
+    ex.advanced_published()
+    srv = srv_factory(exporter=ex, metrics_cache_ttl_s=0)
+    for _ in range(2):
+        assert b"srv_reset_gauge 1.0" in _get(srv.port, "/metrics")[1]
+    ex.reset_advanced()
+    assert b"srv_reset_gauge" not in _get(srv.port, "/metrics")[1]
+    ex.new_adv_gauge("srv_reset_gauge2", []).set(3)
+    assert b"srv_reset_gauge2 3.0" in _get(srv.port, "/metrics")[1]
+
+
 def test_debug_vars_exposes_overload_section(srv_factory):
     """The overload controller's stats ride /debug/vars (wired in
     controllermanager.init): state, pressure, and the active shed set
