@@ -141,11 +141,13 @@ class Config:
     # 1 on single-core hosts, i.e. the single-threaded pass).
     host_combine_threads: int = 0
     # Depth of the in-flight transfer queue between the batcher thread and
-    # the device dispatch thread (engine.py), and the bound on concurrent
-    # fire-and-forget device submissions (transfers queued back-to-back on
-    # the device proxy so the host->device link never idles between
-    # dispatch round-trips). 0 = synchronous dispatch on the feed thread
-    # (no overlap).
+    # the device dispatch thread (engine.py), and the bound on dispatches
+    # in flight: submitted, and their last step not yet finished on the
+    # device (transfers queue back-to-back on the device proxy so the
+    # host->device link never idles between dispatch round-trips). While
+    # any is in flight the dispatch thread holds and folds the feed's
+    # flushes; only a full step's worth of rows takes a second or third
+    # slot. 0 = synchronous dispatch on the feed thread (no overlap).
     feed_pipeline_depth: int = 3
     # Sharded multi-worker host feed (parallel/feed.py): N feed workers
     # each own a staging buffer, combine+partition their quantum in
@@ -247,8 +249,8 @@ class Config:
 
     # --- adaptive overload control (runtime/overload.py) ---
     # NOMINAL -> SAMPLING -> SHEDDING -> DEGRADED driven by the max of
-    # the normalized pressure signals (worker staging fill, dispatch
-    # in-flight fill, handoff wait rate, harvest lag).
+    # the normalized pressure signals (worker staging fill, handoff
+    # wait rate, harvest lag, dispatch latency).
     overload_enabled: bool = True
     # Controller cadence; the feed loop calls tick() at least this often.
     overload_tick_s: float = 0.1

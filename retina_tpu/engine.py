@@ -46,7 +46,7 @@ from retina_tpu.parallel.feed import (
 )
 from retina_tpu.parallel.flowdict import flow_dict_stats, make_flow_dict
 from retina_tpu.parallel.partition import (
-    ShardedBatch, _next_bucket, partition_events,
+    ShardedBatch, _next_bucket, fold_batches, partition_events,
 )
 from retina_tpu.parallel.telemetry import (
     SCOPE_INGEST_UNPACK, ShardedTelemetry, note_op_scopes,
@@ -100,13 +100,38 @@ def pipeline_config_from(cfg: Config) -> PipelineConfig:
     )
 
 
+def fold_side_windows(a, na, b, nb):
+    """(traced) One step window out of two: per device, the ``na``
+    valid rows of window ``a``, then window ``b``'s rows from position
+    ``na`` on (``a``, ``b``: (D, capacity, F); ``na``, ``nb``: (D,)
+    valid rows, ``na + nb <= capacity``). Returns the window and its
+    ``na + nb``; what lies past that is padding."""
+    with jax.named_scope(SCOPE_INGEST_UNPACK):
+        cap = a.shape[1]
+        idx = jnp.arange(cap, dtype=jnp.int32)[None, :]
+        at = na.astype(jnp.int32)[:, None]
+        moved = jnp.take_along_axis(
+            b, jnp.mod(idx - at, cap)[..., None], axis=1
+        )
+        out = jnp.where((idx < at)[..., None], a, moved)
+    return out, na + nb
+
+
 class SketchEngine:
     """Owns device state + the feed/window loop; thread-safe facade."""
 
     def __init__(self, cfg: Config, devices: Optional[list] = None,
-                 supervisor: Optional[Supervisor] = None):
+                 supervisor: Optional[Supervisor] = None,
+                 clock: Callable[[], float] = time.monotonic):
         self.cfg = cfg
         self.log = logger("engine")
+        # The clock of everything the engine decides by time: flush
+        # ages, window ticks, the overload controller's ticks and the
+        # durations its signals are made of. Tests inject one they
+        # advance by hand, so that a loaded machine cannot read as a
+        # late device; idle parking and span times stay on the wall
+        # clock.
+        self._clock = clock
         # Supervision (runtime/supervisor.py): when attached, every
         # long-lived engine thread registers a heartbeat with the
         # shared watchdog; standalone engines (tests, bench) get
@@ -157,14 +182,17 @@ class SketchEngine:
         self._inflight = threading.Semaphore(
             max(1, cfg.feed_pipeline_depth)
         )
-        # Count of submissions currently in flight on the proxy: the
-        # feed loop flushes at flush_interval_s only when this is 0
-        # (idle -> latency priority); while dispatches are in flight it
-        # accumulates bigger quanta up to flush_max_age_s (throughput
-        # priority — bigger quanta combine harder and amortize the
-        # per-flush fixed costs).
+        # Count of dispatches in flight: submitted, and their last step
+        # not yet finished ON THE DEVICE (the completion thread says
+        # when, _dispatch_done). The feed flushes at flush_interval_s
+        # only when this is 0 (idle -> latency priority); while
+        # dispatches are in flight it accumulates bigger quanta up to
+        # flush_max_age_s, and the dispatch thread holds and folds what
+        # accumulates (_dispatch_loop; _held_flushes is how many it
+        # holds, for feed_stats).
         self._busy_lock = threading.Lock()
         self._inflight_busy = 0
+        self._held_flushes = 0
         # Combiner thread count (native rt_combine_mt; 0 keeps the
         # cores-based default — 1 thread on single-core hosts).
         if cfg.host_combine_threads > 0:
@@ -308,7 +336,9 @@ class SketchEngine:
         # loop ticks the controller against the engine's pressure
         # signals; feed workers sample through it, plugins consult
         # shed_active before enrichment work.
-        self._overload = OverloadController(cfg, self._overload_signals)
+        self._overload = OverloadController(
+            cfg, self._overload_signals, clock=clock
+        )
         # Fleet rollup tier (fleet/): ship the device-merged sketch
         # snapshot at every window close instead of raw samples. The
         # shipper owns its worker thread (start()/stop() track the
@@ -363,7 +393,7 @@ class SketchEngine:
         # dispatch-latency EWMA (seconds, updated on the proxy thread
         # where device_step_seconds is observed).
         self._ov_wait_prev = 0.0
-        self._ov_wait_t = time.monotonic()
+        self._ov_wait_t = clock()
         self._dispatch_lat_ewma = 0.0
         # Timestamp of the last EWMA sample: a stale measurement means
         # the pipeline is idle, not slow, and must not read as
@@ -890,6 +920,11 @@ class SketchEngine:
                 packed = bool(self.cfg.transfer_packed)
                 jobs.append(((b, packed), self._ingest_fn, (b, packed)))
             if i == 0:
+                if self._flow_dict is not None:
+                    jobs.append((
+                        ("fold", self.cfg.batch_capacity),
+                        self._fold_sides_fn, (),
+                    ))
                 jobs.append(("snapshot", self._warm_snap_job, ()))
                 jobs.append(
                     ("snapshot flat", self._warm_snap_flat_job, ())
@@ -1388,6 +1423,42 @@ class SketchEngine:
             self._pad_cache[key] = fn
         return fn
 
+    @device_entry("engine.fold_sides", kind="jit")
+    def _fold_sides_fn(self):  # runs-on: device-proxy
+        """The jit that folds the new side's window and the known
+        side's into ONE step window, for a flush whose two sides
+        together fit one (``_dispatch_flowdict``: the step costs the
+        same whatever it holds, so a flush with a few new rows and a
+        few known ones used to pay it twice). Per device: the new
+        window's ``na`` valid rows, then the known window's rows from
+        position ``na`` on; what lies past ``na + nb`` is padding the
+        step masks by its validity count, like any window's."""
+        key = ("fold", self.cfg.batch_capacity)
+        fn = self._pad_cache.get(key)
+        if fn is None:
+            cap = self.cfg.batch_capacity
+            out_sh = (self._rec_sharding, self._rec_sharding)
+
+            # Both windows are single-use outputs of this flush's
+            # ingest programs; there is one output to reuse a buffer.
+            fold = jax.jit(
+                fold_side_windows, out_shardings=out_sh,
+                donate_argnums=(0,),
+            )
+            win = jax.ShapeDtypeStruct(
+                (self.n_devices, cap, NUM_FIELDS), jnp.uint32,
+                sharding=self._rec_sharding,
+            )
+            nv = jax.ShapeDtypeStruct(
+                (self.n_devices,), jnp.uint32,
+                sharding=self._rec_sharding,
+            )
+            fn = self._compile_cached(
+                "fold_sides", key, lambda: fold.lower(win, nv, win, nv)
+            )
+            self._pad_cache[key] = fn
+        return fn
+
     def _wire_bucket(self, n_max: int) -> int:
         cap_total = self.cfg.batch_capacity * max(
             1, self.cfg.feed_coalesce_windows
@@ -1425,7 +1496,7 @@ class SketchEngine:
 
     def _dispatch_flowdict(
         self, sb: "ShardedBatch", now_s: int, n_raw: int,
-        sync: bool, record_metrics: bool,
+        sync: bool, record_metrics: bool, n_flushes: int = 1,
     ) -> None:
         """Flow-dictionary dispatch: split the partitioned batch into
         new-descriptor rows (full 12-lane upload + table insert) and
@@ -1583,8 +1654,20 @@ class SketchEngine:
         n_events = int(sb.events)
         n_valid_total = int(nv_new.sum() + nv_known.sum())
         samp_k = int(sb.sample_k)
+        # One step instead of one a side, where the wire allows it:
+        # each side is one window and together they fit one. The new
+        # rows' descriptors still reach the table first (their ingest
+        # runs first); the two windows are then folded on the device.
+        cap = self.cfg.batch_capacity
+        fold_sides = bool(
+            have_new and have_known and Bn <= cap and Bk <= cap
+            and int((nv_new + nv_known).max()) <= cap
+        )
 
-        def xfer_and_step():
+        def xfer_and_step(done=None):
+            """``done`` (async dispatches) is called once, when the
+            last step has finished on the device or when nothing
+            reached it."""
             faults.inject("transfer")
             # A failure resync after this batch was built invalidated
             # the table its ids reference — drop rather than gather
@@ -1601,6 +1684,8 @@ class SketchEngine:
                         "dropping in-flight flow-dict batch from "
                         "pre-resync epoch"
                     )
+                    if done is not None:
+                        done()
                     return
             self._device_consts()
             # Identity/filter tables captured at proxy-EXECUTION time,
@@ -1636,6 +1721,7 @@ class SketchEngine:
                 mnames.STAGE_TRANSFER_ENQUEUE, tid, record_metrics
             )
             t_x0 = time.perf_counter()
+            c_x0 = self._clock()
             # ONE batched device_put for everything this flush moves:
             # separate puts each pay a client round-trip.
             host_bufs, shardings = [], []
@@ -1672,6 +1758,13 @@ class SketchEngine:
                     Bk
                 )(known_dev, mk_dev, table)
                 sides.append((wins, nvs, now_dev, lost_dev))
+            if fold_sides:
+                (wn, nn_dev, now_dev, lost_dev), (wk, nk_dev, _, _) = sides
+                win, nv_dev = self._fold_sides_fn()(
+                    wn[0], nn_dev[0], wk[0], nk_dev[0]
+                )
+                # The new side's meta carries the host losses.
+                sides = [((win,), (nv_dev,), now_dev, lost_dev)]
             sp_x.end()
             sp_s = self._step_span(
                 mnames.STAGE_DEVICE_STEP, tid, record_metrics
@@ -1696,21 +1789,14 @@ class SketchEngine:
                         n_steps += 1
                 self.state = st
             if record_metrics:
-                t_end = time.perf_counter()
                 m.transfer_seconds.observe(t0 - t_x0)
-                self._watch_steps(sp_s, summary["events"], t0, n_steps)
-                # Overload signal: EWMA of the enqueue wall time of
-                # transfer+step (proxy thread only — no lock needed).
-                self._dispatch_lat_ewma = (
-                    0.8 * self._dispatch_lat_ewma + 0.2 * (t_end - t_x0)
+                self._note_dispatched(
+                    c_x0, n_valid_total, n_steps, n_raw, n_flushes
                 )
-                self._dispatch_lat_t = time.monotonic()
-                m.device_batch_fill.set(
-                    n_valid_total
-                    / max(D * self.cfg.batch_capacity * n_steps, 1)
-                )
-                self._steps += n_steps
-                self._events_in += n_raw
+            self._watch_steps(
+                sp_s if record_metrics else None, summary["events"], t0,
+                n_steps, done,
+            )
 
         if not (have_new or have_known):
             sp_build.end()
@@ -1727,8 +1813,11 @@ class SketchEngine:
 
         def safe_xfer_and_step():
             try:
-                xfer_and_step()
+                xfer_and_step(self._dispatch_done)
             except Exception as e:
+                # Raised before the steps were handed to the completion
+                # thread (that hand-over is the call's last act).
+                self._dispatch_done(e)
                 if self._count_error("device_step"):
                     self.log.exception("flow-dict device step failed")
                 get_metrics().lost_events.labels(
@@ -1742,16 +1831,10 @@ class SketchEngine:
                 self._flowdict_resync()
                 if self._fatal_device_error(e):
                     self._request_recovery(repr(e))
-            finally:
-                with self._busy_lock:
-                    self._inflight_busy -= 1
-                self._inflight.release()
 
         t_d1 = time.monotonic()
         sp_build.end()
-        self._inflight.acquire()
-        with self._busy_lock:
-            self._inflight_busy += 1
+        self._dispatch_submitted()
         submit_on_device(
             safe_xfer_and_step, kind=mnames.KIND_STEP, parent=sp_build.id
         )
@@ -1767,6 +1850,7 @@ class SketchEngine:
     def _dispatch_sharded(
         self, sb: "ShardedBatch", now_s: int, n_raw: int,
         sync: bool = True, record_metrics: bool = True,
+        n_flushes: int = 1,
     ) -> None:
         """Pack + device_put + step dispatch for an already-partitioned
         batch.
@@ -1802,7 +1886,7 @@ class SketchEngine:
         ) >= self.cfg.transfer_min_bucket:
             try:
                 self._dispatch_flowdict(
-                    sb, now_s, n_raw, sync, record_metrics
+                    sb, now_s, n_raw, sync, record_metrics, n_flushes
                 )
             except Exception:
                 # ANY failure after lookup_or_assign may leave
@@ -1855,7 +1939,7 @@ class SketchEngine:
         samp_k = int(sb.sample_k)
         sp_build.end()
 
-        def xfer_and_step():
+        def xfer_and_step(done=None):
             faults.inject("transfer")
             self._device_consts()
             # Execution-time capture — see _dispatch_flowdict: proxy
@@ -1867,6 +1951,7 @@ class SketchEngine:
                 mnames.STAGE_TRANSFER_ENQUEUE, tid, record_metrics
             )
             t_x0 = time.perf_counter()
+            c_x0 = self._clock()
             # One batched put (wire + meta): separate puts each pay a
             # client round-trip.
             wire_dev, meta_dev = jax.device_put(
@@ -1897,31 +1982,14 @@ class SketchEngine:
                 # one-shot 30-100s cold-compile sample would inflate
                 # the histogram p99/max forever and seed transfer_bytes
                 # with a synthetic zero batch.
-                t_end = time.perf_counter()
                 m.transfer_seconds.observe(t0 - t_x0)
-                self._watch_steps(sp_s, summary["events"], t0, len(wins))
-                # Overload signal: EWMA of the enqueue wall time of
-                # transfer+step (proxy thread only — no lock needed).
-                self._dispatch_lat_ewma = (
-                    0.8 * self._dispatch_lat_ewma + 0.2 * (t_end - t_x0)
+                self._note_dispatched(
+                    c_x0, n_valid_total, len(wins), n_raw, n_flushes
                 )
-                self._dispatch_lat_t = time.monotonic()
-                # Fill of the step capacity actually dispatched
-                # (windows x batch_capacity): identical to the
-                # historical series for single-window batches, and
-                # stays a 0..1 ratio for coalesced multi-window
-                # transfers.
-                m.device_batch_fill.set(
-                    n_valid_total
-                    / max(
-                        self.n_devices
-                        * self.cfg.batch_capacity
-                        * len(wins),
-                        1,
-                    )
-                )
-                self._steps += len(wins)
-                self._events_in += n_raw
+            self._watch_steps(
+                sp_s if record_metrics else None, summary["events"], t0,
+                len(wins), done,
+            )
 
         if sync:
             run_on_device(
@@ -1931,8 +1999,9 @@ class SketchEngine:
 
         def safe_xfer_and_step():
             try:
-                xfer_and_step()
+                xfer_and_step(self._dispatch_done)
             except Exception as e:
+                self._dispatch_done(e)
                 if self._count_error("device_step"):
                     self.log.exception("device step failed")
                 get_metrics().lost_events.labels(
@@ -1941,14 +2010,8 @@ class SketchEngine:
                 self._count_unheld(n_raw)
                 if self._fatal_device_error(e):
                     self._request_recovery(repr(e))
-            finally:
-                with self._busy_lock:
-                    self._inflight_busy -= 1
-                self._inflight.release()
 
-        self._inflight.acquire()
-        with self._busy_lock:
-            self._inflight_busy += 1
+        self._dispatch_submitted()
         submit_on_device(
             safe_xfer_and_step, kind=mnames.KIND_STEP, parent=sp_build.id
         )
@@ -1961,7 +2024,44 @@ class SketchEngine:
             return NULL_SPAN
         return self._recorder.span(stage, tid)
 
-    def _watch_steps(self, span, out, t0: float, n_steps: int) -> None:
+    def _note_dispatched(
+        self, c_x0: float, n_rows: int, n_steps: int, n_raw: int,
+        n_flushes: int,
+    ) -> None:
+        """(proxy thread) What both dispatchers record once a
+        dispatch's transfer and steps are enqueued: the overload
+        signal (EWMA of the enqueue time of transfer + steps, on the
+        engine's clock since ``c_x0``, each sample at most the budget
+        it is read against; proxy thread only, so no lock),
+        the fill of the step capacity dispatched (windows x
+        batch_capacity: a 0..1 ratio for coalesced multi-window
+        transfers too), the folding counters and the engine's own
+        totals."""
+        now = self._clock()
+        # One sample weighs at most its budget (half a window, what
+        # _overload_signals divides by): enqueues that are slow one
+        # after another still read 1.0 within a few, but one freeze of
+        # the process caught inside an enqueue is one sample, not an
+        # overload.
+        lat = min(now - c_x0, 0.5 * self.cfg.window_seconds)
+        self._dispatch_lat_ewma = (
+            0.8 * self._dispatch_lat_ewma + 0.2 * lat
+        )
+        self._dispatch_lat_t = now
+        m = get_metrics()
+        m.device_batch_fill.set(
+            n_rows
+            / max(self.n_devices * self.cfg.batch_capacity * n_steps, 1)
+        )
+        m.steps.inc(n_steps)
+        m.step_rows.inc(n_rows)
+        m.dispatch_flushes.inc(n_flushes)
+        self._steps += n_steps
+        self._events_in += n_raw
+
+    def _watch_steps(
+        self, span, out, t0: float, n_steps: int, then=None,
+    ) -> None:
         """(proxy thread) Hand a dispatched group of steps to the
         completion thread: ``out`` is the last step's ``events`` output
         (not donated, unlike the state), ready when the whole group has
@@ -1969,13 +2069,22 @@ class SketchEngine:
         ``t0``, is closed there, and ``tpu_step_seconds`` observes
         completed seconds per step — what the device took (plus, on a
         backlogged device, the wait behind earlier groups), not what
-        the enqueue took. The proxy thread does not wait."""
+        the enqueue took. ``span`` is None for a warm-up dispatch;
+        ``then`` (an async dispatch's ``_dispatch_done``) is called
+        after. The proxy thread does not wait. This is the dispatch's
+        last act on the proxy: past it, ``then`` is the completion
+        thread's to call."""
+        if span is None and then is None:
+            return
         hist = get_metrics().device_step_seconds
 
         def done(err: BaseException | None) -> None:
-            args = {"error": type(err).__name__} if err else {}
-            dt = span.end(n_steps=n_steps, **args)
-            hist.observe((dt or time.perf_counter() - t0) / n_steps)
+            if span is not None:
+                args = {"error": type(err).__name__} if err else {}
+                dt = span.end(n_steps=n_steps, **args)
+                hist.observe((dt or time.perf_counter() - t0) / n_steps)
+            if then is not None:
+                then(err)
 
         on_ready(out, done)
 
@@ -2381,9 +2490,28 @@ class SketchEngine:
 
     def _busy_count(self) -> int:  # runs-on: feed-worker*
         """In-flight dispatch count for feed-worker interval-flush
-        gating (same signal the inline feed loop reads)."""
+        gating (same signal the inline feed loop and the dispatch
+        thread's folding read)."""
         with self._busy_lock:
             return self._inflight_busy
+
+    def _dispatch_submitted(self) -> None:  # runs-on: engine-dispatch
+        """Take a slot of the pipeline for one dispatch (waits for one
+        while ``feed_pipeline_depth`` are in flight)."""
+        self._inflight.acquire()
+        with self._busy_lock:
+            self._inflight_busy += 1
+
+    def _dispatch_done(  # runs-on: device-completion, device-proxy
+        self, err: BaseException | None = None
+    ) -> None:
+        """Give the slot back: called once per submitted dispatch, by
+        the completion thread when its last step has finished on the
+        device, or by the proxy thread when it never reached the
+        device (dropped, failed)."""
+        with self._busy_lock:
+            self._inflight_busy -= 1
+        self._inflight.release()
 
     # -- adaptive overload control (runtime/overload.py) --------------
     def _overload_signals(self) -> dict[str, float]:
@@ -2393,7 +2521,7 @@ class SketchEngine:
         lock-free or a single counter load."""
         sig: dict[str, float] = {}
         pool = self._feed_pool
-        now = time.monotonic()
+        now = self._clock()
         if pool is not None:
             # Worst per-worker staging fill: the first queue to
             # overflow decides when blocks start dropping.
@@ -2408,8 +2536,6 @@ class SketchEngine:
             )
             self._ov_wait_prev = wait
             self._ov_wait_t = now
-        depth = max(1, self.cfg.feed_pipeline_depth)
-        sig["inflight"] = min(1.0, self._busy_count() / depth)
         # Harvest lag: closed windows whose readback hasn't landed.
         sig["harvest"] = min(
             1.0, self._harvest_q.unfinished_tasks / 4.0
@@ -2516,47 +2642,121 @@ class SketchEngine:
             st = {"workers": 0, "mode": "inline", "per_worker": []}
         st["flow_dict"] = flow_dict_stats(self._flow_dict)
         st["overload"] = self._overload.stats()
+        # The dispatch thread's folding: dispatches the device has not
+        # finished, and flushes held behind them.
+        st["dispatch"] = {"in_flight": self._busy_count(),
+                          "held_flushes": self._held_flushes}
         return st
 
     def _dispatch_loop(self, q) -> None:
-        """Dispatch thread: packs partitioned steps and submits them (and
-        window closes) to the device proxy in feed order, without waiting
-        for the device round-trip. Packing batch N+1 here overlaps batch
-        N's in-flight transfer on the proxy thread, and the bounded proxy
-        backlog keeps the host->device link busy back-to-back
-        (VERDICT r2 weak #1, r3 weak #1). ``q`` is either the inline
-        feed's queue.Queue or a feed-pool TransferMux — both block on
-        ``get()`` and deliver ``None`` as the shutdown sentinel. The
-        bounded-timeout get keeps the watchdog heartbeat honest: the
-        thread parks before each wait and beats only when processing."""
+        """Dispatch thread: folds the feed's flushes, packs them and
+        submits them (and window closes) to the device proxy in feed
+        order, without waiting for the device round-trip. Packing batch
+        N+1 here overlaps batch N's in-flight transfer on the proxy
+        thread, and the bounded pipeline keeps the host->device link
+        busy back-to-back (VERDICT r2 weak #1, r3 weak #1). ``q`` is a
+        TransferMux (the feed pool's, or the inline feed's over its one
+        queue): ``get()`` blocks and delivers ``None`` as the shutdown
+        sentinel.
+
+        **Folding.** A fused step costs the device the same whatever
+        it holds, so steps must follow the rows offered, not the
+        hand-overs. A flush taken off the mux is dispatched at once
+        when the pipeline is idle; while a dispatch is in flight it is
+        HELD, and whatever accumulates (across workers, up to one
+        coalesced transfer) is folded into one batch
+        (``fold_batches``) the moment the pipeline has room: idle, or,
+        for a full step's worth of rows, any free slot of
+        ``feed_pipeline_depth``. No timer: a row waits for at most the
+        dispatch in flight. A window close overtakes what is held, as
+        it overtakes what is staged in the workers.
+
+        The bounded-timeout get keeps the watchdog heartbeat honest:
+        the thread parks before each wait and beats only when
+        processing."""
         hb = self._register_hb("engine-dispatch")
+        coal = self.cfg.batch_capacity * max(
+            1, self.cfg.feed_coalesce_windows
+        )
+        held: list[tuple] = []  # step items off the mux, not dispatched
         try:
             while True:
                 hb.park()
                 try:
-                    item = q.get(timeout=1.0)
+                    # Holding all one transfer may carry: take no more
+                    # step items (the workers then wait on their
+                    # handoff, which the controller reads), only ticks.
+                    item = q.get(
+                        timeout=0.002 if held else 1.0,
+                        steps=self._held_rows(held) < coal,
+                    )
                 except queue_mod.Empty:
-                    continue
+                    item = ()
                 hb.beat()
                 if item is None:
+                    while held:
+                        self._dispatch_held(held, coal)
                     return
-                kind, payload, now_s, n_raw = item
-                try:
-                    if kind == "step":
-                        self._dispatch_sharded(
-                            payload, now_s, n_raw, sync=False
-                        )
+                if item:
+                    if item[0] == "step":
+                        held.append(item)
+                        self._held_flushes = len(held)
                     else:
-                        self._submit_close_window()
-                except Exception:
-                    if self._count_error("dispatch"):
-                        self.log.exception("%s dispatch failed", kind)
-                    if kind == "step":
-                        # Raised before the batch reached the proxy
-                        # (the sites past that point count their own).
-                        self._count_unheld(n_raw)
+                        try:
+                            self._submit_close_window()
+                        except Exception:
+                            if self._count_error("dispatch"):
+                                self.log.exception("window dispatch failed")
+                while held and self._room_for(held):
+                    self._dispatch_held(held, coal)
         finally:
             self._deregister_hb("engine-dispatch")
+
+    @staticmethod
+    def _held_rows(held: list[tuple]) -> int:
+        """Rows of the fullest device over the held step items."""
+        if not held:
+            return 0
+        return int(sum(
+            it[1].n_valid.astype(np.int64) for it in held
+        ).max())
+
+    def _room_for(self, held: list[tuple]) -> bool:
+        """Whether the pipeline has room for what is held: an idle
+        pipeline takes anything; a busy one with a free slot takes a
+        full step's worth (folding more could not save a step); a full
+        one takes nothing."""
+        busy = self._busy_count()
+        if busy == 0:
+            return True
+        if busy >= max(1, self.cfg.feed_pipeline_depth):
+            return False
+        return self._held_rows(held) >= self.cfg.batch_capacity
+
+    def _dispatch_held(self, held: list[tuple], coal: int) -> None:
+        """Fold the longest prefix of the held flushes that fits one
+        transfer and dispatch it (waits for a pipeline slot if none is
+        free: only the shutdown drain calls it without room)."""
+        sb, took = fold_batches(
+            [it[1] for it in held], coal,
+            min_bucket=self.cfg.transfer_min_bucket,
+        )
+        items = held[:took]
+        del held[:took]
+        n_raw = sum(it[3] for it in items)
+        try:
+            self._dispatch_sharded(
+                sb, max(it[2] for it in items), n_raw, sync=False,
+                n_flushes=took,
+            )
+        except Exception:
+            if self._count_error("dispatch"):
+                self.log.exception("step dispatch failed")
+            # Raised before the batch reached the proxy (the sites
+            # past that point count their own).
+            self._count_unheld(n_raw)
+        finally:
+            self._held_flushes = len(held)
 
     def start(self, stop: threading.Event) -> None:
         """Feed loop: drain sink → combine → partition → device; close
@@ -2602,7 +2802,7 @@ class SketchEngine:
             # (window closes stay on cadence even under a step
             # backlog) holds in BOTH feed modes.
             inline_data = threading.Event()
-            inline_tq = TransferQueue(depth, inline_data)
+            inline_tq = TransferQueue(depth, inline_data, self._clock)
             q = TransferMux([inline_tq], inline_data)
 
         def drop_item(item):
@@ -2674,6 +2874,7 @@ class SketchEngine:
                     restart_policy=lambda name: policy_from_config(
                         self.cfg, seed_key=name
                     ),
+                    clock=self._clock,
                 )
                 self._feed_pool = pool
                 q = pool.mux
@@ -2686,10 +2887,11 @@ class SketchEngine:
                 pool.start()
 
         m = get_metrics()
+        clock = self._clock
         pending: list[np.ndarray] = []
         n_pending = 0
-        last_flush = time.monotonic()
-        next_window = time.monotonic() + self.cfg.window_seconds
+        last_flush = clock()
+        next_window = clock() + self.cfg.window_seconds
 
         feed_trace = self._feed_trace
         trace_acc = {"accum": 0.0, "build": 0.0,
@@ -2703,7 +2905,7 @@ class SketchEngine:
             blocks = pending
             pending = []
             n_pending = 0
-            last_flush = time.monotonic()
+            last_flush = clock()
             # Shared combine+sample+partition path (_build_quantum) —
             # the SAME code the feed workers run, so overload sampling
             # applies identically in inline mode.
@@ -2785,7 +2987,7 @@ class SketchEngine:
                     if n_pending >= quantum:
                         flush()
                 sp_deal.end()
-                now = time.monotonic()
+                now = clock()
                 if n_pending and now - last_flush >= self.cfg.flush_interval_s:
                     # Interval flushes serve LATENCY and only make sense
                     # when the dispatch pipeline is idle; with work in
@@ -2794,9 +2996,7 @@ class SketchEngine:
                     # the hard age bound. Without this gate the fast
                     # async pipeline settles into many tiny flushes
                     # whose fixed costs cap throughput.
-                    with self._busy_lock:
-                        busy = self._inflight_busy
-                    if busy == 0 or (
+                    if self._busy_count() == 0 or (
                         now - last_flush >= self.cfg.flush_max_age_s
                     ):
                         flush()

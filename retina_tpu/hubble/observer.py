@@ -10,12 +10,16 @@ cursors that observe loss rather than block the writer).
 from __future__ import annotations
 
 import threading
+import time
+from collections import deque
 from typing import Any, Iterator, Optional
 
 import numpy as np
 
 from retina_tpu.hubble.flow import FlowFilter, record_to_flow
 from retina_tpu.log import logger
+from retina_tpu.obs.recorder import get_recorder
+from retina_tpu.utils import metric_names as mn
 
 
 class FlowObserver:
@@ -24,7 +28,18 @@ class FlowObserver:
         assert capacity & (capacity - 1) == 0
         self._log = logger("observer")
         self._cap = capacity
-        self._ring: list[Optional[dict]] = [None] * capacity
+        # The ring is the last ``capacity`` sequence numbers, held as
+        # segments, oldest first: (first sequence number, rows), rows
+        # a raw record block or a list of decoded flows. A slot thus
+        # names (block, row) by arithmetic: the writer appends one
+        # segment a block and never iterates over records, nor touches
+        # an array (a numpy pass over thousands of slots gives up the
+        # interpreter lock, and getting it back costs the writer
+        # milliseconds whenever another thread is busy).
+        self._segs: deque[tuple[int, Any]] = deque()
+        # Raw rows decoded so far, by sequence number (lazy decode's
+        # memo); lapped entries are pruned by the writer.
+        self._memo: dict[int, dict] = {}
         self._seq = 0  # total flows ever written
         self._lock = threading.Condition()
         self.cache = cache
@@ -40,30 +55,70 @@ class FlowObserver:
         """Write raw record rows; decode is LAZY (on read).
 
         The writer sits on the hot mirror path (every flow the engine
-        sees), while readers are few and slow (gRPC streams). Eager
-        per-record dict decode capped the writer at ~0.15M flows/s;
-        storing (block, row) refs moves the ~µs decode to the reader,
-        which only ever materializes the ≤capacity flows it serves."""
+        sees), while readers are few and slow (gRPC streams), so a
+        write costs per BLOCK, not per record: only the last
+        ``capacity`` rows of a block can ever be read, and they enter
+        the ring as one segment. The ~µs decode is the reader's, which
+        only ever materializes the ≤capacity flows it serves."""
+        n = len(records)
+        if n == 0:
+            return
+        sp = get_recorder().span(mn.STAGE_HUBBLE_CONSUME)
+        c0 = time.thread_time()
         with self._lock:
-            for i in range(len(records)):
-                self._ring[self._seq & (self._cap - 1)] = (records, i)
-                self._seq += 1
-            self.flows_seen = self._seq
-            self._lock.notify_all()
+            self._write(n, records[-self._cap:])
+        # The span's seconds include any wait for the interpreter lock
+        # inside it; ``cpu_s`` is what the write itself cost.
+        sp.end(rows=n, cpu_s=time.thread_time() - c0)
 
     def consume_flows(self, flows: list[dict]) -> None:
         """Write already-decoded flow dicts (relay peer ingestion)."""
+        if flows:
+            with self._lock:
+                self._write(len(flows), flows[-self._cap:])
+
+    def _write(self, n: int, rows) -> None:
+        """(lock held) ``n`` flows arrive, of which ``rows`` (the last
+        ``capacity`` at most) can ever be read."""
+        self._seq += n
+        self._segs.append((self._seq - len(rows), rows))
+        floor = self._seq - self._cap
+        while self._segs[0][0] + len(self._segs[0][1]) <= floor:
+            self._segs.popleft()
+        if self._memo:
+            for seq in [q for q in self._memo if q < floor]:
+                del self._memo[seq]
+        self.flows_seen = self._seq
+        self._lock.notify_all()
+
+    def raw_slots(self) -> int:
+        """Slots that still name a raw row (nothing has read them)."""
         with self._lock:
-            for f in flows:
-                self._ring[self._seq & (self._cap - 1)] = f
-                self._seq += 1
-            self.flows_seen = self._seq
-            self._lock.notify_all()
+            return sum(isinstance(e, tuple) for _, e in self._entries(
+                max(0, self._seq - self._cap), self._seq))
 
     # -- lazy decode ----------------------------------------------------
+    def _entries(self, a: int, b: int) -> list[tuple[int, Any]]:
+        """(lock held) ``(seq, entry)`` for the slots ``a <= seq < b``
+        still in the ring, oldest first: a decoded flow, or the
+        ``(block, row)`` pair that :meth:`_materialize` decodes."""
+        out = []
+        memo = self._memo
+        a = max(a, self._seq - self._cap)
+        for first, rows in self._segs:
+            lo, hi = max(a, first), min(b, first + len(rows))
+            if lo >= hi:
+                continue
+            if isinstance(rows, list):
+                out += [(q, rows[q - first]) for q in range(lo, hi)]
+            else:
+                out += [(q, memo.get(q) or (rows, q - first))
+                        for q in range(lo, hi)]
+        return out
+
     def _materialize(self, entry, seq: Optional[int] = None) -> dict:
         """Decode a raw ring entry to a flow dict, memoizing the result
-        back into the ring slot (decode once, however many readers).
+        (decode once, however many readers).
 
         Semantics note: identity/DNS enrichment happens at FIRST READ,
         not at arrival — if a pod IP is recycled while a flow sits
@@ -76,9 +131,8 @@ class FlowObserver:
             f = record_to_flow(block[i], self.cache, self.dns_resolver)
             if seq is not None:
                 with self._lock:
-                    slot = seq & (self._cap - 1)
-                    if self._ring[slot] is entry:
-                        self._ring[slot] = f
+                    if seq >= self._seq - self._cap:  # not lapped since
+                        f = self._memo.setdefault(seq, f)
             return f
         return entry
 
@@ -91,13 +145,9 @@ class FlowObserver:
         with self._lock:
             end = self._seq
             window = min(end, self._cap)
-            entries = [
-                (i, self._ring[i & (self._cap - 1)])
-                for i in range(end - window, end)
-            ]
+            entries = self._entries(end - window, end)
         # Materialize OUTSIDE the lock: decode must never stall writers.
-        return [self._materialize(e, seq) for seq, e in entries
-                if e is not None], end
+        return [self._materialize(e, seq) for seq, e in entries], end
 
     def follow_from(
         self,
@@ -116,11 +166,8 @@ class FlowObserver:
                     lost = floor - cursor
                     self.lost_observed += lost
                     cursor = floor
-                while cursor < self._seq:
-                    f = self._ring[cursor & (self._cap - 1)]
-                    if f is not None:
-                        batch.append((cursor, f))
-                    cursor += 1
+                batch = self._entries(cursor, self._seq)
+                cursor = self._seq
                 if not batch and not lost:
                     self._lock.wait(timeout=0.2)
             if lost:
@@ -157,12 +204,8 @@ class FlowObserver:
                 skipped = floor - cursor
                 self.lost_observed += skipped
                 cursor = floor
-            batch = []
-            while cursor < end0:
-                f = self._ring[cursor & (self._cap - 1)]
-                if f is not None:
-                    batch.append((cursor, f))
-                cursor += 1
+            batch = self._entries(cursor, end0)
+            cursor = max(cursor, end0)
         if skipped and lost_markers:
             yield {"lost_events": int(skipped)}
         for seq, f in batch:

@@ -92,6 +92,9 @@ class Metrics:
             "group to its last output ready on the device, over its steps",
         )
         self.device_batch_fill = g(mn.DEVICE_BATCH_FILL, [])
+        self.steps = c(mn.STEPS, [])
+        self.step_rows = c(mn.STEP_ROWS, [])
+        self.dispatch_flushes = c(mn.DISPATCH_FLUSHES, [])
         self.windows_closed = c(mn.WINDOWS_CLOSED, [])
         # Window ticks deferred while the close program was still
         # queued in the background warm (stall-free close contract).
@@ -130,6 +133,8 @@ class Metrics:
         # Adaptive overload control (runtime/overload.py; see
         # metric_names for semantics).
         self.overload_state = g(mn.OVERLOAD_STATE, [])
+        self.overload_pressure = g(mn.OVERLOAD_PRESSURE, [])
+        self.overload_signal = g(mn.OVERLOAD_SIGNAL, [mn.L_SIGNAL])
         self.events_sampled = c(mn.EVENTS_SAMPLED, [])
         self.events_shed = c(mn.EVENTS_SHED, [mn.L_STAGE])
         self.accuracy_debt = c(mn.ACCURACY_DEBT, [])

@@ -54,14 +54,21 @@ class TransferQueue:
     dispatch thread via :class:`TransferMux`). append/popleft are the
     only hot-path operations; the events are parking lots, not locks."""
 
-    __slots__ = ("q", "depth", "space", "data", "wait_s")
+    __slots__ = ("q", "depth", "space", "data", "wait_s", "clock",
+                 "waiting_since")
 
-    def __init__(self, depth: int, data: threading.Event):
+    def __init__(self, depth: int, data: threading.Event,
+                 clock: Callable[[], float] = time.monotonic):
         self.q: deque = deque()
         self.depth = depth
         self.space = threading.Event()
         self.data = data  # shared with the mux: any producer wakes it
         self.wait_s = 0.0  # producer-side seconds spent waiting for space
+        self.clock = clock  # the engine's (injected in tests)
+        # Start of the producer's wait in progress (None: not waiting),
+        # so that a consumer that never frees a slot reads as a wait
+        # while it lasts, not once it is over (``waited``).
+        self.waiting_since: float | None = None
 
     def put(self, item: Any, alive: Optional[Callable[[], bool]] = None,
             ) -> bool:
@@ -77,19 +84,33 @@ class TransferQueue:
         while len(self.q) >= self.depth:
             if alive is not None and not alive():
                 if t0 is not None:
-                    self.wait_s += time.monotonic() - t0
+                    self.waiting_since = None
+                    self.wait_s += self.clock() - t0
                 return False
             if t0 is None:
-                t0 = time.monotonic()
+                t0 = self.waiting_since = self.clock()
             # Timeout bounds the one benign race (consumer sets space
             # between our len check and wait).
             self.space.wait(0.02)
             self.space.clear()
         if t0 is not None:
-            self.wait_s += time.monotonic() - t0
+            self.waiting_since = None
+            self.wait_s += self.clock() - t0
         self.q.append(item)  # noqa: RT402 — bounded: the loop above spins until len(q) < depth; consumer poplefts via TransferMux.get
         self.data.set()
         return True
+
+    def waited(self) -> float:
+        """Seconds the producer has waited for space, the wait in
+        progress included. A lock-free read of two fields: the total
+        is read first and a finished wait clears ``waiting_since``
+        before it adds to the total, so a wait that ends between the
+        two reads is missed for this reading, never counted twice."""
+        total = self.wait_s
+        since = self.waiting_since
+        if since is None:
+            return total
+        return total + max(0.0, self.clock() - since)
 
 
 class TransferMux:
@@ -126,13 +147,18 @@ class TransferMux:
                 out.append(tq.q.popleft())
         return out
 
-    def get(self, timeout: float | None = None) -> Any:
+    def get(self, timeout: float | None = None, steps: bool = True) -> Any:
+        """The next item: control lane first, then the workers' queues
+        round-robin. With ``steps`` false only the control lane is
+        served (the consumer holds all it may and takes no more step
+        items, but window ticks stay on cadence); the shutdown sentinel
+        then waits, as it does behind any undrained queue."""
         deadline = None if timeout is None else time.monotonic() + timeout
         while True:
             if self._ctl and self._ctl[0] is not None:
                 return self._ctl.popleft()
             draining = bool(self._ctl)  # head is the None sentinel
-            n = len(self._qs)
+            n = len(self._qs) if steps else 0
             for k in range(n):
                 tq = self._qs[(self._rr + k) % n]
                 try:
@@ -142,7 +168,7 @@ class TransferMux:
                 tq.space.set()
                 self._rr = (self._rr + k + 1) % n
                 return item
-            if draining:
+            if draining and steps:
                 return self._ctl.popleft()
             if deadline is not None and time.monotonic() >= deadline:
                 raise queue_mod.Empty
@@ -164,7 +190,7 @@ class FeedWorker(threading.Thread):
         self.idx = idx
         self.pool = pool
         self.staging: deque = deque()
-        self.outq = TransferQueue(pool.depth, data)
+        self.outq = TransferQueue(pool.depth, data, pool.clock)
         self.wake = threading.Event()
         self.events_in = 0       # distributor-only
         self.blocks_in = 0       # distributor-only
@@ -190,7 +216,7 @@ class FeedWorker(threading.Thread):
 
     def push(self, block) -> None:  # hot-path: event
         if self.pending_events() == 0:
-            self.first_t = time.monotonic()
+            self.first_t = self.pool.clock()
         self.staging.append(block)
         self.blocks_in += 1
         self.events_in += len(block)
@@ -260,7 +286,7 @@ class FeedWorker(threading.Thread):
                 continue
             if hb is not None:
                 hb.beat()
-            age = time.monotonic() - self.first_t
+            age = self.pool.clock() - self.first_t
             # Same flush policy as the inline feed: full quantum,
             # or the hard age bound, or an interval flush when the
             # dispatch pipeline is idle (latency priority only when
@@ -294,7 +320,7 @@ class FeedWorker(threading.Thread):
         # crunched.
         self.blocks_out += len(blocks)
         self.events_out += n_raw
-        self.first_t = time.monotonic()
+        self.first_t = self.pool.clock()
         self.fill = n_raw / max(self.pool.quantum, 1)
         from retina_tpu.obs.recorder import get_recorder
         from retina_tpu.utils import metric_names as mn
@@ -361,7 +387,11 @@ class FeedWorkerPool:
         register_hb: Optional[Callable[[str], Any]] = None,
         deregister_hb: Optional[Callable[[str], None]] = None,
         restart_policy: Optional[Callable[[str], Any]] = None,
+        clock: Callable[[], float] = time.monotonic,
     ):
+        # Block ages and handoff waits are read on the engine's clock
+        # (injected in tests); idle parking stays on the wall clock.
+        self.clock = clock
         self.quantum = max(1, int(quantum))
         self.staging_blocks = max(1, int(staging_blocks))
         self.flush_interval_s = flush_interval_s
@@ -445,7 +475,7 @@ class FeedWorkerPool:
         """Cumulative producer seconds spent waiting on a full transfer
         slot, summed over workers; the controller turns the delta into
         a wait rate (seconds waited per wall second)."""
-        return sum(w.outq.wait_s for w in self.workers)
+        return sum(w.outq.waited() for w in self.workers)
 
     def stats(self) -> dict[str, Any]:
         return {

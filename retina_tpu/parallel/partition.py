@@ -93,6 +93,15 @@ def _next_bucket(n: int) -> int:
     return ((n + step - 1) // step) * step
 
 
+def _bucket_for(n_max: int, capacity: int, min_bucket: int | None) -> int:
+    """Minor batch dim for ``n_max`` rows on the fullest device: the
+    full capacity without ``min_bucket``, else the smallest bucket that
+    holds them, at least ``min_bucket`` and at most ``capacity``."""
+    if min_bucket is None:
+        return capacity
+    return min(_next_bucket(max(n_max, min_bucket)), capacity)
+
+
 def partition_events(
     records: np.ndarray,
     n_devices: int,
@@ -127,9 +136,7 @@ def partition_events(
     width = records.shape[1]
 
     def bucket_for(n_max: int) -> int:
-        if min_bucket is None:
-            return capacity
-        return min(_next_bucket(max(n_max, min_bucket)), capacity)
+        return _bucket_for(n_max, capacity, min_bucket)
 
     if n_devices == 1:
         # Fast path: one shard takes everything — no connection hashing,
@@ -166,3 +173,48 @@ def partition_events(
     else:
         out = np.zeros((n_devices, bucket_for(0), width), np.uint32)
     return ShardedBatch(records=out, n_valid=n_valid, lost=lost, events=kept)
+
+
+def fold_batches(
+    batches: list[ShardedBatch],
+    capacity: int,
+    min_bucket: int | None = None,
+) -> tuple[ShardedBatch, int]:
+    """Fold the longest prefix of ``batches`` that fits one transfer
+    (``capacity`` rows a device, one sampling factor) into one
+    ShardedBatch: per device, the valid rows of each batch in order.
+    Returns the folded batch and how many batches it took (at least
+    one: a single batch is handed back as it is).
+
+    Rows are not combined again: a key that two batches carry crosses
+    as two rows, which every consumer of a batch already sums (an
+    uncombined feed hands them the same)."""
+    first = batches[0]
+    n_valid = first.n_valid.astype(np.int64)
+    took = 1
+    for sb in batches[1:]:
+        if sb.sample_k != first.sample_k:
+            break
+        total = n_valid + sb.n_valid
+        if int(total.max()) > capacity:
+            break
+        n_valid = total
+        took += 1
+    if took == 1:
+        return first, 1
+    n_devices, _, width = first.records.shape
+    bucket = _bucket_for(int(n_valid.max()), capacity, min_bucket)
+    out = np.zeros((n_devices, bucket, width), np.uint32)
+    at = np.zeros((n_devices,), np.int64)
+    for sb in batches[:took]:
+        for d in range(n_devices):
+            n = int(sb.n_valid[d])
+            out[d, at[d]:at[d] + n] = sb.records[d, :n]
+            at[d] += n
+    return ShardedBatch(
+        records=out,
+        n_valid=n_valid.astype(np.uint32),
+        lost=sum(sb.lost for sb in batches[:took]),
+        events=sum(sb.events for sb in batches[:took]),
+        sample_k=first.sample_k,
+    ), took

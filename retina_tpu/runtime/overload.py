@@ -8,9 +8,12 @@ monitor must shed LOW-VALUE work first and keep heavy-hitter accuracy;
 this module is that control loop.
 
 The controller watches normalized pressure signals the engine feeds it
-(per-worker staging fill, dispatch in-flight fill, handoff wait rate,
-harvest lag — plus the ``feed.backpressure`` fault site for chaos
-tests) and moves the pipeline through explicit states with hysteresis::
+(per-worker staging fill, handoff wait rate, harvest lag, dispatch
+latency — plus the ``feed.backpressure`` fault site for chaos tests)
+and moves the pipeline through explicit states with hysteresis. A full
+dispatch pipeline that is keeping up is not pressure, so the number of
+dispatches in flight is not a signal: every signal measures something
+piling up or something waiting::
 
     NOMINAL ──p≥enter──► SAMPLING ──p≥shed──► SHEDDING ──p≥degrade──► DEGRADED
        ◄──p≤exit for dwell_s── (one level per dwell period)
@@ -107,9 +110,13 @@ class OverloadController:
         self,
         cfg,
         signals: Callable[[], dict[str, float]] | None = None,
+        clock: Callable[[], float] = time.monotonic,
     ) -> None:
         self.cfg = cfg
         self._signals = signals or (lambda: {})
+        # The engine's clock (injected in tests): ticks, dwell and
+        # escalation periods are read on it.
+        self._clock = clock
         self.log = logger("overload")
         self._lock = threading.Lock()
         self._state = NOMINAL
@@ -120,7 +127,7 @@ class OverloadController:
         self._below_since: float | None = None
         self._shed_above_since: float | None = None
         self._transitions = 0
-        self._last_change = time.monotonic()
+        self._last_change = clock()
         self._phase = 0  # rotating 1-in-k phase  # guarded-by: self._lock
         # Window-scoped accounting the engine snapshots+resets at close.
         self._win_sampled = 0  # events dropped  # guarded-by: self._lock
@@ -134,7 +141,7 @@ class OverloadController:
         cfg = self.cfg
         if not getattr(cfg, "overload_enabled", True):
             return self._state
-        now = time.monotonic() if now is None else now
+        now = self._clock() if now is None else now
         if now - self._last_tick < cfg.overload_tick_s:
             return self._state
         self._last_tick = now
@@ -144,11 +151,23 @@ class OverloadController:
             self.log.exception("overload signal read failed")
             sig = {}
         p = max(sig.values(), default=0.0)
+        self._publish_signals(sig, p)
         with self._lock:
             self._pressure = p
             self._sigvals = dict(sig)
             self._advance(p, now)
             return self._state
+
+    def _publish_signals(self, sig: dict[str, float], p: float) -> None:
+        """What the controller was told, for the scrape: the pressure
+        it compares with its thresholds and every signal behind it (a
+        signal that was not reported this tick reads 0)."""
+        m = get_metrics()
+        m.overload_pressure.set(p)
+        for name in self._sigvals.keys() - sig.keys():
+            m.overload_signal.labels(signal=name).set(0.0)
+        for name, v in sig.items():
+            m.overload_signal.labels(signal=name).set(v)
 
     def _advance(self, p: float, now: float) -> None:
         cfg = self.cfg
@@ -336,7 +355,7 @@ class OverloadController:
                 "shed": list(self.shed_stages()),
                 "transitions": self._transitions,
                 "since_change_s": round(
-                    time.monotonic() - self._last_change, 1
+                    self._clock() - self._last_change, 1
                 ),
             }
 

@@ -117,11 +117,26 @@ RECOVERY_SECONDS = PREFIX + "tpu_recovery_seconds"
 # weight SYNTHESIZED by the device rescaling — the estimated (not
 # observed) share of the sketch totals.
 OVERLOAD_STATE = PREFIX + "tpu_overload_state"
+# What the controller was told at its last tick: overload_pressure is
+# the largest signal (what the thresholds are compared with),
+# overload_signal{signal} each one (staging, handoff_wait, harvest,
+# dispatch_lat, fault, degraded).
+OVERLOAD_PRESSURE = PREFIX + "tpu_overload_pressure"
+OVERLOAD_SIGNAL = PREFIX + "tpu_overload_signal"
+L_SIGNAL = "signal"
 EVENTS_SAMPLED = PREFIX + "tpu_events_sampled_counter"
 EVENTS_SHED = PREFIX + "tpu_events_shed_counter"
 ACCURACY_DEBT = PREFIX + "tpu_accuracy_debt_counter"
 DEVICE_STEP_SECONDS = PREFIX + "tpu_step_seconds"
 DEVICE_BATCH_FILL = PREFIX + "tpu_batch_fill_ratio"
+# The dispatch thread's folding (engine._dispatch_loop): fused steps
+# dispatched, valid rows folded into them (rows / (steps x
+# batch_capacity x devices) is the mean step fill), and flushes of the
+# feed (worker or inline quanta) folded into dispatches; a dispatch is
+# one observation of tpu_step_seconds.
+STEPS = PREFIX + "tpu_steps_counter"
+STEP_ROWS = PREFIX + "tpu_step_rows_counter"
+DISPATCH_FLUSHES = PREFIX + "tpu_dispatch_flushes_counter"
 WINDOWS_CLOSED = PREFIX + "tpu_windows_closed"
 COMBINE_RATIO = PREFIX + "host_combine_ratio"
 TRANSFER_SECONDS = PREFIX + "tpu_transfer_seconds"
@@ -330,6 +345,7 @@ STAGE_SHIP_READBACK = "ship_readback"
 STAGE_SHIP_ENCODE = "ship_encode"
 STAGE_SHIP_SEND = "ship_send"
 STAGE_AGG_MERGE = "aggregator_merge"
+STAGE_HUBBLE_CONSUME = "hubble_consume"
 
 # Ordered registry (pipeline order); drives the fixed label space of
 # tpu_stage_seconds and the bench critical-path report.
@@ -356,6 +372,7 @@ STAGES = (
     STAGE_SHIP_ENCODE,
     STAGE_SHIP_SEND,
     STAGE_AGG_MERGE,
+    STAGE_HUBBLE_CONSUME,
 )
 
 # Device-proxy call-kind registry (the ONLY legal values of the `kind`

@@ -1,0 +1,146 @@
+"""What BENCHMARK.json names, and the per-layer readers ISSUE 29 added
+(ROADMAP D10): every configuration, traffic mix and per-layer metric
+resolves to a file the harness finds by name; each new reader returns a
+number on a recorded load and nothing where its counter or span is
+absent, as on a parent commit's runs of the old cells."""
+
+import json
+import os
+import sys
+import types
+
+import pytest
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(HERE)
+BENCH = os.path.join(ROOT, "benchmarks")
+for p in (os.path.join(BENCH, "layer_metrics"), BENCH):
+    if p not in sys.path:
+        sys.path.insert(0, p)
+
+import harness  # noqa: E402
+import host_spans  # noqa: E402
+import traffic  # noqa: E402
+
+DOC = harness.load_benchmark()
+NEW_CELLS = ("advanced-pod.zipf1m-steady",
+             "advanced-pod-hubble.zipf1m-steady")
+NEW_READERS = ("steps_per_s", "step_fill_pct", "overload_pressure_p95",
+               "hubble_mirror_ms_per_s")
+
+
+def _config(name: str) -> dict:
+    entry = {c["name"]: c for c in DOC["configs"]}[name]
+    with open(os.path.join(ROOT, entry["file"])) as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize("cell", [w["name"] for w in DOC["workloads"]])
+def test_a_cell_resolves_to_its_files(cell):
+    """``harness.load_cell`` and ``traffic.load_mix`` find the cell's
+    configuration and traffic by the names BENCHMARK.json gives, and
+    the cell reports set-up, another end-to-end metric and a per-layer
+    metric whose reader is a file."""
+    w, config = harness.load_cell(DOC, cell)
+    assert config["name"] == w["config"]
+    assert w["name"] == f"{w['config']}.{w['traffic']}"
+    for rehearse in (False, True):
+        mix = traffic.load_mix(w["traffic"], rehearse)
+        assert mix.rate_events_per_s >= mix.ticks_per_s
+    e2e = [m["name"] for m in harness.metrics_of(DOC, "end_to_end", cell)]
+    assert "setup_s" in e2e and len(e2e) >= 2
+    layers = harness.metrics_of(DOC, "per_layer", cell)
+    assert layers
+    for m in layers:
+        assert hasattr(harness.load_reader(m["name"]), "read"), m["name"]
+        assert m["moves"] in e2e
+
+
+def test_the_ring_cadence_traffic_is_the_block_mix_at_sixteen_hand_overs():
+    steady = traffic.load_mix("zipf1m-steady")
+    block = traffic.load_mix("zipf1m-block1s")
+    assert steady.ticks_per_s == 16
+    assert steady.rate_events_per_s >= 262_144
+    assert steady.block_rows == steady.rate_events_per_s // 16 <= 65_536
+    for key in ("n_flows", "n_endpoints", "zipf_a", "drop_fraction",
+                "dns_fraction", "pool_events", "warmup_windows",
+                "poll_interval_s"):
+        assert getattr(steady, key) == getattr(block, key), key
+
+
+def test_the_hubble_configuration_is_the_configmap_with_nothing_off():
+    """``advanced-pod.json``'s agent group, guarantees and limits, key
+    for key; the machine group no longer switches Hubble off."""
+    hub, base = _config("advanced-pod-hubble"), _config("advanced-pod")
+    for group in ("agent", "sizing", "step_shapes", "guarantees", "held",
+                  "assumed", "rehearse", "rehearse_held", "reduced"):
+        assert hub[group] == base[group], group
+    assert hub["agent"]["enable_hubble"] is True
+    assert "enable_hubble" not in hub["machine"]
+    assert base["machine"]["enable_hubble"] is False
+    assert hub["machine"]["hubble_addr"] == "127.0.0.1:0"
+    assert hub["machine"]["hubble_metrics_addr"] == "127.0.0.1:0"
+    cells = {w["name"]: w for w in DOC["workloads"]}
+    for name in NEW_CELLS:
+        assert cells[name]["chips"] == 1
+        assert cells[name]["traffic"] == "zipf1m-steady"
+    hubble = {m["name"]: m for m in DOC["per_layer"]}[
+        "hubble_mirror_ms_per_s"]
+    assert hubble["workloads"] == ["advanced-pod-hubble.zipf1m-steady"]
+
+
+# -- the new readers on a recorded load -------------------------------------
+def _scrape(sent, **c):
+    return {"sent": sent, "done": sent + 0.01, "ok": True, "events": 0,
+            "c": c}
+
+
+def _load(scrapes, before=None, after=None):
+    """What the readers read of a ``harness.Load``: window [10, 60),
+    the configuration, the scrapes and the counters at the start of
+    the load and at the settled scrape."""
+    before, after = before or {}, after or {}
+    return types.SimpleNamespace(
+        trace=None, t_open=10.0, t_close=60.0, scrapes=scrapes,
+        config=_config("advanced-pod"),
+        counter_delta=lambda n: after.get(n, 0.0) - before.get(n, 0.0))
+
+
+RECORDED = _load(
+    [_scrape(9.0, tpu_steps_counter=100.0, tpu_overload_pressure=0.9),
+     _scrape(10.0, tpu_steps_counter=110.0, tpu_overload_pressure=0.10),
+     _scrape(35.0, tpu_steps_counter=360.0, tpu_overload_pressure=0.30),
+     _scrape(59.0, tpu_steps_counter=600.0, tpu_overload_pressure=0.20),
+     _scrape(61.0, tpu_steps_counter=999.0, tpu_overload_pressure=0.95)],
+    before={"tpu_steps_counter": 50.0, "tpu_step_rows_counter": 1000.0},
+    after={"tpu_steps_counter": 650.0,
+           "tpu_step_rows_counter": 1000.0 + 600 * 131072 * 0.25})
+# A parent's run: the poller sums nothing for a series that is not there.
+PARENT = _load(
+    [_scrape(t, tpu_steps_counter=0.0, tpu_overload_pressure=0.0)
+     for t in (9.0, 10.0, 35.0, 59.0)])
+SPANS = [{"stage": "hubble_consume", "t0": 12.0 + i, "t1": 12.004 + i,
+          "args": {"rows": 16384}} for i in range(25)]
+WANT = {"steps_per_s": (600.0 - 110.0) / 49.0, "step_fill_pct": 25.0,
+        "overload_pressure_p95": 0.30, "hubble_mirror_ms_per_s": 2.0}
+
+
+@pytest.mark.parametrize("name", NEW_READERS)
+def test_a_new_reader_returns_a_number_on_a_recorded_load(
+        name, monkeypatch):
+    monkeypatch.setattr(
+        host_spans, "window_spans",
+        lambda run, stage: [s for s in SPANS if s["stage"] == stage])
+    reader = harness.load_reader(name)
+    assert reader.read(RECORDED) == pytest.approx(WANT[name])
+    entry = {m["name"]: m for m in DOC["per_layer"]}[name]
+    assert reader.UNIT == entry["unit"]
+
+
+@pytest.mark.parametrize("name", NEW_READERS)
+def test_a_new_reader_says_nothing_where_its_source_is_absent(
+        name, monkeypatch):
+    monkeypatch.setattr(host_spans, "window_spans", lambda run, stage: [])
+    assert harness.load_reader(name).read(PARENT) is None
+    # Nor with no scrape inside the window at all.
+    assert harness.load_reader(name).read(_load([])) is None

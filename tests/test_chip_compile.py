@@ -178,6 +178,20 @@ def test_wire_unpack_compiles(chip):
     assert ma.output_size_in_bytes == b * NUM_FIELDS * 4
 
 
+def test_fold_of_a_flush_two_sides_compiles(chip):
+    """The program that folds a flush's new-descriptor window and its
+    known window into one step window (engine.fold_side_windows), at
+    the deployment's batch."""
+    from retina_tpu.engine import fold_side_windows
+
+    b = chip.cfg.batch_capacity
+    win = jax.ShapeDtypeStruct((1, b, NUM_FIELDS), jnp.uint32,
+                               sharding=chip.one)
+    nv = jax.ShapeDtypeStruct((1,), jnp.uint32, sharding=chip.one)
+    _, ma = compiled(fold_side_windows, win, nv, win, nv, donate=(0,))
+    assert ma.output_size_in_bytes >= b * NUM_FIELDS * 4
+
+
 def test_snapshot_merge_on_four_chips_has_collectives(chip):
     """The scrape-time merge across a 2x2 host: psum / pmax lower to
     all-reduce, the candidate tables to all-gather."""

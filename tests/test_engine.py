@@ -76,21 +76,31 @@ def test_engine_counts_match_exact():
 
 
 def test_engine_feed_loop_and_window():
+    """Driven by an injected clock (clockdrive): the flush ages, the
+    window tick and what the overload controller is told are the
+    test's, so a loaded machine (a cold ingest key compiling inline
+    for longer than a 0.2 s window) cannot read as a late device and
+    sample the counters."""
+    from clockdrive import Drive, FakeClock
+
     cfg = small_cfg()
-    eng = SketchEngine(cfg)
+    clock = FakeClock()
+    eng = SketchEngine(cfg, clock=clock)
     eng.update_identities({POD_NET + i: i for i in range(1, 20)})
     eng.compile()
     stop = threading.Event()
     t = threading.Thread(target=eng.start, args=(stop,), daemon=True)
     t.start()
-    assert eng.started.wait(2.0)
+    assert eng.started.wait(10.0)
+    drive = Drive(eng, clock)
     gen = TrafficGen(n_flows=500, n_pods=16, seed=3)
     for _ in range(5):
-        eng.sink.write_records(gen.batch(500), "test")
-        time.sleep(0.05)
-    time.sleep(0.5)  # at least one window close at 0.2s cadence
+        drive.hand_over(gen.batch(500), 0.05)
+    drive.settle()
+    drive.close_a_window()  # at least one close at 0.2 s cadence
+    assert eng.overload.stats()["transitions"] == 0
     stop.set()
-    t.join(3.0)
+    t.join(30.0)
     snap = eng.snapshot(max_age_s=0)
     assert snap["totals"][0] == 2500
     assert "entropy_bits" in eng.last_window

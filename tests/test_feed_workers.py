@@ -75,6 +75,46 @@ def test_transfer_queue_accounts_handoff_wait():
     assert tq.wait_s > 0.0
 
 
+def test_a_handoff_wait_in_progress_is_read_on_the_injected_clock():
+    """A consumer that never frees a slot reads as a wait while it
+    lasts (the overload controller's ``handoff_wait``), on the clock
+    the queue was given."""
+    from clockdrive import FakeClock, wait_until
+
+    clock = FakeClock()
+    tq = TransferQueue(1, threading.Event(), clock)
+    assert tq.put("a") and tq.waited() == 0.0
+    alive = [True]
+    t = threading.Thread(target=lambda: tq.put("b", alive=lambda: alive[0]))
+    t.start()
+    wait_until(lambda: tq.waiting_since is not None, "the producer waits")
+    clock.advance(2.5)
+    assert tq.waited() == pytest.approx(2.5) and tq.wait_s == 0.0
+    tq.q.popleft()
+    tq.space.set()
+    t.join(10.0)
+    assert not t.is_alive() and list(tq.q) == ["b"]
+    assert tq.waiting_since is None
+    assert tq.wait_s == tq.waited() == pytest.approx(2.5)
+
+
+def test_mux_serves_only_the_control_lane_when_asked():
+    """The dispatch thread, holding all one transfer may carry, takes
+    window ticks but no more step items; the shutdown sentinel waits
+    behind them."""
+    data = threading.Event()
+    q0 = TransferQueue(2, data)
+    mux = TransferMux([q0], data)
+    q0.put("s0")
+    mux.put_ctl("win")
+    mux.put_ctl(None)
+    assert mux.get(timeout=1.0, steps=False) == "win"
+    with pytest.raises(queue_mod.Empty):
+        mux.get(timeout=0.05, steps=False)
+    assert mux.get(timeout=1.0) == "s0"
+    assert mux.get(timeout=1.0) is None
+
+
 def test_mux_control_lane_has_priority_and_sentinel_drains_last():
     data = threading.Event()
     q0 = TransferQueue(2, data)
@@ -192,22 +232,27 @@ def test_dead_consumer_drops_are_counted_not_wedged():
 
 
 def _run_feed(cfg, n_events=1600):
-    eng = SketchEngine(cfg)
+    """One feed of ``n_events`` by an injected clock (clockdrive): on
+    the wall clock a loaded machine reads as a late device (with
+    ``feed_pipeline_depth=2`` one cold ingest key compiling inline
+    used to be the whole in-flight budget), the controller samples,
+    and the totals are estimates."""
+    from clockdrive import Drive, FakeClock
+
+    clock = FakeClock()
+    eng = SketchEngine(cfg, clock=clock)
     eng.update_identities({POD_NET + i: i for i in range(1, 20)})
     eng.compile()
     stop = threading.Event()
     t = threading.Thread(target=eng.start, args=(stop,), daemon=True)
     t.start()
-    assert eng.started.wait(5.0)
+    assert eng.started.wait(30.0)
+    drive = Drive(eng, clock)
     gen = TrafficGen(n_flows=50, n_pods=16, seed=3)
     for _ in range(n_events // 400):
-        eng.sink.write_records(gen.batch(400), "test")
-        time.sleep(0.03)
-    deadline = time.monotonic() + 20.0
-    while time.monotonic() < deadline:
-        if int(eng.snapshot(max_age_s=0)["totals"][0]) == n_events:
-            break
-        time.sleep(0.05)
+        drive.hand_over(gen.batch(400), 0.03)
+    drive.settle()
+    assert eng.overload.stats()["transitions"] == 0
     snap = eng.snapshot(max_age_s=0)
     stats = eng.feed_stats()
     stop.set()

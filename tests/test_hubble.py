@@ -7,6 +7,7 @@ import threading
 import time
 
 import numpy as np
+import pytest
 
 from retina_tpu.common import RetinaEndpoint
 from retina_tpu.controllers.cache import Cache
@@ -245,14 +246,15 @@ def test_observer_lazy_decode_memoizes():
     rec = b._batch.valid_rows()
     obs = FlowObserver(capacity=16)
     obs.consume(rec)
-    # Raw tuples in the ring before any read.
-    assert any(isinstance(e, tuple) for e in obs._ring if e is not None)
+    # Raw (block, row) slots in the ring before any read, nothing
+    # decoded.
+    assert obs.raw_slots() == 8 and not obs._memo
     flows, _ = obs.snapshot_flows()
     assert len(flows) == 8
     assert flows[0]["ip"]["source"] == "10.0.0.0"
-    # Memoized: ring now holds decoded dicts, not tuples.
-    assert all(not isinstance(e, tuple)
-               for e in obs._ring if e is not None)
+    # Memoized: every slot now reads as its decoded dict.
+    assert obs.raw_slots() == 0
+    assert len(obs._memo) == 8
     # Second read returns identical objects (no re-decode).
     flows2, _ = obs.snapshot_flows()
     assert flows2[0] is flows[0]
@@ -320,3 +322,82 @@ def test_relay_accounts_peer_reported_loss():
         if relay is not None:
             relay.stop()
         srv.stop()
+
+
+def _block(n, start=0):
+    import numpy as np
+
+    return np.stack([
+        mk_record(src=f"10.{(start + i) >> 16 & 255}."
+                  f"{(start + i) >> 8 & 255}.{(start + i) & 255}")
+        for i in range(n)])
+
+
+@pytest.mark.parametrize("sizes", [(5,), (64,), (200,), (40, 40, 40),
+                                   (64, 1), (3, 300, 2)])
+def test_observer_ring_is_the_last_capacity_records(sizes):
+    """A write costs per block: only the last ``capacity`` rows of a
+    block can ever be read, and the ring after any run of blocks is
+    ``record_to_flow`` of the last ``capacity`` records, oldest first."""
+    import numpy as np
+
+    from retina_tpu.hubble.flow import record_to_flow
+
+    obs = FlowObserver(capacity=64)
+    every = []
+    for k, n in enumerate(sizes):
+        b = _block(n, start=1000 * k)
+        obs.consume(b)
+        every.append(b)
+    rows = np.concatenate(every)
+    flows, end = obs.snapshot_flows()
+    assert end == obs.flows_seen == len(rows)
+    assert flows == [record_to_flow(r) for r in rows[-64:]]
+    # No block outlives its last readable row, and none is held
+    # beyond the rows that can be read.
+    assert len(obs._segs) <= 1 + sum(n < 64 for n in sizes)
+    assert all(first + len(rows) > end - 64 and len(rows) <= 64
+               for first, rows in obs._segs)
+    # The memo of decoded rows is pruned as they are lapped.
+    obs.consume(_block(64, start=9000))
+    assert not obs._memo and obs.raw_slots() == 64
+
+
+def test_observer_mixes_raw_blocks_and_decoded_flows():
+    """Relay peers write decoded flows into the same ring; a raw slot
+    they overwrite names its block no longer, and a reader that was
+    lapped is told how many it lost."""
+    obs = FlowObserver(capacity=8)
+    obs.consume(_block(6))
+    obs.consume_flows([{"peer": i} for i in range(4)])
+    flows, end = obs.snapshot_flows()
+    assert end == 10 and len(flows) == 8
+    assert [f.get("peer") for f in flows[-4:]] == [0, 1, 2, 3]
+    assert flows[0]["ip"]["source"] == "10.0.0.2"
+    obs.consume(_block(20, start=500))
+    got = []
+    for kind, payload in obs.follow_from(end):
+        got.append((kind, payload))
+        if len(got) == 9:
+            break
+    assert got[0] == ("lost", 12)
+    assert [p["ip"]["source"] for _, p in got[1:]] == [
+        f"10.0.{(500 + i) >> 8}.{(500 + i) & 255}" for i in range(12, 20)]
+    assert len(obs._segs) == 1 and obs.lost_observed == 12
+
+
+def test_observer_write_is_one_span_a_block():
+    from retina_tpu.obs.recorder import get_recorder
+    from retina_tpu.utils import metric_names as mn
+
+    rec = get_recorder()
+    n0 = len([s for s in rec.spans()
+              if s["stage"] == mn.STAGE_HUBBLE_CONSUME])
+    obs = FlowObserver(capacity=64)
+    obs.consume(_block(100))
+    obs.consume(np.zeros((0, 16), np.uint32))  # nothing to write: no span
+    spans = [s for s in rec.spans()
+             if s["stage"] == mn.STAGE_HUBBLE_CONSUME]
+    assert len(spans) == n0 + 1
+    assert spans[-1]["args"]["rows"] == 100
+    assert mn.STAGE_HUBBLE_CONSUME in mn.STAGES

@@ -735,6 +735,11 @@ def entry_audits() -> list[EntryAudit]:
     audits.append(
         _audit("engine.desc_table", eng._desc_table_fn().lower(), 0)
     )
+    # Folding a flush's two one-window sides into one step window is
+    # per device: each moves its own rows, nothing crosses the mesh.
+    audits.append(
+        _audit("engine.fold_sides", eng._fold_sides_fn(), 4, donate=(0,))
+    )
 
     # -- fleet merge ---------------------------------------------------
     agg = _fleet_stub()
@@ -938,6 +943,7 @@ RECIPE_COVERAGE = {
     "engine.ingest_new": "audit",
     "engine.ingest_known": "audit",
     "engine.desc_table": "audit",
+    "engine.fold_sides": "audit",
     "fleet.merge": "merge+audit",
     "timetravel.range_fold": "merge+audit",
     "timetravel.range_decode": "audit",
