@@ -42,7 +42,8 @@ from retina_tpu.models.pipeline import PipelineConfig
 from retina_tpu.obs.recorder import NULL_SPAN, initialize_recorder
 from retina_tpu.parallel.combine import combine_blocks
 from retina_tpu.parallel.feed import (
-    FeedWorkerPool, TransferMux, TransferQueue,
+    FEED_PARK_MAX_S, PARK_MAX_S, FeedWorkerPool, TransferMux,
+    TransferQueue, park,
 )
 from retina_tpu.parallel.flowdict import flow_dict_stats, make_flow_dict
 from retina_tpu.parallel.partition import (
@@ -127,11 +128,15 @@ class SketchEngine:
         self.log = logger("engine")
         # The clock of everything the engine decides by time: flush
         # ages, window ticks, the overload controller's ticks and the
-        # durations its signals are made of. Tests inject one they
-        # advance by hand, so that a loaded machine cannot read as a
-        # late device; idle parking and span times stay on the wall
-        # clock.
+        # durations its signals are made of, and the deadlines its
+        # feed threads sleep to (parallel/feed.park). Tests inject one
+        # they advance by hand, so that a loaded machine cannot read
+        # as a late device; a clock that can be advanced tells its
+        # sleepers (``on_advance``). Span times stay on the wall clock.
         self._clock = clock
+        on_advance = getattr(clock, "on_advance", None)
+        if on_advance is not None:
+            on_advance(self.wake)
         # Supervision (runtime/supervisor.py): when attached, every
         # long-lived engine thread registers a heartbeat with the
         # shared watchdog; standalone engines (tests, bench) get
@@ -193,6 +198,9 @@ class SketchEngine:
         self._busy_lock = threading.Lock()
         self._inflight_busy = 0
         self._held_flushes = 0
+        # What _dispatch_done calls once the count has fallen: start()
+        # sets it to wake whoever holds rows for a pipeline with room.
+        self._room_wake: Optional[Callable[[], None]] = None
         # Combiner thread count (native rt_combine_mt; 0 keeps the
         # cores-based default — 1 thread on single-core hosts).
         if cfg.host_combine_threads > 0:
@@ -2512,6 +2520,22 @@ class SketchEngine:
         with self._busy_lock:
             self._inflight_busy -= 1
         self._inflight.release()
+        # The count fell, then the wake: whoever holds rows for a
+        # pipeline with room (a worker's or the inline feed's partial
+        # quantum, the dispatch thread's held flushes) re-reads it.
+        wake = self._room_wake
+        if wake is not None:
+            wake()
+
+    def wake(self) -> None:
+        """Every thread of the feed path that sleeps to a deadline on
+        the engine's clock re-reads it: the hook of a clock advanced by
+        hand (tests/clockdrive). The dispatch thread waits for no time
+        of the engine's."""
+        self.sink.data.set()
+        pool = self._feed_pool
+        if pool is not None:
+            pool.wake_all()
 
     # -- adaptive overload control (runtime/overload.py) --------------
     def _overload_signals(self) -> dict[str, float]:
@@ -2668,12 +2692,14 @@ class SketchEngine:
         (``fold_batches``) the moment the pipeline has room: idle, or,
         for a full step's worth of rows, any free slot of
         ``feed_pipeline_depth``. No timer: a row waits for at most the
-        dispatch in flight. A window close overtakes what is held, as
-        it overtakes what is staged in the workers.
+        dispatch in flight, whose completion (``_dispatch_done``) wakes
+        this thread out of ``get``. A window close overtakes what is
+        held, as it overtakes what is staged in the workers.
 
-        The bounded-timeout get keeps the watchdog heartbeat honest:
-        the thread parks before each wait and beats only when
-        processing."""
+        ``get`` blocks until an item, that wake or its timeout (a
+        safety bound: nothing here is due by time). The thread parks
+        its watchdog heartbeat before each wait and beats only when
+        processing, so a long wait is not a stall."""
         hb = self._register_hb("engine-dispatch")
         coal = self.cfg.batch_capacity * max(
             1, self.cfg.feed_coalesce_windows
@@ -2687,7 +2713,7 @@ class SketchEngine:
                     # step items (the workers then wait on their
                     # handoff, which the controller reads), only ticks.
                     item = q.get(
-                        timeout=0.002 if held else 1.0,
+                        timeout=PARK_MAX_S,
                         steps=self._held_rows(held) < coal,
                     )
                 except queue_mod.Empty:
@@ -2804,6 +2830,9 @@ class SketchEngine:
             inline_data = threading.Event()
             inline_tq = TransferQueue(depth, inline_data, self._clock)
             q = TransferMux([inline_tq], inline_data)
+            # The pending quantum is this loop's and the held flushes
+            # the dispatch thread's: both wait for room.
+            self._room_wake = lambda: (q.wake(), self.sink.data.set())
 
         def drop_item(item):
             """Dead-worker path: account the loss, never enqueue into a
@@ -2877,6 +2906,7 @@ class SketchEngine:
                     clock=self._clock,
                 )
                 self._feed_pool = pool
+                self._room_wake = pool.wake_pending
                 q = pool.mux
             worker = threading.Thread(
                 target=self._dispatch_loop, args=(q,),
@@ -2988,7 +3018,11 @@ class SketchEngine:
                         flush()
                 sp_deal.end()
                 now = clock()
-                if n_pending and now - last_flush >= self.cfg.flush_interval_s:
+                # The pending quantum's two ages, as the deadlines the
+                # wait below sleeps to.
+                interval_due = last_flush + self.cfg.flush_interval_s
+                age_due = last_flush + self.cfg.flush_max_age_s
+                if n_pending and now >= interval_due:
                     # Interval flushes serve LATENCY and only make sense
                     # when the dispatch pipeline is idle; with work in
                     # flight, keep accumulating (bigger quanta combine
@@ -2996,9 +3030,7 @@ class SketchEngine:
                     # the hard age bound. Without this gate the fast
                     # async pipeline settles into many tiny flushes
                     # whose fixed costs cap throughput.
-                    if self._busy_count() == 0 or (
-                        now - last_flush >= self.cfg.flush_max_age_s
-                    ):
+                    if self._busy_count() == 0 or now >= age_due:
                         flush()
                 if now >= next_window:
                     submit(("window", None, 0, 0))
@@ -3015,7 +3047,24 @@ class SketchEngine:
                         (n_missed + 1) * self.cfg.window_seconds
                     )
                 if not blocks:
-                    stop.wait(0.002)
+                    # Sleep until a block arrives (the sink sets its
+                    # event) or the next thing due by the clock: the
+                    # window tick, the controller's tick, the pending
+                    # quantum's age (inline feed; past the interval
+                    # the pipeline is busy, and its going idle is
+                    # signalled by _dispatch_done). The caller's stop
+                    # event cannot signal this wait, hence its bound.
+                    due = [next_window]
+                    tick = self._overload.next_tick()
+                    if tick is not None:
+                        due.append(tick)
+                    if n_pending:
+                        due.append(
+                            interval_due if now < interval_due else age_due
+                        )
+                    hb_feed.park()
+                    park(self.sink.data, mnames.WAKE_FEED, clock,
+                         min(due), max_s=FEED_PARK_MAX_S)
         finally:
             hb_feed.park()
             self._deregister_hb("engine-feed")

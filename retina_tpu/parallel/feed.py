@@ -16,6 +16,14 @@ batch in flight on the dispatch side while the next is fully built —
 with no lock on the hot path (CPython deque append/popleft are atomic;
 events only park a side that has nothing to do).
 
+Every wait of the feed path is one idiom, :func:`park`: block on an
+event until whoever produces the data or changes the condition sets
+it, or until the next deadline that really exists on the engine's
+clock (a flush age, a window tick, the controller's tick), and never
+on a polling period. A thread woken a few hundred times a second costs
+nothing; six threads woken every 2 ms cost the chip host two thirds of
+the agent's CPU (PERF.md, PR 30).
+
 What does NOT move off the dispatch thread: flow-dict assignment, wire
 build, and the proxy submission. The v3 wire ordering contract (a new
 descriptor row must reach the device table before any known row
@@ -40,8 +48,51 @@ from collections import deque
 from typing import Any, Callable, Optional
 
 from retina_tpu.log import logger
+from retina_tpu.utils import metric_names as mn
 
 _log = logger("feed")
+
+# Longest single wait where no deadline exists: a lost wake-up costs at
+# most this (and tools/lint.py's RT400 wants every wait bounded).
+PARK_MAX_S = 1.0
+# Shortest: a deadline nearer than this is waited for this long.
+PARK_MIN_S = 0.001
+# The engine's feed loop is the one waiter its stop signal cannot reach
+# (the caller's own Event): with the overload controller off and no
+# window tick near, this bounds how late it notices a stop.
+FEED_PARK_MAX_S = 0.25
+
+
+def park(evt: threading.Event, thread: str,
+         clock: Callable[[], float] = time.monotonic,
+         deadline: float | None = None,
+         max_s: float | None = None) -> bool:
+    """Block until ``evt`` is set or ``clock`` reaches ``deadline``,
+    for ``max_s`` at most (None: ``PARK_MAX_S``), clear the event, and
+    count the wake-up under its cause. True if it was the event.
+
+    The caller re-reads its state AFTER this returns (the clear comes
+    before the re-read, so a ``set`` that lands in between is kept for
+    the next wait); a producer changes the state first and sets after.
+    The timeout is the distance to the deadline as ``clock`` reads it
+    now: a clock that is not the wall clock wakes its waiters when it
+    is advanced (``SketchEngine.wake``, which tests/clockdrive's
+    ``FakeClock.advance`` calls), and a waiter nobody wakes re-reads a
+    still clock once per that distance, not once per 2 ms."""
+    timeout = PARK_MAX_S if max_s is None else max_s
+    if deadline is not None:
+        # Never under PARK_MIN_S: a clock that stands an ulp short of
+        # the deadline (one advanced by hand) must not make this a spin.
+        timeout = min(timeout, max(PARK_MIN_S, deadline - clock()))
+    hit = evt.wait(timeout)
+    evt.clear()
+    from retina_tpu.metrics import get_metrics
+
+    get_metrics().feed_wakeups.labels(
+        thread=thread, cause=mn.CAUSE_DATA if hit else mn.CAUSE_DEADLINE
+    ).inc()
+    return hit
+
 
 # Handoff queue depth: double buffering. One batch being consumed, one
 # built and waiting. Deeper queues only add host memory and latency —
@@ -125,16 +176,30 @@ class TransferMux:
     as if they were still in the sink. The shutdown sentinel is the one
     exception: it is delivered only after EVERY worker queue has
     drained (workers are joined before the sentinel is enqueued, so
-    their queues are strictly draining by then)."""
+    their queues are strictly draining by then).
+
+    The consumer parks on ONE event (:func:`park`) for as long as its
+    caller's timeout says: every producer sets it after its append
+    (``TransferQueue.put``, ``put_ctl``), and ``wake`` sets it for a
+    condition the consumer waits on beside the items (the engine's
+    ``_dispatch_done``: the pipeline has room for what is held)."""
 
     def __init__(self, queues: list[TransferQueue], data: threading.Event):
         self._qs = queues
         self._ctl: deque = deque()
         self._data = data
         self._rr = 0
+        self._woken = False
 
     def put_ctl(self, item: Any) -> None:
         self._ctl.append(item)
+        self._data.set()
+
+    def wake(self) -> None:
+        """Return the consumer from ``get`` with no item
+        (``queue.Empty``) if none is there: something it waits on
+        beside the mux's items has changed."""
+        self._woken = True
         self._data.set()
 
     def drain_unconsumed(self) -> list:
@@ -152,7 +217,9 @@ class TransferMux:
         round-robin. With ``steps`` false only the control lane is
         served (the consumer holds all it may and takes no more step
         items, but window ticks stay on cadence); the shutdown sentinel
-        then waits, as it does behind any undrained queue."""
+        then waits, as it does behind any undrained queue. With nothing
+        to return it parks until a producer or ``wake`` sets the event,
+        for ``timeout`` at most (``queue.Empty``)."""
         deadline = None if timeout is None else time.monotonic() + timeout
         while True:
             if self._ctl and self._ctl[0] is not None:
@@ -170,10 +237,12 @@ class TransferMux:
                 return item
             if draining and steps:
                 return self._ctl.popleft()
+            if self._woken:
+                self._woken = False
+                raise queue_mod.Empty
             if deadline is not None and time.monotonic() >= deadline:
                 raise queue_mod.Empty
-            self._data.wait(0.002)
-            self._data.clear()
+            park(self._data, mn.WAKE_DISPATCH, deadline=deadline)
 
 
 class FeedWorker(threading.Thread):
@@ -273,35 +342,47 @@ class FeedWorker(threading.Thread):
                 self.pool.deregister_hb(self.name)
 
     def _loop(self, hb) -> None:  # hot-path: event
+        """Flush when a quantum is due; otherwise park (heartbeat
+        parked too) until what would make one due: a block
+        (``push`` sets ``wake``), the stop (``FeedWorkerPool.stop``),
+        the pipeline going idle (``FeedWorkerPool.wake_pending``, from
+        the engine's ``_dispatch_done``), or the staged quantum's next
+        age deadline on the pool's clock. Nothing staged: no
+        deadline."""
+        pool = self.pool
         while True:
-            stopping = self.pool.stop_evt.is_set()
+            stopping = pool.stop_evt.is_set()
             pend = self.pending_events()
-            if pend == 0:
-                if stopping:
-                    return
-                if hb is not None:
-                    hb.park()
-                self.wake.wait(0.002)
-                self.wake.clear()
-                continue
+            deadline = None
+            if pend:
+                # Same flush policy as the inline feed: full quantum,
+                # or the hard age bound, or an interval flush when the
+                # dispatch pipeline is idle (latency priority only when
+                # nothing is in flight). The two ages are read as the
+                # deadlines the wait below sleeps to, so that a clock
+                # that has reached one has reached the other.
+                first_t = self.first_t
+                now = pool.clock()
+                interval_due = first_t + pool.flush_interval_s
+                age_due = first_t + pool.flush_max_age_s
+                if (
+                    pend >= pool.quantum
+                    or stopping
+                    or now >= age_due
+                    or (now >= interval_due and pool.busy() == 0)
+                ):
+                    if hb is not None:
+                        hb.beat()
+                    self._flush()
+                    continue
+                # Past the interval the pipeline is busy: its going
+                # idle is signalled, the age bound is the deadline.
+                deadline = interval_due if now < interval_due else age_due
+            elif stopping:
+                return
             if hb is not None:
-                hb.beat()
-            age = self.pool.clock() - self.first_t
-            # Same flush policy as the inline feed: full quantum,
-            # or the hard age bound, or an interval flush when the
-            # dispatch pipeline is idle (latency priority only when
-            # nothing is in flight).
-            if not (
-                pend >= self.pool.quantum
-                or stopping
-                or age >= self.pool.flush_max_age_s
-                or (age >= self.pool.flush_interval_s
-                    and self.pool.busy() == 0)
-            ):
-                self.wake.wait(0.002)
-                self.wake.clear()
-                continue
-            self._flush()
+                hb.park()
+            park(self.wake, mn.WAKE_WORKER, pool.clock, deadline)
 
     def _flush(self) -> None:
         blocks = []
@@ -323,7 +404,6 @@ class FeedWorker(threading.Thread):
         self.first_t = self.pool.clock()
         self.fill = n_raw / max(self.pool.quantum, 1)
         from retina_tpu.obs.recorder import get_recorder
-        from retina_tpu.utils import metric_names as mn
 
         rec = get_recorder()
         with rec.span(mn.STAGE_FEED_FILL):
@@ -370,7 +450,8 @@ class FeedWorkerPool:
     ``drop(item)`` is called for any finished item the dispatch side
     will never consume (dead consumer) so losses are counted, never
     silent; ``busy()`` returns the in-flight dispatch count (interval
-    flush gating); ``alive()`` reports dispatch-thread liveness."""
+    flush gating: whoever lowers it calls ``wake_pending``);
+    ``alive()`` reports dispatch-thread liveness."""
 
     def __init__(
         self,
@@ -389,8 +470,8 @@ class FeedWorkerPool:
         restart_policy: Optional[Callable[[str], Any]] = None,
         clock: Callable[[], float] = time.monotonic,
     ):
-        # Block ages and handoff waits are read on the engine's clock
-        # (injected in tests); idle parking stays on the wall clock.
+        # Block ages, their flush deadlines and handoff waits are read
+        # on the engine's clock (injected in tests).
         self.clock = clock
         self.quantum = max(1, int(quantum))
         self.staging_blocks = max(1, int(staging_blocks))
@@ -435,6 +516,24 @@ class FeedWorkerPool:
                 return True
         return False
 
+    def wake_pending(self) -> None:
+        """The dispatch pipeline has gone idle (or has room): wake the
+        dispatch thread, which may hold flushes for it, and every
+        worker that holds a partial quantum for it (past
+        ``flush_interval_s`` it would otherwise sleep on to
+        ``flush_max_age_s``). The caller has already changed what
+        ``busy()`` returns. A worker with nothing staged needs no
+        wake: the block that changes that brings its own."""
+        self.mux.wake()
+        for w in self.workers:
+            if w.pending_events():
+                w.wake.set()
+
+    def wake_all(self) -> None:
+        """Every worker re-reads its clock (it was advanced by hand)."""
+        for w in self.workers:
+            w.wake.set()
+
     def count_drop(self, n_events: int) -> None:
         """Distributor-side drop accounting for a block no worker could
         take (the caller also counts it into lost_events)."""
@@ -453,8 +552,7 @@ class FeedWorkerPool:
         engine enqueues only after this returns)."""
         self.stop_evt.set()
         deadline = time.monotonic() + timeout
-        for w in self.workers:
-            w.wake.set()
+        self.wake_all()
         for w in self.workers:
             w.join(max(0.0, deadline - time.monotonic()))
             if w.is_alive():

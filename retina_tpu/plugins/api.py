@@ -153,6 +153,9 @@ class QueueSink:
         )
         self._accepted = 0
         self._accept_lock = threading.Lock()
+        # Set after every accepted block: the drainer parks on it
+        # (parallel/feed.park) instead of polling an empty queue.
+        self.data = threading.Event()
 
     def write_records(self, records: np.ndarray, plugin: str) -> int:
         # Stamped under the lock that orders the put, so the ring's
@@ -164,6 +167,7 @@ class QueueSink:
                 return 0
             self._accepted += len(records)
             self._accepts.append((time.monotonic(), self._accepted))
+        self.data.set()
         return len(records)
 
     def oldest_unheld(self, events_held: int) -> float | None:

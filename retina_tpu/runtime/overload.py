@@ -142,7 +142,7 @@ class OverloadController:
         if not getattr(cfg, "overload_enabled", True):
             return self._state
         now = self._clock() if now is None else now
-        if now - self._last_tick < cfg.overload_tick_s:
+        if now < self._last_tick + cfg.overload_tick_s:  # = next_tick()
             return self._state
         self._last_tick = now
         try:
@@ -157,6 +157,14 @@ class OverloadController:
             self._sigvals = dict(sig)
             self._advance(p, now)
             return self._state
+
+    def next_tick(self) -> float | None:
+        """When, on the controller's clock, the next ``tick`` will do
+        something (None: never, the controller is off): the deadline
+        the feed loop sleeps to when no block arrives sooner."""
+        if not getattr(self.cfg, "overload_enabled", True):
+            return None
+        return self._last_tick + self.cfg.overload_tick_s
 
     def _publish_signals(self, sig: dict[str, float], p: float) -> None:
         """What the controller was told, for the scrape: the pressure

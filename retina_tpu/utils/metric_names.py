@@ -87,6 +87,20 @@ FEED_WORKER_FILL = PREFIX + "tpu_feed_worker_fill_ratio"
 FEED_HANDOFF_WAIT = PREFIX + "tpu_feed_handoff_wait_seconds"
 FEED_BLOCKS_DROPPED = PREFIX + "tpu_feed_blocks_dropped"
 L_WORKER = "worker"
+# Returns from a wait of the feed path (parallel/feed.park), by the
+# thread that waited and by what ended the wait: ``data`` (the event it
+# parks on was set: a block, a finished batch, a window tick, a stop,
+# the pipeline going idle) or ``deadline`` (the wait ran out: a flush
+# age, a window tick, the controller's tick, or the safety bound of an
+# idle thread). A few hundred a second at any rate; thousands mean a
+# thread polls.
+FEED_WAKEUPS = PREFIX + "tpu_feed_wakeups_counter"
+L_CAUSE = "cause"
+WAKE_FEED = "feed"
+WAKE_WORKER = "worker"
+WAKE_DISPATCH = "dispatch"
+CAUSE_DATA = "data"
+CAUSE_DEADLINE = "deadline"
 # Window ticks deferred because the close program was still queued in
 # the background warm (engine._close_window_impl): the window stays
 # open instead of cold-compiling end_window inline mid-feed.

@@ -6,8 +6,11 @@ overload controller is told on the clock it is given
 (``SketchEngine(cfg, clock=...)``). A test that advances that clock by
 hand decides how much time the agent has seen, whatever the machine's
 load: a slow compile or a starved thread costs the test real seconds and
-the agent none. Waiting for a condition (``wait_until``) is not a sleep:
-it bounds nothing but the test's patience.
+the agent none. The engine's feed threads sleep to deadlines on that
+clock and poll nothing, so ``FakeClock.advance`` wakes them
+(``on_advance``: the engine registers its ``wake``). Waiting for a
+condition (``wait_until``) is not a sleep: it bounds nothing but the
+test's patience.
 """
 
 from __future__ import annotations
@@ -27,13 +30,33 @@ class FakeClock:
     def __init__(self, start: float = 1000.0):
         self._t = start
         self._lock = threading.Lock()
+        self._hooks: list = []
 
     def __call__(self) -> float:
         return self._t
 
+    def on_advance(self, hook) -> None:
+        """Call ``hook()`` after every ``advance``: whoever sleeps to a
+        deadline on this clock registers the wake of its sleepers."""
+        self._hooks.append(hook)
+
     def advance(self, dt: float) -> None:
         with self._lock:
             self._t += dt
+        for hook in list(self._hooks):
+            hook()
+
+
+def wakeups(thread: str | None = None, cause: str | None = None) -> float:
+    """``tpu_feed_wakeups_counter`` summed over the labels not given:
+    returns from a wait of the feed path so far in this test."""
+    from retina_tpu.metrics import get_metrics
+
+    return sum(
+        s.value for mf in get_metrics().feed_wakeups.collect()
+        for s in mf.samples if s.name.endswith("_total")
+        and thread in (None, s.labels["thread"])
+        and cause in (None, s.labels["cause"]))
 
 
 def wait_until(pred, what: str, timeout_s: float = 120.0) -> None:
@@ -98,7 +121,7 @@ class Drive:
             if eng._events_in >= self.offered and eng._busy_count() == 0:
                 return
             self.tick(dt)
-            # The threads this waits on wake on the wall clock.
+            # The tick woke the feed's sleepers: let them run.
             time.sleep(0.002)
         raise AssertionError(
             f"never settled: {eng._events_in} of {self.offered} events")

@@ -40,6 +40,19 @@ def _fresh_metric_singletons():
     yield
 
 
+@pytest.fixture
+def long_parks(monkeypatch):
+    """Moves the safety bound of the feed path's idle waits (1 s) out
+    of reach, so that a test can tell a signalled wake from it: a lost
+    wake-up then hangs the test instead of costing a second. The feed
+    loop keeps its own bound (its stop is not signalled)."""
+    import retina_tpu.engine as engine_mod
+    import retina_tpu.parallel.feed as feed_mod
+
+    monkeypatch.setattr(feed_mod, "PARK_MAX_S", 60.0)
+    monkeypatch.setattr(engine_mod, "PARK_MAX_S", 60.0)
+
+
 class CountingRender:
     """Stand-in for ``exporter.render_exposition``: the real bytes,
     counted by registry."""

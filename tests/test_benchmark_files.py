@@ -26,7 +26,7 @@ DOC = harness.load_benchmark()
 NEW_CELLS = ("advanced-pod.zipf1m-steady",
              "advanced-pod-hubble.zipf1m-steady")
 NEW_READERS = ("steps_per_s", "step_fill_pct", "overload_pressure_p95",
-               "hubble_mirror_ms_per_s")
+               "hubble_mirror_ms_per_s", "feed_wakeups_per_s")
 
 
 def _config(name: str) -> dict:
@@ -106,23 +106,31 @@ def _load(scrapes, before=None, after=None):
         counter_delta=lambda n: after.get(n, 0.0) - before.get(n, 0.0))
 
 
+# tpu_feed_wakeups_counter as the poller sums it over {thread, cause}.
 RECORDED = _load(
-    [_scrape(9.0, tpu_steps_counter=100.0, tpu_overload_pressure=0.9),
-     _scrape(10.0, tpu_steps_counter=110.0, tpu_overload_pressure=0.10),
-     _scrape(35.0, tpu_steps_counter=360.0, tpu_overload_pressure=0.30),
-     _scrape(59.0, tpu_steps_counter=600.0, tpu_overload_pressure=0.20),
-     _scrape(61.0, tpu_steps_counter=999.0, tpu_overload_pressure=0.95)],
+    [_scrape(9.0, tpu_steps_counter=100.0, tpu_overload_pressure=0.9,
+             tpu_feed_wakeups_counter=900.0),
+     _scrape(10.0, tpu_steps_counter=110.0, tpu_overload_pressure=0.10,
+             tpu_feed_wakeups_counter=1000.0),
+     _scrape(35.0, tpu_steps_counter=360.0, tpu_overload_pressure=0.30,
+             tpu_feed_wakeups_counter=3500.0),
+     _scrape(59.0, tpu_steps_counter=600.0, tpu_overload_pressure=0.20,
+             tpu_feed_wakeups_counter=5900.0),
+     _scrape(61.0, tpu_steps_counter=999.0, tpu_overload_pressure=0.95,
+             tpu_feed_wakeups_counter=9999.0)],
     before={"tpu_steps_counter": 50.0, "tpu_step_rows_counter": 1000.0},
     after={"tpu_steps_counter": 650.0,
            "tpu_step_rows_counter": 1000.0 + 600 * 131072 * 0.25})
 # A parent's run: the poller sums nothing for a series that is not there.
 PARENT = _load(
-    [_scrape(t, tpu_steps_counter=0.0, tpu_overload_pressure=0.0)
+    [_scrape(t, tpu_steps_counter=0.0, tpu_overload_pressure=0.0,
+             tpu_feed_wakeups_counter=0.0)
      for t in (9.0, 10.0, 35.0, 59.0)])
 SPANS = [{"stage": "hubble_consume", "t0": 12.0 + i, "t1": 12.004 + i,
           "args": {"rows": 16384}} for i in range(25)]
 WANT = {"steps_per_s": (600.0 - 110.0) / 49.0, "step_fill_pct": 25.0,
-        "overload_pressure_p95": 0.30, "hubble_mirror_ms_per_s": 2.0}
+        "overload_pressure_p95": 0.30, "hubble_mirror_ms_per_s": 2.0,
+        "feed_wakeups_per_s": (5900.0 - 1000.0) / 49.0}
 
 
 @pytest.mark.parametrize("name", NEW_READERS)
@@ -144,3 +152,31 @@ def test_a_new_reader_says_nothing_where_its_source_is_absent(
     assert harness.load_reader(name).read(PARENT) is None
     # Nor with no scrape inside the window at all.
     assert harness.load_reader(name).read(_load([])) is None
+
+
+def test_the_wakeup_metric_is_read_in_every_cell_off_the_programs_counter():
+    """``feed_wakeups_per_s`` names the feed's layer and the host's CPU,
+    lists no cells (every cell runs the feed), and the counter it asks
+    the poller for is the one the program registers, labels and all."""
+    from retina_tpu.metrics import get_metrics
+    from retina_tpu.utils import metric_names as mn
+
+    entry = {m["name"]: m for m in DOC["per_layer"]}["feed_wakeups_per_s"]
+    assert entry == {"name": "feed_wakeups_per_s", "unit": "wakeups/s",
+                     "better": "lower", "source": "program_counter",
+                     "layer": "feed + combine",
+                     "moves": "host_cpu_us_per_event"}
+    assert DOC["per_layer"][-1] is entry  # appended, nothing moved
+    reader = harness.load_reader("feed_wakeups_per_s")
+    assert mn.FEED_WAKEUPS == "networkobservability_" + reader.WAKEUPS
+    assert reader.COUNTERS == (reader.WAKEUPS,)
+    get_metrics().feed_wakeups.labels(
+        thread=mn.WAKE_FEED, cause=mn.CAUSE_DATA).inc(3)
+    get_metrics().feed_wakeups.labels(
+        thread=mn.WAKE_WORKER, cause=mn.CAUSE_DEADLINE).inc(4)
+    import poller
+    from retina_tpu.exporter import get_exporter
+
+    body = get_exporter().gather_text()
+    name = mn.FEED_WAKEUPS.encode()
+    assert poller.series_sum(body, (name,))[name] == 7.0

@@ -321,3 +321,310 @@ def test_paced_feed_no_subfloor_windows_with_workers():
     assert not t.is_alive()
     assert samples, "no ingest samples collected"
     assert all(s > 0 for s in samples), samples
+
+
+# -- waits: on data and on real deadlines, never on a period -----------
+# (ISSUE 30). Where a test has to tell a signalled wake from the safety
+# bound of an idle wait, conftest's ``long_parks`` moves that bound out
+# of reach: a wake-up that is lost then hangs the test instead of
+# costing a second.
+
+from clockdrive import wakeups  # noqa: E402
+
+
+def _take(pool, timeout_s: float = 10.0):
+    """The next item off the pool's mux, as the dispatch thread takes
+    it: a ``wake`` returns it empty-handed and it asks again."""
+    deadline = time.monotonic() + timeout_s
+    while time.monotonic() < deadline:
+        try:
+            return pool.mux.get(timeout=deadline - time.monotonic())
+        except queue_mod.Empty:
+            continue
+    raise AssertionError("nothing reached the mux")
+
+
+def _clocked_pool(clock, **kw):
+    pool = _mk_pool(clock=clock, **kw)
+    clock.on_advance(pool.wake_all)
+    return pool
+
+
+def test_park_counts_a_wake_by_its_cause_and_keeps_a_set_for_the_next():
+    from retina_tpu.parallel.feed import park
+
+    evt = threading.Event()
+    d0, t0 = wakeups("worker", "data"), wakeups("worker", "deadline")
+    assert park(evt, "worker", deadline=time.monotonic() + 0.01) is False
+    evt.set()
+    assert park(evt, "worker") is True and not evt.is_set()
+    # A deadline already past on the caller's clock is no wait at all.
+    t = time.monotonic()
+    assert park(evt, "worker", lambda: 100.0, deadline=99.0) is False
+    assert time.monotonic() - t < 0.5
+    assert wakeups("worker", "data") - d0 == 1
+    assert wakeups("worker", "deadline") - t0 == 2
+
+
+def test_mux_wake_returns_the_consumer_with_no_item(long_parks):
+    """``wake`` is for a condition the consumer waits on beside the
+    items: ``get`` comes back empty at once, and an item that is there
+    is still served first."""
+    data = threading.Event()
+    q0 = TransferQueue(2, data)
+    mux = TransferMux([q0], data)
+    got = []
+
+    def consume():
+        try:
+            got.append(mux.get(timeout=30.0))
+        except queue_mod.Empty:
+            got.append("empty")
+
+    t = threading.Thread(target=consume, daemon=True)
+    t.start()
+    time.sleep(0.05)
+    mux.wake()
+    t.join(10.0)
+    assert got == ["empty"]
+    q0.put("s0")
+    mux.wake()
+    assert mux.get(timeout=1.0) == "s0"
+    with pytest.raises(queue_mod.Empty):
+        mux.get(timeout=30.0)  # the wake that was kept
+
+
+def test_a_parked_worker_flushes_at_the_interval_with_no_poll_between(
+        long_parks):
+    """(b) A block pushed to a parked worker wakes it once; it then
+    sleeps to ``first_t + flush_interval_s`` on the injected clock, is
+    woken when that clock is advanced, and flushes when the interval
+    has passed: three wake-ups, however long the wall clock runs."""
+    from clockdrive import FakeClock, wait_until
+
+    clock = FakeClock()
+    pool = _clocked_pool(clock, n_workers=1, quantum=10_000,
+                         flush_interval_s=30.0, flush_max_age_s=3600.0)
+    pool.start()
+    w = pool.workers[0]
+    w0 = wakeups("worker")
+    assert pool.stage(np.zeros((7, 2), np.uint32))
+    wait_until(lambda: wakeups("worker") - w0 == 1, "the push wakes it")
+    time.sleep(0.3)  # 150 polls of 2 ms
+    assert wakeups("worker") - w0 == 1 and w.batches == 0
+    clock.advance(29.9)
+    wait_until(lambda: wakeups("worker") - w0 == 2, "the clock wakes it")
+    time.sleep(0.05)
+    assert w.batches == 0 and w.pending_events() == 7
+    clock.advance(0.2)
+    item = _take(pool)
+    assert len(item[1]) == 7 and wakeups("worker") - w0 == 3
+    pool.stop()
+
+
+def test_a_partial_quantum_behind_a_busy_pipeline_leaves_when_it_goes_idle(
+        long_parks):
+    """(c) Past ``flush_interval_s`` with a dispatch in flight, a
+    worker sleeps on to ``flush_max_age_s``. ``busy()`` falling to 0 is
+    signalled (``wake_pending``, which the engine's ``_dispatch_done``
+    calls): the quantum leaves then, on a clock that never reaches the
+    age bound. A worker with nothing staged is left asleep."""
+    from clockdrive import FakeClock, wait_until
+
+    clock = FakeClock()
+    busy = [1]
+    pool = _clocked_pool(clock, n_workers=2, quantum=10_000,
+                         flush_interval_s=0.05, flush_max_age_s=3600.0,
+                         busy=lambda: busy[0])
+    pool.start()
+    w0 = wakeups("worker")
+    assert pool.stage(np.zeros((7, 2), np.uint32))
+    wait_until(lambda: wakeups("worker") - w0 == 1, "the push wakes it")
+    clock.advance(0.06)  # past the interval; both workers re-read it
+    wait_until(lambda: wakeups("worker") - w0 == 3, "the clock wakes them")
+    time.sleep(0.05)
+    holder = pool.workers[0]
+    assert holder.batches == 0 and holder.pending_events() == 7
+    busy[0] = 0  # the state first, then the wake
+    pool.wake_pending()
+    item = _take(pool)
+    assert len(item[1]) == 7
+    assert clock() - 1000.0 < 0.1  # nowhere near the age bound
+    # The holder's wake and no other worker's.
+    assert wakeups("worker") - w0 == 4
+    pool.stop()
+
+
+@pytest.mark.parametrize("seed", [1, 2, 3, 4])
+def test_many_producers_and_a_stop_at_any_moment_lose_no_block_and_no_wake(
+        long_parks, seed):
+    """(g) Producers write a sink from many threads; a distributor
+    parks on the sink's event and deals to the pool as the engine's
+    feed loop does; a consumer takes the mux as the dispatch thread
+    does; the stop falls at a random moment. Every wait is bounded
+    only by a bound out of reach, so one lost wake-up hangs the round.
+    Every accepted event is consumed, counted as dropped by the pool,
+    or still in the sink the distributor left."""
+    import random
+
+    from retina_tpu.parallel.feed import park
+    from retina_tpu.plugins.api import QueueSink
+
+    rng = random.Random(seed)
+    sink = QueueSink(max_blocks=64)
+    pool = _mk_pool(n_workers=3, quantum=40, staging_blocks=4,
+                    flush_interval_s=0.002, flush_max_age_s=0.01)
+    stop = threading.Event()
+    consumed = [0]
+    accepted = [0] * 6
+
+    def distributor():
+        while not stop.is_set():
+            blocks = sink.drain()
+            for rec, _ in blocks:
+                if not pool.stage(rec):
+                    pool.count_drop(len(rec))
+            if not blocks:
+                park(sink.data, "feed", max_s=0.05)  # its stop bound
+        pool.stop()
+        pool.mux.put_ctl(None)
+
+    def consumer():
+        while True:
+            try:
+                item = pool.mux.get(timeout=60.0)
+            except queue_mod.Empty:
+                continue
+            if item is None:
+                return
+            consumed[0] += len(item[1])
+            if rng.random() < 0.3:
+                pool.wake_pending()  # completions come at any moment
+
+    def producer(k, n, gaps):
+        for i in range(n):
+            accepted[k] += sink.write_records(
+                np.full((1 + (i % 9), 2), k, np.uint32), "p")
+            if gaps[i]:
+                time.sleep(gaps[i])
+
+    pool.start()
+    plans = [(k, rng.randrange(20, 60),
+              [rng.choice((0, 0, 0.0005, 0.003)) for _ in range(60)])
+             for k in range(6)]
+    threads = [threading.Thread(target=distributor, daemon=True),
+               threading.Thread(target=consumer, daemon=True)]
+    threads += [threading.Thread(target=producer, args=p, daemon=True)
+                for p in plans]
+    for t in threads:
+        t.start()
+    time.sleep(rng.uniform(0.0, 0.15))
+    stop.set()
+    for t in threads:
+        t.join(30.0)
+        assert not t.is_alive(), "a waiter was never woken"
+    left = sum(len(rec) for rec, _ in sink.drain(max_blocks=10_000))
+    assert consumed[0] + pool.staging_dropped_events + left \
+        == sum(accepted) > 0
+    assert sum(w.events_out for w in pool.workers) == consumed[0]
+
+
+# -- the engine's six waiters ------------------------------------------
+
+WAITERS = ("engine-feed", "engine-dispatch", "feed-worker-0",
+           "feed-worker-1", "feed-worker-2", "feed-worker-3")
+
+
+class _Started:
+    """A started engine of the pool's size (four workers, the feed
+    loop, the dispatch thread) with its heartbeats in reach."""
+
+    def __init__(self, clock=None, **cfg_kw):
+        from retina_tpu.runtime.supervisor import Supervisor
+
+        cfg = small_cfg(feed_pipeline_depth=2, feed_workers=4,
+                        window_seconds=1.0, **cfg_kw)
+        self.sup = Supervisor(deadline_s=cfg.watchdog_deadline_s)
+        kw = {} if clock is None else {"clock": clock}
+        self.eng = SketchEngine(cfg, supervisor=self.sup, **kw)
+        self.eng.compile()
+        self.stop = threading.Event()
+        self.thread = threading.Thread(
+            target=self.eng.start, args=(self.stop,), daemon=True)
+
+    def __enter__(self):
+        from clockdrive import wait_until
+
+        self.thread.start()
+        assert self.eng.started.wait(30.0)
+        wait_until(lambda: all(self.parked(n) for n in WAITERS),
+                   "all six threads park")
+        return self
+
+    def parked(self, name: str) -> bool:
+        hb = self.sup.heartbeat(name)
+        return hb is not None and hb.parked
+
+    def __exit__(self, *exc):
+        self.stop.set()
+        self.thread.join(30.0)
+        assert not self.thread.is_alive()
+
+
+@pytest.mark.parametrize("which_clock", ["injected", "wall"])
+def test_an_idle_engine_wakes_under_30_times_a_second(which_clock):
+    """(a) Nothing to do: the feed loop wakes for the controller's
+    ticks and the window's, the rest for their safety bound. Six
+    pollers of 2 ms made 3,000 wake-ups of this second."""
+    from clockdrive import FakeClock
+
+    clock = FakeClock() if which_clock == "injected" else None
+    with _Started(clock):
+        time.sleep(0.2)
+        w0 = {t: wakeups(t) for t in ("feed", "worker", "dispatch")}
+        t0 = time.monotonic()
+        time.sleep(1.0)
+        dt = time.monotonic() - t0
+        got = {t: wakeups(t) - w0[t] for t in w0}
+    assert sum(got.values()) / dt < 30, got
+    assert got["feed"] >= 5, got  # the controller's ticks are kept
+
+
+def test_stop_wakes_every_waiter(long_parks):
+    """(e) All six threads parked, four of them with no deadline in
+    reach: the stop reaches each (the pool's stop sets the workers'
+    events, the sentinel the mux's; the feed loop's own bound is a
+    quarter of a second) and the engine is down well inside the limits
+    its shutdown allows (30 s a join)."""
+    s = _Started()
+    with s:
+        time.sleep(0.3)
+        assert all(s.parked(n) for n in WAITERS)
+        t0 = time.monotonic()
+        s.stop.set()
+        s.thread.join(10.0)
+        took = time.monotonic() - t0
+    assert not s.thread.is_alive() and took < 5.0, took
+    assert all(s.sup.heartbeat(n) is None for n in WAITERS)
+
+
+def test_a_thread_parked_past_the_watchdog_deadline_is_not_stalled(
+        long_parks):
+    """(f) The waits are long now: a worker with nothing staged and
+    the dispatch thread with nothing to take sleep for as long as
+    nothing happens. They park their heartbeats first, so a watchdog
+    whose deadline they outsleep many times over reports nothing."""
+    from retina_tpu.metrics import get_metrics
+
+    with _Started(watchdog_deadline_s=0.05) as s:
+        w0 = wakeups("worker") + wakeups("dispatch")
+        for _ in range(10):
+            time.sleep(0.06)
+            assert s.sup.scan_once() == []
+        # Nobody woke them meanwhile: they really slept through it.
+        assert wakeups("worker") + wakeups("dispatch") == w0
+        assert all(s.sup.heartbeat(n).stalls == 0 for n in WAITERS)
+        stalls = [smp.value for mf in
+                  get_metrics().watchdog_stalls.collect()
+                  for smp in mf.samples if smp.name.endswith("_total")]
+        assert sum(stalls) == 0

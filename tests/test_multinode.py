@@ -45,9 +45,11 @@ def test_flow_from_agent_a_visible_via_relay_b(agent_a):
     )
     relay.start()
     try:
-        # Flows ingested in process A must reach B's local ring.
+        # Flows ingested in process A must reach B's local ring: the
+        # five asked for below (the relay takes them off A's stream one
+        # by one; asking at the first used to race the other four).
         assert wait_until(
-            lambda: relay.observer.flows_seen > 0, deadline_s=30.0
+            lambda: relay.observer.flows_seen >= 5, deadline_s=30.0
         ), "no flows crossed processes"
 
         # And be served from B's own Cilium-compatible surface, with A's

@@ -29,7 +29,7 @@ import reference  # noqa: E402
 import traffic  # noqa: E402
 
 from clockdrive import (  # noqa: E402
-    Drive, FakeClock, controller_after, wait_until,
+    Drive, FakeClock, controller_after, wait_until, wakeups,
 )
 
 from retina_tpu.engine import SketchEngine  # noqa: E402
@@ -204,6 +204,52 @@ def test_the_dispatch_thread_folds_what_accumulates_behind_a_busy_device(
     # behind it is not pressure: no transition.
     assert eng.feed_stats()["dispatch"] == {"in_flight": 0,
                                            "held_flushes": 0}
+    assert eng.overload.stats()["transitions"] == 0
+
+
+def test_a_completion_wakes_the_holders_of_rows_not_the_clock(
+        rig, long_parks):
+    """(c), (d) With a dispatch hung on the proxy, one worker holds a
+    partial quantum past ``flush_interval_s`` and the dispatch thread
+    holds a flush: both wait for the pipeline, and neither waits by
+    the clock (their idle bound is out of reach and the clock stands
+    still). The completion (``_dispatch_done``) wakes both: the held
+    flush and the partial quantum are dispatched with no further tick,
+    far from ``flush_max_age_s``."""
+    eng, mix, drive, clock = rig.eng, rig.mix, rig.drive, rig.clock
+    drive.settle()
+    pool = traffic.make_pool(mix, seed=2904)
+    fwd0, _ = rig.counters()
+    m = get_metrics()
+    d0 = _count(m.device_step_seconds)
+    faults.configure("transfer:hang@1")
+    per = 512
+    drive.stage(pool[:per].copy())
+    clock.advance(0.06)
+    wait_until(lambda: eng._busy_count() == 1, "the first dispatch hangs")
+    # A flush for the dispatch thread to hold: old enough to leave its
+    # worker by age.
+    drive.stage(pool[per:2 * per].copy())
+    clock.advance(eng.cfg.flush_max_age_s)
+    wait_until(lambda: eng._held_flushes == 1, "a flush is held")
+    # A partial quantum for a worker to hold: past the interval only.
+    drive.stage(pool[2 * per:3 * per].copy())
+    clock.advance(eng.cfg.flush_interval_s + 0.01)
+    holders = [w for w in eng._feed_pool.workers if w.pending_events()]
+    assert len(holders) == 1
+    w0, t0 = wakeups("worker"), clock()
+    wait_until(lambda: wakeups("worker") > w0, "the clock wakes them")
+    assert holders[0].pending_events() == per
+    assert eng._held_flushes == 1 and eng._busy_count() == 1
+    assert _count(m.device_step_seconds) == d0
+    faults.release_hangs()
+    wait_until(lambda: eng._events_in >= drive.offered
+               and eng._busy_count() == 0 and eng._held_flushes == 0,
+               "the completion wakes the holders")
+    assert clock() == t0  # no tick did it
+    want = reference.Counts(mix.n_endpoints).add(pool[:3 * per])
+    fwd1, _ = rig.counters()
+    assert np.array_equal(fwd1 - fwd0, want.fwd)
     assert eng.overload.stats()["transitions"] == 0
 
 
