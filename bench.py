@@ -444,7 +444,7 @@ def run_e2e(smoke: bool, duration_s: float | None = None) -> dict:
     log(f"e2e: link bandwidth probe {link_mbs:.0f} MB/s")
 
     # Host-path capability probe (no device): the REAL per-quantum feed
-    # work — combine + partition + flow-dict assign + v3 wire build —
+    # work — combine + partition + flow-dict assign + dense wire build —
     # the ceiling the host CPU side imposes when the link stops being
     # the bottleneck (production PCIe). Median of 3 quanta; the steady
     # state (all descriptors known) is what it measures.
@@ -452,7 +452,11 @@ def run_e2e(smoke: bool, duration_s: float | None = None) -> dict:
     from retina_tpu.parallel.combine import combine_blocks
     from retina_tpu.parallel.flowdict import make_flow_dict
     from retina_tpu.parallel.partition import partition_events
-    from retina_tpu.parallel.wire import known_rows
+    from retina_tpu.events.schema import F
+    from retina_tpu.native import flowwire_dense_native
+    from retina_tpu.parallel.wire import (
+        DENSE_BY_BITS, DENSE_PK_BITS, dense_known_rows, dense_words,
+    )
 
     probe_gen = TrafficGen(
         n_flows=50_000 if smoke else 1_000_000,
@@ -464,7 +468,6 @@ def run_e2e(smoke: bool, duration_s: float | None = None) -> dict:
     n_quantum = sum(len(b) for b in blocks)
     fd_bits = 18 if smoke else 21
     fdict = make_flow_dict(1 << fd_bits)
-    id_bits = np.uint32(fd_bits)
     comb0 = combine_blocks(blocks)
     fdict.lookup_or_assign(
         partition_events(comb0, 1, 1 << 19, min_bucket=1 << 12)
@@ -477,11 +480,24 @@ def run_e2e(smoke: bool, duration_s: float | None = None) -> dict:
         sb = partition_events(comb, 1, 1 << 19, min_bucket=1 << 12)
         rows = sb.records[0, : int(sb.n_valid[0])]
         ids, is_new = fdict.lookup_or_assign(rows)
-        rk = rows[~is_new]
-        known_wire = np.empty((len(rk), 2), np.uint32)
-        # Same encoding helper the engine's dispatch uses — the probe
-        # must price the real wire build, not an approximation of it.
-        known_rows(rk, ids[~is_new], id_bits, known_wire)
+        # Same mask and builders as the engine's dispatch (rows here
+        # are stamped and carry no TSval) — the probe must price the
+        # real wire build, not an approximation of it.
+        sel = (
+            is_new
+            | (rows[:, F.PACKETS] >= 1 << DENSE_PK_BITS)
+            | (rows[:, F.BYTES] >= 1 << DENSE_BY_BITS)
+        )
+        n_new = int(sel.sum())
+        new_wire = np.zeros((n_new, 13), np.uint32)
+        known_wire = np.zeros(
+            dense_words(len(rows) - n_new, fd_bits), np.uint32
+        )
+        if flowwire_dense_native(
+            np.ascontiguousarray(rows), ids, sel.astype(np.uint8), 0,
+            fd_bits, DENSE_PK_BITS, DENSE_BY_BITS, new_wire, known_wire,
+        ) is None:
+            dense_known_rows(rows[~sel], ids[~sel], fd_bits, known_wire)
         rates.append(n_quantum / (time.perf_counter() - t0))
     host_path_rate = sorted(rates)[1]
     log(f"e2e: host-path probe {host_path_rate / 1e6:.1f}M ev/s median "
@@ -903,7 +919,6 @@ def run_e2e(smoke: bool, duration_s: float | None = None) -> dict:
         # because every worker's staging was saturated.
         "feed": {
             "workers": feed.get("workers", 0),
-            "mode": feed.get("mode", "inline"),
             "worker_fill": [
                 w["fill"] for w in feed.get("per_worker", [])
             ],

@@ -516,9 +516,8 @@ class Agent:
         quantum is dealt over the feed workers and each flushes at most
         its share, so no wire bucket above that share's is reached."""
         eng = self.engine
-        workers = eng._resolve_feed_workers() \
-            if eng.cfg.feed_pipeline_depth > 0 else 1
-        share = -(-self.size.quantum_blocks // max(1, workers))
+        workers = eng._resolve_feed_workers()
+        share = -(-self.size.quantum_blocks // workers)
         top = eng._wire_bucket(share * self.size.block_rows)
         buckets = [b for b in eng._reachable_buckets() if b <= top]
         keys = [(kind, b) for b in buckets for kind in ("known", "new")]

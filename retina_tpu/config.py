@@ -131,33 +131,27 @@ class Config:
     # stay below the metrics publish interval (1s) or scrapes lag.
     flush_max_age_s: float = 0.4
     mesh_devices: int = 0  # 0 = all local devices
-    # Host-side RLE combining before the host->device transfer (the eBPF
-    # map pre-aggregation analog, parallel/combine.py). Lossless; off only
-    # for debugging raw row flow.
-    host_combine: bool = True
     # Worker threads for the native combiner (combine.cpp
-    # rt_combine_mt): per-thread partial combines + one small merge.
+    # rt_combine_mt; host-side RLE combining before the host->device
+    # transfer, the eBPF map pre-aggregation analog, lossless):
+    # per-thread partial combines + one small merge.
     # 0 = auto (RETINA_COMBINE_THREADS env, else cores-1 capped at 4 —
     # 1 on single-core hosts, i.e. the single-threaded pass).
     host_combine_threads: int = 0
-    # Depth of the in-flight transfer queue between the batcher thread and
-    # the device dispatch thread (engine.py), and the bound on dispatches
-    # in flight: submitted, and their last step not yet finished on the
-    # device (transfers queue back-to-back on the device proxy so the
-    # host->device link never idles between dispatch round-trips). While
-    # any is in flight the dispatch thread holds and folds the feed's
-    # flushes; only a full step's worth of rows takes a second or third
-    # slot. 0 = synchronous dispatch on the feed thread (no overlap).
+    # Bound on dispatches in flight behind the dispatch thread
+    # (engine.py): submitted, and their last step not yet finished on
+    # the device (transfers queue back-to-back on the device proxy so
+    # the host->device link never idles between dispatch round-trips).
+    # While any is in flight the dispatch thread holds and folds the
+    # feed's flushes; only a full step's worth of rows takes a second
+    # or third slot. At least 1.
     feed_pipeline_depth: int = 3
-    # Sharded multi-worker host feed (parallel/feed.py): N feed workers
-    # each own a staging buffer, combine+partition their quantum in
-    # parallel (the native combiner releases the GIL), and hand
-    # finished batches to the single dispatch thread through a
-    # double-buffered transfer queue. 0 = auto (cores-1 capped at 4);
-    # values <= 1 keep the inline single-thread feed — a pool of one
-    # adds a handoff without adding a core. Requires
-    # feed_pipeline_depth > 0 (the sync path has no dispatch thread to
-    # hand off to).
+    # Host feed pool (parallel/feed.py): N feed workers each own a
+    # staging buffer, combine+partition their quantum in parallel (the
+    # native combiner releases the GIL), and hand finished batches to
+    # the single dispatch thread through a double-buffered transfer
+    # queue. 0 = auto (cores-1 capped at 4, at least 1); n >= 1 = a
+    # pool of n.
     feed_workers: int = 0
     # Per-worker staging bound, in raw sink blocks. A block that finds
     # every worker's staging full is dropped + counted (lost_events
@@ -188,25 +182,15 @@ class Config:
     # link at their own (bucketed) size and are padded to batch_capacity
     # on device, where HBM bandwidth makes padding free (engine pad jit).
     transfer_min_bucket: int = 1 << 12
-    # 12-lane packed wire format (parallel/wire.py) instead of the 16-lane
-    # schema layout; unpacked on device. Off only for debugging.
-    transfer_packed: bool = True
-    # v2/v3 wire: device-resident flow-descriptor dictionary. Each
-    # distinct combined-flow descriptor crosses the link ONCE (12 lanes
-    # + id); every later occurrence crosses as an 8-byte
-    # [id | packets << id_bits, bytes] pair and the descriptor lanes are
-    # gathered back from HBM (parallel/flowdict.py + engine ingest).
-    # Steady-state wire bytes/event drop ~6x on long-lived flows.
-    # Requires transfer_packed.
+    # Device-resident flow-descriptor dictionary over the 12-lane packed
+    # wire (parallel/wire.py). Each distinct combined-flow descriptor
+    # crosses the link ONCE (12 lanes + id); every later occurrence
+    # crosses as a dense (id_bits + 10 + 22)-bit row (6.25 B at an
+    # 18-bit id space; rows whose PACKETS/BYTES overflow the narrow
+    # lanes ship full rows) and the descriptor lanes are gathered back
+    # from HBM (parallel/flowdict.py + engine ingest). Off = every row
+    # ships packed in full.
     wire_flow_dict: bool = True
-    # v4 wire: pack known-flow rows as a DENSE bitstream —
-    # (id_bits + 10 + 22) contiguous bits per row (parallel/wire.py
-    # dense layer) instead of two full u32 lanes: 6.25 B/row at the
-    # default 18-bit id space vs 8. Rows whose PACKETS/BYTES overflow
-    # the narrow lanes escalate to the full-row side (same contract as
-    # the v3 packet-overflow escalation). Off = v3 two-lane rows, for
-    # debugging/bisection only.
-    wire_dense_known: bool = True
     # Device descriptor-table slots (48 B/slot/device). Must exceed the
     # live distinct-descriptor count or the dictionary cycles
     # (generation clear -> one re-upload burst).
@@ -485,6 +469,11 @@ class Config:
                 f"dataAggregationLevel must be {AGG_LOW!r} or {AGG_HIGH!r}, "
                 f"got {self.data_aggregation_level!r}"
             )
+        if self.feed_pipeline_depth < 1:
+            raise ValueError(
+                f"feed_pipeline_depth must be >= 1, "
+                f"got {self.feed_pipeline_depth}"
+            )
         if not (0.0 < self.warm_duty_cycle <= 1.0):
             raise ValueError(
                 f"warm_duty_cycle must be in (0, 1], "
@@ -646,13 +635,10 @@ class Config:
                 "heavy_keys_source must be 'flowdict', 'invertible' or "
                 f"'both', got {self.heavy_keys_source!r}"
             )
-        if self.heavy_keys_source == "both" and not (
-            self.transfer_packed and self.wire_flow_dict
-        ):
+        if self.heavy_keys_source == "both" and not self.wire_flow_dict:
             raise ValueError(
                 "heavy_keys_source='both' validates the invertible decode "
-                "against the flow dict, which requires transfer_packed "
-                "and wire_flow_dict"
+                "against the flow dict, which requires wire_flow_dict"
             )
         for f in ("invertible_width", "invertible_hi_width"):
             v = getattr(self, f)

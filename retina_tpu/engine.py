@@ -42,8 +42,7 @@ from retina_tpu.models.pipeline import PipelineConfig
 from retina_tpu.obs.recorder import NULL_SPAN, initialize_recorder
 from retina_tpu.parallel.combine import combine_blocks
 from retina_tpu.parallel.feed import (
-    FEED_PARK_MAX_S, PARK_MAX_S, FeedWorkerPool, TransferMux,
-    TransferQueue, park,
+    FEED_PARK_MAX_S, PARK_MAX_S, FeedWorkerPool, park,
 )
 from retina_tpu.parallel.flowdict import flow_dict_stats, make_flow_dict
 from retina_tpu.parallel.partition import (
@@ -184,9 +183,7 @@ class SketchEngine:
         # the host->device link runs back-to-back transfers instead of
         # idling for a dispatch round-trip between quanta (VERDICT r3
         # weak #1).
-        self._inflight = threading.Semaphore(
-            max(1, cfg.feed_pipeline_depth)
-        )
+        self._inflight = threading.Semaphore(cfg.feed_pipeline_depth)
         # Count of dispatches in flight: submitted, and their last step
         # not yet finished ON THE DEVICE (the completion thread says
         # when, _dispatch_done). The feed flushes at flush_interval_s
@@ -198,16 +195,13 @@ class SketchEngine:
         self._busy_lock = threading.Lock()
         self._inflight_busy = 0
         self._held_flushes = 0
-        # What _dispatch_done calls once the count has fallen: start()
-        # sets it to wake whoever holds rows for a pipeline with room.
-        self._room_wake: Optional[Callable[[], None]] = None
         # Combiner thread count (native rt_combine_mt; 0 keeps the
         # cores-based default — 1 thread on single-core hosts).
         if cfg.host_combine_threads > 0:
             from retina_tpu.native import set_combine_threads
 
             set_combine_threads(cfg.host_combine_threads)
-        # v2 wire: flow-descriptor dictionary (parallel/flowdict.py).
+        # Flow-descriptor dictionary (parallel/flowdict.py).
         # Host side assigns stable device-table slots; the device table
         # itself is created lazily ON device (zeros jit — a host-side
         # 48MB/device upload would saturate the link it exists to save).
@@ -217,29 +211,22 @@ class SketchEngine:
         # close invertible decode instead of host descriptor slots.
         self._flow_dict = (
             make_flow_dict(cfg.flow_dict_slots)
-            if cfg.transfer_packed and cfg.wire_flow_dict
+            if cfg.wire_flow_dict
             and cfg.heavy_keys_source != "invertible"
             else None
         )
-        # v3 wire: known-flow rows are TWO u32 lanes — [id | packets <<
-        # id_bits, bytes] — 8 bytes/row instead of 16. Packets ride the
-        # id lane's headroom; rows whose packet count exceeds it (or any
-        # new descriptor) ship full rows instead (escalation is
-        # idempotent: re-scattering a resident descriptor is a no-op for
+        # Known-flow rows pack DENSE — (id_bits + 10 + 22) contiguous
+        # bits per row streamed into one u32 word array (parallel/wire.py
+        # dense layer): 6.25 B/row at an 18-bit id space. Rows whose
+        # PACKETS/BYTES overflow the narrow lanes (or any new
+        # descriptor) ship full rows instead (escalation is idempotent:
+        # re-scattering a resident descriptor is a no-op for
         # correctness). Known rows' per-row timestamps are replaced by
         # the flush's base timestamp; rows where exact per-row time
         # matters — TSval/TSecr carriers (RTT matcher) and unstamped
         # rows (TS_REL=0 round-trip) — escalate to the full-row side
         # (see _dispatch_flowdict).
         self._fd_id_bits = max(1, (cfg.flow_dict_slots - 1).bit_length())
-        self._fd_pk_bits = 32 - self._fd_id_bits
-        # v4 wire: known rows pack DENSE — (id_bits + 10 + 22)
-        # contiguous bits per row streamed into one u32 word array
-        # (parallel/wire.py dense layer) instead of two full u32 lanes:
-        # 6.25 B/row at the default 18-bit id space vs 8. Rows whose
-        # PACKETS/BYTES overflow the narrow lanes escalate to the
-        # full-row side exactly like the v3 packet-overflow escalation.
-        self._fd_dense = bool(cfg.wire_dense_known)
         self._fd_lock = threading.Lock()
         # AOT disk-cache signature for the per-bucket ingest
         # executables (_compile_cached): every config field that
@@ -248,8 +235,7 @@ class SketchEngine:
         self._aot_sig = "|".join(
             str(x) for x in (
                 cfg.batch_capacity, cfg.flow_dict_slots,
-                int(bool(cfg.transfer_packed)), self._fd_id_bits,
-                int(self._fd_dense), NUM_FIELDS,
+                self._fd_id_bits, NUM_FIELDS,
             )
         )
         # heavy_keys_source="both": host-side per-key packet ground
@@ -265,10 +251,6 @@ class SketchEngine:
         # via invertible_report()).
         self._inv_lock = threading.Lock()
         self._inv_last: Optional[dict] = None  # guarded-by: self._inv_lock
-        import os as _os
-
-        # Cached once: the trace flag is read on every dispatch.
-        self._feed_trace = _os.environ.get("RETINA_FEED_TRACE") == "1"
         self._desc_table: Any = None  # guarded-by: self._fd_lock
         # Bumped ONLY by failure resyncs (not by capacity-overflow
         # generation clears, which keep the device table intact and are
@@ -337,8 +319,7 @@ class SketchEngine:
         # cold-compiling end_window inline on the proxy mid-feed
         # (windows_deferred counts them; the window just stays open).
         self._close_warmed = threading.Event()
-        # Sharded multi-worker feed pool (parallel/feed.py), created by
-        # start() when feed_workers resolves to > 1.
+        # Feed worker pool (parallel/feed.py), created by start().
         self._feed_pool: Any = None
         # Adaptive overload control (runtime/overload.py): the feed
         # loop ticks the controller against the engine's pressure
@@ -379,8 +360,8 @@ class SketchEngine:
         self.anomaly_hook: Any = None
         # Record tap (detect/base.py DetectorBank.observe): sees every
         # record block on the ingest path before partitioning — in
-        # _build_quantum post-combine on the live feed (inline flush
-        # AND feed workers; the bank serializes internally), and in
+        # _build_quantum post-combine on the live feed (feed workers;
+        # the bank serializes internally), and in
         # _dispatch for direct callers (step_records, recovery probe).
         # The two sites are disjoint, so no block is tapped twice.
         # Must stay cheap — the bank does vectorized feature folds
@@ -925,8 +906,7 @@ class SketchEngine:
                 jobs.append((("known", b), self._ingest_known_fn, (b,)))
                 jobs.append((("new", b), self._ingest_new_fn, (b,)))
             else:
-                packed = bool(self.cfg.transfer_packed)
-                jobs.append(((b, packed), self._ingest_fn, (b, packed)))
+                jobs.append((b, self._ingest_fn, (b,)))
             if i == 0:
                 if self._flow_dict is not None:
                     jobs.append((
@@ -1100,10 +1080,10 @@ class SketchEngine:
         return ex
 
     @device_entry("engine.ingest", kind="jit")
-    def _ingest_fn(self, bucket: int, packed: bool):  # runs-on: device-proxy
+    def _ingest_fn(self, bucket: int):  # runs-on: device-proxy
         """Per-bucket jit that turns ONE transferred (D, bucket, P) wire
         array + a small metadata vector into step-ready device inputs:
-        unpack the 12-lane wire format (when packed), slice the bucket
+        unpack the 12-lane wire format, slice the bucket
         into ceil(bucket/capacity) windows of the step's static
         (D, B, 16) shape (zero-extending the last), and derive each
         window's validity counts — the host->device link carries only the
@@ -1116,8 +1096,7 @@ class SketchEngine:
         Returns (windows, window_n_valid, now_s, lost) — all on device,
         so the following step dispatches move no further host data.
         """
-        key = (bucket, packed)
-        fn = self._pad_cache.get(key)
+        fn = self._pad_cache.get(bucket)
         if fn is None:
             cap = self.cfg.batch_capacity
             n_win = max(1, -(-bucket // cap))
@@ -1142,10 +1121,7 @@ class SketchEngine:
             @_partial(jax.jit, out_shardings=out_sh, donate_argnums=(0,))
             def ingest(small, meta):
                 with jax.named_scope(SCOPE_INGEST_UNPACK):
-                    if packed:
-                        small = unpack_records_device(
-                            small, meta[0], meta[1]
-                        )
+                    small = unpack_records_device(small, meta[0], meta[1])
                     nv = meta[5:].astype(jnp.int32)
                     wins, nvs = [], []
                     for w in range(n_win):
@@ -1170,10 +1146,9 @@ class SketchEngine:
             # cache miss at feed time costs only the compile (persistent
             # XLA cache across restarts), never a mid-feed trace+infer
             # surprise on the proxy thread.
-            width = PACKED_FIELDS if packed else NUM_FIELDS
-            fn = self._compile_cached("ingest", key, lambda: ingest.lower(
+            fn = self._compile_cached("ingest", bucket, lambda: ingest.lower(
                 jax.ShapeDtypeStruct(
-                    (self.n_devices, bucket, width), jnp.uint32,
+                    (self.n_devices, bucket, PACKED_FIELDS), jnp.uint32,
                     sharding=self._rec_sharding,
                 ),
                 jax.ShapeDtypeStruct(
@@ -1181,10 +1156,10 @@ class SketchEngine:
                     sharding=self._replicated,
                 ),
             ))
-            self._pad_cache[key] = fn
+            self._pad_cache[bucket] = fn
         return fn
 
-    # -- v2 wire: flow-descriptor dictionary path ---------------------
+    # -- flow-descriptor dictionary path ------------------------------
     def _flowdict_resync(self) -> None:
         """Invalidate host dict + device table together after a failure
         that may have desynced them (one descriptor re-upload burst, no
@@ -1337,13 +1312,10 @@ class SketchEngine:
         row (1 = stamped at the flush base meta[0:2], 0 = unstamped
         flush).
 
-        Wire layout depends on ``_fd_dense`` (wire_dense_known):
-          v3 (dense off): (D, bucket, 2) of [id | packets << id_bits,
-              bytes] — 8 B/row instead of the 48 B full row.
-          v4 (dense on, default): (D, W) bitstream of
-              (id_bits + 10 + 22)-bit rows (parallel/wire.py dense
-              layer) — 6.25 B/row at the default 18-bit id space; the
-              device side unpacks with two-word gathers.
+        Wire layout: a (D, W) bitstream of (id_bits + 10 + 22)-bit rows
+        (parallel/wire.py dense layer) — 6.25 B/row at an 18-bit id
+        space instead of the 48 B full row; the device side unpacks
+        with two-word gathers.
 
         Reference analog: the kernel map hit path — established flows
         move counters only (conntrack.c ct_process_packet accumulate).
@@ -1360,17 +1332,6 @@ class SketchEngine:
                 unpack_records_device,
             )
 
-            # HOST scalars (np, not jnp), deliberately: a jnp scalar
-            # here becomes a committed DEVICE array captured as a
-            # trace-closure constant, and lowering such a constant
-            # does a device->host _value copy — which, issued from a
-            # background-warm lower() while the feed keeps the device
-            # queue busy, has starved for minutes and frozen the whole
-            # proxy (unverified on the attached chip). np scalars lower
-            # to MLIR literals with zero device traffic.
-            id_bits = np.uint32(self._fd_id_bits)
-            id_mask = np.uint32((1 << self._fd_id_bits) - 1)
-            dense = self._fd_dense
             out_sh = (
                 (self._rec_sharding,) * n_win,
                 (self._rec_sharding,) * n_win,
@@ -1385,14 +1346,9 @@ class SketchEngine:
             @_partial(jax.jit, out_shardings=out_sh, donate_argnums=(0,))
             def ingest(wire, meta, table):
                 with jax.named_scope(SCOPE_INGEST_UNPACK):
-                    if dense:
-                        ids, pk, by = dense_known_unpack_device(
-                            wire, bucket, self._fd_id_bits
-                        )
-                    else:
-                        ids = wire[..., 0] & id_mask
-                        pk = wire[..., 0] >> id_bits
-                        by = wire[..., 1]
+                    ids, pk, by = dense_known_unpack_device(
+                        wire, bucket, self._fd_id_bits
+                    )
                     d_idx = jnp.arange(ids.shape[0])[:, None]
                     desc = table[d_idx, ids]  # (D, bucket, 12)
                     desc = desc.at[..., 6].set(pk)  # PACKETS
@@ -1407,14 +1363,10 @@ class SketchEngine:
                     )
                 return wins, nvs, meta[2], meta[3]
 
-            wire_shape = (
-                (self.n_devices, dense_words(bucket, self._fd_id_bits))
-                if dense else (self.n_devices, bucket, 2)
-            )
             fn = self._compile_cached("ingest_known", key, lambda: ingest.lower(
                 jax.ShapeDtypeStruct(
-                    wire_shape, jnp.uint32,
-                    sharding=self._rec_sharding,
+                    (self.n_devices, dense_words(bucket, self._fd_id_bits)),
+                    jnp.uint32, sharding=self._rec_sharding,
                 ),
                 jax.ShapeDtypeStruct(
                     (5 + self.n_devices,), jnp.uint32,
@@ -1508,17 +1460,16 @@ class SketchEngine:
     ) -> None:
         """Flow-dictionary dispatch: split the partitioned batch into
         new-descriptor rows (full 12-lane upload + table insert) and
-        known rows (8-byte [id|packets, bytes] tuples against the
-        resident table — v3 wire, see __init__). Known rows whose packet
-        count overflows the id lane's headroom escalate to the new side
+        known rows (dense [id, packets, bytes] bit-rows against the
+        resident table, see __init__). Known rows whose packet or byte
+        count overflows its narrow lane escalate to the new side
         (idempotent re-scatter). Both ride one proxy submission,
         FIFO-ordered so inserts land before gathers."""
         from retina_tpu.parallel.wire import (
             DENSE_BY_BITS, DENSE_PK_BITS, batch_ts_base,
-            dense_known_rows, dense_words, known_rows, pack_records,
+            dense_known_rows, dense_words, pack_records,
         )
 
-        t_d0 = time.monotonic()
         tid = fleet_epoch(self.cfg.window_seconds)
         sp_build = self._recorder.span(mnames.STAGE_WIRE_BUILD, tid)
         m = get_metrics()
@@ -1540,45 +1491,36 @@ class SketchEngine:
             fd_entries = len(self._flow_dict)
             fd_generation = self._flow_dict.generation
         base = batch_ts_base(sb.records)
-        dense = self._fd_dense
-        pk_cap = np.uint32(1) << np.uint32(
-            DENSE_PK_BITS if dense else self._fd_pk_bits
-        )
-        id_bits = np.uint32(self._fd_id_bits)
+        pk_cap = np.uint32(1) << np.uint32(DENSE_PK_BITS)
+        by_cap = np.uint32(1) << np.uint32(DENSE_BY_BITS)
+        id_bits = self._fd_id_bits
         # Escalate to the full-row side (exact per-row fields) any known
         # row the narrow lanes cannot represent faithfully: packet
-        # counts over the packets lane's headroom, rows carrying
-        # TSval/TSecr (the RTT matcher needs their EXACT send time —
-        # the flush-base stamp below would record phantom times), and
-        # unstamped rows (TS_REL=0 must round-trip to ts 0,
-        # wire.py:17-23). The dense wire additionally escalates rows
-        # whose BYTES overflow the 22-bit lane (v3 ships bytes as a
-        # full u32). The masks are computed once and reused for
-        # sizing + build. All in-tree sources stamp and TSval rows are
-        # apiserver-RTT traffic only, so escalation stays rare.
+        # counts over the 10-bit packets lane, bytes over the 22-bit
+        # bytes lane, rows carrying TSval/TSecr (the RTT matcher needs
+        # their EXACT send time — the flush-base stamp below would
+        # record phantom times), and unstamped rows (TS_REL=0 must
+        # round-trip to ts 0, wire.py:17-23). The masks are computed
+        # once and reused for sizing + build. All in-tree sources stamp
+        # and TSval rows are apiserver-RTT traffic only, so escalation
+        # stays rare.
         sel_new = [
             x[2]
             | (x[0][:, F.PACKETS] >= pk_cap)
+            | (x[0][:, F.BYTES] >= by_cap)
             | ((x[0][:, F.TSVAL] | x[0][:, F.TSECR]) != 0)
             | ((x[0][:, F.TS_LO] | x[0][:, F.TS_HI]) == 0)
             for x in per_dev
         ]
-        if dense:
-            by_cap = np.uint32(1) << np.uint32(DENSE_BY_BITS)
-            for s, x in zip(sel_new, per_dev):
-                s |= x[0][:, F.BYTES] >= by_cap
         n_new = [int(s.sum()) for s in sel_new]
         n_known = [len(x[0]) - nn for x, nn in zip(per_dev, n_new)]
         Bn = self._wire_bucket(max(n_new) if n_new else 0)
         Bk = self._wire_bucket(max(n_known) if n_known else 0)
         new_wire = np.zeros((D, Bn, 13), np.uint32)
-        known_wire = np.zeros(
-            (D, dense_words(Bk, int(id_bits))) if dense else (D, Bk, 2),
-            np.uint32,
-        )
+        known_wire = np.zeros((D, dense_words(Bk, id_bits)), np.uint32)
         nv_new = np.zeros((D,), np.uint32)
         nv_known = np.zeros((D,), np.uint32)
-        from retina_tpu.native import flowwire_dense_native, flowwire_native
+        from retina_tpu.native import flowwire_dense_native
 
         for d, (rows, ids, _) in enumerate(per_dev):
             sel = sel_new[d]
@@ -1599,21 +1541,12 @@ class SketchEngine:
                 # One native pass builds both sides in place — the
                 # numpy path below pays two fancy-indexed row copies +
                 # a pack pass + two bit-pack passes per device.
-                if dense:
-                    got = flowwire_dense_native(
-                        np.ascontiguousarray(rows), ids,
-                        sel.astype(np.uint8), int(base),
-                        int(self._fd_id_bits),
-                        DENSE_PK_BITS, DENSE_BY_BITS,
-                        new_wire[d], known_wire[d],
-                    )
-                else:
-                    got = flowwire_native(
-                        np.ascontiguousarray(rows), ids,
-                        sel.astype(np.uint8), int(base),
-                        int(self._fd_id_bits),
-                        new_wire[d], known_wire[d],
-                    )
+                got = flowwire_dense_native(
+                    np.ascontiguousarray(rows), ids,
+                    sel.astype(np.uint8), int(base), id_bits,
+                    DENSE_PK_BITS, DENSE_BY_BITS,
+                    new_wire[d], known_wire[d],
+                )
             if got is not None:
                 assert got == nn, (got, nn)
             elif len(rows):
@@ -1624,14 +1557,7 @@ class SketchEngine:
                     new_wire[d, : len(rn), 0] = idn
                     new_wire[d, : len(rn), 1:] = packed12
                 if len(rk):
-                    if dense:
-                        dense_known_rows(
-                            rk, idk, int(id_bits), known_wire[d]
-                        )
-                    else:
-                        known_rows(
-                            rk, idk, id_bits, known_wire[d, : len(rk)]
-                        )
+                    dense_known_rows(rk, idk, id_bits, known_wire[d])
             nv_new[d] = nn
             nv_known[d] = nk
         if record_metrics and lost:
@@ -1840,20 +1766,11 @@ class SketchEngine:
                 if self._fatal_device_error(e):
                     self._request_recovery(repr(e))
 
-        t_d1 = time.monotonic()
         sp_build.end()
         self._dispatch_submitted()
         submit_on_device(
             safe_xfer_and_step, kind=mnames.KIND_STEP, parent=sp_build.id
         )
-        if self._feed_trace:
-            self.log.info(
-                "dispatch trace: build %.0fms inflight-wait %.0fms "
-                "(%d new / %d known rows)",
-                (t_d1 - t_d0) * 1e3,
-                (time.monotonic() - t_d1) * 1e3,
-                int(nv_new.sum()), int(nv_known.sum()),
-            )
 
     def _dispatch_sharded(
         self, sb: "ShardedBatch", now_s: int, n_raw: int,
@@ -1921,18 +1838,12 @@ class SketchEngine:
         sp_build = self._step_span(
             mnames.STAGE_WIRE_BUILD, tid, record_metrics
         )
-        if self.cfg.transfer_packed:
-            from retina_tpu.parallel.wire import pack_records
+        from retina_tpu.parallel.wire import pack_records
 
-            wire, b_lo, b_hi = pack_records(sb.records)
-            packed = True
-        else:
-            # Async consumption below: the single-device partition fast
-            # path may alias the caller's buffer (ALIASING CONTRACT in
-            # partition_events) — copy so the producer can reuse it.
-            wire = sb.records if sync else np.array(sb.records)
-            b_lo = b_hi = np.uint32(0)
-            packed = False
+        # A fresh array: the partition fast path may alias the caller's
+        # buffer (ALIASING CONTRACT in partition_events), the packed
+        # wire never does, so the producer can reuse it.
+        wire, b_lo, b_hi = pack_records(sb.records)
         if record_metrics:
             m.transfer_bytes.inc(wire.nbytes)
         bucket = wire.shape[1]
@@ -1965,9 +1876,9 @@ class SketchEngine:
             wire_dev, meta_dev = jax.device_put(
                 (wire, meta), (self._rec_sharding, self._replicated)
             )
-            wins, nvs, now_dev, lost_dev = self._ingest_fn(
-                bucket, packed
-            )(wire_dev, meta_dev)
+            wins, nvs, now_dev, lost_dev = self._ingest_fn(bucket)(
+                wire_dev, meta_dev
+            )
             sp_x.end()
             sp_s = self._step_span(
                 mnames.STAGE_DEVICE_STEP, tid, record_metrics
@@ -2486,10 +2397,11 @@ class SketchEngine:
         submit_on_device(safe_close, kind=mnames.KIND_CLOSE)
 
     def _resolve_feed_workers(self) -> int:
-        """Feed-worker count: config value, or auto-size to the machine
-        (cores minus one for the distributor+dispatch threads, capped at
-        4 — staging memory and combine-lock contention grow past that
-        with no measured throughput gain). 1 means inline feed."""
+        """Size of the feed-worker pool, at least 1: the config value,
+        or auto-sized to the machine (cores minus one for the
+        distributor+dispatch threads, capped at 4 — staging memory and
+        combine-lock contention grow past that with no measured
+        throughput gain)."""
         n = self.cfg.feed_workers
         if n <= 0:
             cores = os.cpu_count() or 1
@@ -2497,9 +2409,8 @@ class SketchEngine:
         return n
 
     def _busy_count(self) -> int:  # runs-on: feed-worker*
-        """In-flight dispatch count for feed-worker interval-flush
-        gating (same signal the inline feed loop and the dispatch
-        thread's folding read)."""
+        """In-flight dispatch count: gates the feed workers' interval
+        flushes and the dispatch thread's folding."""
         with self._busy_lock:
             return self._inflight_busy
 
@@ -2521,11 +2432,11 @@ class SketchEngine:
             self._inflight_busy -= 1
         self._inflight.release()
         # The count fell, then the wake: whoever holds rows for a
-        # pipeline with room (a worker's or the inline feed's partial
-        # quantum, the dispatch thread's held flushes) re-reads it.
-        wake = self._room_wake
-        if wake is not None:
-            wake()
+        # pipeline with room (a worker's partial quantum, the dispatch
+        # thread's held flushes) re-reads it.
+        pool = self._feed_pool
+        if pool is not None:
+            pool.wake_pending()
 
     def wake(self) -> None:
         """Every thread of the feed path that sleeps to a deadline on
@@ -2609,9 +2520,9 @@ class SketchEngine:
         self, blocks: list[np.ndarray], n_raw: int, now_s: int
     ) -> list[tuple]:
         """Combine + partition one flush quantum into dispatchable step
-        items. Pure host work, shared by the inline flush and the feed
-        workers (parallel/feed.py), where it runs concurrently — the
-        native combiner releases the GIL and partition is numpy."""
+        items. Pure host work that the feed workers (parallel/feed.py)
+        run concurrently — the native combiner releases the GIL and
+        partition is numpy."""
         cap = self.cfg.batch_capacity * self.n_devices
         coal = cap * max(1, self.cfg.feed_coalesce_windows)
         coal_per_dev = self.cfg.batch_capacity * max(
@@ -2620,15 +2531,8 @@ class SketchEngine:
         with self._recorder.span(
             mnames.STAGE_COMBINE, fleet_epoch(self.cfg.window_seconds)
         ):
-            if self.cfg.host_combine:
-                all_rec = combine_blocks(blocks)
-                get_metrics().combine_ratio.set(
-                    n_raw / max(len(all_rec), 1)
-                )
-            elif len(blocks) == 1:
-                all_rec = blocks[0]
-            else:
-                all_rec = np.concatenate(blocks, axis=0)
+            all_rec = combine_blocks(blocks)
+            get_metrics().combine_ratio.set(n_raw / max(len(all_rec), 1))
         if self.record_hook is not None:
             try:
                 self.record_hook(all_rec, now_s)
@@ -2659,11 +2563,8 @@ class SketchEngine:
         ``feed`` debug var and bench result JSON: per-worker fill /
         staged backlog / handoff wait, pool drop counters, and the
         flow-dict residency summary."""
-        pool = self._feed_pool
-        if pool is not None:
-            st = pool.stats()
-        else:
-            st = {"workers": 0, "mode": "inline", "per_worker": []}
+        pool = self._feed_pool  # None until start()
+        st = pool.stats() if pool is not None else {}
         st["flow_dict"] = flow_dict_stats(self._flow_dict)
         st["overload"] = self._overload.stats()
         # The dispatch thread's folding: dispatches the device has not
@@ -2678,10 +2579,9 @@ class SketchEngine:
         order, without waiting for the device round-trip. Packing batch
         N+1 here overlaps batch N's in-flight transfer on the proxy
         thread, and the bounded pipeline keeps the host->device link
-        busy back-to-back (VERDICT r2 weak #1, r3 weak #1). ``q`` is a
-        TransferMux (the feed pool's, or the inline feed's over its one
-        queue): ``get()`` blocks and delivers ``None`` as the shutdown
-        sentinel.
+        busy back-to-back (VERDICT r2 weak #1, r3 weak #1). ``q`` is the
+        feed pool's TransferMux: ``get()`` blocks and delivers ``None``
+        as the shutdown sentinel.
 
         **Folding.** A fused step costs the device the same whatever
         it holds, so steps must follow the rows offered, not the
@@ -2755,7 +2655,7 @@ class SketchEngine:
         busy = self._busy_count()
         if busy == 0:
             return True
-        if busy >= max(1, self.cfg.feed_pipeline_depth):
+        if busy >= self.cfg.feed_pipeline_depth:
             return False
         return self._held_rows(held) >= self.cfg.batch_capacity
 
@@ -2789,12 +2689,15 @@ class SketchEngine:
         windows on time.
 
         Sits where Enricher.Run + Module.run sit in the reference
-        (enricher.go:68-99, metrics_module.go:266-330). With
-        ``feed_pipeline_depth > 0`` the device_put + step dispatch run on
-        a separate thread behind a bounded queue, so batch N's transfer
-        overlaps batch N+1's host-side prep; the queue is the only
-        blocking edge (backpressure then reaches the bounded sink, which
-        drops and counts — never the producers)."""
+        (enricher.go:68-99, metrics_module.go:266-330). This loop is the
+        DISTRIBUTOR: it drains the sink, runs observers, and deals
+        blocks to the feed workers (parallel/feed.py), which
+        combine+partition in parallel and hand finished batches to the
+        dispatch thread through the pool's double-buffered transfer mux.
+        Flow-dict/wire/submit stay on the one dispatch thread (wire
+        ordering contract), so batch N's transfer overlaps batch N+1's
+        host-side prep; no edge blocks a producer (a saturated pool
+        drops and counts, as the bounded sink does)."""
         self.started.set()
         if self._fleet_shipper is not None:
             self._fleet_shipper.start()
@@ -2804,35 +2707,11 @@ class SketchEngine:
         # Flush threshold: accumulating beyond one device batch raises the
         # combine ratio (more duplicate descriptors per pass); the
         # interval timeout still bounds latency. Coalescing into device
-        # batches happens inside _build_quantum.
+        # batches happens inside _build_quantum. Per-worker quantum
+        # splits the configured flush quantum so total staged latency
+        # stays put as workers scale.
         quantum = max(cap, self.cfg.flush_max_events)
-        depth = self.cfg.feed_pipeline_depth
-        # Sharded multi-worker feed (parallel/feed.py): with more than
-        # one resolved worker, this loop becomes the DISTRIBUTOR — it
-        # drains the sink, runs observers, and deals blocks to the
-        # workers, which combine+partition in parallel and hand
-        # finished batches to the dispatch thread through the pool's
-        # double-buffered transfer mux. Flow-dict/wire/submit stay on
-        # the one dispatch thread (v3 ordering contract). Per-worker
-        # quantum splits the configured flush quantum so total staged
-        # latency stays put as workers scale.
-        n_workers = self._resolve_feed_workers() if depth > 0 else 0
-        q: Any = None
-        worker: threading.Thread | None = None
-        pool: FeedWorkerPool | None = None
-        inline_tq: TransferQueue | None = None
-        if depth > 0 and n_workers <= 1:
-            # Inline mode rides the same mux shape as the pool: step
-            # items through one bounded TransferQueue, window ticks
-            # through the control lane — the protected-lane contract
-            # (window closes stay on cadence even under a step
-            # backlog) holds in BOTH feed modes.
-            inline_data = threading.Event()
-            inline_tq = TransferQueue(depth, inline_data, self._clock)
-            q = TransferMux([inline_tq], inline_data)
-            # The pending quantum is this loop's and the held flushes
-            # the dispatch thread's: both wait for room.
-            self._room_wake = lambda: (q.wake(), self.sink.data.set())
+        n_workers = self._resolve_feed_workers()
 
         def drop_item(item):
             """Dead-worker path: account the loss, never enqueue into a
@@ -2849,119 +2728,35 @@ class SketchEngine:
                 ).inc(int(item[1].events) + int(item[1].lost))
                 self._count_unheld(item[3])
 
-        def submit(item):
-            if q is not None:
-                if item[0] != "step":
-                    # Window/control items (both feed modes) ride the
-                    # mux control lane: closes overtake the step
-                    # backlog and stay on cadence under overload.
-                    if worker is None or not worker.is_alive():
-                        drop_item(item)
-                    else:
-                        q.put_ctl(item)
-                else:
-                    # Inline mode only (pool workers hand step items
-                    # off directly). Block only while the worker
-                    # lives: if it died (fatal runtime error escaping
-                    # its catch), drop + count rather than wedging the
-                    # feed loop on a full queue forever.
-                    if not inline_tq.put(
-                        item, alive=lambda: worker.is_alive()
-                    ):
-                        drop_item(item)
-            elif item[0] == "step":
-                self._dispatch_sharded(item[1], item[2], item[3])
-            else:
-                # Fire-and-forget close on the protected lane, same as
-                # pipeline mode: the proxy FIFO still orders it after
-                # every step submitted before the tick, but the feed
-                # loop no longer waits out the device round-trip — a
-                # blocking close here serialized the feed for the full
-                # end_window dispatch and was the single biggest
-                # stall-window source in depth==0 runs (BENCH_r05
-                # 0.00M windows). Errors are handled inside the
-                # submission (safe_close), including fatal-device
-                # recovery.
-                self._submit_close_window()
-
-        if depth > 0:
-            if n_workers > 1:
-                pool = FeedWorkerPool(
-                    n_workers=n_workers,
-                    quantum=max(cap, quantum // n_workers),
-                    staging_blocks=self.cfg.feed_staging_blocks,
-                    flush_interval_s=self.cfg.flush_interval_s,
-                    flush_max_age_s=self.cfg.flush_max_age_s,
-                    build_steps=self._build_quantum,
-                    drop=drop_item,
-                    busy=self._busy_count,
-                    alive=lambda: (
-                        worker is not None and worker.is_alive()
-                    ),
-                    register_hb=self._register_hb,
-                    deregister_hb=self._deregister_hb,
-                    restart_policy=lambda name: policy_from_config(
-                        self.cfg, seed_key=name
-                    ),
-                    clock=self._clock,
-                )
-                self._feed_pool = pool
-                self._room_wake = pool.wake_pending
-                q = pool.mux
-            worker = threading.Thread(
-                target=self._dispatch_loop, args=(q,),
-                name="engine-dispatch", daemon=True,
-            )
-            worker.start()
-            if pool is not None:
-                pool.start()
+        pool = FeedWorkerPool(
+            n_workers=n_workers,
+            quantum=max(cap, quantum // n_workers),
+            staging_blocks=self.cfg.feed_staging_blocks,
+            flush_interval_s=self.cfg.flush_interval_s,
+            flush_max_age_s=self.cfg.flush_max_age_s,
+            build_steps=self._build_quantum,
+            drop=drop_item,
+            busy=self._busy_count,
+            alive=lambda: worker.is_alive(),
+            register_hb=self._register_hb,
+            deregister_hb=self._deregister_hb,
+            restart_policy=lambda name: policy_from_config(
+                self.cfg, seed_key=name
+            ),
+            clock=self._clock,
+        )
+        self._feed_pool = pool
+        q = pool.mux
+        worker = threading.Thread(
+            target=self._dispatch_loop, args=(q,),
+            name="engine-dispatch", daemon=True,
+        )
+        worker.start()
+        pool.start()
 
         m = get_metrics()
         clock = self._clock
-        pending: list[np.ndarray] = []
-        n_pending = 0
-        last_flush = clock()
         next_window = clock() + self.cfg.window_seconds
-
-        feed_trace = self._feed_trace
-        trace_acc = {"accum": 0.0, "build": 0.0,
-                     "submit": 0.0, "n": 0, "ev": 0}
-        t_flush_end = time.monotonic()
-
-        def flush():
-            nonlocal pending, n_pending, last_flush, t_flush_end
-            t0 = time.monotonic()
-            n_raw = n_pending
-            blocks = pending
-            pending = []
-            n_pending = 0
-            last_flush = clock()
-            # Shared combine+sample+partition path (_build_quantum) —
-            # the SAME code the feed workers run, so overload sampling
-            # applies identically in inline mode.
-            items = self._build_quantum(blocks, n_raw, int(time.time()))
-            t1 = time.monotonic()
-            for item in items:
-                submit(item)
-            if feed_trace:
-                t3 = time.monotonic()
-                trace_acc["accum"] += t0 - t_flush_end
-                trace_acc["build"] += t1 - t0
-                trace_acc["submit"] += t3 - t1
-                trace_acc["n"] += 1
-                trace_acc["ev"] += n_raw
-                t_flush_end = t3
-                if trace_acc["n"] % 8 == 0:
-                    per = {k: trace_acc[k] / trace_acc["n"]
-                           for k in ("accum", "build", "submit")}
-                    self.log.info(
-                        "feed trace: %d flushes, %.2fM ev/flush, "
-                        "accum %.0fms build %.0fms submit %.0fms",
-                        trace_acc["n"],
-                        trace_acc["ev"] / trace_acc["n"] / 1e6,
-                        per["accum"] * 1e3, per["build"] * 1e3,
-                        per["submit"] * 1e3,
-                    )
 
         hb_feed = self._register_hb("engine-feed")
         try:
@@ -2995,45 +2790,27 @@ class SketchEngine:
                             # failing one must not log at feed rate.
                             if self._count_error("observer"):
                                 self.log.exception("observer failed")
-                    if pool is not None:
-                        # Sharded mode: deal the block to a worker and
-                        # move on — the distributor NEVER blocks on a
-                        # saturated pool (backpressure contract: drop
-                        # and count, packet-weighted like every other
-                        # loss site).
-                        if not pool.stage(rec):
-                            pool.count_drop(len(rec))
-                            self._count_unheld(len(rec))
-                            m.lost_events.labels(
-                                stage="handoff", plugin="engine"
-                            ).inc(int(rec[:, F.PACKETS].sum()))
-                        continue
-                    pending.append(rec)
-                    n_pending += len(rec)
-                    # Flush in bounded quanta AS blocks accumulate: a
-                    # backlogged sink must never turn into one multi-GB
-                    # concat+combine — each flush handles at most one
-                    # quantum plus a block's worth of overshoot.
-                    if n_pending >= quantum:
-                        flush()
+                    # Deal the block to a worker and move on — the
+                    # distributor NEVER blocks on a saturated pool
+                    # (backpressure contract: drop and count,
+                    # packet-weighted like every other loss site).
+                    if not pool.stage(rec):
+                        pool.count_drop(len(rec))
+                        self._count_unheld(len(rec))
+                        m.lost_events.labels(
+                            stage="handoff", plugin="engine"
+                        ).inc(int(rec[:, F.PACKETS].sum()))
                 sp_deal.end()
                 now = clock()
-                # The pending quantum's two ages, as the deadlines the
-                # wait below sleeps to.
-                interval_due = last_flush + self.cfg.flush_interval_s
-                age_due = last_flush + self.cfg.flush_max_age_s
-                if n_pending and now >= interval_due:
-                    # Interval flushes serve LATENCY and only make sense
-                    # when the dispatch pipeline is idle; with work in
-                    # flight, keep accumulating (bigger quanta combine
-                    # harder and amortize per-flush fixed costs) up to
-                    # the hard age bound. Without this gate the fast
-                    # async pipeline settles into many tiny flushes
-                    # whose fixed costs cap throughput.
-                    if self._busy_count() == 0 or now >= age_due:
-                        flush()
                 if now >= next_window:
-                    submit(("window", None, 0, 0))
+                    # Window ticks ride the mux control lane: closes
+                    # overtake the step backlog and stay on cadence
+                    # under overload. Never into a queue nobody drains.
+                    win = ("window", None, 0, 0)
+                    if worker.is_alive():
+                        q.put_ctl(win)
+                    else:
+                        drop_item(win)
                     # Batched tick: one close per catch-up, however many
                     # boundaries a stall skipped. Advancing by the missed
                     # count keeps the cadence phase-locked to the start
@@ -3049,42 +2826,29 @@ class SketchEngine:
                 if not blocks:
                     # Sleep until a block arrives (the sink sets its
                     # event) or the next thing due by the clock: the
-                    # window tick, the controller's tick, the pending
-                    # quantum's age (inline feed; past the interval
-                    # the pipeline is busy, and its going idle is
-                    # signalled by _dispatch_done). The caller's stop
-                    # event cannot signal this wait, hence its bound.
-                    due = [next_window]
+                    # window tick, the controller's tick. The caller's
+                    # stop event cannot signal this wait, hence its
+                    # bound.
+                    due = next_window
                     tick = self._overload.next_tick()
                     if tick is not None:
-                        due.append(tick)
-                    if n_pending:
-                        due.append(
-                            interval_due if now < interval_due else age_due
-                        )
+                        due = min(due, tick)
                     hb_feed.park()
                     park(self.sink.data, mnames.WAKE_FEED, clock,
-                         min(due), max_s=FEED_PARK_MAX_S)
+                         due, max_s=FEED_PARK_MAX_S)
         finally:
             hb_feed.park()
             self._deregister_hb("engine-feed")
-            if pool is not None:
-                # Stop the workers FIRST so their final flushes land in
-                # the transfer mux, then send the sentinel down the
-                # control lane — the mux hands it to the dispatch
-                # thread only after every worker queue drains, so
-                # nothing staged at shutdown is silently lost.
-                pool.stop(timeout=30.0)
-                q.put_ctl(None)
-                worker.join(timeout=30.0)
-            elif q is not None:
-                # Mux sentinel: delivered only after the step queue
-                # drains (same contract as pool mode), and put_ctl
-                # never blocks — the join timeout bounds a wedged
-                # worker.
-                q.put_ctl(None)
-                worker.join(timeout=30.0)
-            if q is not None and not worker.is_alive():
+            # Stop the workers FIRST so their final flushes land in
+            # the transfer mux, then send the sentinel down the control
+            # lane — the mux hands it to the dispatch thread only after
+            # every worker queue drains, so nothing staged at shutdown
+            # is silently lost. put_ctl never blocks; the join timeout
+            # bounds a wedged worker.
+            pool.stop(timeout=30.0)
+            q.put_ctl(None)
+            worker.join(timeout=30.0)
+            if not worker.is_alive():
                 # A dispatch thread that died mid-run leaves whatever
                 # was handed off before its death sitting in the
                 # transfer queues: count it, like every other item the

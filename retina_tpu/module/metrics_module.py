@@ -204,15 +204,16 @@ class MetricsModule:
 
     def start(self, stop: threading.Event) -> None:
         # Adaptive cadence: the 1 s module interval
-        # (metrics_module.go:37) assumes snapshot readback is cheap. On
-        # a slow host<->device link a fresh snapshot (~1.4 MB D2H)
-        # costs real link time that the feed path's H2D wire shares;
-        # back off to 4x cost so gauge freshness degrades before feed
-        # throughput does — but never beyond 5 s: under sustained load
-        # the snapshot's cost is mostly FIFO queueing behind in-flight
-        # steps (not link bytes), and unbounded backoff turned
-        # pod-gauge staleness into 12-15 s. On a fast link cost is
-        # milliseconds and the cadence stays 1 s.
+        # (metrics_module.go:37) assumes a publish cycle is cheap. Here
+        # it is not, and what it costs is host work: on the chip
+        # `publish_wait_pct` reads 5-16 (PERF.md, ledger PR 30) — the
+        # snapshot's dispatch and fetch waits are that small a share of
+        # a cycle, the rest is `series_publish` (0.3 s of Python under
+        # the GIL at 35k series) and the render that follows it. Back
+        # off to 4x cost so the cycle cannot take the host from the
+        # feed, but never beyond 5 s (unbounded backoff turned
+        # pod-gauge staleness into 12-15 s). A cheap cycle keeps the
+        # 1 s cadence.
         while not stop.is_set():
             t0 = time.perf_counter()
             try:

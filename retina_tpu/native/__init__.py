@@ -40,7 +40,7 @@ _build_failed = False
 # reporting anything else: a stale prebuilt .so (wrong checkout, wrong
 # arch cache) would otherwise misparse the dense wire bitstream or the
 # striped-combine arguments silently. Bump BOTH sides together.
-NATIVE_ABI_VERSION = 2
+NATIVE_ABI_VERSION = 3
 
 
 def _build(force: bool = False) -> bool:
@@ -175,14 +175,6 @@ def get_lib() -> Optional[ctypes.CDLL]:
         lib.rt_pack.argtypes = [
             ctypes.POINTER(ctypes.c_uint32), ctypes.c_size_t,
             ctypes.c_uint64, ctypes.POINTER(ctypes.c_uint32),
-        ]
-        lib.rt_flowwire.restype = ctypes.c_long
-        lib.rt_flowwire.argtypes = [
-            ctypes.POINTER(ctypes.c_uint32), ctypes.c_size_t,
-            ctypes.POINTER(ctypes.c_uint32),
-            ctypes.POINTER(ctypes.c_uint8), ctypes.c_uint64,
-            ctypes.c_uint32, ctypes.POINTER(ctypes.c_uint32),
-            ctypes.POINTER(ctypes.c_uint32),
         ]
         lib.rt_flowwire_dense.restype = ctypes.c_long
         lib.rt_flowwire_dense.argtypes = [
@@ -452,57 +444,20 @@ def combine_native_blocks_striped(
     )
 
 
-def flowwire_native(
-    rows: np.ndarray, ids: np.ndarray, sel_new: np.ndarray,
-    base: int, id_bits: int, new_out: np.ndarray,
-    known_out: np.ndarray,
-) -> Optional[int]:
-    """C++ v3 flow-dict wire build (pack.cpp rt_flowwire): one pass
-    splits ``rows`` by ``sel_new`` into the new wire (id + 12 packed
-    lanes, written to ``new_out``) and the known wire (id|pk<<id_bits,
-    bytes -> ``known_out``). Returns the new-row count, or None when
-    the library is unavailable / inputs don't match the fast-path
-    layout (caller falls back to the numpy build). Semantics are
-    cross-checked against the numpy path by tests/test_native.py."""
-    lib = get_lib()
-    n = len(rows)
-    if (lib is None or rows.ndim != 2 or rows.shape[1] != NUM_FIELDS
-            or rows.dtype != np.uint32 or not rows.flags.c_contiguous
-            or ids.dtype != np.uint32 or not ids.flags.c_contiguous
-            or sel_new.dtype != np.uint8
-            or not sel_new.flags.c_contiguous
-            or len(ids) != n or len(sel_new) != n
-            or new_out.dtype != np.uint32 or known_out.dtype != np.uint32
-            or not new_out.flags.c_contiguous
-            or not known_out.flags.c_contiguous
-            or new_out.ndim != 2 or new_out.shape[1] != 13
-            or known_out.ndim != 2 or known_out.shape[1] != 2):
-        return None
-    # Capacity guard: the C++ side writes n_new*13 + n_known*2 words
-    # unchecked — an undersized buffer must fall back, not corrupt.
-    n_sel = int(sel_new.sum())
-    if len(new_out) < n_sel or len(known_out) < n - n_sel:
-        return None
-    u32p = ctypes.POINTER(ctypes.c_uint32)
-    return int(lib.rt_flowwire(
-        rows.ctypes.data_as(u32p), n, ids.ctypes.data_as(u32p),
-        sel_new.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
-        ctypes.c_uint64(int(base)), ctypes.c_uint32(int(id_bits)),
-        new_out.ctypes.data_as(u32p), known_out.ctypes.data_as(u32p),
-    ))
-
-
 def flowwire_dense_native(
     rows: np.ndarray, ids: np.ndarray, sel_new: np.ndarray,
     base: int, id_bits: int, pk_bits: int, by_bits: int,
     new_out: np.ndarray, known_words: np.ndarray,
 ) -> Optional[int]:
-    """C++ v4 dense flow-dict wire build (pack.cpp rt_flowwire_dense):
-    like flowwire_native but known rows land in the ZEROED 1-D
-    ``known_words`` bitstream at (id_bits + pk_bits + by_bits) bits per
-    row (parallel/wire.py dense_known_rows is the numpy twin). Returns
-    the new-row count, or None when the library is unavailable / the
-    inputs don't match the fast-path layout."""
+    """C++ dense flow-dict wire build (pack.cpp rt_flowwire_dense): one
+    pass splits ``rows`` by ``sel_new`` into the new wire (id + 12
+    packed lanes, written to ``new_out``) and the known wire, the
+    ZEROED 1-D ``known_words`` bitstream at (id_bits + pk_bits +
+    by_bits) bits per row (parallel/wire.py dense_known_rows is the
+    numpy twin). Returns the new-row count, or None when the library
+    is unavailable / the inputs don't match the fast-path layout
+    (caller falls back to the numpy build). Semantics are cross-checked
+    against the numpy path by tests/test_wire.py."""
     lib = get_lib()
     n = len(rows)
     row_bits = int(id_bits) + int(pk_bits) + int(by_bits)

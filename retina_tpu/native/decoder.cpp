@@ -219,9 +219,10 @@ long rt_decode_pcap(const uint8_t* data, size_t len, uint32_t obs_point,
 // NATIVE_ABI_VERSION) refuses a mismatched binary and rebuilds from
 // source, so a stale .so from another checkout can never silently
 // misparse the wire.
-//   v1: rt_combine/rt_combine_mt/rt_flowwire era
+//   v1: rt_combine/rt_combine_mt and the two-lane flow-wire builder
 //   v2: + rt_combine_stripe (striped multi-consumer combine) and
-//       rt_flowwire_dense (v4 dense known-row bitstream)
-uint32_t rt_abi_version(void) { return 2; }
+//       rt_flowwire_dense (dense known-row bitstream)
+//   v3: - the two-lane flow-wire builder (known rows are dense only)
+uint32_t rt_abi_version(void) { return 3; }
 
 }  // extern "C"

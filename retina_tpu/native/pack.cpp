@@ -28,7 +28,7 @@ constexpr int F_TS_LO = 0, F_TS_HI = 1, F_SRC_IP = 2, F_DST_IP = 3,
 
 inline uint32_t min_u32(uint32_t a, uint32_t b) { return a < b ? a : b; }
 
-// One packed wire row (the body shared by rt_pack and rt_flowwire —
+// One packed wire row (the body shared by rt_pack and rt_flowwire_dense —
 // must stay semantically identical to pack_records' numpy math).
 inline void pack_row(const uint32_t* r, uint32_t* o, uint64_t base) {
   constexpr uint64_t U32 = 0xFFFFFFFFull;
@@ -78,48 +78,21 @@ void rt_pack(const uint32_t* rows, size_t n, uint64_t base,
     pack_row(rows + i * NUM_FIELDS, out + i * PACKED_FIELDS, base);
 }
 
-// v3 flow-dict wire build: ONE pass splits a device's rows into the
+// Dense flow-dict wire build: ONE pass splits a device's rows into the
 // new-descriptor wire ([table_id | 12 packed lanes], 13 u32/row) and
-// the known wire ([id | packets << id_bits, bytes], 2 u32/row) by the
-// caller-computed escalation mask (engine._dispatch_flowdict computes
-// it in numpy: is_new | pk overflow | TSval/TSecr | unstamped). The
-// numpy equivalent needed two fancy-indexed row copies + a pack pass +
-// two bit-pack passes per flush — this is the dispatch worker's
-// largest remaining cost at production quanta.
-// new_out must hold at least (popcount(sel), 13); known_out at least
-// (n - popcount, 2). Returns n_new.
-long rt_flowwire(const uint32_t* rows, size_t n, const uint32_t* ids,
-                 const uint8_t* sel_new, uint64_t base,
-                 uint32_t id_bits, uint32_t* new_out,
-                 uint32_t* known_out) {
-  size_t n_new = 0, n_known = 0;
-  for (size_t i = 0; i < n; i++) {
-    const uint32_t* r = rows + i * NUM_FIELDS;
-    if (sel_new[i]) {
-      uint32_t* o = new_out + n_new * 13;
-      o[0] = ids[i];
-      pack_row(r, o + 1, base);
-      n_new++;
-    } else {
-      uint32_t* o = known_out + n_known * 2;
-      o[0] = ids[i] | (r[F_PACKETS] << id_bits);
-      o[1] = r[F_BYTES];
-      n_known++;
-    }
-  }
-  return (long)n_new;
-}
-
-// v4 dense flow-dict wire build: like rt_flowwire, but known rows go
-// into a CONTIGUOUS BITSTREAM of (id_bits + pk_bits + by_bits)-bit
-// rows instead of two full u32 lanes — at the default 18-bit dict and
-// 10/22-bit packet/byte lanes that is 6.25 B/row vs 8, and the row
-// width shrinks further as deployments tune the dict smaller. The
-// caller's escalation mask must already route rows whose PACKETS or
-// BYTES overflow their lane to the new/full side (engine adds the
-// `bytes >= 1 << by_bits` term for this path), so the stream stores
-// every surviving row exactly.
+// the known wire by the caller-computed escalation mask
+// (engine._dispatch_flowdict computes it in numpy: is_new | pk or
+// bytes overflow | TSval/TSecr | unstamped). The numpy equivalent
+// needs two fancy-indexed row copies + a pack pass + two bit-pack
+// passes per flush. Known rows go into a CONTIGUOUS BITSTREAM of
+// (id_bits + pk_bits + by_bits)-bit rows — at an 18-bit dict and
+// 10/22-bit packet/byte lanes that is 6.25 B/row, and the row width
+// shrinks further as deployments tune the dict smaller. The caller's
+// mask must already route rows whose PACKETS or BYTES overflow their
+// lane to the new/full side, so the stream stores every surviving row
+// exactly.
 //
+// new_out must hold at least (popcount(sel_new), 13);
 // known_out must be ZEROED by the caller and hold at least
 // ceil(n_known * row_bits / 32) + 1 u32 words (the +1 pad word keeps
 // the device unpack's two-word gather in bounds for the last row).

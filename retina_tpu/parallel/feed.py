@@ -25,7 +25,7 @@ nothing; six threads woken every 2 ms cost the chip host two thirds of
 the agent's CPU (PERF.md, PR 30).
 
 What does NOT move off the dispatch thread: flow-dict assignment, wire
-build, and the proxy submission. The v3 wire ordering contract (a new
+build, and the proxy submission. The wire ordering contract (a new
 descriptor row must reach the device table before any known row
 references its slot — engine._dispatch_flowdict) requires ONE
 serialization point, and the dispatch thread is it.
@@ -35,8 +35,8 @@ block a producer. A block that finds every worker's staging full is
 dropped and counted (per-worker drop counters + the lost_events
 ``handoff`` stage); a worker whose handoff queue stays full because the
 dispatch thread died drops the finished batch through the pool's
-``drop`` callback, which counts it exactly like the inline feed's
-dead-worker path.
+``drop`` callback (the engine's dead-worker path), which counts it
+under the lost_events ``dispatch`` stage.
 """
 
 from __future__ import annotations
@@ -166,9 +166,9 @@ class TransferQueue:
 
 class TransferMux:
     """Single-consumer fan-in over every worker's TransferQueue plus a
-    control lane (window ticks, shutdown sentinel). Drop-in for the
-    inline feed's queue.Queue in engine._dispatch_loop: ``get()``
-    blocks and returns items; ``None`` means shut down.
+    control lane (window ticks, shutdown sentinel), consumed by
+    engine._dispatch_loop: ``get()`` blocks and returns items; ``None``
+    means shut down.
 
     The control lane has priority — window closes stay on cadence even
     under a step backlog. A close overtaking batches still staged in
@@ -355,10 +355,14 @@ class FeedWorker(threading.Thread):
             pend = self.pending_events()
             deadline = None
             if pend:
-                # Same flush policy as the inline feed: full quantum,
-                # or the hard age bound, or an interval flush when the
-                # dispatch pipeline is idle (latency priority only when
-                # nothing is in flight). The two ages are read as the
+                # Flush policy: a full quantum, or the hard age bound,
+                # or an interval flush when the dispatch pipeline is
+                # idle. Interval flushes serve LATENCY; with work in
+                # flight, keep accumulating (bigger quanta combine
+                # harder and amortize per-flush fixed costs) up to the
+                # age bound — without this gate the async pipeline
+                # settles into many tiny flushes whose fixed costs cap
+                # throughput. The two ages are read as the
                 # deadlines the wait below sleeps to, so that a clock
                 # that has reached one has reached the other.
                 first_t = self.first_t
@@ -578,7 +582,6 @@ class FeedWorkerPool:
     def stats(self) -> dict[str, Any]:
         return {
             "workers": len(self.workers),
-            "mode": "sharded",
             "quantum": self.quantum,
             "dropped_blocks": self.staging_dropped_blocks,
             "dropped_events": self.staging_dropped_events,

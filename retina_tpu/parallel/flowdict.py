@@ -1,17 +1,16 @@
-"""Host-side flow-descriptor dictionary for the v2 wire format.
+"""Host-side flow-descriptor dictionary of the wire format.
 
 The combiner (parallel/combine.py) already collapses a flush quantum to
 its distinct flow descriptors — but across quanta the SAME descriptors
 recur (flows are long-lived; the reference's kernel maps bank on exactly
 that). This dictionary closes the loop: every distinct descriptor gets a
 stable id once, the descriptor's 12 packed lanes cross the host->device
-link once (a "new" row), and every later occurrence crosses as an
-8-byte ``[id | packets << id_bits, bytes]`` pair (v3 wire; v2 used a
-16-byte 4-tuple) against the device-resident descriptor table (engine
-ingest gathers the lanes back in HBM, where the bandwidth is ~3 orders
-of magnitude above the link). Packet counts beyond the id lane's
-headroom escalate to a full-row re-upload (idempotent), keeping exact
-counters exact.
+link once (a "new" row), and every later occurrence crosses as a
+dense ``id | packets | bytes`` bit-row (parallel/wire.py dense layer)
+against the device-resident descriptor table (engine ingest gathers the
+lanes back in HBM, where the bandwidth is ~3 orders of magnitude above
+the link). Packet or byte counts beyond their narrow lanes escalate to
+a full-row re-upload (idempotent), keeping exact counters exact.
 
 Reference analog: the eBPF map key set — pkg/plugin/conntrack and
 packetforward keep per-flow keys resident kernel-side and move only

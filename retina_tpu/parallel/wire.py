@@ -75,34 +75,18 @@ def ts_rel(records: np.ndarray, base: np.uint64) -> np.ndarray:
     ).astype(np.uint32)
 
 
-def known_rows(
-    rows: np.ndarray, ids: np.ndarray, id_bits: int, out: np.ndarray
-) -> None:
-    """Fill the 2-word known-row wire encoding in place:
-    ``word0 = flow_id | packets << id_bits``, ``word1 = bytes``.
-
-    One definition shared by the engine's numpy fallback
-    (engine._dispatch_flowdict) and bench's host-path probe — the
-    encoding IS the v3 wire contract, and two hand-rolled copies of the
-    bit layout can silently drift apart."""
-    out[:, 0] = ids | (rows[:, F.PACKETS] << id_bits)
-    out[:, 1] = rows[:, F.BYTES]
-
-
-# -- v4 dense known-row bitstream -------------------------------------
+# -- dense known-row bitstream ----------------------------------------
 #
-# The v3 known row spends two full u32 lanes per row; at the default
-# 18-bit flow dictionary only 18 + ~14 of the first 32 bits carry
-# information and BYTES almost never needs 32. v4 packs each known row
+# Under the flow dictionary a known row needs its id, PACKETS and BYTES
+# only, and BYTES almost never needs 32 bits. Each known row is packed
 # as (id_bits + DENSE_PK_BITS + DENSE_BY_BITS) CONTIGUOUS bits —
 # ``id | packets << id_bits | bytes << (id_bits + DENSE_PK_BITS)`` —
-# streamed into one u32 word array: 50 bits = 6.25 B/row at id_bits=18
-# vs 8, and the row narrows further for smaller dictionaries. Rows
-# whose PACKETS or BYTES overflow their lane escalate to the full
-# 13-word new-row side exactly like the v3 packet-overflow escalation
-# (engine._dispatch_flowdict adds the bytes term to the mask), so the
-# stream stores every surviving row exactly. The +1 pad word keeps the
-# device unpack's two-word gather in bounds for the final row.
+# streamed into one u32 word array: 50 bits = 6.25 B/row at id_bits=18,
+# and the row narrows further for smaller dictionaries. Rows whose
+# PACKETS or BYTES overflow their lane escalate to the full 13-word
+# new-row side (engine._dispatch_flowdict's mask), so the stream stores
+# every surviving row exactly. The +1 pad word keeps the device
+# unpack's two-word gather in bounds for the final row.
 #
 # Three implementations, cross-checked bit-for-bit by
 # tests/test_wire.py: native/pack.cpp rt_flowwire_dense (the fast

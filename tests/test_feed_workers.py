@@ -1,7 +1,7 @@
 """Sharded multi-worker host feed (parallel/feed.py): the bounded
 double-buffered handoff primitives, the worker pool's staging/flush/
-backpressure contract, and engine-level agreement between the sharded
-and inline feed paths.
+backpressure contract, and engine-level agreement between pools of
+different sizes (the engine's one feed shape).
 
 The reference analog is per-CPU perf rings drained by independent
 readers (packetparser_linux.go:556-652) with the same loss rule
@@ -175,7 +175,6 @@ def test_pool_end_to_end_delivers_every_event():
     assert got == total
     st = pool.stats()
     assert st["workers"] == 2
-    assert st["mode"] == "sharded"
     assert st["dropped_blocks"] == 0
     assert sum(w["events"] for w in st["per_worker"]) == total
 
@@ -262,26 +261,83 @@ def _run_feed(cfg, n_events=1600):
 
 
 def test_sharded_feed_agrees_with_inline():
-    """The sharded pool lands exactly the events the inline pipelined
-    feed lands — combining/partitioning in workers is lossless and the
-    dispatch thread still serializes flow-dict/wire/submit."""
-    _, snap_inline, st_inline = _run_feed(
+    """A pool of two lands exactly the events a pool of one lands —
+    dealing blocks over workers is lossless and the dispatch thread
+    still serializes flow-dict/wire/submit."""
+    _, snap_one, st_one = _run_feed(
         small_cfg(feed_pipeline_depth=2, feed_workers=1)
     )
     _, snap_pool, st_pool = _run_feed(
         small_cfg(feed_pipeline_depth=2, feed_workers=2)
     )
-    assert st_inline["mode"] == "inline"
-    assert st_pool["mode"] == "sharded"
+    assert st_one["workers"] == 1
     assert st_pool["workers"] == 2
-    assert st_pool["dropped_blocks"] == 0
+    assert st_one["dropped_blocks"] == st_pool["dropped_blocks"] == 0
     assert int(snap_pool["totals"][0]) == 1600
-    assert int(snap_pool["totals"][0]) == int(snap_inline["totals"][0])
+    assert int(snap_pool["totals"][0]) == int(snap_one["totals"][0])
     assert int(snap_pool["totals"][1]) == int(
         np.asarray(snap_pool["pod_forward"])[:, :, 0].sum()
     )
     # Per-worker accounting covers the full stream.
     assert sum(w["events"] for w in st_pool["per_worker"]) == 1600
+
+
+def test_one_core_host_boots_pool_of_one(monkeypatch):
+    """There is one feed shape: a host with one core auto-sizes to a
+    pool of ONE worker (not to a feed loop that builds its own
+    quanta), and that pool lands every event exactly."""
+    import os
+
+    monkeypatch.setattr(os, "cpu_count", lambda: 1)
+    cfg = small_cfg(feed_pipeline_depth=2)
+    assert cfg.feed_workers == 0  # auto
+    eng, snap, st = _run_feed(cfg)
+    assert eng._resolve_feed_workers() == 1
+    assert st["workers"] == 1 and len(st["per_worker"]) == 1
+    assert st["dropped_blocks"] == 0
+    assert st["per_worker"][0]["events"] == 1600
+    assert int(snap["totals"][0]) == 1600
+    assert int(snap["totals"][1]) == int(
+        np.asarray(snap["pod_forward"])[:, :, 0].sum()
+    )
+
+
+def test_yaml_with_removed_feed_and_wire_keys_runs_the_one_path(tmp_path):
+    """A deployed YAML that still carries ``transfer_packed``,
+    ``wire_dense_known`` or ``host_combine`` (removed: each chose a
+    path nothing ran) loads — unknown keys are ignored, like viper —
+    and the agent runs the one path: combined, packed, dense known
+    rows under the dictionary, every event exact."""
+    from retina_tpu.config import load_config
+    from retina_tpu.metrics import get_metrics
+
+    p = tmp_path / "config.yaml"
+    p.write_text(
+        "transfer_packed: false\n"
+        "wire_dense_known: false\n"
+        "host_combine: false\n"
+        "feed_pipeline_depth: 2\n"
+        "feed_workers: 2\n"
+        "transfer_min_bucket: 16\n"
+    )
+    cfg = load_config(str(p), env={})
+    for gone in ("transfer_packed", "wire_dense_known", "host_combine"):
+        assert not hasattr(cfg, gone)
+    for k, v in vars(small_cfg()).items():
+        if k not in ("feed_pipeline_depth", "feed_workers",
+                     "transfer_min_bucket"):
+            setattr(cfg, k, v)
+    m = get_metrics()
+    known0 = m.wire_rows.labels(kind="known")._value.get()
+    eng, snap, st = _run_feed(cfg)
+    assert int(snap["totals"][0]) == 1600
+    # Combined (50 flows in 400-event blocks) and carried as dense
+    # known rows: the dictionary's ingest pair is what compiled.
+    assert m.combine_ratio._value.get() > 1.0
+    assert m.wire_rows.labels(kind="known")._value.get() > known0
+    assert any(
+        isinstance(k, tuple) and k[0] == "known" for k in eng._pad_cache
+    )
 
 
 def test_paced_feed_no_subfloor_windows_with_workers():

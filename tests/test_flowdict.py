@@ -170,22 +170,21 @@ def test_dict_path_state_equals_plain_path():
     assert len(eng_dict._flow_dict) > 0
 
 
-def test_v3_known_rows_are_8_bytes_and_escalate_on_overflow():
-    """v3 wire: known rows ship as TWO u32 lanes (8 B/row). Packet
-    counts that overflow the id lane's headroom must ESCALATE to the
-    full-row side — never clamp — so pod packet counters stay exact."""
+def test_known_rows_escalate_on_packet_overflow():
+    """Known rows ship dense, with a DENSE_PK_BITS-wide packets lane.
+    Packet counts that overflow it must ESCALATE to the full-row side
+    — never clamp — so pod packet counters stay exact."""
     from retina_tpu.events.schema import F
     from retina_tpu.metrics import get_metrics
+    from retina_tpu.parallel.wire import DENSE_PK_BITS
 
     kw = dict(topk_slots=1 << 9, data_aggregation_level="high")
     gen = TrafficGen(n_flows=60, n_pods=24, seed=9)
-    # small_cfg slots = 2^12 -> id_bits 12, pk_bits 20 -> headroom 2^20.
     big = np.uint32(1 << 21)
 
     q = gen.batch(300)
-    # Half the rows carry packet counts beyond the known-lane headroom
-    # (pk_bits = 32 - id_bits; small_cfg slots = 2^12 -> 20-bit
-    # headroom), half stay tiny.
+    # Half the rows carry packet counts beyond the known-lane headroom,
+    # half stay tiny.
     q[: len(q) // 2, F.PACKETS] = big
     quanta = [q, q.copy(), q.copy()]  # passes 2-3: all descriptors known
 
@@ -195,8 +194,7 @@ def test_v3_known_rows_are_8_bytes_and_escalate_on_overflow():
 
     eng_dict = SketchEngine(small_cfg(**kw))
     eng_dict.update_identities({0x0A000000 + i: i for i in range(1, 20)})
-    assert eng_dict._fd_pk_bits == 32 - eng_dict._fd_id_bits
-    assert int(big) >= (1 << eng_dict._fd_pk_bits)
+    assert int(big) >= (1 << DENSE_PK_BITS)
     m0 = get_metrics().wire_rows.labels(kind="known")._value.get()
     snap_b = _feed(eng_dict, quanta)
     known_rows = (

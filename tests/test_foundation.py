@@ -111,6 +111,20 @@ def test_config_rejects_bad_values(tmp_path):
         load_config(None, overrides={"batch_capacity": 1000})  # not pow2
 
 
+def test_config_refuses_feed_pipeline_depth_under_one(tmp_path):
+    """There is no synchronous feed to select: the depth is a depth,
+    and 0 is refused at load by name (YAML and overrides alike)."""
+    p = tmp_path / "config.yaml"
+    p.write_text("feed_pipeline_depth: 0\n")
+    with pytest.raises(ValueError, match="feed_pipeline_depth"):
+        load_config(str(p), env={})
+    with pytest.raises(ValueError, match="feed_pipeline_depth"):
+        load_config(None, overrides={"feed_pipeline_depth": -1})
+    assert load_config(
+        None, overrides={"feed_pipeline_depth": 1}
+    ).feed_pipeline_depth == 1
+
+
 # ---------------------------------------------------------------- pubsub
 def test_pubsub_publish_subscribe_unsubscribe():
     ps = PubSub()

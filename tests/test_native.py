@@ -364,46 +364,6 @@ def test_combine_blocks_bit_identical_to_concat():
         set_combine_threads(prev)
 
 
-def test_flowwire_native_matches_numpy_build():
-    """rt_flowwire (one-pass v3 wire build) must produce exactly the
-    rows the engine's numpy fallback builds: new side = id + the 12
-    packed lanes, known side = [id | packets << id_bits, bytes], both
-    in row order."""
-    from retina_tpu.events.synthetic import TrafficGen
-    from retina_tpu.native import flowwire_native
-    from retina_tpu.parallel.wire import batch_ts_base, pack_records
-
-    gen = TrafficGen(n_flows=300, n_pods=32, seed=33)
-    rows = gen.batch(2000)
-    rng = np.random.default_rng(5)
-    # Exercise saturation bounds + zero timestamps through pack_row.
-    rows[:50, 8] = 9  # VERDICT beyond the 3-bit clamp
-    rows[50:80, 0] = 0
-    rows[50:80, 1] = 0  # unstamped
-    ids = rng.integers(1, 1 << 12, len(rows), dtype=np.uint32)
-    sel = rng.random(len(rows)) < 0.3
-    base = batch_ts_base(rows)
-    id_bits = 12
-
-    nn = int(sel.sum())
-    new_nat = np.zeros((len(rows), 13), np.uint32)
-    known_nat = np.zeros((len(rows), 2), np.uint32)
-    got = flowwire_native(rows, ids, sel.astype(np.uint8), int(base),
-                          id_bits, new_nat, known_nat)
-    assert got == nn
-
-    rn, idn = rows[sel], ids[sel]
-    rk, idk = rows[~sel], ids[~sel]
-    packed12, _, _ = pack_records(rn, base=base)
-    np.testing.assert_array_equal(new_nat[:nn, 0], idn)
-    np.testing.assert_array_equal(new_nat[:nn, 1:], packed12)
-    np.testing.assert_array_equal(
-        known_nat[: len(rk), 0],
-        idk | (rk[:, 7] << np.uint32(id_bits)),
-    )
-    np.testing.assert_array_equal(known_nat[: len(rk), 1], rk[:, 6])
-
-
 def test_combine_hint_grow_path_identical():
     """rt_combine_hint must return identical groups for any hint —
     including one that undershoots so far the table doubles repeatedly

@@ -11,6 +11,7 @@ from retina_tpu.parallel.wire import (
     DENSE_BY_BITS,
     DENSE_PK_BITS,
     PACKED_FIELDS,
+    batch_ts_base,
     dense_known_rows,
     dense_known_unpack_device,
     dense_known_unpack_numpy,
@@ -185,6 +186,11 @@ def test_dense_native_pack_bit_identical_to_numpy():
         rows, ids = _dense_batch(rng, n, id_bits)
         rows[:, F.TS_LO] = rng.integers(1, 2**31, n)
         rows[:, F.TS_HI] = 0
+        # The shared row body's zero timestamps against a nonzero
+        # base (the random rows already pass every saturation bound).
+        rows[50:80, F.TS_LO] = 0  # unstamped
+        base = batch_ts_base(rows)
+        assert int(base) > 0
         sel = (rng.random(n) < 0.3).astype(np.uint8)
         rows = np.ascontiguousarray(rows)
         n_sel = int(sel.sum())
@@ -193,8 +199,8 @@ def test_dense_native_pack_bit_identical_to_numpy():
             dense_words(n - n_sel, id_bits), np.uint32
         )
         got = flowwire_dense_native(
-            rows, ids, sel, 0, id_bits, DENSE_PK_BITS, DENSE_BY_BITS,
-            new_nat, known_nat,
+            rows, ids, sel, int(base), id_bits, DENSE_PK_BITS,
+            DENSE_BY_BITS, new_nat, known_nat,
         )
         if got is None:
             import pytest
@@ -205,8 +211,8 @@ def test_dense_native_pack_bit_identical_to_numpy():
         known_ref = np.zeros_like(known_nat)
         dense_known_rows(rows[keep], ids[keep], id_bits, known_ref)
         np.testing.assert_array_equal(known_nat, known_ref)
-        # New side unchanged from v3: id lane + the 12 packed lanes.
-        packed12, _, _ = pack_records(rows[sel == 1], base=np.uint64(0))
+        # New side: id lane + the 12 packed lanes.
+        packed12, _, _ = pack_records(rows[sel == 1], base=base)
         np.testing.assert_array_equal(new_nat[:n_sel, 0], ids[sel == 1])
         np.testing.assert_array_equal(new_nat[:n_sel, 1:], packed12)
 

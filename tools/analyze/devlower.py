@@ -583,9 +583,8 @@ def _engine_stub(mesh: Mesh):
     eng._fd_id_bits = max(
         1, (eng.cfg.flow_dict_slots - 1).bit_length()
     )
-    # Audit the DEFAULT wire shape (v4 dense known stream); the stub
-    # never touches a disk cache, so the AOT signature is inert here.
-    eng._fd_dense = bool(eng.cfg.wire_dense_known)
+    # The stub never touches a disk cache, so the AOT signature is
+    # inert here.
     eng._aot_sig = ""
     return eng
 
@@ -710,7 +709,7 @@ def entry_audits() -> list[EntryAudit]:
     eng = _engine_stub(mesh)
     audits.append(
         _audit(
-            "engine.ingest", eng._ingest_fn(8, packed=True), 2,
+            "engine.ingest", eng._ingest_fn(8), 2,
             donate=(0,),
             allowed=frozenset({"collective-permute"}),
         )
