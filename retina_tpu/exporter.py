@@ -14,9 +14,13 @@ the HTTP server re-register its handler like the reference does.
 from __future__ import annotations
 
 import threading
-from typing import Callable
+import time
+from typing import Callable, Iterable, Iterator, Sequence
 
+import numpy as np
 from prometheus_client import CollectorRegistry, Counter, Gauge, Histogram
+from prometheus_client.metrics_core import Metric
+from prometheus_client.samples import Sample
 
 from retina_tpu.log import logger
 from retina_tpu.utils import metric_names as mn
@@ -56,20 +60,63 @@ def _float_str(d: float) -> str:
     return s
 
 
+def _label_str(names: Iterable[str], values: Iterable[str]) -> str:
+    """``{k="v",...}``, the labels sorted by name and the values
+    escaped, or nothing where there are no labels: what a sample line
+    carries between the name and the value."""
+    lbl = ",".join(
+        f'{k}="{_escape_label(v)}"' for k, v in sorted(zip(names, values))
+    )
+    return "{" + lbl + "}" if lbl else ""
+
+
 def _sample_line(s) -> str:
-    if s.labels:
-        lbl = ",".join(
-            f'{k}="{_escape_label(v)}"'
-            for k, v in sorted(s.labels.items())
-        )
-        labelstr = "{" + lbl + "}"
-    else:
-        labelstr = ""
+    labelstr = _label_str(s.labels, s.labels.values())
     if s.timestamp is not None:
         ts = f" {int(float(s.timestamp) * 1000):d}"
     else:
         ts = ""
     return f"{s.name}{labelstr} {_float_str(s.value)}{ts}\n"
+
+
+def _render_family(metric: Metric, output: list[str]) -> None:
+    """HELP, TYPE and one line per sample of one metric family."""
+    mname = metric.name
+    mtype = metric.type
+    if mtype == "counter":
+        mname += "_total"
+    elif mtype == "info":
+        mname += "_info"
+        mtype = "gauge"
+    elif mtype == "stateset":
+        mtype = "gauge"
+    elif mtype == "gaugehistogram":
+        mtype = "histogram"
+    elif mtype == "unknown":
+        mtype = "untyped"
+    doc = metric.documentation.replace("\\", r"\\").replace(
+        "\n", r"\n"
+    )
+    output.append(f"# HELP {mname} {doc}\n")
+    output.append(f"# TYPE {mname} {mtype}\n")
+    om_samples: dict[str, list[str]] = {}
+    base = metric.name
+    for s in metric.samples:
+        name = s.name
+        if (
+            name == base + "_created"
+            or name == base + "_gsum"
+            or name == base + "_gcount"
+        ):
+            om_samples.setdefault(name[len(base):], []).append(
+                _sample_line(s)
+            )
+        else:
+            output.append(_sample_line(s))
+    for suffix, lines in sorted(om_samples.items()):
+        output.append(f"# HELP {base}{suffix} {doc}\n")
+        output.append(f"# TYPE {base}{suffix} gauge\n")
+        output.extend(lines)
 
 
 def render_exposition(registry: CollectorRegistry) -> bytes:
@@ -82,46 +129,137 @@ def render_exposition(registry: CollectorRegistry) -> bytes:
     samples, the agent's single largest CPU cost under scrape load; this
     writer emits the same bytes with plain string operations. The test
     suite cross-checks byte equality against generate_latest.
+
+    Every family goes sample by sample through ``collect()``: the path
+    of the default and the hubble registry, and the oracle for
+    :func:`render_rows`.
     """
     output: list[str] = []
     for metric in registry.collect():
-        mname = metric.name
-        mtype = metric.type
-        if mtype == "counter":
-            mname += "_total"
-        elif mtype == "info":
-            mname += "_info"
-            mtype = "gauge"
-        elif mtype == "stateset":
-            mtype = "gauge"
-        elif mtype == "gaugehistogram":
-            mtype = "histogram"
-        elif mtype == "unknown":
-            mtype = "untyped"
-        doc = metric.documentation.replace("\\", r"\\").replace(
-            "\n", r"\n"
-        )
-        output.append(f"# HELP {mname} {doc}\n")
-        output.append(f"# TYPE {mname} {mtype}\n")
-        om_samples: dict[str, list[str]] = {}
-        base = metric.name
-        for s in metric.samples:
-            name = s.name
-            if (
-                name == base + "_created"
-                or name == base + "_gsum"
-                or name == base + "_gcount"
-            ):
-                om_samples.setdefault(name[len(base):], []).append(
-                    _sample_line(s)
-                )
-            else:
-                output.append(_sample_line(s))
-        for suffix, lines in sorted(om_samples.items()):
-            output.append(f"# HELP {base}{suffix} {doc}\n")
-            output.append(f"# TYPE {base}{suffix} gauge\n")
-            output.extend(lines)
+        _render_family(metric, output)
     return "".join(output).encode("utf-8")
+
+
+def render_rows(registry: CollectorRegistry) -> bytes:
+    """The same bytes as :func:`render_exposition`, for a registry that
+    keeps families as rows (:class:`SeriesTable`): such a family is its
+    HELP and TYPE lines and the join of the lines its rows already
+    hold; any other collector registered there is rendered sample by
+    sample, each in registration order."""
+    output: list[str] = []
+    for metric in registry.collect():
+        if isinstance(metric, RowsFamily):
+            output.append(metric.text())
+        else:
+            _render_family(metric, output)
+    return "".join(output).encode("utf-8")
+
+
+class SeriesTable:
+    """One pod-level gauge family kept as rows, in place of one
+    prometheus_client child per series.
+
+    A row is appended the first time its label values are set and
+    stays: its label values, the line prefix ``name{k="v",...} `` built
+    once, the value last set and the finished line. :meth:`set` is what
+    ``gauge.labels(...).set(v)`` was (find the row by its label values,
+    or append it); :meth:`update` takes rows the caller already holds
+    the numbers of and compares their values with the last ones in
+    numpy. Either touches a row only when its value changed: format the
+    value, rebuild the line. A render is the join of the lines
+    (:func:`render_rows`); ``collect()`` yields the same family sample
+    by sample for whoever reads it through the registry
+    (``generate_latest``, ``get_sample_value``).
+
+    Written by one thread (the publisher's), read by any: a row is
+    complete before its line is appended, and a reader takes the lines
+    there are when it starts.
+    """
+
+    def __init__(self, name: str, labelnames: Sequence[str],
+                 documentation: str) -> None:
+        self._family = Metric(name, documentation, "gauge")  # no samples
+        self.name = name
+        self.labelnames = tuple(labelnames)
+        head: list[str] = []
+        _render_family(self._family, head)  # its HELP and TYPE lines
+        self._head = "".join(head)
+        self._by_labels: dict[tuple[str, ...], int] = {}
+        self._labels: list[tuple[str, ...]] = []
+        self._prefix: list[str] = []
+        self._lines: list[str] = []
+        self._values = np.zeros(64, np.float64)
+        if not self.labelnames:
+            self.set((), 0.0)  # a gauge without labels reads 0 from birth
+
+    def __len__(self) -> int:
+        return len(self._lines)
+
+    def set(self, labels: tuple[str, ...], value: float) -> tuple[int, bool]:
+        """Set the row of these label values, appended if it is new:
+        its number, and whether it is new or its line was rewritten."""
+        row = self._by_labels.get(labels)
+        if row is not None:
+            if value == self._values[row]:
+                return row, False
+            self._values[row] = value
+            self._lines[row] = f"{self._prefix[row]}{_float_str(value)}\n"
+            return row, True
+        row = len(self._lines)
+        if row == len(self._values):
+            self._values = np.concatenate(
+                [self._values, np.zeros(row, np.float64)])
+        prefix = f"{self.name}{_label_str(self.labelnames, labels)} "
+        self._values[row] = value
+        self._labels.append(labels)
+        self._prefix.append(prefix)
+        self._by_labels[labels] = row
+        self._lines.append(f"{prefix}{_float_str(value)}\n")
+        return row, True
+
+    def update(self, rows: np.ndarray, values: np.ndarray) -> int:
+        """Set rows by number (float64 values): how many changed."""
+        changed = np.nonzero(values != self._values[rows])[0]
+        if changed.size:
+            rows, values = rows[changed], values[changed]
+            self._values[rows] = values
+            lines, prefix, fmt = self._lines, self._prefix, _float_str
+            for row, value in zip(rows.tolist(), values.tolist()):
+                lines[row] = f"{prefix[row]}{fmt(value)}\n"
+        return int(changed.size)
+
+    # -- the registry's side: a collector ------------------------------
+    def describe(self) -> Iterable[Metric]:
+        return [self._family]
+
+    def collect(self) -> Iterator[Metric]:
+        yield RowsFamily(self, len(self._lines))
+
+
+class RowsFamily(Metric):
+    """What a :class:`SeriesTable` yields to its registry: the gauge
+    family of the rows there were when it was collected. ``text()`` is
+    the join of their lines; ``samples`` are built for whoever asks."""
+
+    def __init__(self, table: SeriesTable, n_rows: int) -> None:
+        self._table, self._n_rows = table, n_rows
+        family = table._family
+        Metric.__init__(self, family.name, family.documentation, "gauge")
+
+    @property
+    def samples(self) -> list[Sample]:
+        t, n = self._table, self._n_rows
+        return [
+            Sample(t.name, dict(zip(t.labelnames, labels)), value, None)
+            for labels, value in zip(t._labels[:n], t._values[:n].tolist())
+        ]
+
+    @samples.setter
+    def samples(self, _unused: list[Sample]) -> None:
+        """``Metric.__init__`` assigns an empty list."""
+
+    def text(self) -> str:
+        return self._table._head + "".join(self._table._lines[:self._n_rows])
 
 
 class Exporter:
@@ -138,7 +276,11 @@ class Exporter:
         self._lock = threading.Lock()
         # The advanced registry is written in bursts (a publish cycle of
         # the metrics module every 1-5 s, a reconcile) and gathered far
-        # more often, at ~0.5 s of Python for 35k pod-level series.
+        # more often. A render of its ~35k pod-level series is the join
+        # of their rows' lines: 12-13 ms of CPU on the chip's host,
+        # where walking one prometheus_client child per series took
+        # 0.4 s (PERF.md section 6, PR 32), and still the larger part
+        # of a gather.
         # Every change to it advances the generation; a publisher that
         # has finished a cycle also declares the generation published,
         # and any other change takes that word back.
@@ -158,6 +300,30 @@ class Exporter:
         self._gathers = {
             how: gathers.labels(advanced=how)
             for how in (mn.ADVANCED_RENDERED, mn.ADVANCED_REUSED)
+        }
+        # How often the row tables engage, and what the publisher's
+        # cycle costs this process: counted by the metrics module per
+        # cycle (rows looked at; rows new or rewritten; CPU seconds of
+        # its thread inside the snapshot and inside series_publish) and
+        # by gather() below (CPU seconds of the gathering thread inside
+        # a render of the pod-level bytes).
+        self.publish_rows = self.new_counter(
+            mn.TPU_PUBLISH_ROWS, [],
+            "rows of the pod-level tables the publish cycles looked at",
+        )
+        self.publish_rows_changed = self.new_counter(
+            mn.TPU_PUBLISH_ROWS_CHANGED, [],
+            "rows of the pod-level tables the publish cycles appended "
+            "or rewrote (the value differed from the one last set)",
+        )
+        cpu = self.new_counter(
+            mn.TPU_PUBLISH_CPU_SECONDS, [mn.L_PART],
+            "CPU seconds (time.thread_time) of the publisher's thread "
+            "inside a cycle's snapshot and series_publish, and of a "
+            "gathering thread inside a render of the pod-level bytes",
+        )
+        self.publish_cpu = {
+            part: cpu.labels(part=part) for part in mn.PUBLISH_PARTS
         }
 
     # -- reset (prometheusexporter.go:35-40) --
@@ -197,12 +363,12 @@ class Exporter:
         done with the advanced one: ``mn.ADVANCED_RENDERED`` or
         ``mn.ADVANCED_REUSED``.
 
-        Rendered by :func:`render_exposition`, not prometheus_client's
-        generate_latest: at production cardinality (~30k pod-level
-        samples) the library's per-sample regex validation/escaping cost
-        ~1.1s per render on one core — over half the agent's CPU under
-        scrape load. The fast path emits the same text format ~10x
-        cheaper; a round-trip test pins it byte-compatible.
+        Rendered by :func:`render_exposition` (the default registry)
+        and :func:`render_rows` (the advanced one), not
+        prometheus_client's generate_latest: at production cardinality
+        (~30k pod-level samples) the library's per-sample regex
+        validation/escaping cost ~1.1s per render on one core. Both
+        emit the same text format; tests pin them byte-compatible.
 
         The default registry (the agent's own counters, histograms and
         health series, which change all the time) is rendered on every
@@ -223,7 +389,9 @@ class Exporter:
         # own count is in its bytes.
         self._gathers[how].inc()
         if how == mn.ADVANCED_RENDERED:
-            adv_bytes = render_exposition(advanced)
+            t0 = time.thread_time()
+            adv_bytes = render_rows(advanced)
+            self.publish_cpu[mn.PART_RENDER].inc(time.thread_time() - t0)
             if published:
                 with self._lock:
                     if self._adv_gen == gen:
@@ -285,6 +453,17 @@ class Exporter:
             self._advanced_changed()
             return Counter(name, help_ or name, labels,
                            registry=self.advanced_registry)
+
+    def new_adv_table(
+        self, name: str, labels: list[str], help_: str = ""
+    ) -> SeriesTable:
+        """A gauge family kept as rows: what the metric objects
+        publish into."""
+        table = SeriesTable(name, labels, help_ or name)
+        with self._lock:
+            self._advanced_changed()
+            self.advanced_registry.register(table)
+        return table
 
 
 _singleton: Exporter | None = None

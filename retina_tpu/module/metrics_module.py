@@ -41,6 +41,7 @@ from retina_tpu.utils import metric_names as mn
 from retina_tpu.module.metric_objects import (
     METRIC_CONSTRUCTORS,
     AdvMetricBase,
+    PodLabels,
     PublishCtx,
 )
 
@@ -67,6 +68,7 @@ class MetricsModule:
         self.dns_resolver = dns_resolver
         self._lock = threading.Lock()
         self._metrics: dict[str, AdvMetricBase] = {}
+        self._pods = PodLabels()
         self._spec: MetricsSpec = MetricsSpec()
         if pubsub is not None:
             pubsub.subscribe(TOPIC_PODS, self._on_pod_event)
@@ -132,6 +134,7 @@ class MetricsModule:
             # recreate objects against the fresh registry.
             self.exporter.reset_advanced()
             self._metrics = {}
+            self._pods = PodLabels()
             for co in conf.spec.context_options:
                 ctor = METRIC_CONSTRUCTORS.get(co.metric_name)
                 if ctor is None:
@@ -150,19 +153,27 @@ class MetricsModule:
     def publish_once(self) -> None:
         with self._lock:
             metrics = dict(self._metrics)
-            spec = self._spec
+            spec, pods = self._spec, self._pods
         if not metrics:
             return
         rec = get_recorder()
+        cpu = self.exporter.publish_cpu
         with rec.span(
             mn.STAGE_POD_PUBLISH,
             window_epoch(self.cfg.window_seconds),
         ) as span:
+            t0 = time.thread_time()
             snap = self.engine.snapshot()
+            t1 = time.thread_time()
+            cpu[mn.PART_SNAPSHOT].inc(t1 - t0)
             try:
-                with rec.span(mn.STAGE_SERIES_PUBLISH, span.trace_id):
-                    self._publish_series(metrics, spec, snap)
+                with rec.span(mn.STAGE_SERIES_PUBLISH,
+                              span.trace_id) as series:
+                    ctx = self._publish_series(metrics, spec, pods, snap)
+                    series.set(rows=ctx.n_rows, changed=ctx.n_changed,
+                               created=ctx.n_created)
             finally:
+                cpu[mn.PART_SERIES].inc(time.thread_time() - t1)
                 # The one place the advanced registry's values are
                 # written ends here, whatever a metric object raised:
                 # until now the exporter serves the previous cycle's
@@ -178,7 +189,7 @@ class MetricsModule:
                 span.set(events_included=included,
                          lag_ms=round(lag_s * 1e3, 1))
 
-    def _publish_series(self, metrics, spec, snap) -> None:
+    def _publish_series(self, metrics, spec, pods, snap) -> PublishCtx:
         shed = getattr(self.engine, "shed_active", None)
         labeler: dict = {}
         if shed is not None and shed("labels"):
@@ -190,30 +201,36 @@ class MetricsModule:
             self.engine.overload.note_shed("labels")
         else:
             labeler = self.cache.index_label_map()
+        pods.refresh(labeler, spec.namespaces)
         ctx = PublishCtx(
-            labeler=labeler,
-            namespaces=spec.namespaces,
+            pods=pods,
             remote_context=self.cfg.remote_context,
             dns_resolver=self.dns_resolver,
         )
-        for name, m in metrics.items():
-            try:
-                m.publish(snap, ctx)
-            except Exception:
-                self._log.exception("metric %s publish failed", name)
+        try:
+            for name, m in metrics.items():
+                try:
+                    m.publish(snap, ctx)
+                except Exception:
+                    self._log.exception("metric %s publish failed", name)
+        finally:
+            self.exporter.publish_rows.inc(ctx.n_rows)
+            self.exporter.publish_rows_changed.inc(ctx.n_changed)
+        return ctx
 
     def start(self, stop: threading.Event) -> None:
         # Adaptive cadence: the 1 s module interval
-        # (metrics_module.go:37) assumes a publish cycle is cheap. Here
-        # it is not, and what it costs is host work: on the chip
-        # `publish_wait_pct` reads 5-16 (PERF.md, ledger PR 30) — the
-        # snapshot's dispatch and fetch waits are that small a share of
-        # a cycle, the rest is `series_publish` (0.3 s of Python under
-        # the GIL at 35k series) and the render that follows it. Back
-        # off to 4x cost so the cycle cannot take the host from the
+        # (metrics_module.go:37) assumes a publish cycle is cheap. At
+        # the benchmark's 35k series it is, since the series are rows
+        # and a cycle sets only those that changed: on the chip's host
+        # `pod_publish` is 40-48 ms (`series_publish` 25-26 ms, the
+        # rest the snapshot's fetch; PERF.md section 6, PR 32), so the
+        # rule below sleeps its 1 s floor, about 47 cycles in 50 s. It
+        # backs off to 4x cost for a cycle that is not cheap (more
+        # series, a slower host; 0.3 s a cycle slept 1.1-1.4 s until
+        # PR 32), so that the cycle cannot take the host from the
         # feed, but never beyond 5 s (unbounded backoff turned
-        # pod-gauge staleness into 12-15 s). A cheap cycle keeps the
-        # 1 s cadence.
+        # pod-gauge staleness into 12-15 s).
         while not stop.is_set():
             t0 = time.perf_counter()
             try:

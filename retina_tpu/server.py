@@ -65,11 +65,14 @@ class Server:
         ] = {}
         self._httpd: ThreadingHTTPServer | None = None
         self._thread: threading.Thread | None = None
-        # Rendering ~50k pod-level series is Python-heavy (~0.5s at 2k
-        # pods), and they change only when the metrics module finishes
-        # a publish cycle: the exporter keeps those bytes per publish
-        # (Exporter.gather), so most renders here cost the default
-        # registry and a join. This cache holds the whole body for a
+        # The pod-level series (~35k at 2k pods, 6.9 MB) change only
+        # when the metrics module finishes a publish cycle: the exporter
+        # keeps their bytes per publish (Exporter.gather) and makes
+        # them by joining the lines its row tables hold, so a render
+        # here is 13 ms in the mean on the chip's host (`render_ms`,
+        # PERF.md section 6, PR 32; 157-176 ms while the first render
+        # after a publish walked 35k children) and most cost the
+        # default registry alone. This cache holds the whole body for a
         # TTL, which bounds how often the default registry is rendered
         # and how soon a finished publish is picked up. On TTL expiry
         # the scrape serves the STALE body and kicks a background

@@ -330,6 +330,23 @@ TPU_EXPOSITION_GATHERS = PREFIX + "tpu_exposition_gathers_counter"
 L_ADVANCED = "advanced"
 ADVANCED_RENDERED = "rendered"
 ADVANCED_REUSED = "reused"
+# The pod-level families are row tables (exporter.SeriesTable), and a
+# publish cycle sets only what changed. publish_rows counts the rows
+# the cycles looked at (the active entries of the snapshot, after the
+# namespace filter), publish_rows_changed those they appended or
+# rewrote; publish_cpu_seconds is what the cycle costs the process:
+# time.thread_time() of the publisher's thread inside the cycle's
+# snapshot (``snapshot``) and inside series_publish (``series``), and of
+# a gathering thread inside a render of the pod-level bytes
+# (``render``; a gather that reuses the kept bytes adds nothing).
+TPU_PUBLISH_ROWS = PREFIX + "tpu_publish_rows_counter"
+TPU_PUBLISH_ROWS_CHANGED = PREFIX + "tpu_publish_rows_changed_counter"
+TPU_PUBLISH_CPU_SECONDS = PREFIX + "tpu_publish_cpu_seconds_counter"
+L_PART = "part"
+PART_SNAPSHOT = "snapshot"
+PART_SERIES = "series"
+PART_RENDER = "render"
+PUBLISH_PARTS = (PART_SNAPSHOT, PART_SERIES, PART_RENDER)
 
 # Pipeline stage-name registry (the ONLY legal values of the
 # tpu_stage_seconds `stage` label and of every recorder span). The

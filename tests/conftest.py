@@ -54,16 +54,20 @@ def long_parks(monkeypatch):
 
 
 class CountingRender:
-    """Stand-in for ``exporter.render_exposition``: the real bytes,
+    """Stand-in for the exporter's two renderers
+    (``exporter.render_exposition`` for the default registry,
+    ``exporter.render_rows`` for the pod-level one): the real bytes,
     counted by registry."""
 
-    def __init__(self, real):
-        self._real = real
+    def __init__(self):
         self.calls: list = []
 
-    def __call__(self, registry) -> bytes:
-        self.calls.append(registry)
-        return self._real(registry)
+    def counting(self, real):
+        def render(registry) -> bytes:
+            self.calls.append(registry)
+            return real(registry)
+
+        return render
 
     def count(self, registry) -> int:
         return sum(1 for r in self.calls if r is registry)
@@ -74,15 +78,18 @@ def counting_render(monkeypatch):
     """Counts the exporter's renders by registry from here on."""
     import retina_tpu.exporter as exporter_mod
 
-    render = CountingRender(_real_render)
-    monkeypatch.setattr(exporter_mod, "render_exposition", render)
+    render = CountingRender()
+    for name in ("render_exposition", "render_rows"):
+        monkeypatch.setattr(exporter_mod, name,
+                            render.counting(getattr(exporter_mod, name)))
     return render
 
 
 @pytest.fixture
 def fresh_exposition():
     """What rendering both registries of an exporter afresh gives (by
-    the real renderer, uncounted)."""
+    the real renderer, uncounted): every family sample by sample
+    through ``collect()``, a row table's too."""
 
     def fresh(ex) -> bytes:
         return (_real_render(ex.default_registry)

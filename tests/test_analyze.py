@@ -12,6 +12,8 @@ import sys
 import textwrap
 from pathlib import Path
 
+import pytest
+
 REPO = Path(__file__).resolve().parent.parent
 if str(REPO) not in sys.path:
     sys.path.insert(0, str(REPO))
@@ -512,6 +514,36 @@ def test_rt221_literal_for_declared_series(tmp_path):
     rep = Reporter()
     rt220.check_program(ctxs, rep, tmp_path)
     assert codes(rep.findings) == ["RT221"]
+
+
+@pytest.mark.parametrize("call, code", [
+    ('ex.new_adv_table("networkobservability_rogue", ["pod"])', "RT220"),
+    ('ex.new_adv_table("networkobservability_bar", ["pod"])', "RT221"),
+    ('ex.new_adv_table("networkobservability_" + kind, [])', "RT221"),
+])
+def test_a_row_table_is_held_to_a_metric_names_constant(tmp_path, call, code):
+    """The pod-level families are made by ``Exporter.new_adv_table``
+    (PR 32): a name that is not declared, a literal for a declared
+    name and a computed name are findings there as for a gauge."""
+    ctxs = _mini_repo(
+        tmp_path,
+        doc_metrics="`networkobservability_foo` "
+                    "`networkobservability_bar`\n",
+        doc_config="window_seconds dead_knob\n",
+        metrics_src=METRIC_DECLS,
+        config_src=CONFIG_SRC,
+        usage_src=f"""
+            from retina_tpu.utils import metric_names as mn
+
+            def setup(ex, kind):
+                ex.new_adv_table(mn.FOO, ["pod"])
+                ex.new_adv_table(mn.BAR, [])
+                {call}
+        """,
+    )
+    rep = Reporter()
+    rt220.check_program(ctxs, rep, tmp_path)
+    assert codes(rep.findings) == [code]
 
 
 # --------------------------------------------------------------- RT226

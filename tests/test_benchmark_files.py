@@ -26,7 +26,8 @@ DOC = harness.load_benchmark()
 NEW_CELLS = ("advanced-pod.zipf1m-steady",
              "advanced-pod-hubble.zipf1m-steady")
 NEW_READERS = ("steps_per_s", "step_fill_pct", "overload_pressure_p95",
-               "hubble_mirror_ms_per_s", "feed_wakeups_per_s")
+               "hubble_mirror_ms_per_s", "feed_wakeups_per_s",
+               "publish_cpu_ms_per_s", "publish_changed_pct")
 
 
 def _config(name: str) -> dict:
@@ -106,31 +107,52 @@ def _load(scrapes, before=None, after=None):
         counter_delta=lambda n: after.get(n, 0.0) - before.get(n, 0.0))
 
 
-# tpu_feed_wakeups_counter as the poller sums it over {thread, cause}.
+# tpu_feed_wakeups_counter as the poller sums it over {thread, cause},
+# tpu_publish_cpu_seconds_counter over {part}.
 RECORDED = _load(
     [_scrape(9.0, tpu_steps_counter=100.0, tpu_overload_pressure=0.9,
-             tpu_feed_wakeups_counter=900.0),
+             tpu_feed_wakeups_counter=900.0,
+             tpu_publish_cpu_seconds_counter=0.5,
+             tpu_publish_rows_counter=70_000.0,
+             tpu_publish_rows_changed_counter=70_000.0),
      _scrape(10.0, tpu_steps_counter=110.0, tpu_overload_pressure=0.10,
-             tpu_feed_wakeups_counter=1000.0),
+             tpu_feed_wakeups_counter=1000.0,
+             tpu_publish_cpu_seconds_counter=0.6,
+             tpu_publish_rows_counter=105_000.0,
+             tpu_publish_rows_changed_counter=90_000.0),
      _scrape(35.0, tpu_steps_counter=360.0, tpu_overload_pressure=0.30,
-             tpu_feed_wakeups_counter=3500.0),
+             tpu_feed_wakeups_counter=3500.0,
+             tpu_publish_cpu_seconds_counter=2.0,
+             tpu_publish_rows_counter=900_000.0,
+             tpu_publish_rows_changed_counter=500_000.0),
      _scrape(59.0, tpu_steps_counter=600.0, tpu_overload_pressure=0.20,
-             tpu_feed_wakeups_counter=5900.0),
+             tpu_feed_wakeups_counter=5900.0,
+             tpu_publish_cpu_seconds_counter=4.03,
+             tpu_publish_rows_counter=1_785_000.0,
+             tpu_publish_rows_changed_counter=1_098_000.0),
      _scrape(61.0, tpu_steps_counter=999.0, tpu_overload_pressure=0.95,
-             tpu_feed_wakeups_counter=9999.0)],
+             tpu_feed_wakeups_counter=9999.0,
+             tpu_publish_cpu_seconds_counter=9.0,
+             tpu_publish_rows_counter=9_999_999.0,
+             tpu_publish_rows_changed_counter=9_999_999.0)],
     before={"tpu_steps_counter": 50.0, "tpu_step_rows_counter": 1000.0},
     after={"tpu_steps_counter": 650.0,
            "tpu_step_rows_counter": 1000.0 + 600 * 131072 * 0.25})
 # A parent's run: the poller sums nothing for a series that is not there.
 PARENT = _load(
     [_scrape(t, tpu_steps_counter=0.0, tpu_overload_pressure=0.0,
-             tpu_feed_wakeups_counter=0.0)
+             tpu_feed_wakeups_counter=0.0,
+             tpu_publish_cpu_seconds_counter=0.0,
+             tpu_publish_rows_counter=0.0,
+             tpu_publish_rows_changed_counter=0.0)
      for t in (9.0, 10.0, 35.0, 59.0)])
 SPANS = [{"stage": "hubble_consume", "t0": 12.0 + i, "t1": 12.004 + i,
           "args": {"rows": 16384}} for i in range(25)]
 WANT = {"steps_per_s": (600.0 - 110.0) / 49.0, "step_fill_pct": 25.0,
         "overload_pressure_p95": 0.30, "hubble_mirror_ms_per_s": 2.0,
-        "feed_wakeups_per_s": (5900.0 - 1000.0) / 49.0}
+        "feed_wakeups_per_s": (5900.0 - 1000.0) / 49.0,
+        "publish_cpu_ms_per_s": 1e3 * (4.03 - 0.6) / 49.0,
+        "publish_changed_pct": 100.0 * 1_008_000 / 1_680_000}
 
 
 @pytest.mark.parametrize("name", NEW_READERS)
@@ -166,7 +188,7 @@ def test_the_wakeup_metric_is_read_in_every_cell_off_the_programs_counter():
                      "better": "lower", "source": "program_counter",
                      "layer": "feed + combine",
                      "moves": "host_cpu_us_per_event"}
-    assert DOC["per_layer"][-1] is entry  # appended, nothing moved
+    assert DOC["per_layer"][-3] is entry  # PR 32's two follow it
     reader = harness.load_reader("feed_wakeups_per_s")
     assert mn.FEED_WAKEUPS == "networkobservability_" + reader.WAKEUPS
     assert reader.COUNTERS == (reader.WAKEUPS,)
@@ -180,3 +202,47 @@ def test_the_wakeup_metric_is_read_in_every_cell_off_the_programs_counter():
     body = get_exporter().gather_text()
     name = mn.FEED_WAKEUPS.encode()
     assert poller.series_sum(body, (name,))[name] == 7.0
+
+
+def test_the_publish_metrics_are_read_in_every_cell_off_the_programs_counters():
+    """``publish_cpu_ms_per_s`` and ``publish_changed_pct`` (PR 32) name
+    the publisher's layer and the host's CPU, list no cells (every cell
+    publishes the pod-level series), stand last in the list, and the
+    counters they ask the poller for are the ones the program
+    registers: a publish cycle and a gather that renders move all
+    three, and the poller sums the CPU seconds over their parts."""
+    import numpy as np
+    import poller
+    from retina_tpu.exporter import get_exporter
+    from retina_tpu.utils import metric_names as mn
+
+    entries = DOC["per_layer"][-2:]
+    assert entries == [
+        {"name": name, "unit": unit, "better": "lower",
+         "source": "program_counter", "layer": "snapshot + publish",
+         "moves": "host_cpu_us_per_event"}
+        for name, unit in (("publish_cpu_ms_per_s", "ms/s"),
+                           ("publish_changed_pct", "%"))]
+    cpu = harness.load_reader("publish_cpu_ms_per_s")
+    pct = harness.load_reader("publish_changed_pct")
+    assert mn.TPU_PUBLISH_CPU_SECONDS == "networkobservability_" + cpu.CPU
+    assert mn.TPU_PUBLISH_ROWS == "networkobservability_" + pct.ROWS
+    assert mn.TPU_PUBLISH_ROWS_CHANGED == (
+        "networkobservability_" + pct.CHANGED)
+    assert cpu.COUNTERS == (cpu.CPU,)
+    assert pct.COUNTERS == (pct.ROWS, pct.CHANGED)
+    ex = get_exporter()
+    for part, seconds in zip(mn.PUBLISH_PARTS, (0.25, 0.5, 1.0)):
+        ex.publish_cpu[part].inc(seconds)
+    ex.publish_rows.inc(40)
+    ex.publish_rows_changed.inc(10)
+    table = ex.new_adv_table("bench_files_rows", ["pod"])
+    table.update(np.array([table.set(("a",), 1.0)[0]]), np.array([2.0]))
+    ex.advanced_published()
+    assert ex.gather()[1] == "rendered"  # adds its render's CPU seconds
+    names = tuple(n.encode() for n in (
+        mn.TPU_PUBLISH_CPU_SECONDS, mn.TPU_PUBLISH_ROWS,
+        mn.TPU_PUBLISH_ROWS_CHANGED))
+    sums = poller.series_sum(ex.gather_text(), names)
+    assert sums[names[0]] >= 1.75
+    assert (sums[names[1]], sums[names[2]]) == (40.0, 10.0)

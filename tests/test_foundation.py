@@ -190,15 +190,30 @@ def _gather_counts(ex: Exporter) -> dict[str, float]:
     }
 
 
+KINDS = ["plain_gauge", "row_table"]
+
+
+def _adv_family(ex: Exporter, kind: str, name: str, labelnames=()):
+    """A pod-level family of either kind, a plain prometheus_client
+    gauge or a row table, as ``put(label_values, value)``."""
+    if kind == "row_table":
+        table = ex.new_adv_table(name, list(labelnames))
+        return lambda labels, v: table.set(tuple(labels), float(v))
+    gauge = ex.new_adv_gauge(name, list(labelnames))
+    return lambda labels, v: (
+        gauge.labels(*labels) if labels else gauge).set(v)
+
+
+@pytest.mark.parametrize("kind", KINDS)
 def test_published_generation_is_rendered_once(
-        counting_render, fresh_exposition):
+        kind, counting_render, fresh_exposition):
     """(a) With a publisher-declared generation, gathers render the
     advanced registry once and the default registry every time, and
     every body is what rendering both registries gives."""
     ex = Exporter()
-    adv = ex.new_adv_gauge("gen_adv_gauge", ["pod"])
+    put = _adv_family(ex, kind, "gen_adv_gauge", ["pod"])
     for i in range(40):
-        adv.labels(pod=f"p{i}").set(i)
+        put([f"p{i}"], i)
     ex.advanced_published()
     render = counting_render
     bodies = [ex.gather() for _ in range(4)]
@@ -215,19 +230,20 @@ def test_published_generation_is_rendered_once(
     assert render.count(ex.advanced_registry) == 1
 
 
+@pytest.mark.parametrize("kind", KINDS)
 def test_publish_that_changes_one_series_shows_in_next_gather(
-        counting_render):
+        kind, counting_render):
     """(b) One series changed and the generation declared: the next
     gather carries it, at the cost of one render."""
     ex = Exporter()
-    adv = ex.new_adv_gauge("pub_adv_gauge", ["pod"])
-    adv.labels(pod="a").set(1)
-    adv.labels(pod="b").set(1)
+    put = _adv_family(ex, kind, "pub_adv_gauge", ["pod"])
+    put(["a"], 1)
+    put(["b"], 1)
     ex.advanced_published()
     render = counting_render
     assert b'pub_adv_gauge{pod="a"} 1.0' in ex.gather_text()
     assert b'pub_adv_gauge{pod="a"} 1.0' in ex.gather_text()
-    adv.labels(pod="a").set(2)
+    put(["a"], 2)
     # Mid-cycle: the previous complete publish is what is served.
     assert b'pub_adv_gauge{pod="a"} 1.0' in ex.gather_text()
     ex.advanced_published()
@@ -238,7 +254,8 @@ def test_publish_that_changes_one_series_shows_in_next_gather(
     assert render.count(ex.advanced_registry) == 2
 
 
-@pytest.mark.parametrize("change", ["reset", "new_gauge", "new_counter"])
+@pytest.mark.parametrize(
+    "change", ["reset", "new_gauge", "new_counter", "new_table"])
 def test_reset_and_new_family_invalidate_kept_bytes(
         change, fresh_exposition):
     """(c) A reset drops the kept bytes at once, and so does a newly
@@ -258,10 +275,15 @@ def test_reset_and_new_family_invalidate_kept_bytes(
         ex.new_adv_gauge("inv_new_gauge", []).set(1)
         body, how = ex.gather()
         assert b"inv_new_gauge 1.0" in body and b"inv_adv_gauge 7.0" in body
-    else:
+    elif change == "new_counter":
         ex.new_adv_counter("inv_new_counter", []).inc(3)
         body, how = ex.gather()
         assert b"inv_new_counter_total 3.0" in body
+    else:
+        ex.new_adv_table("inv_new_table", ["pod"]).set(("a",), 2.0)
+        body, how = ex.gather()
+        assert body.endswith(b'inv_new_table{pod="a"} 2.0\n')
+        assert b"inv_adv_gauge 7.0" in body
     assert how == "rendered"
     assert ex.gather()[1] == "rendered"  # nobody declared this state
     assert ex.gather_text() == fresh_exposition(ex)
@@ -270,7 +292,8 @@ def test_reset_and_new_family_invalidate_kept_bytes(
     assert ex.gather_text() == fresh_exposition(ex)
 
 
-def test_publish_landing_during_a_render_is_rendered_next(monkeypatch):
+@pytest.mark.parametrize("kind", KINDS)
+def test_publish_landing_during_a_render_is_rendered_next(kind, monkeypatch):
     """(d) The generation is read BEFORE the render and kept with the
     bytes: a publish that ends while a render is in flight (held here
     on an event) makes the next gather render again instead of being
@@ -278,10 +301,10 @@ def test_publish_landing_during_a_render_is_rendered_next(monkeypatch):
     import retina_tpu.exporter as exporter_mod
 
     ex = Exporter()
-    adv = ex.new_adv_gauge("race_adv_gauge", [])
-    adv.set(1)
+    put = _adv_family(ex, kind, "race_adv_gauge")
+    put([], 1)
     ex.advanced_published()
-    real = exporter_mod.render_exposition
+    real = exporter_mod.render_rows
     in_render, release = threading.Event(), threading.Event()
     renders = []
 
@@ -295,12 +318,12 @@ def test_publish_landing_during_a_render_is_rendered_next(monkeypatch):
         return body
 
     got = []
-    monkeypatch.setattr(exporter_mod, "render_exposition", held)
+    monkeypatch.setattr(exporter_mod, "render_rows", held)
     try:
         t = threading.Thread(target=lambda: got.append(ex.gather()))
         t.start()
         assert in_render.wait(10.0)
-        adv.set(2)  # the publisher's cycle lands mid-render
+        put([], 2)  # the publisher's cycle lands mid-render
         ex.advanced_published()
         release.set()
         t.join(10.0)
@@ -393,21 +416,39 @@ def test_render_span_and_counter_say_reused_or_rendered(ttl):
     assert _gather_counts(ex) == {"reused": 2.0, "rendered": 3.0}
 
 
-def test_concurrent_gathers_never_keep_stale_bytes_under_a_new_generation():
+@pytest.mark.parametrize("kind", KINDS)
+def test_concurrent_gathers_never_keep_stale_bytes_under_a_new_generation(
+        kind):
     """A publisher writes every series and declares the cycle, cycle
     after cycle, while more gatherers than cores gather: whatever a
     gatherer reads once cycle v has been declared holds no value older
     than v (a render that overlapped the writes may hold newer ones).
     Bytes kept under a generation they do not belong to would break
-    it."""
+    it. A row table is written as the metric objects write it: the
+    rows by number, in one ``update``."""
     import sys
 
+    import numpy as np
+
     ex = Exporter()
-    g = ex.new_adv_gauge("stress_adv_gauge", ["pod"])
     n_series, cycles = 300, 60
-    children = [g.labels(pod=f"p{i}") for i in range(n_series)]
-    for c in children:
-        c.set(0)
+    if kind == "row_table":
+        table = ex.new_adv_table("stress_adv_gauge", ["pod"])
+        rows = np.array([table.set((f"p{i}",), 0.0)[0]
+                         for i in range(n_series)])
+
+        def write(v: int) -> None:
+            assert table.update(rows, np.full(n_series, float(v))) == (
+                n_series if v else 0)
+    else:
+        g = ex.new_adv_gauge("stress_adv_gauge", ["pod"])
+        children = [g.labels(pod=f"p{i}") for i in range(n_series)]
+
+        def write(v: int) -> None:
+            for c in children:
+                c.set(v)
+
+    write(0)
     ex.advanced_published()
     declared = [0]
     stop = threading.Event()
@@ -428,8 +469,7 @@ def test_concurrent_gathers_never_keep_stale_bytes_under_a_new_generation():
 
     def publisher() -> None:
         for v in range(1, cycles + 1):
-            for c in children:
-                c.set(v)
+            write(v)
             ex.advanced_published()
             declared[0] = v
             time.sleep(0.002)  # let gathers start between cycles
@@ -655,3 +695,60 @@ def test_telemetry_heartbeat_and_noop():
 
     noop = new_telemetry(enabled=False)
     assert noop.heartbeat() == {}
+
+
+# ---------------------------------------------- row tables (exporter)
+def test_row_table_is_a_gauge_family_to_its_registry():
+    """A SeriesTable renders, collects and registers as the Gauge it
+    stands for: the same bytes through the join of its lines
+    (render_rows), sample by sample through collect()
+    (render_exposition) and through the library's generate_latest;
+    label values escaped, labels sorted, a family without labels 0.0
+    from birth, rows in first-set order and past the first allocation;
+    plain collectors beside it in registration order; a second family
+    of the name refused."""
+    import numpy as np
+    from prometheus_client import CollectorRegistry, Gauge
+    from prometheus_client.exposition import generate_latest
+
+    from retina_tpu.exporter import render_exposition, render_rows
+
+    ex, ref = Exporter(), CollectorRegistry()
+    before = ex.new_adv_gauge("tbl_before", ["z", "a"])
+    Gauge("tbl_before", "tbl_before", ["z", "a"], registry=ref).labels(
+        z="1", a="2").set(5)
+    before.labels(z="1", a="2").set(5)
+    table = ex.new_adv_table("tbl_rows", ["zone", "pod"], "rows\nof \\ it")
+    plain = Gauge("tbl_rows", "rows\nof \\ it", ["zone", "pod"], registry=ref)
+    bare = ex.new_adv_table("tbl_bare", [])
+    Gauge("tbl_bare", "tbl_bare", registry=ref)
+    ex.new_adv_gauge("tbl_after", []).set(2)
+    Gauge("tbl_after", "tbl_after", registry=ref).set(2)
+    values = [0, 1, 999999, 1000000, 12345678, 2.5, float("inf"), -3]
+    for i in range(200):
+        labels = (f"z{i % 3}", 'p"\\\n' if i == 7 else f"pod-{i}")
+        v = values[i % len(values)]
+        assert table.set(labels, float(v)) == (i, True)
+        plain.labels(*labels).set(v)
+    assert len(table) == 200 and len(bare) == 1
+    assert table.set(("z1", "pod-1"), 1.0) == (1, False)  # unchanged
+    assert table.set(("z1", "pod-1"), 4.0) == (1, True)
+    plain.labels("z1", "pod-1").set(4)
+
+    def same():
+        want = generate_latest(ref)
+        got = render_rows(ex.advanced_registry)
+        assert got == render_exposition(ex.advanced_registry) == want
+        assert generate_latest(ex.advanced_registry) == want
+
+    same()
+    # row 6 keeps its value; row 5 twice in one call: the last wins
+    assert table.update(np.array([5, 6, 5]),
+                        np.array([1.0, float("inf"), 7.0])) == 2
+    plain.labels("z2", "pod-5").set(7)
+    same()
+    assert ex.advanced_registry.get_sample_value(
+        "tbl_rows", {"zone": "z2", "pod": "pod-5"}) == 7.0
+    assert ex.advanced_registry.get_sample_value("tbl_bare") == 0.0
+    with pytest.raises(ValueError, match="Duplicated"):
+        ex.new_adv_table("tbl_rows", ["zone"])

@@ -35,6 +35,7 @@ PREFIX = "networkobservability_"
 REG_FUNCS = {
     "new_gauge", "new_counter", "new_histogram",
     "new_adv_gauge", "new_adv_counter", "new_adv_histogram",
+    "new_adv_table",
 }
 
 DOC_SERIES_RE = re.compile(r"networkobservability_[a-z0-9_]+")
