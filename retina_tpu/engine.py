@@ -1586,7 +1586,7 @@ class SketchEngine:
         meta_known[3] = 0 if have_new else meta_new[3]
         meta_known[5:] = nv_known
         n_events = int(sb.events)
-        n_valid_total = int(nv_new.sum() + nv_known.sum())
+        shard_rows = nv_new + nv_known
         samp_k = int(sb.sample_k)
         # One step instead of one a side, where the wire allows it:
         # each side is one window and together they fit one. The new
@@ -1595,7 +1595,7 @@ class SketchEngine:
         cap = self.cfg.batch_capacity
         fold_sides = bool(
             have_new and have_known and Bn <= cap and Bk <= cap
-            and int((nv_new + nv_known).max()) <= cap
+            and int(shard_rows.max()) <= cap
         )
 
         def xfer_and_step(done=None):
@@ -1725,7 +1725,7 @@ class SketchEngine:
             if record_metrics:
                 m.transfer_seconds.observe(t0 - t_x0)
                 self._note_dispatched(
-                    c_x0, n_valid_total, n_steps, n_raw, n_flushes
+                    c_x0, shard_rows, n_steps, n_raw, n_flushes
                 )
             self._watch_steps(
                 sp_s if record_metrics else None, summary["events"], t0,
@@ -1853,7 +1853,7 @@ class SketchEngine:
         meta[3] = np.uint32(int(sb.lost) & 0xFFFFFFFF)
         meta[4] = 0  # ts_rel_rep: unused on the full-row path
         meta[5:] = sb.n_valid
-        n_valid_total = int(sb.n_valid.sum())
+        shard_rows = sb.n_valid
         n_events = int(sb.events)
         samp_k = int(sb.sample_k)
         sp_build.end()
@@ -1903,7 +1903,7 @@ class SketchEngine:
                 # with a synthetic zero batch.
                 m.transfer_seconds.observe(t0 - t_x0)
                 self._note_dispatched(
-                    c_x0, n_valid_total, len(wins), n_raw, n_flushes
+                    c_x0, shard_rows, len(wins), n_raw, n_flushes
                 )
             self._watch_steps(
                 sp_s if record_metrics else None, summary["events"], t0,
@@ -1944,8 +1944,8 @@ class SketchEngine:
         return self._recorder.span(stage, tid)
 
     def _note_dispatched(
-        self, c_x0: float, n_rows: int, n_steps: int, n_raw: int,
-        n_flushes: int,
+        self, c_x0: float, shard_rows: np.ndarray, n_steps: int,
+        n_raw: int, n_flushes: int,
     ) -> None:
         """(proxy thread) What both dispatchers record once a
         dispatch's transfer and steps are enqueued: the overload
@@ -1954,8 +1954,8 @@ class SketchEngine:
         it is read against; proxy thread only, so no lock),
         the fill of the step capacity dispatched (windows x
         batch_capacity: a 0..1 ratio for coalesced multi-window
-        transfers too), the folding counters and the engine's own
-        totals."""
+        transfers too), the folding counters (``shard_rows``: the valid
+        rows dispatched to each device) and the engine's own totals."""
         now = self._clock()
         # One sample weighs at most its budget (half a window, what
         # _overload_signals divides by): enqueues that are slow one
@@ -1968,12 +1968,15 @@ class SketchEngine:
         )
         self._dispatch_lat_t = now
         m = get_metrics()
+        n_rows = int(shard_rows.sum())
         m.device_batch_fill.set(
             n_rows
             / max(self.n_devices * self.cfg.batch_capacity * n_steps, 1)
         )
         m.steps.inc(n_steps)
         m.step_rows.inc(n_rows)
+        for d, n in enumerate(shard_rows.tolist()):
+            m.shard_rows.labels(device=str(d)).inc(n)
         m.dispatch_flushes.inc(n_flushes)
         self._steps += n_steps
         self._events_in += n_raw
@@ -2546,16 +2549,25 @@ class SketchEngine:
         # dispatch paths.
         all_rec, samp_k = self._overload.sample_rows(all_rec)
         items: list[tuple] = []
-        for off in range(0, len(all_rec), coal):
-            chunk = all_rec[off : off + coal]
-            sb = partition_events(
-                chunk, self.n_devices, coal_per_dev,
-                min_bucket=self.cfg.transfer_min_bucket,
-            )
-            sb.sample_k = samp_k
-            # raw-row accounting goes to the chunk that carries it;
-            # chunk boundaries are an implementation detail
-            items.append(("step", sb, now_s, n_raw if off == 0 else 0))
+        # One span a flush: on one device the partition is a zero-copy
+        # view, on a mesh it is the connection hash and a masked copy
+        # per device.
+        with self._recorder.span(
+            mnames.STAGE_PARTITION, fleet_epoch(self.cfg.window_seconds),
+            rows=len(all_rec), devices=self.n_devices,
+        ):
+            for off in range(0, len(all_rec), coal):
+                chunk = all_rec[off : off + coal]
+                sb = partition_events(
+                    chunk, self.n_devices, coal_per_dev,
+                    min_bucket=self.cfg.transfer_min_bucket,
+                )
+                sb.sample_k = samp_k
+                # raw-row accounting goes to the chunk that carries it;
+                # chunk boundaries are an implementation detail
+                items.append(
+                    ("step", sb, now_s, n_raw if off == 0 else 0)
+                )
         return items
 
     def feed_stats(self) -> dict[str, Any]:

@@ -94,6 +94,7 @@ class Metrics:
         self.device_batch_fill = g(mn.DEVICE_BATCH_FILL, [])
         self.steps = c(mn.STEPS, [])
         self.step_rows = c(mn.STEP_ROWS, [])
+        self.shard_rows = c(mn.SHARD_ROWS, [mn.L_DEVICE])
         self.dispatch_flushes = c(mn.DISPATCH_FLUSHES, [])
         self.windows_closed = c(mn.WINDOWS_CLOSED, [])
         # Window ticks deferred while the close program was still

@@ -288,11 +288,11 @@ def on_ready(arr: Any, fn: Callable[[BaseException | None], Any]) -> None:
     copies. The direct wait was tried on one attached v5e chip (PR 26:
     a probe of 300 waits beside a dispatching thread, then every run of
     the benchmark) and is kept. On a four-chip mesh, where the awaited
-    output is sharded over the devices, a probe of 300 waits was clean
-    too, but the engine itself has not booted there with it (ROADMAP
-    D11): the next four-chip bring-up call decides it, and the fallback
-    is to poll ``is_ready`` through the proxy as ``fetch_on_device``
-    does.
+    output lives on every device, it is what the engine runs with too:
+    every run of the benchmark's four-chip cell since PR 33 boots the
+    agent over the v5e-4 host's mesh and ends exact (ROADMAP D11). The
+    fallback, should a runtime ever refuse the wait, is to poll
+    ``is_ready`` through the proxy as ``fetch_on_device`` does.
     Callers bound the number of outstanding hand-overs (the engine
     hands over one per dispatch, inside its in-flight semaphore)."""
     global _ready_q, _ready_thread

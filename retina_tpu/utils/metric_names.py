@@ -151,6 +151,12 @@ DEVICE_BATCH_FILL = PREFIX + "tpu_batch_fill_ratio"
 STEPS = PREFIX + "tpu_steps_counter"
 STEP_ROWS = PREFIX + "tpu_step_rows_counter"
 DISPATCH_FLUSHES = PREFIX + "tpu_dispatch_flushes_counter"
+# Valid rows dispatched to each device of the mesh (the host partition's
+# shares: partition.partition_events); summed over its label it is
+# tpu_step_rows_counter. The fullest device sizes every device's wire
+# and overflows first.
+SHARD_ROWS = PREFIX + "tpu_shard_rows_counter"
+L_DEVICE = "device"
 WINDOWS_CLOSED = PREFIX + "tpu_windows_closed"
 COMBINE_RATIO = PREFIX + "host_combine_ratio"
 TRANSFER_SECONDS = PREFIX + "tpu_transfer_seconds"
@@ -356,6 +362,7 @@ PUBLISH_PARTS = (PART_SNAPSHOT, PART_SERIES, PART_RENDER)
 # emission site and the doc row together.
 STAGE_DISTRIBUTOR_DEAL = "distributor_deal"
 STAGE_COMBINE = "combine"
+STAGE_PARTITION = "partition"
 STAGE_FEED_FILL = "feed_fill"
 STAGE_STAGING_HANDOFF = "staging_handoff"
 STAGE_WIRE_BUILD = "wire_build"
@@ -383,6 +390,7 @@ STAGE_HUBBLE_CONSUME = "hubble_consume"
 STAGES = (
     STAGE_DISTRIBUTOR_DEAL,
     STAGE_COMBINE,
+    STAGE_PARTITION,
     STAGE_FEED_FILL,
     STAGE_STAGING_HANDOFF,
     STAGE_WIRE_BUILD,

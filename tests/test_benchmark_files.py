@@ -1,9 +1,10 @@
-"""What BENCHMARK.json names, and the per-layer readers ISSUE 29 added
-(ROADMAP D10): every configuration, traffic mix and per-layer metric
-resolves to a file the harness finds by name; each new reader returns a
-number on a recorded load and nothing where its counter or span is
-absent, as on a parent commit's runs of the old cells."""
+"""What BENCHMARK.json names, and the per-layer readers added since
+ISSUE 29 (ROADMAP D10): every configuration, traffic mix and per-layer
+metric resolves to a file the harness finds by name; each new reader
+returns a number on a recorded load and nothing where its counter, span
+or trace is absent, as on a parent commit's runs."""
 
+import dataclasses
 import json
 import os
 import sys
@@ -24,10 +25,15 @@ import traffic  # noqa: E402
 
 DOC = harness.load_benchmark()
 NEW_CELLS = ("advanced-pod.zipf1m-steady",
-             "advanced-pod-hubble.zipf1m-steady")
+             "advanced-pod-hubble.zipf1m-steady",
+             "advanced-pod-noct.zipf1m-steady")
+MESH_CELL = "advanced-pod-v5e4.zipf1m-steady-x4"  # ISSUE 33, four chips
+MESH_READERS = ("partition_ms_per_s", "shard_skew_pct",
+                "collective_ms_per_s")
 NEW_READERS = ("steps_per_s", "step_fill_pct", "overload_pressure_p95",
                "hubble_mirror_ms_per_s", "feed_wakeups_per_s",
-               "publish_cpu_ms_per_s", "publish_changed_pct")
+               "publish_cpu_ms_per_s", "publish_changed_pct",
+               *MESH_READERS)
 
 
 def _config(name: str) -> dict:
@@ -82,6 +88,7 @@ def test_the_hubble_configuration_is_the_configmap_with_nothing_off():
     assert hub["machine"]["hubble_addr"] == "127.0.0.1:0"
     assert hub["machine"]["hubble_metrics_addr"] == "127.0.0.1:0"
     cells = {w["name"]: w for w in DOC["workloads"]}
+    assert {MESH_CELL, *NEW_CELLS} <= cells.keys()
     for name in NEW_CELLS:
         assert cells[name]["chips"] == 1
         assert cells[name]["traffic"] == "zipf1m-steady"
@@ -90,21 +97,89 @@ def test_the_hubble_configuration_is_the_configmap_with_nothing_off():
     assert hubble["workloads"] == ["advanced-pod-hubble.zipf1m-steady"]
 
 
+def test_the_v5e4_configuration_is_the_configmap_on_four_chips():
+    """``advanced-pod.json`` key for key but the mesh, the name and the
+    prose that says where it runs; its traffic the ``steady`` mix field
+    for field but the rate, four times one chip's; its cell the one
+    four-chip cell, the only one the mesh metrics list."""
+    mesh, base = _config("advanced-pod-v5e4"), _config("advanced-pod")
+    for group in ("agent", "machine", "step_shapes", "step_program",
+                  "guarantees", "held", "rehearse", "rehearse_held",
+                  "reduced"):
+        assert mesh[group] == base[group], group
+    assert mesh["sizing"] == {**base["sizing"], "mesh_devices": 4}
+    assert mesh["assumed"][:len(base["assumed"])] == base["assumed"]
+    said = {"name", "source", "deployment", "sizing", "assumed",
+            "reference"}
+    assert {k for k in mesh if mesh[k] != base.get(k)
+            and not k.endswith("_note")} == said
+    assert mesh["reduced"] == [] and "four" in mesh["deployment"]
+    x4 = traffic.load_mix("zipf1m-steady-x4")
+    steady = traffic.load_mix("zipf1m-steady")
+    assert x4.rate_events_per_s <= 4 * steady.rate_events_per_s
+    assert x4.rate_events_per_s % steady.rate_events_per_s == 0
+    assert x4.block_rows == x4.rate_events_per_s // 16 <= 65_536
+    for key in ("n_flows", "n_endpoints", "zipf_a", "drop_fraction",
+                "dns_fraction", "pool_events", "ticks_per_s",
+                "warmup_windows", "poll_interval_s"):
+        assert getattr(x4, key) == getattr(steady, key), key
+    assert traffic.load_mix("zipf1m-steady-x4", rehearse=True) == \
+        dataclasses.replace(traffic.load_mix("zipf1m-steady", True),
+                            name="zipf1m-steady-x4")
+    four = [w for w in DOC["workloads"] if w["chips"] == 4]
+    assert [w["name"] for w in four] == [MESH_CELL]
+    assert four[0]["config"] == "advanced-pod-v5e4"
+    by_name = {m["name"]: m for m in DOC["per_layer"]}
+    for name in MESH_READERS:
+        assert by_name[name]["workloads"] == [MESH_CELL], name
+    entry = {c["name"]: c for c in DOC["configs"]}["advanced-pod-v5e4"]
+    assert entry["reduced"] == [] and len(entry["source"]) <= 200
+
+
 # -- the new readers on a recorded load -------------------------------------
 def _scrape(sent, **c):
     return {"sent": sent, "done": sent + 0.01, "ok": True, "events": 0,
             "c": c}
 
 
-def _load(scrapes, before=None, after=None):
+def _load(scrapes, before=None, after=None, trace=None):
     """What the readers read of a ``harness.Load``: window [10, 60),
-    the configuration, the scrapes and the counters at the start of
-    the load and at the settled scrape."""
+    the configuration, the scrapes, the counters at the start of the
+    load and at the settled scrape, and the reduced trace."""
     before, after = before or {}, after or {}
     return types.SimpleNamespace(
-        trace=None, t_open=10.0, t_close=60.0, scrapes=scrapes,
-        config=_config("advanced-pod"),
+        trace=trace, t_open=10.0, t_close=60.0, scrapes=scrapes,
+        config=_config("advanced-pod-v5e4"),
         counter_delta=lambda n: after.get(n, 0.0) - before.get(n, 0.0))
+
+
+def _shards(*rows):
+    """Each device's ``tpu_shard_rows_counter`` sample as the poller
+    keys it: by the full sample name it was asked for."""
+    series = harness.load_reader("shard_skew_pct").SERIES
+    return {series % d: float(n) for d, n in enumerate(rows)}
+
+
+def _traced(ops_by_chip, window_s=5.0):
+    import trace_reduce
+
+    return trace_reduce.Trace(
+        [trace_reduce.Chip(i, [], ops)
+         for i, ops in enumerate(ops_by_chip)], window_s)
+
+
+STEP_OPS = [("%fusion.46 u32[524288]", 0, 60_000_000),
+            ("%copy.3 u32[4,16]", 60_000_000, 1_000_000)]
+# Two chips of a mesh: a plain all-reduce, the two halves of an
+# asynchronous all-gather, and a fusion that is no collective.
+MESH_TRACE = _traced([
+    STEP_OPS + [("%all-reduce.7 u32[2]", 61_000_000, 2_000_000),
+                ("%all-gather-start.1 u32[4,2048,5]", 63_000_000, 500_000),
+                ("%all-gather-done.1 u32[4,2048,5]", 64_000_000, 1_500_000)],
+    STEP_OPS + [("%all-reduce.7 u32[2]", 61_000_000, 4_000_000),
+                ("%all-gather-start.1 u32[4,2048,5]", 65_000_000, 500_000),
+                ("%all-gather-done.1 u32[4,2048,5]", 66_000_000, 1_500_000)],
+])
 
 
 # tpu_feed_wakeups_counter as the poller sums it over {thread, cause},
@@ -119,7 +194,8 @@ RECORDED = _load(
              tpu_feed_wakeups_counter=1000.0,
              tpu_publish_cpu_seconds_counter=0.6,
              tpu_publish_rows_counter=105_000.0,
-             tpu_publish_rows_changed_counter=90_000.0),
+             tpu_publish_rows_changed_counter=90_000.0,
+             **_shards(1000, 1000, 1000, 1000)),
      _scrape(35.0, tpu_steps_counter=360.0, tpu_overload_pressure=0.30,
              tpu_feed_wakeups_counter=3500.0,
              tpu_publish_cpu_seconds_counter=2.0,
@@ -129,7 +205,8 @@ RECORDED = _load(
              tpu_feed_wakeups_counter=5900.0,
              tpu_publish_cpu_seconds_counter=4.03,
              tpu_publish_rows_counter=1_785_000.0,
-             tpu_publish_rows_changed_counter=1_098_000.0),
+             tpu_publish_rows_changed_counter=1_098_000.0,
+             **_shards(3000, 4000, 7000, 6000)),
      _scrape(61.0, tpu_steps_counter=999.0, tpu_overload_pressure=0.95,
              tpu_feed_wakeups_counter=9999.0,
              tpu_publish_cpu_seconds_counter=9.0,
@@ -137,22 +214,31 @@ RECORDED = _load(
              tpu_publish_rows_changed_counter=9_999_999.0)],
     before={"tpu_steps_counter": 50.0, "tpu_step_rows_counter": 1000.0},
     after={"tpu_steps_counter": 650.0,
-           "tpu_step_rows_counter": 1000.0 + 600 * 131072 * 0.25})
+           "tpu_step_rows_counter": 1000.0 + 600 * 131072 * 0.25},
+    trace=MESH_TRACE)
 # A parent's run: the poller sums nothing for a series that is not there.
 PARENT = _load(
     [_scrape(t, tpu_steps_counter=0.0, tpu_overload_pressure=0.0,
              tpu_feed_wakeups_counter=0.0,
              tpu_publish_cpu_seconds_counter=0.0,
              tpu_publish_rows_counter=0.0,
-             tpu_publish_rows_changed_counter=0.0)
-     for t in (9.0, 10.0, 35.0, 59.0)])
+             tpu_publish_rows_changed_counter=0.0, **_shards(0, 0, 0, 0))
+     for t in (9.0, 10.0, 35.0, 59.0)],
+    trace=_traced([STEP_OPS]))  # one chip: no collective to read
 SPANS = [{"stage": "hubble_consume", "t0": 12.0 + i, "t1": 12.004 + i,
-          "args": {"rows": 16384}} for i in range(25)]
+          "args": {"rows": 16384}} for i in range(25)] + [
+    {"stage": "partition", "t0": 12.5 + i, "t1": 12.506 + i,
+     "args": {"rows": 40_000, "devices": 4}} for i in range(40)]
 WANT = {"steps_per_s": (600.0 - 110.0) / 49.0, "step_fill_pct": 25.0,
         "overload_pressure_p95": 0.30, "hubble_mirror_ms_per_s": 2.0,
         "feed_wakeups_per_s": (5900.0 - 1000.0) / 49.0,
         "publish_cpu_ms_per_s": 1e3 * (4.03 - 0.6) / 49.0,
-        "publish_changed_pct": 100.0 * 1_008_000 / 1_680_000}
+        "publish_changed_pct": 100.0 * 1_008_000 / 1_680_000,
+        "partition_ms_per_s": 40 * 6.0 / 50.0,
+        # Rows of the window 2,000 / 3,000 / 6,000 / 5,000: mean 4,000.
+        "shard_skew_pct": 50.0,
+        # (2 + 0.5 + 1.5) ms and (4 + 0.5 + 1.5) ms over 5 s, the mean.
+        "collective_ms_per_s": 1.0}
 
 
 @pytest.mark.parametrize("name", NEW_READERS)
@@ -188,7 +274,6 @@ def test_the_wakeup_metric_is_read_in_every_cell_off_the_programs_counter():
                      "better": "lower", "source": "program_counter",
                      "layer": "feed + combine",
                      "moves": "host_cpu_us_per_event"}
-    assert DOC["per_layer"][-3] is entry  # PR 32's two follow it
     reader = harness.load_reader("feed_wakeups_per_s")
     assert mn.FEED_WAKEUPS == "networkobservability_" + reader.WAKEUPS
     assert reader.COUNTERS == (reader.WAKEUPS,)
@@ -207,7 +292,7 @@ def test_the_wakeup_metric_is_read_in_every_cell_off_the_programs_counter():
 def test_the_publish_metrics_are_read_in_every_cell_off_the_programs_counters():
     """``publish_cpu_ms_per_s`` and ``publish_changed_pct`` (PR 32) name
     the publisher's layer and the host's CPU, list no cells (every cell
-    publishes the pod-level series), stand last in the list, and the
+    publishes the pod-level series), and the
     counters they ask the poller for are the ones the program
     registers: a publish cycle and a gather that renders move all
     three, and the poller sums the CPU seconds over their parts."""
@@ -216,7 +301,9 @@ def test_the_publish_metrics_are_read_in_every_cell_off_the_programs_counters():
     from retina_tpu.exporter import get_exporter
     from retina_tpu.utils import metric_names as mn
 
-    entries = DOC["per_layer"][-2:]
+    by_name = {m["name"]: m for m in DOC["per_layer"]}
+    entries = [by_name[n] for n in ("publish_cpu_ms_per_s",
+                                    "publish_changed_pct")]
     assert entries == [
         {"name": name, "unit": unit, "better": "lower",
          "source": "program_counter", "layer": "snapshot + publish",
@@ -246,3 +333,27 @@ def test_the_publish_metrics_are_read_in_every_cell_off_the_programs_counters():
     sums = poller.series_sum(ex.gather_text(), names)
     assert sums[names[0]] >= 1.75
     assert (sums[names[1]], sums[names[2]]) == (40.0, 10.0)
+
+
+def test_the_skew_metric_reads_each_devices_series_off_the_programs_counter():
+    """The poller sums a counter over its labels, so ``shard_skew_pct``
+    asks for each device's sample by its full name: the name the
+    program's ``tpu_shard_rows_counter{device}`` renders to."""
+    import poller
+    from retina_tpu.exporter import get_exporter
+    from retina_tpu.metrics import get_metrics
+    from retina_tpu.utils import metric_names as mn
+
+    reader = harness.load_reader("shard_skew_pct")
+    assert reader.SERIES.startswith(
+        mn.SHARD_ROWS.removeprefix("networkobservability_") + "_total{"
+        + mn.L_DEVICE + "=")
+    assert all("," not in c for c in reader.COUNTERS)  # --counters a,b,c
+    shard = get_metrics().shard_rows
+    names = tuple(poller.PREFIX + c.encode() for c in reader.COUNTERS)
+    before = poller.series_sum(get_exporter().gather_text(), names)
+    for d, n in enumerate((5, 7, 11, 13)):
+        shard.labels(device=str(d)).inc(n)
+    after = poller.series_sum(get_exporter().gather_text(), names)
+    assert [after[n] - before[n] for n in names] == [
+        5.0, 7.0, 11.0, 13.0, 0.0, 0.0, 0.0, 0.0]

@@ -92,6 +92,53 @@ class TestPartition:
         assert sb.lost == 4096 - int(sb.n_valid.sum())
         assert sb.lost > 0
 
+    @pytest.mark.parametrize("capacity", [4096, 600])
+    def test_four_devices_keep_a_connection_whole_and_conserve(
+            self, capacity):
+        """The v5e-4 host's partition (D = 4): both directions of a
+        connection land on one device, the packets kept plus the
+        packets counted lost are the input's, and the wire's minor
+        dimension is the bucket of the fullest device."""
+        from retina_tpu.parallel.partition import _bucket_for
+
+        fwd = _events(2048)
+        rev = fwd.copy()
+        rev[:, F.SRC_IP], rev[:, F.DST_IP] = (
+            fwd[:, F.DST_IP].copy(), fwd[:, F.SRC_IP].copy())
+        ports = fwd[:, F.PORTS]
+        rev[:, F.PORTS] = ((ports & np.uint32(0xFFFF)) << np.uint32(16)) \
+            | (ports >> np.uint32(16))
+        rec = np.concatenate([fwd, rev])
+        rec[:, F.PACKETS] = np.arange(len(rec), dtype=np.uint32) % 7 + 1
+        sb = partition_events(rec, 4, capacity, min_bucket=64)
+
+        def conn(rows):
+            """The connections of ``rows``, whichever way they point."""
+            ends = set()
+            for r in rows.tolist():
+                a = (r[F.SRC_IP], r[F.PORTS] >> 16)
+                b = (r[F.DST_IP], r[F.PORTS] & 0xFFFF)
+                ends.add((min(a, b), max(a, b), r[F.META] >> 24))
+            return ends
+
+        homes = [conn(sb.records[d, :sb.n_valid[d]]) for d in range(4)]
+        for a in range(4):
+            assert sb.n_valid[a] > 0
+            for b in range(a + 1, 4):
+                assert not homes[a] & homes[b]
+        kept = sum(int(sb.records[d, :sb.n_valid[d], F.PACKETS].sum())
+                   for d in range(4))
+        assert kept == sb.events
+        assert kept + sb.lost == int(rec[:, F.PACKETS].sum())
+        dev = canonical_conn_hash(rec) % np.uint32(4)
+        fullest = int(np.bincount(dev, minlength=4).max())
+        assert (sb.lost > 0) == (fullest > capacity)
+        assert int(sb.n_valid.max()) == min(fullest, capacity)
+        assert sb.records.shape == (
+            4, _bucket_for(min(fullest, capacity), capacity, 64),
+            NUM_FIELDS)
+        assert not sb.records[0, sb.n_valid[0]:].any()  # padding is zero
+
 
 class TestShardedMatchesSingle:
     @pytest.fixture(scope="class")

@@ -5,7 +5,9 @@ same seeded events handed over as 1, 16 and 32 blocks a window give the
 per-pod counters of the benchmark's plain reference with the overload
 controller NOMINAL throughout, with Hubble's mirror on; the dispatch
 thread folds what accumulates behind a busy device; a device that
-cannot keep up still takes the controller to DEGRADED.
+cannot keep up still takes the controller to DEGRADED. The v5e-4 host's
+layout (ISSUE 33): the same events over a four-device mesh give the
+reference's counters and the one-device mesh's sketches.
 """
 
 from __future__ import annotations
@@ -81,6 +83,8 @@ class Rig:
         self.source.set_sink(self.eng.sink)
         self.source.setup_channel(self.monitor.channel)
         self.monitor.start(self.stop)
+        # The feed loop counts its window boundaries from here.
+        self.t_started, self.closes0 = self.clock(), _window_ticks_answered()
         self.thread = threading.Thread(
             target=self.eng.start, args=(self.stop,), daemon=True)
         self.thread.start()
@@ -93,10 +97,36 @@ class Rig:
         return (np.asarray(snap["pod_forward"])[:n].astype(np.int64),
                 np.asarray(snap["pod_drop"])[:n].astype(np.int64))
 
+    def close_lane_follows(self) -> None:
+        """Wait until every window boundary the clock has passed has
+        been answered (closed, or deferred and counted) and read back.
+        A test that walks the clock on by ``controller_after`` alone
+        hands the agent a window every few ticks and waits for none: on
+        a loaded machine the closes and their readbacks then pile up
+        behind the clock, and ``harvest`` (closed windows not yet read
+        back, over four) reads as the pressure of a device that cannot
+        keep up. ``Drive.tick`` waits for the same before it lets time
+        pass. (No other engine of the session closes windows meanwhile:
+        the module's rig stands on a clock nobody advances.)"""
+        eng = self.eng
+
+        def followed() -> bool:
+            due = int((self.clock() - self.t_started)
+                      / eng.cfg.window_seconds)
+            return _window_ticks_answered() - self.closes0 >= due \
+                and not eng._harvest_q.unfinished_tasks
+
+        wait_until(followed, "the close lane follows the clock")
+
     def close(self) -> None:
         self.stop.set()
         self.thread.join(60.0)
         assert not self.thread.is_alive()
+
+
+def _window_ticks_answered() -> float:
+    m = get_metrics()
+    return m.windows_closed._value.get() + m.windows_deferred._value.get()
 
 
 @pytest.fixture(scope="module")
@@ -159,6 +189,84 @@ def test_handovers_a_window_agree_with_the_plain_reference(rig, handovers):
     lost = get_metrics().lost_events.labels(
         stage="external", plugin="seededsource")._value.get()
     assert lost == 0
+
+
+def _shard_rows(n_devices: int) -> np.ndarray:
+    m = get_metrics().shard_rows
+    return np.array([m.labels(device=str(d))._value.get()
+                     for d in range(n_devices)])
+
+
+def _ring_cadence_on_a_mesh(tmp: str, n_devices: int, pool) -> dict:
+    """WINDOWS windows of ``pool`` at 16 hand-overs a window over a mesh
+    of ``n_devices``: what the agent answers, and what it dispatched."""
+    r = Rig(tmp, mesh_devices=n_devices)
+    try:
+        eng, mix, drive = r.eng, r.mix, r.drive
+        assert eng.n_devices == n_devices
+        m = get_metrics()
+        rows = int(mix.rate_events_per_s * eng.cfg.window_seconds)
+        tick, per = eng.cfg.window_seconds / 16, rows // 16
+        shard0, step0 = _shard_rows(n_devices), m.step_rows._value.get()
+        lost0 = _lost_events()
+        for a in range(0, WINDOWS * rows, per):
+            drive.hand_over(pool[a:a + per].copy(), tick)
+        drive.settle(tick)
+        drive.close_a_window()
+        fwd, drop = r.counters()
+        snap = eng.snapshot(max_age_s=0)
+        keys, counts = eng.top_flows(10)
+        return {
+            "fwd": fwd, "drop": drop[:, :reference.N_REASONS],
+            "overload": eng.overload.stats(),
+            "hll": np.asarray(snap["hll_flows"]).tolist(),
+            "heavy": dict(zip(map(tuple, keys.tolist()), counts.tolist())),
+            "shard_rows": _shard_rows(n_devices) - shard0,
+            "step_rows": m.step_rows._value.get() - step0,
+            "lost": _lost_events() - lost0,
+        }
+    finally:
+        r.close()
+
+
+def _lost_events() -> float:
+    return sum(s.value for mf in get_metrics().lost_events.collect()
+               for s in mf.samples if s.name.endswith("_total"))
+
+
+def test_a_four_device_mesh_gives_the_reference_and_one_devices_answers(
+        tmp_path):
+    """The v5e-4 host's layout at a ring's cadence: the events are
+    partitioned over four devices by connection and the answers merged
+    on the device. The per-pod counters are the plain reference's, the
+    heaviest flows and the HLL estimate those of the same events on a
+    one-device mesh, nothing is lost or sampled, and
+    ``tpu_shard_rows_counter`` says where the rows went: every device
+    served, the shares summing to ``tpu_step_rows_counter``."""
+    mix = traffic.load_mix("zipf1m-steady", rehearse=True)
+    pool = traffic.make_pool(mix, seed=4000003301)
+    four = _ring_cadence_on_a_mesh(str(tmp_path / "four"), 4, pool)
+    one = _ring_cadence_on_a_mesh(str(tmp_path / "one"), 1, pool)
+    total = WINDOWS * mix.rate_events_per_s
+    want = reference.offered(pool, total, mix.n_endpoints)
+    for got in (four, one):
+        assert np.array_equal(got["fwd"], want.fwd)
+        assert np.array_equal(got["drop"], want.drop)
+        st = got["overload"]
+        assert st["state"] == "NOMINAL" and st["transitions"] == 0, st
+        assert got["lost"] == 0
+        assert got["shard_rows"].sum() == got["step_rows"] > 0
+    assert (four["shard_rows"] > 0).all()
+    assert len(one["shard_rows"]) == 1
+    # pmax of the devices' registers is the union stream's registers.
+    assert four["hll"] == one["hll"]
+    # A connection lives on one device, so its flow's candidates do:
+    # the ten heaviest flows are the same ones. Their counts are what
+    # conntrack had reported when the snapshot was taken, which the
+    # flushes' timing moves by a few packets.
+    assert four["heavy"].keys() == one["heavy"].keys()
+    for key, n in one["heavy"].items():
+        assert four["heavy"][key] == pytest.approx(n, rel=0.15), key
 
 
 def test_the_dispatch_thread_folds_what_accumulates_behind_a_busy_device(
@@ -323,6 +431,9 @@ def test_a_device_that_cannot_keep_up_takes_the_controller_to_degraded(
         wait_until(lambda: eng._busy_count() == 0
                    and eng._events_in >= drive.offered, "the pile drains")
         for _ in range(200):
+            # A quarter of a dwell is half a window: let the close lane
+            # follow, or its backlog is the pressure that is read.
+            r.close_lane_follows()
             seen.append(controller_after(
                 eng, clock, eng.cfg.overload_dwell_s / 4))
             if seen[-1] == ov.NOMINAL:
@@ -332,6 +443,7 @@ def test_a_device_that_cannot_keep_up_takes_the_controller_to_degraded(
         assert arc == [ov.NOMINAL, ov.SAMPLING, ov.SHEDDING, ov.DEGRADED,
                        ov.SHEDDING, ov.SAMPLING, ov.NOMINAL]
         # feed.backpressure by itself: SHEDDING, and no further.
+        r.close_lane_follows()
         faults.configure("feed.backpressure:press")
         assert controller_after(
             eng, clock, 2 * eng.cfg.overload_tick_s) == ov.SHEDDING
@@ -380,9 +492,10 @@ def test_one_frozen_enqueue_is_one_sample_of_dispatch_latency(rig):
     eng, clock = rig.eng, rig.clock
     rig.drive.settle()
     eng._dispatch_lat_ewma = 0.0
-    eng._note_dispatched(clock() - 5.0, 0, 1, 0, 1)
+    none = np.zeros((eng.n_devices,), np.uint32)
+    eng._note_dispatched(clock() - 5.0, none, 1, 0, 1)
     assert eng._overload_signals()["dispatch_lat"] == pytest.approx(0.2)
     for _ in range(12):
-        eng._note_dispatched(clock() - 5.0, 0, 1, 0, 1)
+        eng._note_dispatched(clock() - 5.0, none, 1, 0, 1)
     assert eng._overload_signals()["dispatch_lat"] > 0.9
     eng._dispatch_lat_ewma = 0.0
