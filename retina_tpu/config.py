@@ -121,14 +121,20 @@ class Config:
     compilation_cache_dir: str = ""
     batch_capacity: int = 1 << 15  # events per device batch
     window_seconds: float = 1.0  # entropy/anomaly window
-    # Host-side batching latency when the dispatch pipeline is IDLE: a
-    # lightly-loaded agent flushes small batches at this cadence for
-    # low metric latency.
+    # How long a feed worker stages blocks before it combines them and
+    # hands the flush to the dispatch thread, when the dispatch
+    # pipeline is IDLE.
     flush_interval_s: float = 0.05
-    # Under load (dispatches in flight) the feed keeps accumulating past
-    # flush_interval_s — bigger quanta raise the combine ratio and
-    # amortize per-flush fixed costs — but never beyond this age. Must
-    # stay below the metrics publish interval (1s) or scrapes lag.
+    # The longest a row waits on the host for the device, in either
+    # place it can wait. Under load (dispatches in flight) a feed
+    # worker keeps accumulating past flush_interval_s — bigger quanta
+    # raise the combine ratio and amortize per-flush fixed costs — but
+    # never beyond this age. The dispatch thread holds the flushes it is
+    # handed (a step costs the device the same whatever it holds, and
+    # every dispatch costs the host) until a step's worth of rows is
+    # held, a window close or a snapshot is about to read the state, or
+    # the oldest flush has been held this long. Must stay below the
+    # metrics publish interval (1s) or scrapes lag.
     flush_max_age_s: float = 0.4
     mesh_devices: int = 0  # 0 = all local devices
     # Worker threads for the native combiner (combine.cpp
@@ -142,9 +148,9 @@ class Config:
     # (engine.py): submitted, and their last step not yet finished on
     # the device (transfers queue back-to-back on the device proxy so
     # the host->device link never idles between dispatch round-trips).
-    # While any is in flight the dispatch thread holds and folds the
-    # feed's flushes; only a full step's worth of rows takes a second
-    # or third slot. At least 1.
+    # The dispatch thread dispatches what it holds (a step's worth, rows
+    # of age flush_max_age_s, rows a reader asked for) only while a slot
+    # is free. At least 1.
     feed_pipeline_depth: int = 3
     # Host feed pool (parallel/feed.py): N feed workers each own a
     # staging buffer, combine+partition their quantum in parallel (the

@@ -186,15 +186,27 @@ class SketchEngine:
         self._inflight = threading.Semaphore(cfg.feed_pipeline_depth)
         # Count of dispatches in flight: submitted, and their last step
         # not yet finished ON THE DEVICE (the completion thread says
-        # when, _dispatch_done). The feed flushes at flush_interval_s
-        # only when this is 0 (idle -> latency priority); while
-        # dispatches are in flight it accumulates bigger quanta up to
-        # flush_max_age_s, and the dispatch thread holds and folds what
-        # accumulates (_dispatch_loop; _held_flushes is how many it
-        # holds, for feed_stats).
+        # when, _dispatch_done). The feed workers flush at
+        # flush_interval_s only when this is 0; while dispatches are in
+        # flight they accumulate bigger quanta up to flush_max_age_s.
+        # The dispatch thread holds and folds the flushes whatever the
+        # count, until a step's worth is held, the oldest has waited
+        # flush_max_age_s, or a reader asks (_dispatch_loop;
+        # _held_flushes is how many it holds and _held_since when it
+        # took the oldest, for feed_stats).
         self._busy_lock = threading.Lock()
         self._inflight_busy = 0
+        self._submitted_n = 0  # dispatches ever submitted; guarded-by: self._busy_lock
         self._held_flushes = 0
+        self._held_since: float | None = None  # the oldest's, on the clock
+        # The dispatch thread (start() sets it, the thread clears it on
+        # its way out) and the readers that wait for it to submit what
+        # it holds (_release_held_for_read): requests made, and the
+        # last one served.
+        self._reads = threading.Condition()
+        self._dispatch_thread: threading.Thread | None = None  # guarded-by: self._reads
+        self._reads_asked = 0  # guarded-by: self._reads
+        self._reads_served = 0  # guarded-by: self._reads
         # Combiner thread count (native rt_combine_mt; 0 keeps the
         # cores-based default — 1 thread on single-core hosts).
         if cfg.host_combine_threads > 0:
@@ -905,6 +917,8 @@ class SketchEngine:
             if self._flow_dict is not None:
                 jobs.append((("known", b), self._ingest_known_fn, (b,)))
                 jobs.append((("new", b), self._ingest_new_fn, (b,)))
+                # Resident is not run: see _warm_run_ingest.
+                jobs.append((("run", b), self._warm_run_ingest, (b,)))
             else:
                 jobs.append((b, self._ingest_fn, (b,)))
             if i == 0:
@@ -918,6 +932,41 @@ class SketchEngine:
                     ("snapshot flat", self._warm_snap_flat_job, ())
                 )
         return jobs
+
+    def _warm_run_ingest(self, bucket: int) -> None:  # runs-on: device-proxy
+        """(proxy thread) Run the flow-dict ingest pair of one bucket
+        once, on all-padding wires of the bucket's own shapes (no valid
+        row: the new side writes zeros to the sacrificial slot 0 of the
+        descriptor table and nothing else). A resident program that has
+        never run, and a transfer larger than any before it, are not
+        warm on the chip: since the dispatch thread folds by what is
+        held and not by what the device's idling hands it, a dispatch's
+        bucket varies from one to the next, and the first dispatch of a
+        bucket inside a measured window stalled its transfer and the
+        completions behind it for 1.8-5 s (PERF.md, PR 34). The wires
+        are the host->device traffic the first real dispatch of the
+        bucket would have been."""
+        from retina_tpu.parallel.wire import PACKED_FIELDS, dense_words
+
+        D = self.n_devices
+        meta = np.zeros((5 + D,), np.uint32)
+        new_dev, known_dev, meta_dev = jax.device_put(
+            (np.zeros((D, bucket, PACKED_FIELDS + 1), np.uint32),
+             np.zeros((D, dense_words(bucket, self._fd_id_bits)),
+                      np.uint32), meta),
+            (self._rec_sharding, self._rec_sharding, self._replicated),
+        )
+        with self._fd_lock:
+            epoch = self._fd_epoch
+        table = self._ensure_desc_table()
+        *_, table = self._ingest_new_fn(bucket)(new_dev, meta_dev, table)
+        # As _dispatch_flowdict stores it: a resync meanwhile has
+        # cleared the table this one was built against.
+        with self._fd_lock:
+            if self._fd_epoch == epoch:
+                self._desc_table = table
+        out = self._ingest_known_fn(bucket)(known_dev, meta_dev, table)
+        jax.block_until_ready(out)
 
     def start_background_warm(
         self, stop: threading.Event | None = None
@@ -1457,6 +1506,7 @@ class SketchEngine:
     def _dispatch_flowdict(
         self, sb: "ShardedBatch", now_s: int, n_raw: int,
         sync: bool, record_metrics: bool, n_flushes: int = 1,
+        cause: str = "",
     ) -> None:
         """Flow-dictionary dispatch: split the partitioned batch into
         new-descriptor rows (full 12-lane upload + table insert) and
@@ -1725,7 +1775,7 @@ class SketchEngine:
             if record_metrics:
                 m.transfer_seconds.observe(t0 - t_x0)
                 self._note_dispatched(
-                    c_x0, shard_rows, n_steps, n_raw, n_flushes
+                    c_x0, shard_rows, n_steps, n_raw, n_flushes, cause
                 )
             self._watch_steps(
                 sp_s if record_metrics else None, summary["events"], t0,
@@ -1775,7 +1825,7 @@ class SketchEngine:
     def _dispatch_sharded(
         self, sb: "ShardedBatch", now_s: int, n_raw: int,
         sync: bool = True, record_metrics: bool = True,
-        n_flushes: int = 1,
+        n_flushes: int = 1, cause: str = "",
     ) -> None:
         """Pack + device_put + step dispatch for an already-partitioned
         batch.
@@ -1811,7 +1861,8 @@ class SketchEngine:
         ) >= self.cfg.transfer_min_bucket:
             try:
                 self._dispatch_flowdict(
-                    sb, now_s, n_raw, sync, record_metrics, n_flushes
+                    sb, now_s, n_raw, sync, record_metrics, n_flushes,
+                    cause,
                 )
             except Exception:
                 # ANY failure after lookup_or_assign may leave
@@ -1903,7 +1954,7 @@ class SketchEngine:
                 # with a synthetic zero batch.
                 m.transfer_seconds.observe(t0 - t_x0)
                 self._note_dispatched(
-                    c_x0, shard_rows, len(wins), n_raw, n_flushes
+                    c_x0, shard_rows, len(wins), n_raw, n_flushes, cause
                 )
             self._watch_steps(
                 sp_s if record_metrics else None, summary["events"], t0,
@@ -1945,7 +1996,7 @@ class SketchEngine:
 
     def _note_dispatched(
         self, c_x0: float, shard_rows: np.ndarray, n_steps: int,
-        n_raw: int, n_flushes: int,
+        n_raw: int, n_flushes: int, cause: str = "",
     ) -> None:
         """(proxy thread) What both dispatchers record once a
         dispatch's transfer and steps are enqueued: the overload
@@ -1955,7 +2006,9 @@ class SketchEngine:
         the fill of the step capacity dispatched (windows x
         batch_capacity: a 0..1 ratio for coalesced multi-window
         transfers too), the folding counters (``shard_rows``: the valid
-        rows dispatched to each device) and the engine's own totals."""
+        rows dispatched to each device; ``cause``: what released the
+        rows the dispatch thread held, "" for a synchronous dispatch,
+        which nobody held) and the engine's own totals."""
         now = self._clock()
         # One sample weighs at most its budget (half a window, what
         # _overload_signals divides by): enqueues that are slow one
@@ -1978,6 +2031,8 @@ class SketchEngine:
         for d, n in enumerate(shard_rows.tolist()):
             m.shard_rows.labels(device=str(d)).inc(n)
         m.dispatch_flushes.inc(n_flushes)
+        if cause:
+            m.dispatches.labels(cause=cause).inc()
         self._steps += n_steps
         self._events_in += n_raw
 
@@ -2423,6 +2478,7 @@ class SketchEngine:
         self._inflight.acquire()
         with self._busy_lock:
             self._inflight_busy += 1
+            self._submitted_n += 1
 
     def _dispatch_done(  # runs-on: device-completion, device-proxy
         self, err: BaseException | None = None
@@ -2434,9 +2490,12 @@ class SketchEngine:
         with self._busy_lock:
             self._inflight_busy -= 1
         self._inflight.release()
-        # The count fell, then the wake: whoever holds rows for a
-        # pipeline with room (a worker's partial quantum, the dispatch
-        # thread's held flushes) re-reads it.
+        # The count fell, then the wake: whoever waits for it (a
+        # worker's partial quantum for an idle pipeline; the dispatch
+        # thread's held rows that are due, for a slot; a readback for
+        # the dispatches ahead of it, _dispatches_landed) re-reads it.
+        with self._reads:
+            self._reads.notify_all()
         pool = self._feed_pool
         if pool is not None:
             pool.wake_pending()
@@ -2444,12 +2503,14 @@ class SketchEngine:
     def wake(self) -> None:
         """Every thread of the feed path that sleeps to a deadline on
         the engine's clock re-reads it: the hook of a clock advanced by
-        hand (tests/clockdrive). The dispatch thread waits for no time
-        of the engine's."""
+        hand (tests/clockdrive): the feed loop for its ticks, the
+        workers for their flush ages, the dispatch thread for the age
+        of what it holds."""
         self.sink.data.set()
         pool = self._feed_pool
         if pool is not None:
             pool.wake_all()
+            pool.mux.wake()
 
     # -- adaptive overload control (runtime/overload.py) --------------
     def _overload_signals(self) -> dict[str, float]:
@@ -2580,9 +2641,14 @@ class SketchEngine:
         st["flow_dict"] = flow_dict_stats(self._flow_dict)
         st["overload"] = self._overload.stats()
         # The dispatch thread's folding: dispatches the device has not
-        # finished, and flushes held behind them.
-        st["dispatch"] = {"in_flight": self._busy_count(),
-                          "held_flushes": self._held_flushes}
+        # finished, the flushes it holds and how long the oldest.
+        since = self._held_since
+        st["dispatch"] = {
+            "in_flight": self._busy_count(),
+            "held_flushes": self._held_flushes,
+            "held_age_s": 0.0 if since is None
+            else round(max(0.0, self._clock() - since), 3),
+        }
         return st
 
     def _dispatch_loop(self, q) -> None:
@@ -2596,27 +2662,46 @@ class SketchEngine:
         as the shutdown sentinel.
 
         **Folding.** A fused step costs the device the same whatever
-        it holds, so steps must follow the rows offered, not the
-        hand-overs. A flush taken off the mux is dispatched at once
-        when the pipeline is idle; while a dispatch is in flight it is
-        HELD, and whatever accumulates (across workers, up to one
-        coalesced transfer) is folded into one batch
-        (``fold_batches``) the moment the pipeline has room: idle, or,
-        for a full step's worth of rows, any free slot of
-        ``feed_pipeline_depth``. No timer: a row waits for at most the
-        dispatch in flight, whose completion (``_dispatch_done``) wakes
-        this thread out of ``get``. A window close overtakes what is
-        held, as it overtakes what is staged in the workers.
+        it holds and every dispatch costs the host, so steps follow the
+        rows offered and the reads of the state, not the hand-overs
+        and not the device falling idle. A flush taken off the mux is
+        HELD, and what is held (across workers, up to one coalesced
+        transfer) is folded into one batch (``fold_batches``) and
+        dispatched when the pipeline has a slot
+        (``feed_pipeline_depth``) and one of these holds
+        (``tpu_dispatches_counter{cause}``):
 
-        ``get`` blocks until an item, that wake or its timeout (a
-        safety bound: nothing here is due by time). The thread parks
-        its watchdog heartbeat before each wait and beats only when
-        processing, so a long wait is not a stall."""
+        * ``full``: the fullest device's held rows reach
+          ``batch_capacity`` (holding longer could not save a step);
+        * ``age``: the oldest held flush has been held for
+          ``flush_max_age_s`` of the engine's clock;
+        * ``read``: something is about to read the state the rows
+          belong in. A window tick dispatches what is held before its
+          close is submitted, so rows taken before the tick land in the
+          window they were taken in (with the pipeline full the close
+          overtakes what is held, as it overtakes what is staged in the
+          workers: no close waits). A snapshot about to submit its
+          readback asks (``_release_held_for_read``) and is served once
+          the mux is empty and what was held has been submitted. A
+          readback that released rows onto an idle device waits for
+          their step to finish before it is submitted
+          (``_dispatches_landed``: the tick here, for some 75 ms, the
+          snapshot on its own thread);
+        * ``drain``: shutdown.
+
+        ``get`` blocks until an item, a wake (a completion gave a slot
+        back: ``_dispatch_done``; a reader asked; the clock was
+        advanced by hand: ``wake``) or the age bound of what is held
+        with a slot free; its timeout is a safety bound. The thread
+        parks its watchdog heartbeat before each wait and beats only
+        when processing, so a long wait is not a stall."""
         hb = self._register_hb("engine-dispatch")
         coal = self.cfg.batch_capacity * max(
             1, self.cfg.feed_coalesce_windows
         )
-        held: list[tuple] = []  # step items off the mux, not dispatched
+        # Step items off the mux, not dispatched: each with the clock's
+        # reading when it was taken.
+        held: list[tuple] = []
         try:
             while True:
                 hb.park()
@@ -2624,30 +2709,54 @@ class SketchEngine:
                     # Holding all one transfer may carry: take no more
                     # step items (the workers then wait on their
                     # handoff, which the controller reads), only ticks.
+                    # With the pipeline full nothing is due by time:
+                    # the completion wakes this wait.
                     item = q.get(
                         timeout=PARK_MAX_S,
                         steps=self._held_rows(held) < coal,
+                        due=self._held_due(held)
+                        if held and self._has_slot() else None,
                     )
                 except queue_mod.Empty:
                     item = ()
                 hb.beat()
                 if item is None:
                     while held:
-                        self._dispatch_held(held, coal)
+                        self._dispatch_held(
+                            held, coal, mnames.DISPATCH_DRAIN)
                     return
-                if item:
-                    if item[0] == "step":
-                        held.append(item)
-                        self._held_flushes = len(held)
-                    else:
-                        try:
-                            self._submit_close_window()
-                        except Exception:
-                            if self._count_error("dispatch"):
-                                self.log.exception("window dispatch failed")
-                while held and self._room_for(held):
-                    self._dispatch_held(held, coal)
+                if not item:
+                    # The mux read empty: what was handed off before a
+                    # reader asked (its wake came after) is held.
+                    asked = self._reads_asked
+                    if asked > self._reads_served \
+                            and self._dispatch_for_read(held, coal):
+                        with self._reads:
+                            self._reads_served = asked
+                            self._reads.notify_all()
+                elif item[0] == "step":
+                    held.append(item + (self._clock(),))
+                    self._note_held(held)
+                else:
+                    onto_idle = bool(held) and self._busy_count() == 0
+                    self._dispatch_for_read(held, coal)
+                    if onto_idle:
+                        # Never for a window's length: ticks behind
+                        # this one find what arrived meanwhile held.
+                        self._dispatches_landed(min(
+                            self.cfg.flush_max_age_s,
+                            self.cfg.window_seconds / 4))
+                    try:
+                        self._submit_close_window()
+                    except Exception:
+                        if self._count_error("dispatch"):
+                            self.log.exception("window dispatch failed")
+                while held and (cause := self._release_cause(held)):
+                    self._dispatch_held(held, coal, cause)
         finally:
+            with self._reads:
+                self._dispatch_thread = None
+                self._reads.notify_all()
             self._deregister_hb("engine-dispatch")
 
     @staticmethod
@@ -2659,22 +2768,96 @@ class SketchEngine:
             it[1].n_valid.astype(np.int64) for it in held
         ).max())
 
-    def _room_for(self, held: list[tuple]) -> bool:
-        """Whether the pipeline has room for what is held: an idle
-        pipeline takes anything; a busy one with a free slot takes a
-        full step's worth (folding more could not save a step); a full
-        one takes nothing."""
-        busy = self._busy_count()
-        if busy == 0:
-            return True
-        if busy >= self.cfg.feed_pipeline_depth:
-            return False
-        return self._held_rows(held) >= self.cfg.batch_capacity
+    def _held_due(self, held: list[tuple]) -> float:
+        """When the oldest held flush reaches ``flush_max_age_s``."""
+        return held[0][4] + self.cfg.flush_max_age_s
 
-    def _dispatch_held(self, held: list[tuple], coal: int) -> None:
+    def _has_slot(self) -> bool:
+        return self._busy_count() < self.cfg.feed_pipeline_depth
+
+    def _release_cause(self, held: list[tuple]) -> str:
+        """Why what is held goes to the device now, or "": a full
+        pipeline takes nothing; otherwise a step's worth of rows goes
+        at once (folding more could not save a step) and anything goes
+        once its oldest flush has waited ``flush_max_age_s``. An idle
+        pipeline alone is no reason: nobody reads what an early step
+        wrote before the next window close or snapshot, and those ask
+        (``_dispatch_for_read``)."""
+        if not self._has_slot():
+            return ""
+        if self._held_rows(held) >= self.cfg.batch_capacity:
+            return mnames.DISPATCH_FULL
+        if self._clock() >= self._held_due(held):
+            return mnames.DISPATCH_AGE
+        return ""
+
+    def _dispatch_for_read(self, held: list[tuple], coal: int) -> bool:
+        """Something is about to read the device state: dispatch what
+        is held while the pipeline has a slot. True when nothing is
+        left held."""
+        while held and self._has_slot():
+            self._dispatch_held(held, coal, mnames.DISPATCH_READ)
+        return not held
+
+    def _release_held_for_read(self) -> None:
+        """(a reader's thread, before it submits a readback) Have the
+        dispatch thread submit what it holds, and wait until it has
+        and the device has finished it (``_dispatches_landed``): the
+        proxy is FIFO, so the readback then holds every event flushed
+        before this call. Bounded by ``flush_max_age_s`` (past it the
+        rows have gone by age; 1 s at most), and a dispatch thread
+        that is not running, has died or is on its way out is not
+        waited for."""
+        pool, t = self._feed_pool, self._dispatch_thread
+        if pool is None or t is None or not t.is_alive():
+            return
+        bound = min(self.cfg.flush_max_age_s, PARK_MAX_S)
+        t_end = time.monotonic() + bound
+        with self._reads:
+            self._reads_asked += 1
+            want = self._reads_asked
+            pool.mux.wake()
+            self._reads.wait_for(
+                lambda: self._reads_served >= want
+                or self._dispatch_thread is not t or not t.is_alive(),
+                timeout=bound,
+            )
+        self._dispatches_landed(max(0.0, t_end - time.monotonic()))
+
+    def _dispatches_landed(self, bound: float | None = None) -> bool:
+        """Wait until the device has finished every dispatch submitted
+        so far, for ``bound`` seconds at most (None:
+        ``flush_max_age_s``, 1 s at most); True if it has. A readback
+        (a snapshot's, a window close's) released rows onto an idle
+        device just before: it would wait for their step on the device
+        anyway, and waits for it here, so that it is launched onto a
+        device that has just finished and not on the heels of a
+        dispatch that has just woken it. Measured, not understood: on
+        the v5e a dispatch that met a wire bucket for the first time
+        stalled the runtime (cured by ``_warm_run_ingest``), and where
+        a readback had been launched right behind such a dispatch the
+        stall was 2.5-5 s and piled window readbacks up into SAMPLING,
+        against 0.9-1.8 s with the readback kept apart (PERF.md, PR 34:
+        four cases each). Every launch pattern this leaves is one the
+        one-block-a-second cells have always had."""
+        if bound is None:
+            bound = min(self.cfg.flush_max_age_s, PARK_MAX_S)
+        def done() -> int:
+            with self._busy_lock:
+                return self._submitted_n - self._inflight_busy
+
+        with self._busy_lock:
+            target = self._submitted_n
+        with self._reads:
+            return self._reads.wait_for(
+                lambda: done() >= target, timeout=bound)
+
+    def _dispatch_held(
+        self, held: list[tuple], coal: int, cause: str
+    ) -> None:
         """Fold the longest prefix of the held flushes that fits one
         transfer and dispatch it (waits for a pipeline slot if none is
-        free: only the shutdown drain calls it without room)."""
+        free: only the shutdown drain calls it without one)."""
         sb, took = fold_batches(
             [it[1] for it in held], coal,
             min_bucket=self.cfg.transfer_min_bucket,
@@ -2685,7 +2868,7 @@ class SketchEngine:
         try:
             self._dispatch_sharded(
                 sb, max(it[2] for it in items), n_raw, sync=False,
-                n_flushes=took,
+                n_flushes=took, cause=cause,
             )
         except Exception:
             if self._count_error("dispatch"):
@@ -2694,7 +2877,11 @@ class SketchEngine:
             # past that point count their own).
             self._count_unheld(n_raw)
         finally:
-            self._held_flushes = len(held)
+            self._note_held(held)
+
+    def _note_held(self, held: list[tuple]) -> None:
+        self._held_flushes = len(held)
+        self._held_since = held[0][4] if held else None
 
     def start(self, stop: threading.Event) -> None:
         """Feed loop: drain sink → combine → partition → device; close
@@ -2763,6 +2950,8 @@ class SketchEngine:
             target=self._dispatch_loop, args=(q,),
             name="engine-dispatch", daemon=True,
         )
+        with self._reads:
+            self._dispatch_thread = worker
         worker.start()
         pool.start()
 
@@ -2965,6 +3154,9 @@ class SketchEngine:
                 with rec.span(
                     mnames.STAGE_SNAPSHOT_DISPATCH, tid, shared=True
                 ):
+                    # The rows the dispatch thread holds go first: the
+                    # readback is submitted behind them.
+                    self._release_held_for_read()
                     flat_dev, steps, events_in, unheld = run_on_device(
                         snap_dispatch, kind=mnames.KIND_SNAPSHOT
                     )

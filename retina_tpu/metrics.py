@@ -96,6 +96,7 @@ class Metrics:
         self.step_rows = c(mn.STEP_ROWS, [])
         self.shard_rows = c(mn.SHARD_ROWS, [mn.L_DEVICE])
         self.dispatch_flushes = c(mn.DISPATCH_FLUSHES, [])
+        self.dispatches = c(mn.DISPATCHES, [mn.L_CAUSE])
         self.windows_closed = c(mn.WINDOWS_CLOSED, [])
         # Window ticks deferred while the close program was still
         # queued in the background warm (stall-free close contract).

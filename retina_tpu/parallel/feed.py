@@ -179,15 +179,20 @@ class TransferMux:
     their queues are strictly draining by then).
 
     The consumer parks on ONE event (:func:`park`) for as long as its
-    caller's timeout says: every producer sets it after its append
-    (``TransferQueue.put``, ``put_ctl``), and ``wake`` sets it for a
-    condition the consumer waits on beside the items (the engine's
-    ``_dispatch_done``: the pipeline has room for what is held)."""
+    caller's timeout says, or until ``clock`` reaches the caller's
+    ``due`` (the age bound of the flushes the dispatch thread holds):
+    every producer sets it after its append (``TransferQueue.put``,
+    ``put_ctl``), and ``wake`` sets it for a condition the consumer
+    waits on beside the items (the pipeline has room again:
+    ``_dispatch_done``; a snapshot is about to read the state; the
+    clock was advanced by hand)."""
 
-    def __init__(self, queues: list[TransferQueue], data: threading.Event):
+    def __init__(self, queues: list[TransferQueue], data: threading.Event,
+                 clock: Callable[[], float] = time.monotonic):
         self._qs = queues
         self._ctl: deque = deque()
         self._data = data
+        self._clock = clock  # the engine's: what ``due`` is read on
         self._rr = 0
         self._woken = False
 
@@ -212,15 +217,17 @@ class TransferMux:
                 out.append(tq.q.popleft())
         return out
 
-    def get(self, timeout: float | None = None, steps: bool = True) -> Any:
+    def get(self, timeout: float | None = None, steps: bool = True,
+            due: float | None = None) -> Any:
         """The next item: control lane first, then the workers' queues
         round-robin. With ``steps`` false only the control lane is
         served (the consumer holds all it may and takes no more step
         items, but window ticks stay on cadence); the shutdown sentinel
         then waits, as it does behind any undrained queue. With nothing
         to return it parks until a producer or ``wake`` sets the event,
-        for ``timeout`` at most (``queue.Empty``)."""
-        deadline = None if timeout is None else time.monotonic() + timeout
+        for ``timeout`` seconds at most or until the mux's clock reads
+        ``due`` (``queue.Empty``)."""
+        t_end = None if timeout is None else time.monotonic() + timeout
         while True:
             if self._ctl and self._ctl[0] is not None:
                 return self._ctl.popleft()
@@ -240,9 +247,11 @@ class TransferMux:
             if self._woken:
                 self._woken = False
                 raise queue_mod.Empty
-            if deadline is not None and time.monotonic() >= deadline:
+            left = None if t_end is None else t_end - time.monotonic()
+            if (left is not None and left <= 0) or (
+                    due is not None and self._clock() >= due):
                 raise queue_mod.Empty
-            park(self._data, mn.WAKE_DISPATCH, deadline=deadline)
+            park(self._data, mn.WAKE_DISPATCH, self._clock, due, left)
 
 
 class FeedWorker(threading.Thread):
@@ -497,7 +506,7 @@ class FeedWorkerPool:
         self.workers = [
             FeedWorker(i, self, data) for i in range(max(1, n_workers))
         ]
-        self.mux = TransferMux([w.outq for w in self.workers], data)
+        self.mux = TransferMux([w.outq for w in self.workers], data, clock)
         self._rr = 0
         # Distributor-only counters: blocks no worker could take.
         self.staging_dropped_blocks = 0

@@ -151,6 +151,18 @@ DEVICE_BATCH_FILL = PREFIX + "tpu_batch_fill_ratio"
 STEPS = PREFIX + "tpu_steps_counter"
 STEP_ROWS = PREFIX + "tpu_step_rows_counter"
 DISPATCH_FLUSHES = PREFIX + "tpu_dispatch_flushes_counter"
+# Dispatches of the feed path by what released the rows the dispatch
+# thread held (engine._dispatch_loop): a step's worth was held
+# (``full``), the oldest held flush reached flush_max_age_s (``age``),
+# a window close or a snapshot was about to read the state (``read``),
+# shutdown (``drain``). Counted where tpu_dispatch_flushes_counter is,
+# so summed over its label it is tpu_step_seconds_count less the
+# synchronous dispatches (tests, the recovery probe), which nobody held.
+DISPATCHES = PREFIX + "tpu_dispatches_counter"
+DISPATCH_FULL = "full"
+DISPATCH_AGE = "age"
+DISPATCH_READ = "read"
+DISPATCH_DRAIN = "drain"
 # Valid rows dispatched to each device of the mesh (the host partition's
 # shares: partition.partition_events); summed over its label it is
 # tpu_step_rows_counter. The fullest device sizes every device's wire

@@ -80,22 +80,26 @@ class Drive:
 
     def tick(self, dt: float) -> None:
         """Let ``dt`` seconds pass, once the pipeline has followed: it
-        is idle, or has been busy (a dispatch in flight, flushes held,
-        or flushes handed off that the dispatch thread has not taken
-        yet) for less than KEEPS_UP_S of the clock, and at most one
-        closed window awaits its readback. Without the third, a
-        dispatch thread starved of the machine's CPU reads as idle
-        while the workers' hand-off queues fill behind it, and the
-        seconds then let pass are a hand-off wait of 1.0 to the
-        controller."""
+        is idle, or has been busy (a dispatch in flight, flushes held
+        past ``flush_max_age_s``, or flushes handed off that the
+        dispatch thread has not taken yet) for less than KEEPS_UP_S of
+        the clock, and at most one closed window awaits its readback.
+        Flushes the dispatch thread holds that are not yet due are no
+        backlog: it holds them by design, for a step's worth, their
+        age or a reader. Without the third, a dispatch thread starved
+        of the machine's CPU reads as idle while the workers' hand-off
+        queues fill behind it, and the seconds then let pass are a
+        hand-off wait of 1.0 to the controller."""
         eng = self.eng
 
         def followed() -> bool:
             pool = eng._feed_pool
             handed_off = pool is not None and any(
                 w.outq.q for w in pool.workers)
-            if eng._held_flushes == 0 and eng._busy_count() == 0 \
-                    and not handed_off:
+            since = eng._held_since
+            overdue = since is not None and \
+                self.clock() >= since + eng.cfg.flush_max_age_s
+            if not overdue and eng._busy_count() == 0 and not handed_off:
                 self._busy_since = None
             elif self._busy_since is None:
                 self._busy_since = self.clock()

@@ -4,10 +4,12 @@ At rehearsal sizes, on the CPU, by an injected clock (clockdrive): the
 same seeded events handed over as 1, 16 and 32 blocks a window give the
 per-pod counters of the benchmark's plain reference with the overload
 controller NOMINAL throughout, with Hubble's mirror on; the dispatch
-thread folds what accumulates behind a busy device; a device that
-cannot keep up still takes the controller to DEGRADED. The v5e-4 host's
-layout (ISSUE 33): the same events over a four-device mesh give the
-reference's counters and the one-device mesh's sketches.
+thread holds and folds the flushes until a step's worth is held, the
+oldest has aged out or a reader asks (ISSUE 34), never because the
+device fell idle; a device that cannot keep up still takes the
+controller to DEGRADED. The v5e-4 host's layout (ISSUE 33): the same
+events over a four-device mesh give the reference's counters and the
+one-device mesh's sketches.
 """
 
 from __future__ import annotations
@@ -16,6 +18,7 @@ import json
 import os
 import sys
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -41,8 +44,12 @@ from retina_tpu.metrics import get_metrics  # noqa: E402
 from retina_tpu.plugins.api import Plugin  # noqa: E402
 from retina_tpu.runtime import faults  # noqa: E402
 from retina_tpu.runtime import overload as ov  # noqa: E402
+from retina_tpu.utils import metric_names as mn  # noqa: E402
 
 WINDOWS = 3
+# Seconds of the engine's clock a test that holds flushes keeps between
+# itself and the next window tick (``Rig.to_before_a_window_tick``).
+ROOM = 0.95
 
 
 class _Source(Plugin):
@@ -89,6 +96,12 @@ class Rig:
             target=self.eng.start, args=(self.stop,), daemon=True)
         self.thread.start()
         assert self.eng.started.wait(30.0)
+        # The feed loop has read the clock for its first boundary once
+        # it has waited (its wait clears the event it waits on): only
+        # then may the clock move.
+        self.eng.sink.data.set()
+        wait_until(lambda: not self.eng.sink.data.is_set(),
+                   "the feed loop waits")
         self.drive = Drive(self.eng, self.clock, write=self.source.emit)
 
     def counters(self) -> tuple[np.ndarray, np.ndarray]:
@@ -118,6 +131,39 @@ class Rig:
 
         wait_until(followed, "the close lane follows the clock")
 
+    def hold(self, block) -> None:
+        """Hand a block over and let its worker's interval pass: the
+        dispatch thread holds one flush more (the pipeline idle)."""
+        eng = self.eng
+        n = eng._held_flushes
+        self.drive.stage(block)
+        self.clock.advance(eng.cfg.flush_interval_s)
+        wait_until(lambda: eng._held_flushes == n + 1,
+                   "the dispatch thread holds the flush")
+
+    def to_before_a_window_tick(self, before: float) -> None:
+        """Let the clock run to ``before`` seconds short of a window
+        boundary of the feed loop: a test that holds flushes by the
+        clock decides where its window tick falls, because the tick
+        reads, and a read releases what is held. A boundary in the way
+        is passed first, and its close and readback waited for."""
+        eng, ws, margin = self.eng, self.eng.cfg.window_seconds, 0.01
+        assert before <= ws - 2 * margin
+
+        def left() -> float:
+            return ws - (self.clock() - self.t_started) % ws
+
+        if left() < before + margin:
+            # This engine's own close: the counters are the process's,
+            # and an engine some earlier test left running closes
+            # windows too.
+            w0 = eng.last_window
+            self.drive.tick(left() + margin)
+            wait_until(lambda: eng.last_window is not w0
+                       and not eng._harvest_q.unfinished_tasks,
+                       "the window in the way closes")
+        self.drive.tick(left() - before)
+
     def close(self) -> None:
         self.stop.set()
         self.thread.join(60.0)
@@ -136,10 +182,34 @@ def rig(tmp_path_factory):
     r.close()
 
 
+@pytest.fixture(scope="module")
+def rig1(tmp_path_factory):
+    """A pipeline of one slot: one dispatch in flight fills it."""
+    r = Rig(str(tmp_path_factory.mktemp("ring1")), feed_pipeline_depth=1)
+    yield r
+    r.close()
+
+
 @pytest.fixture(autouse=True)
 def _clean_faults():
     yield
     faults.clear()
+
+
+def _dispatches() -> dict[str, float]:
+    """``tpu_dispatches_counter`` by cause, and ``all``: the count of
+    ``tpu_step_seconds`` (a dispatch is one observation of it, made
+    when the device has finished it)."""
+    m = get_metrics()
+    out = {c: m.dispatches.labels(cause=c)._value.get()
+           for c in (mn.DISPATCH_FULL, mn.DISPATCH_AGE, mn.DISPATCH_READ,
+                     mn.DISPATCH_DRAIN)}
+    out["all"] = _count(m.device_step_seconds)
+    return out
+
+
+def _since(d0: dict[str, float]) -> dict[str, float]:
+    return {k: v - d0[k] for k, v in _dispatches().items() if v != d0[k]}
 
 
 @pytest.mark.parametrize("handovers", [1, 16, 32])
@@ -158,6 +228,7 @@ def test_handovers_a_window_agree_with_the_plain_reference(rig, handovers):
     sampled0 = get_metrics().events_sampled._value.get()
     flushes0 = get_metrics().dispatch_flushes._value.get()
     steps0 = get_metrics().steps._value.get()
+    d0 = _dispatches()
     tick = eng.cfg.window_seconds / handovers
     per = rows // handovers
     for a in range(0, total, per):
@@ -178,6 +249,12 @@ def test_handovers_a_window_agree_with_the_plain_reference(rig, handovers):
     flushes = get_metrics().dispatch_flushes._value.get() - flushes0
     steps = get_metrics().steps._value.get() - steps0
     assert 0 < steps <= flushes
+    # Every dispatch had one cause: a step's worth held, its age, or a
+    # reader (a window's tick, this test's snapshots); none because
+    # the device fell idle.
+    by = _since(d0)
+    assert by.pop("all") == sum(by.values()) > 0, by
+    assert mn.DISPATCH_DRAIN not in by
     # The mirror: the monitor agent has drained its channel.
     wait_until(lambda: rig.monitor.channel.empty()
                and rig.observer.flows_seen % total == 0,
@@ -272,93 +349,351 @@ def test_a_four_device_mesh_gives_the_reference_and_one_devices_answers(
 def test_the_dispatch_thread_folds_what_accumulates_behind_a_busy_device(
         rig):
     """One dispatch hangs on the proxy; the flushes that arrive behind
-    it are held, and leave as ONE dispatch when it completes."""
+    it are held, its completion alone releases none of them (an idle
+    device is no reason to step), and they leave as ONE dispatch when a
+    reader asks."""
     eng, mix, drive, clock = rig.eng, rig.mix, rig.drive, rig.clock
     drive.settle()
+    rig.to_before_a_window_tick(ROOM)
     pool = traffic.make_pool(mix, seed=2902)
     m = get_metrics()
     fwd0, drop0 = rig.counters()
-    d0 = m.device_step_seconds._sum.get(), _count(m.device_step_seconds)
+    d0 = _dispatches()
     f0 = m.dispatch_flushes._value.get()
     faults.configure("transfer:hang@1")
     per = 512
-    # The first block flushes at once (the pipeline is idle) and hangs.
-    drive.stage(pool[:per].copy())
-    clock.advance(0.06)
+    # The first block is held, goes by its age and hangs.
+    rig.hold(pool[:per].copy())
+    clock.advance(eng.cfg.flush_max_age_s)
     wait_until(lambda: eng._busy_count() == 1, "the first dispatch hangs")
-    # Six more, each old enough to flush at the feed's max age: the
-    # workers hand them to the dispatch thread, which holds them.
+    # Six more, three to each worker: the workers keep them to the
+    # feed's max age (a dispatch is in flight), then hand each its one
+    # flush to the dispatch thread, which holds them: they have not
+    # aged there.
     for k in range(1, 7):
         drive.stage(pool[k * per:(k + 1) * per].copy())
-        clock.advance(0.05)
     clock.advance(eng.cfg.flush_max_age_s)
-    wait_until(lambda: eng._held_flushes > 0
-               and sum(w["events"] for w in
-                       eng.feed_stats()["per_worker"]) >= drive.offered
-               and all(not w.outq.q for w in eng._feed_pool.workers)
-               and eng._busy_count() == 1,
+    held = len(eng._feed_pool.workers)
+    wait_until(lambda: eng._held_flushes == held
+               and not any(w.pending_events()
+                           for w in eng._feed_pool.workers),
                "the flushes behind it are held")
-    assert _count(m.device_step_seconds) == d0[1]
+    assert eng._busy_count() == 1
+    assert _since(d0) == {}  # the one in flight has not finished
+    assert eng._dispatches_landed(0.05) is False
+    w0 = wakeups("dispatch", "data")
     faults.release_hangs()
-    drive.settle()
-    dispatches = _count(m.device_step_seconds) - d0[1]
-    flushes = m.dispatch_flushes._value.get() - f0
-    assert dispatches == 2, (dispatches, flushes)
-    assert flushes >= 3  # the first alone, the rest folded
+    # The completion wakes the dispatch thread, which finds nothing
+    # due: the device is idle and the flushes stay held.
+    wait_until(lambda: eng._busy_count() == 0
+               and wakeups("dispatch", "data") > w0,
+               "the completion wakes the dispatch thread")
+    assert eng._held_flushes == held == 2
+    assert eng._dispatches_landed(0.0) is True
+    assert _since(d0) == {mn.DISPATCH_AGE: 1, "all": 1}
+    st = eng.feed_stats()["dispatch"]
+    assert st["in_flight"] == 0 and st["held_flushes"] == held
+    assert 0 <= st["held_age_s"] < eng.cfg.flush_max_age_s
     want = reference.Counts(mix.n_endpoints).add(pool[:7 * per])
-    fwd1, drop1 = rig.counters()
+    fwd1, drop1 = rig.counters()  # a snapshot: a reader
     assert np.array_equal(fwd1 - fwd0, want.fwd)
-    # A stall of 0.7 s of the engine's time with nothing piling up
+    drive.settle()
+    assert _since(d0) == {mn.DISPATCH_AGE: 1, mn.DISPATCH_READ: 1, "all": 2}
+    assert m.dispatch_flushes._value.get() - f0 == 1 + held
+    # A stall of 0.8 s of the engine's time with nothing piling up
     # behind it is not pressure: no transition.
-    assert eng.feed_stats()["dispatch"] == {"in_flight": 0,
-                                           "held_flushes": 0}
+    assert eng.feed_stats()["dispatch"] == {
+        "in_flight": 0, "held_flushes": 0, "held_age_s": 0.0}
     assert eng.overload.stats()["transitions"] == 0
 
 
 def test_a_completion_wakes_the_holders_of_rows_not_the_clock(
-        rig, long_parks):
-    """(c), (d) With a dispatch hung on the proxy, one worker holds a
-    partial quantum past ``flush_interval_s`` and the dispatch thread
-    holds a flush: both wait for the pipeline, and neither waits by
-    the clock (their idle bound is out of reach and the clock stands
-    still). The completion (``_dispatch_done``) wakes both: the held
-    flush and the partial quantum are dispatched with no further tick,
-    far from ``flush_max_age_s``."""
+        rig1, long_parks):
+    """(c), (d) With the pipeline's one slot taken by a dispatch hung
+    on the proxy, one worker holds a partial quantum past
+    ``flush_interval_s`` (for an idle pipeline) and the dispatch thread
+    holds a flush past ``flush_max_age_s`` (for a slot): neither waits
+    by the clock (their idle bound is out of reach and the clock stands
+    still). The completion (``_dispatch_done``) wakes both: the overdue
+    flush is dispatched and the partial quantum flushed, with no
+    further tick."""
+    rig = rig1
     eng, mix, drive, clock = rig.eng, rig.mix, rig.drive, rig.clock
     drive.settle()
+    # The window's tick falls where the pipeline is full: its close
+    # overtakes, and releases nothing.
+    rig.to_before_a_window_tick(ROOM)
     pool = traffic.make_pool(mix, seed=2904)
     fwd0, _ = rig.counters()
-    m = get_metrics()
-    d0 = _count(m.device_step_seconds)
+    d0 = _dispatches()
     faults.configure("transfer:hang@1")
     per = 512
-    drive.stage(pool[:per].copy())
-    clock.advance(0.06)
+    rig.hold(pool[:per].copy())
+    clock.advance(eng.cfg.flush_max_age_s)
     wait_until(lambda: eng._busy_count() == 1, "the first dispatch hangs")
-    # A flush for the dispatch thread to hold: old enough to leave its
-    # worker by age.
+    # A flush for the dispatch thread to hold past its age: old enough
+    # to leave its worker by age, then as old again.
     drive.stage(pool[per:2 * per].copy())
     clock.advance(eng.cfg.flush_max_age_s)
     wait_until(lambda: eng._held_flushes == 1, "a flush is held")
+    clock.advance(eng.cfg.flush_max_age_s)
     # A partial quantum for a worker to hold: past the interval only.
     drive.stage(pool[2 * per:3 * per].copy())
     clock.advance(eng.cfg.flush_interval_s + 0.01)
     holders = [w for w in eng._feed_pool.workers if w.pending_events()]
     assert len(holders) == 1
-    w0, t0 = wakeups("worker"), clock()
-    wait_until(lambda: wakeups("worker") > w0, "the clock wakes them")
+    w0, p0, t0 = wakeups("worker"), wakeups("dispatch"), clock()
+    wait_until(lambda: wakeups("worker") > w0 and wakeups("dispatch") > p0,
+               "the clock wakes them")
     assert holders[0].pending_events() == per
     assert eng._held_flushes == 1 and eng._busy_count() == 1
-    assert _count(m.device_step_seconds) == d0
+    assert eng.feed_stats()["dispatch"]["held_age_s"] \
+        >= eng.cfg.flush_max_age_s
+    assert _since(d0) == {}
+    b0 = holders[0].batches
     faults.release_hangs()
-    wait_until(lambda: eng._events_in >= drive.offered
-               and eng._busy_count() == 0 and eng._held_flushes == 0,
+    # The overdue flush goes by its age; the partial quantum is flushed
+    # for the idle pipeline and goes with it, or is held for the read
+    # below, as the two holders happen to wake.
+    wait_until(lambda: _since(d0) == {mn.DISPATCH_AGE: 2, "all": 2}
+               and eng._busy_count() == 0
+               and holders[0].batches == b0 + 1
+               and not holders[0].outq.q,
                "the completion wakes the holders")
     assert clock() == t0  # no tick did it
     want = reference.Counts(mix.n_endpoints).add(pool[:3 * per])
     fwd1, _ = rig.counters()
     assert np.array_equal(fwd1 - fwd0, want.fwd)
+    assert clock() == t0
     assert eng.overload.stats()["transitions"] == 0
+
+
+def test_an_idle_pipeline_holds_a_small_flush_until_it_ages_out(
+        rig, long_parks):
+    """Nothing in flight and a flush of a few hundred rows: the
+    dispatch thread holds it. It goes when the engine's clock reaches
+    ``flush_max_age_s`` from the moment it was taken, and not an
+    instant before; the wait for that moment is a ``deadline`` wake-up
+    of the dispatch thread (its idle bound is out of reach here)."""
+    eng, mix, drive, clock = rig.eng, rig.mix, rig.drive, rig.clock
+    drive.settle()
+    rig.to_before_a_window_tick(ROOM)
+    pool = traffic.make_pool(mix, seed=3401)
+    fwd0, _ = rig.counters()
+    d0, e0 = _dispatches(), eng._events_in
+    rig.hold(pool[:512].copy())
+    assert eng._busy_count() == 0
+    since = eng._held_since
+    assert since == clock()
+    # Just short of the bound: woken (the clock was advanced) and the
+    # rows stay.
+    w0 = wakeups("dispatch")
+    clock.advance(eng.cfg.flush_max_age_s - 0.002)
+    wait_until(lambda: wakeups("dispatch") > w0, "the advance wakes it")
+    p0 = wakeups("dispatch", "deadline")
+    wait_until(lambda: wakeups("dispatch", "deadline") > p0,
+               "it sleeps to the age bound, two milliseconds away")
+    assert eng._held_flushes == 1 and eng._events_in == e0
+    assert _since(d0) == {}
+    clock.advance(0.004)  # past the bound, whatever the sums round to
+    wait_until(lambda: eng._events_in == e0 + 512 and eng._busy_count() == 0,
+               "the flush goes at its age")
+    assert clock() >= since + eng.cfg.flush_max_age_s
+    assert _since(d0) == {mn.DISPATCH_AGE: 1, "all": 1}
+    want = reference.Counts(mix.n_endpoints).add(pool[:512])
+    assert np.array_equal(rig.counters()[0] - fwd0, want.fwd)
+    assert _since(d0) == {mn.DISPATCH_AGE: 1, "all": 1}  # nothing held
+
+
+def test_a_steps_worth_of_held_rows_goes_at_once(rig):
+    """Flushes are held while the fullest device's rows are short of
+    ``batch_capacity``; the flush that makes a step's worth releases
+    them all as one dispatch, the clock far from their age."""
+    from retina_tpu.parallel.combine import combine_blocks
+    from retina_tpu.parallel.partition import partition_events
+
+    eng, mix, drive, clock = rig.eng, rig.mix, rig.drive, rig.clock
+    drive.settle()
+    rig.to_before_a_window_tick(ROOM)
+    pool = traffic.make_pool(mix, seed=3402)
+    fwd0, _ = rig.counters()
+    d0, e0, t0 = _dispatches(), eng._events_in, clock()
+    # A block of 8,192 events combines to about 670 rows a device:
+    # which one makes a step's worth on the fullest.
+    per, n, rows = 8192, 0, np.zeros((eng.n_devices,), np.int64)
+    while rows.max() < eng.cfg.batch_capacity:
+        rows += partition_events(
+            combine_blocks([pool[n * per:(n + 1) * per].copy()]),
+            eng.n_devices, eng.cfg.batch_capacity).n_valid
+        n += 1
+    assert 2 <= n <= 6
+    for k in range(n - 1):
+        rig.hold(pool[k * per:(k + 1) * per].copy())
+    assert eng._held_flushes == n - 1 and _since(d0) == {}
+    drive.stage(pool[(n - 1) * per:n * per].copy())
+    clock.advance(eng.cfg.flush_interval_s)
+    wait_until(lambda: eng._events_in == e0 + n * per
+               and eng._busy_count() == 0, "they go together")
+    assert clock() - t0 < eng.cfg.flush_max_age_s
+    assert eng._held_flushes == 0
+    assert _since(d0) == {mn.DISPATCH_FULL: 1, "all": 1}
+    want = reference.Counts(mix.n_endpoints).add(pool[:n * per])
+    assert np.array_equal(rig.counters()[0] - fwd0, want.fwd)
+
+
+def _closed_window_events(eng) -> int:
+    return eng.last_window["overload"]["events"]
+
+
+def test_a_window_tick_dispatches_what_is_held_before_its_close(rig):
+    """A flush held when the window's tick comes off the mux goes to
+    the device before the close is submitted: its rows land in the
+    window they were taken in (the close's annotation counts them)."""
+    eng, mix, drive, clock = rig.eng, rig.mix, rig.drive, rig.clock
+    drive.settle()
+    pool = traffic.make_pool(mix, seed=3403)
+    rig.to_before_a_window_tick(0.2)
+    d0, e0 = _dispatches(), eng._events_in
+    open0 = e0 - eng._closed_events_in  # earlier tests'
+    rig.hold(pool[:512].copy())
+    assert _since(d0) == {}
+    clock.advance(0.2 - eng.cfg.flush_interval_s + 0.01)  # the boundary
+    wait_until(lambda: eng._closed_events_in == e0 + 512
+               and not eng._harvest_q.unfinished_tasks
+               and eng._busy_count() == 0, "the window closes")
+    assert _since(d0) == {mn.DISPATCH_READ: 1, "all": 1}
+    assert _closed_window_events(eng) == open0 + 512
+    assert eng._held_flushes == 0
+
+
+def test_a_close_overtakes_what_is_held_when_the_pipeline_is_full(rig1):
+    """The pipeline's one slot is taken by a dispatch hung on the
+    proxy and a flush is held for it: the window's tick does not wait,
+    its close is submitted ahead of the held rows, which land in the
+    next window."""
+    rig = rig1
+    eng, mix, drive, clock = rig.eng, rig.mix, rig.drive, rig.clock
+    drive.settle()
+    pool = traffic.make_pool(mix, seed=3404)
+    age, gap = eng.cfg.flush_max_age_s, 0.1
+    before = eng.cfg.flush_interval_s + 2 * age + gap
+    assert before < eng.cfg.window_seconds
+    rig.to_before_a_window_tick(before)
+    d0, e0 = _dispatches(), eng._events_in
+    open0 = e0 - eng._closed_events_in  # earlier tests'
+    faults.configure("transfer:hang@1")
+    rig.hold(pool[:512].copy())
+    clock.advance(age)
+    wait_until(lambda: eng._busy_count() == 1, "the first dispatch hangs")
+    drive.stage(pool[512:1280].copy())
+    clock.advance(age)
+    wait_until(lambda: eng._held_flushes == 1, "a flush is held")
+    # The boundary: the tick comes off the mux with no slot free.
+    clock.advance(gap + 0.01)
+    wait_until(lambda: eng._close_inflight._value < 2,
+               "the close is submitted")
+    assert eng._held_flushes == 1 and eng._events_in == e0
+    faults.release_hangs()
+    # (This engine's own closes: the process's counters also count
+    # those of an engine some earlier test left running.)
+    wait_until(lambda: eng._closed_events_in == e0 + 512
+               and not eng._harvest_q.unfinished_tasks
+               and eng._busy_count() == 0, "the window closes")
+    assert _closed_window_events(eng) == open0 + 512
+    # The slot is free again and the held flush not yet of age.
+    assert eng._held_flushes == 1 and eng._events_in == e0 + 512
+    assert _since(d0) == {mn.DISPATCH_AGE: 1, "all": 1}
+    # Half a window on it goes by its age; the other half, and the
+    # next tick closes the window it landed in.
+    clock.advance(eng.cfg.window_seconds / 2)
+    wait_until(lambda: eng._events_in == e0 + 1280
+               and eng._busy_count() == 0, "the held flush goes by age")
+    clock.advance(eng.cfg.window_seconds / 2)
+    wait_until(lambda: eng._closed_events_in == e0 + 1280
+               and not eng._harvest_q.unfinished_tasks,
+               "the next window closes")
+    assert _closed_window_events(eng) == 768
+    by = _since(d0)
+    assert by.pop("all") == sum(by.values()) == 2
+
+
+def test_a_snapshot_holds_every_event_flushed_before_it(rig):
+    """``engine.snapshot()`` about to submit a readback has the
+    dispatch thread submit what it holds first: the snapshot holds
+    every event accepted and flushed before the call, and nothing the
+    sink accepted lags it; its readback is submitted once the device
+    has finished what was released, not on its heels. A snapshot served
+    from the cache asks for nothing and releases nothing."""
+    eng, mix, drive, clock = rig.eng, rig.mix, rig.drive, rig.clock
+    drive.settle()
+    rig.to_before_a_window_tick(ROOM)
+    pool = traffic.make_pool(mix, seed=3405)
+    # Once unasserted, so that no program of this shape compiles below.
+    rig.hold(pool[2048:2560].copy())
+    rig.hold(pool[2560:3072].copy())
+    fwd0, _ = rig.counters()
+    wait_until(lambda: eng._busy_count() == 0, "the device finishes")
+    d0, e0 = _dispatches(), eng._events_in
+    rig.hold(pool[:512].copy())
+    rig.hold(pool[512:1024].copy())
+    asked = eng._reads_asked
+    snap = eng.snapshot(max_age_s=0)
+    assert snap["events_in"] == e0 + 1024 == eng._events_in
+    assert eng.publish_lag_s(snap) == (0.0, e0 + 1024)
+    assert eng._reads_asked == asked + 1 == eng._reads_served
+    assert eng._held_flushes == 0
+    # The readback was submitted behind a dispatch that had landed.
+    assert eng._busy_count() == 0
+    want = reference.Counts(mix.n_endpoints).add(pool[:1024])
+    n = mix.n_endpoints
+    assert np.array_equal(
+        np.asarray(snap["pod_forward"])[:n].astype(np.int64) - fwd0,
+        want.fwd)
+    wait_until(lambda: eng._busy_count() == 0, "the device finishes")
+    assert _since(d0) == {mn.DISPATCH_READ: 1, "all": 1}
+    # From the cache: the same snapshot, and the flush stays held.
+    rig.hold(pool[1024:1536].copy())
+    assert eng.snapshot(max_age_s=3600.0) is snap
+    assert eng._reads_asked == asked + 1 and eng._held_flushes == 1
+    assert eng.publish_lag_s(snap)[0] > 0.0
+    assert _since(d0) == {mn.DISPATCH_READ: 1, "all": 1}
+    drive.settle()
+
+
+def test_shutdown_drains_what_is_held_and_no_snapshot_waits_for_the_dead(
+        tmp_path):
+    """The stop finds a flush held: it is dispatched (``drain``) before
+    the dispatch thread ends, and the engine's totals hold it. With the
+    thread gone, by the sentinel or by a fault that killed it, a
+    snapshot asks nobody and returns. Over the rig's life every
+    dispatch had exactly one cause."""
+    r = Rig(str(tmp_path))
+    eng = r.eng
+    d0 = _dispatches()
+    try:
+        pool = traffic.make_pool(r.mix, seed=3406)
+        fwd0, _ = r.counters()
+        r.hold(pool[:512].copy())
+        clock_t = r.clock()
+    finally:
+        r.close()
+    assert r.clock() == clock_t  # no age: the drain did it
+    assert eng._events_in == 512 and eng._held_flushes == 0
+    wait_until(lambda: _since(d0) == {mn.DISPATCH_DRAIN: 1, "all": 1},
+               "the completion thread has seen the drained dispatch")
+    assert eng._dispatch_thread is None
+    t0 = time.monotonic()
+    want = reference.Counts(r.mix.n_endpoints).add(pool[:512])
+    assert np.array_equal(r.counters()[0] - fwd0, want.fwd)
+    # A thread that died without its farewell (an error escaped it).
+    dead = threading.Thread(target=lambda: None)
+    dead.start()
+    dead.join()
+    eng._dispatch_thread = dead
+    asked = eng._reads_asked
+    assert eng.snapshot(max_age_s=0)["events_in"] == 512
+    assert eng._reads_asked == asked
+    assert time.monotonic() - t0 < 30.0  # two readbacks, no wait
 
 
 def test_fold_of_two_side_windows_is_their_valid_rows_in_order():
@@ -401,8 +736,10 @@ def test_a_device_that_cannot_keep_up_takes_the_controller_to_degraded(
         eng, clock, drive = r.eng, r.clock, r.drive
         pool = traffic.make_pool(r.mix, seed=2903)
         faults.configure("transfer:hang@1")
-        drive.stage(pool[:512].copy())
-        clock.advance(0.06)
+        # Held by the dispatch thread (its age bound is out of the way
+        # too) until the window's tick reads: dispatched, it hangs.
+        r.hold(pool[:512].copy())
+        clock.advance(eng.cfg.window_seconds)
         wait_until(lambda: eng._busy_count() == 1, "the dispatch hangs")
         seen = [eng.overload.state]
         fills = {}
@@ -425,11 +762,18 @@ def test_a_device_that_cannot_keep_up_takes_the_controller_to_degraded(
         assert m.overload_signal.labels(
             signal="staging")._value.get() >= 0.98
         assert eng.feed_stats()["dispatch"]["in_flight"] == 1
-        # The device returns: the pile drains, one level down a dwell.
+        # The device returns: the pile drains (the workers flush for
+        # the idle pipeline, and a reader, as the publisher is once a
+        # second, releases what the dispatch thread then holds), one
+        # level down a dwell.
         faults.release_hangs()
         faults.clear()
-        wait_until(lambda: eng._busy_count() == 0
-                   and eng._events_in >= drive.offered, "the pile drains")
+        def drained() -> bool:
+            r.counters()
+            return eng._busy_count() == 0 \
+                and eng._events_in >= drive.offered
+
+        wait_until(drained, "the pile drains")
         for _ in range(200):
             # A quarter of a dwell is half a window: let the close lane
             # follow, or its backlog is the pressure that is read.
