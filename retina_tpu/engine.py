@@ -1526,6 +1526,7 @@ class SketchEngine:
         lost = sb.lost
         D = self.n_devices
         with self._fd_lock:
+            gen0 = self._flow_dict.generation
             per_dev = []
             for d in range(D):
                 nv = int(sb.n_valid[d])
@@ -1540,6 +1541,12 @@ class SketchEngine:
             # on the hot path).
             fd_entries = len(self._flow_dict)
             fd_generation = self._flow_dict.generation
+        # Capacity clears this batch's assignments caused: a resync
+        # bumps the generation too, but under _fd_lock, so never
+        # between the two readings.
+        fd_cleared = fd_generation - gen0
+        # Rows the full table had no slot for (id 0 is never assigned).
+        n_tableless = sum(int(np.count_nonzero(x[1] == 0)) for x in per_dev)
         base = batch_ts_base(sb.records)
         pk_cap = np.uint32(1) << np.uint32(DENSE_PK_BITS)
         by_cap = np.uint32(1) << np.uint32(DENSE_BY_BITS)
@@ -1610,6 +1617,10 @@ class SketchEngine:
                     dense_known_rows(rk, idk, id_bits, known_wire[d])
             nv_new[d] = nn
             nv_known[d] = nk
+        sp_build.set(
+            new_rows=sum(n_new), known_rows=sum(n_known),
+            cleared=fd_cleared,
+        )
         if record_metrics and lost:
             m.lost_events.labels(
                 stage="partition", plugin="engine"
@@ -1695,12 +1706,18 @@ class SketchEngine:
                     (new_wire.nbytes if have_new else 0)
                     + (known_wire.nbytes if have_known else 0)
                 )
-                m.wire_rows.labels(kind="new").inc(int(nv_new.sum()))
-                m.wire_rows.labels(kind="known").inc(
+                m.wire_rows.labels(kind=mnames.WIRE_NEW).inc(
+                    int(nv_new.sum()) - n_tableless
+                )
+                m.wire_rows.labels(kind=mnames.WIRE_TABLELESS).inc(
+                    n_tableless
+                )
+                m.wire_rows.labels(kind=mnames.WIRE_KNOWN).inc(
                     int(nv_known.sum())
                 )
                 m.flow_dict_entries.set(fd_entries)
                 m.flow_dict_generation.set(fd_generation)
+                m.flow_dict_clears.inc(fd_cleared)
             sp_x = self._step_span(
                 mnames.STAGE_TRANSFER_ENQUEUE, tid, record_metrics
             )

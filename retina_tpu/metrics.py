@@ -82,6 +82,7 @@ class Metrics:
         self.filter_push_failures = c(mn.FILTER_PUSH_FAILURES, [])
         self.flow_dict_entries = g(mn.FLOW_DICT_ENTRIES, [])
         self.flow_dict_generation = g(mn.FLOW_DICT_GENERATION, [])
+        self.flow_dict_clears = c(mn.FLOW_DICT_CLEARS, [])
         self.wire_rows = c(mn.WIRE_ROWS, [mn.L_KIND])
         self.parsed_packets = c(mn.PARSED_PACKETS, [mn.L_PLUGIN])
         self.device_step_seconds = ex.new_histogram(

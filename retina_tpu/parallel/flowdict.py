@@ -19,10 +19,12 @@ counters per read interval. Here the "map" spans the host/device link.
 Capacity contract: ids are slots in the device table. When the table
 fills, the dictionary CLEARS and bumps its generation — every flow is
 "new" again and re-uploads its descriptor (a one-quantum burst, not an
-error). The engine never references an id the current generation did not
-assign, so the device table needs no generation tag: slots are always
-rewritten by a new-row upload before a known-row references them (proxy
-FIFO order).
+error; the engine counts it in ``tpu_flow_dict_clears_counter``, and
+the rows a full table had no slot for as
+``tpu_wire_rows_counter{kind="tableless"}``). The engine never
+references an id the current generation did not assign, so the device
+table needs no generation tag: slots are always rewritten by a new-row
+upload before a known-row references them (proxy FIFO order).
 """
 
 from __future__ import annotations

@@ -71,12 +71,22 @@ LOST_TABLE_ENTRIES = PREFIX + "lost_table_entries_counter"
 # the next successful push — invisible without this counter.
 FILTER_PUSH_FAILURES = PREFIX + "filter_push_failures_counter"
 # v2-wire flow dictionary self-observability: resident descriptors,
-# generation (bumps = capacity cycles or failure resyncs), and wire
-# rows by kind — known/new ratio IS the wire savings factor.
+# generation (bumps = capacity cycles or failure resyncs), capacity
+# clears alone (a dispatch would have overflowed the table: every flow
+# re-uploads its descriptor; a failure resync is not one), and wire
+# rows by kind — known/new ratio IS the wire savings factor. ``new``
+# rows cross as full 52-byte rows and enter the device table;
+# ``tableless`` rows cross the same way but found the table full (more
+# new descriptors in one dispatch than it has slots) and enter nothing;
+# ``known`` rows cross dense against the table.
 FLOW_DICT_ENTRIES = PREFIX + "tpu_flow_dict_entries"
 FLOW_DICT_GENERATION = PREFIX + "tpu_flow_dict_generation"
+FLOW_DICT_CLEARS = PREFIX + "tpu_flow_dict_clears_counter"
 WIRE_ROWS = PREFIX + "tpu_wire_rows_counter"
 L_KIND = "kind"
+WIRE_NEW = "new"
+WIRE_KNOWN = "known"
+WIRE_TABLELESS = "tableless"
 PARSED_PACKETS = PREFIX + "parsed_packets_counter"
 # Sharded feed-worker backpressure (parallel/feed.py): per-worker
 # quantum fill at flush, seconds spent waiting for a free handoff slot

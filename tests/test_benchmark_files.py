@@ -30,10 +30,13 @@ NEW_CELLS = ("advanced-pod.zipf1m-steady",
 MESH_CELL = "advanced-pod-v5e4.zipf1m-steady-x4"  # ISSUE 33, four chips
 MESH_READERS = ("partition_ms_per_s", "shard_skew_pct",
                 "collective_ms_per_s")
+CHURN_CELL = "advanced-pod-churn.churn8m-steady"  # ISSUE 35
+CHURN_READERS = ("dict_new_rows_pct", "dict_clears_per_s",
+                 "ingest_device_ms_per_s")
 NEW_READERS = ("steps_per_s", "step_fill_pct", "overload_pressure_p95",
                "hubble_mirror_ms_per_s", "feed_wakeups_per_s",
                "publish_cpu_ms_per_s", "publish_changed_pct",
-               *MESH_READERS)
+               *MESH_READERS, *CHURN_READERS)
 
 
 def _config(name: str) -> dict:
@@ -136,6 +139,63 @@ def test_the_v5e4_configuration_is_the_configmap_on_four_chips():
     assert entry["reduced"] == [] and len(entry["source"]) <= 200
 
 
+def test_the_churn_configuration_is_the_configmap_with_nothing_sized():
+    """``advanced-pod.json`` key for key but what says where it runs
+    and what was set by hand: ``sizing`` is empty, so the dictionary is
+    the agent's default, the documented 262,144 that conntrack has too;
+    the guarantees are carried over whole. Its traffic is the ``steady``
+    mix field for field but the two numbers that make the regime."""
+    from retina_tpu.config import Config
+
+    churn, base = _config("advanced-pod-churn"), _config("advanced-pod")
+    for group in ("agent", "machine", "step_shapes", "step_program",
+                  "guarantees", "rehearse", "rehearse_held", "reduced"):
+        assert churn[group] == base[group], group
+    assert {k for k in {*churn, *base} if churn.get(k) != base.get(k)
+            and not k.endswith("_note")} == {
+        "name", "source", "deployment", "sizing", "assumed"} | (
+        {"held"} if churn["held"] != base["held"] else set())
+    assert churn["sizing"] == {} and churn["reduced"] == []
+    assert Config().flow_dict_slots == 262_144 \
+        == churn["agent"]["conntrack_slots"]
+    assert churn["agent"]["enable_conntrack_metrics"] is True
+    assert churn["held"].keys() == base["held"].keys()
+    kept = [a for a in base["assumed"] if "flow_dict_slots" not in a]
+    assert churn["assumed"][:len(kept)] == kept
+    assert not any("flow_dict_slots" in a for a in churn["assumed"])
+    said = " ".join(churn["assumed"][len(kept):])
+    assert "n_flows 8388608" in said and "zipf_a 0.8" in said \
+        and "rate" in said
+    entry = {c["name"]: c for c in DOC["configs"]}["advanced-pod-churn"]
+    assert entry["reduced"] == [] and len(entry["source"]) <= 200
+    cell = {w["name"]: w for w in DOC["workloads"]}[CHURN_CELL]
+    assert cell["chips"] == 1 and len(cell["why"]) <= 200
+    assert (cell["config"], cell["traffic"]) == (
+        "advanced-pod-churn", "churn8m-steady")
+
+    mix = traffic.load_mix("churn8m-steady")
+    steady = traffic.load_mix("zipf1m-steady")
+    assert (mix.n_flows, mix.zipf_a) == (8_388_608, 0.8)
+    assert mix.block_rows == mix.rate_events_per_s // 16 <= 65_536
+    for f in dataclasses.fields(traffic.Mix):
+        if f.name not in ("name", "n_flows", "zipf_a"):
+            assert getattr(mix, f.name) == getattr(steady, f.name), f.name
+    # The rehearsal's dictionary of 16,384 turns over too.
+    small = traffic.load_mix("churn8m-steady", rehearse=True)
+    assert small == dataclasses.replace(
+        traffic.load_mix("zipf1m-steady", True), name="churn8m-steady",
+        n_flows=65_536, zipf_a=0.8)
+    assert small.n_flows >= 4 * churn["rehearse"]["flow_dict_slots"]
+    by_name = {m["name"]: m for m in DOC["per_layer"]}
+    assert "workloads" not in by_name["dict_new_rows_pct"]
+    assert by_name["dict_clears_per_s"]["workloads"] == [CHURN_CELL]
+    assert by_name["ingest_device_ms_per_s"]["workloads"] == [
+        CHURN_CELL, "advanced-pod.zipf1m-steady"]
+    assert [m["name"] for m in DOC["per_layer"][-3:]] == list(CHURN_READERS)
+    for name in CHURN_READERS:
+        assert by_name[name]["layer"] == "flow dict + wire"
+
+
 # -- the new readers on a recorded load -------------------------------------
 def _scrape(sent, **c):
     return {"sent": sent, "done": sent + 0.01, "ok": True, "events": 0,
@@ -160,19 +220,27 @@ def _shards(*rows):
     return {series % d: float(n) for d, n in enumerate(rows)}
 
 
-def _traced(ops_by_chip, window_s=5.0):
+def _traced(ops_by_chip, window_s=5.0, modules=()):
     import trace_reduce
 
     return trace_reduce.Trace(
-        [trace_reduce.Chip(i, [], ops)
+        [trace_reduce.Chip(i, list(modules), ops)
          for i, ops in enumerate(ops_by_chip)], window_s)
 
 
 STEP_OPS = [("%fusion.46 u32[524288]", 0, 60_000_000),
             ("%copy.3 u32[4,16]", 60_000_000, 1_000_000)]
+# The programs each chip ran: two steps, and three ingest programs (a
+# new side, a known side, a plain wire) of 4 + 1 + 2.5 ms.
+PROGRAMS = [("jit_local_step", 0, 60_000_000),
+            ("jit_ingest", 70_000_000, 4_000_000),
+            ("jit_ingest", 75_000_000, 1_000_000),
+            ("jit_fold_side_windows", 77_000_000, 300_000),
+            ("jit_local_step", 80_000_000, 60_000_000),
+            ("jit_ingest", 150_000_000, 2_500_000)]
 # Two chips of a mesh: a plain all-reduce, the two halves of an
 # asynchronous all-gather, and a fusion that is no collective.
-MESH_TRACE = _traced([
+MESH_TRACE = _traced(modules=PROGRAMS, ops_by_chip=[
     STEP_OPS + [("%all-reduce.7 u32[2]", 61_000_000, 2_000_000),
                 ("%all-gather-start.1 u32[4,2048,5]", 63_000_000, 500_000),
                 ("%all-gather-done.1 u32[4,2048,5]", 64_000_000, 1_500_000)],
@@ -195,6 +263,7 @@ RECORDED = _load(
              tpu_publish_cpu_seconds_counter=0.6,
              tpu_publish_rows_counter=105_000.0,
              tpu_publish_rows_changed_counter=90_000.0,
+             tpu_flow_dict_clears_counter=7.0,
              **_shards(1000, 1000, 1000, 1000)),
      _scrape(35.0, tpu_steps_counter=360.0, tpu_overload_pressure=0.30,
              tpu_feed_wakeups_counter=3500.0,
@@ -206,6 +275,7 @@ RECORDED = _load(
              tpu_publish_cpu_seconds_counter=4.03,
              tpu_publish_rows_counter=1_785_000.0,
              tpu_publish_rows_changed_counter=1_098_000.0,
+             tpu_flow_dict_clears_counter=42.0,
              **_shards(3000, 4000, 7000, 6000)),
      _scrape(61.0, tpu_steps_counter=999.0, tpu_overload_pressure=0.95,
              tpu_feed_wakeups_counter=9999.0,
@@ -222,7 +292,8 @@ PARENT = _load(
              tpu_feed_wakeups_counter=0.0,
              tpu_publish_cpu_seconds_counter=0.0,
              tpu_publish_rows_counter=0.0,
-             tpu_publish_rows_changed_counter=0.0, **_shards(0, 0, 0, 0))
+             tpu_publish_rows_changed_counter=0.0,
+             tpu_flow_dict_clears_counter=0.0, **_shards(0, 0, 0, 0))
      for t in (9.0, 10.0, 35.0, 59.0)],
     trace=_traced([STEP_OPS]))  # one chip: no collective to read
 SPANS = [{"stage": "hubble_consume", "t0": 12.0 + i, "t1": 12.004 + i,
@@ -238,7 +309,14 @@ WANT = {"steps_per_s": (600.0 - 110.0) / 49.0, "step_fill_pct": 25.0,
         # Rows of the window 2,000 / 3,000 / 6,000 / 5,000: mean 4,000.
         "shard_skew_pct": 50.0,
         # (2 + 0.5 + 1.5) ms and (4 + 0.5 + 1.5) ms over 5 s, the mean.
-        "collective_ms_per_s": 1.0}
+        "collective_ms_per_s": 1.0,
+        # 600 new and 150 table-less rows of 1,000 under the dictionary.
+        "dict_new_rows_pct": 75.0,
+        "dict_clears_per_s": (42.0 - 7.0) / 49.0,
+        # 7.5 ms of ingest programs on each chip over 5 s.
+        "ingest_device_ms_per_s": 1.5}
+# ``tpu_wire_rows_counter`` by kind, as the recorded process counted.
+WIRE_ROWS = {"new": 600, "tableless": 150, "known": 250}
 
 
 @pytest.mark.parametrize("name", NEW_READERS)
@@ -247,6 +325,10 @@ def test_a_new_reader_returns_a_number_on_a_recorded_load(
     monkeypatch.setattr(
         host_spans, "window_spans",
         lambda run, stage: [s for s in SPANS if s["stage"] == stage])
+    from retina_tpu.metrics import get_metrics
+
+    for kind, rows in WIRE_ROWS.items():
+        get_metrics().wire_rows.labels(kind=kind).inc(rows)
     reader = harness.load_reader(name)
     assert reader.read(RECORDED) == pytest.approx(WANT[name])
     entry = {m["name"]: m for m in DOC["per_layer"]}[name]
@@ -357,3 +439,48 @@ def test_the_skew_metric_reads_each_devices_series_off_the_programs_counter():
     after = poller.series_sum(get_exporter().gather_text(), names)
     assert [after[n] - before[n] for n in names] == [
         5.0, 7.0, 11.0, 13.0, 0.0, 0.0, 0.0, 0.0]
+
+
+def test_the_dictionary_metrics_read_the_programs_counters():
+    """``dict_clears_per_s`` asks the poller for the counter the program
+    registers. ``dict_new_rows_pct`` cannot ask it for the kinds of
+    ``tpu_wire_rows_counter``: ``combine_ratio`` asks for that counter's
+    sum in every cell, and the poller's prefix match then gives each
+    kind's line to the sum; so it parses the process's own exposition
+    for the sample names the program's counter renders to, table-less
+    rows counted with the new ones."""
+    import poller
+    from retina_tpu.exporter import get_exporter
+    from retina_tpu.metrics import get_metrics
+    from retina_tpu.utils import metric_names as mn
+
+    clears = harness.load_reader("dict_clears_per_s")
+    assert mn.FLOW_DICT_CLEARS == "networkobservability_" + clears.CLEARS
+    assert clears.COUNTERS == (clears.CLEARS,)
+    new = harness.load_reader("dict_new_rows_pct")
+    assert not hasattr(new, "COUNTERS")
+    assert set(new.KINDS) == {mn.WIRE_NEW, mn.WIRE_KNOWN, mn.WIRE_TABLELESS}
+    assert set(new.FULL_ROWS) == {mn.WIRE_NEW, mn.WIRE_TABLELESS}
+    assert new.SERIES.startswith(
+        mn.WIRE_ROWS.removeprefix("networkobservability_") + "_total{"
+        + mn.L_KIND + "=")
+    m = get_metrics()
+    for kind, rows in ((mn.WIRE_NEW, 30), (mn.WIRE_KNOWN, 50),
+                       (mn.WIRE_TABLELESS, 20)):
+        m.wire_rows.labels(kind=kind).inc(rows)
+    m.flow_dict_clears.inc(3)
+    assert new.rows_by_kind() == {"new": 30.0, "tableless": 20.0,
+                                  "known": 50.0}
+    assert new.read(None) == 50.0
+    body = get_exporter().gather_text()
+    total = poller.PREFIX + harness.load_reader(
+        "combine_ratio").COUNTERS[0].encode()
+    one = poller.PREFIX + (new.SERIES % "new").encode()
+    both = poller.series_sum(body, tuple(sorted((total, one))))
+    assert both == {total: 100.0, one: 0.0}  # why the poller is not asked
+    name = mn.FLOW_DICT_CLEARS.encode()
+    assert poller.series_sum(body, (name,))[name] == 3.0
+    # A parent's counter has two kinds and no clears: the share stands.
+    by_name = {m["name"]: m for m in DOC["per_layer"]}
+    assert by_name["dict_new_rows_pct"]["source"] == "program_counter"
+    assert by_name["ingest_device_ms_per_s"]["source"] == "device_trace"
