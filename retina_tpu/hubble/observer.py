@@ -10,7 +10,6 @@ cursors that observe loss rather than block the writer).
 from __future__ import annotations
 
 import threading
-import time
 from collections import deque
 from typing import Any, Iterator, Optional
 
@@ -63,13 +62,11 @@ class FlowObserver:
         n = len(records)
         if n == 0:
             return
-        sp = get_recorder().span(mn.STAGE_HUBBLE_CONSUME)
-        c0 = time.thread_time()
-        with self._lock:
-            self._write(n, records[-self._cap:])
         # The span's seconds include any wait for the interpreter lock
-        # inside it; ``cpu_s`` is what the write itself cost.
-        sp.end(rows=n, cpu_s=time.thread_time() - c0)
+        # inside it; its ``cpu_s`` is what the write itself cost.
+        with get_recorder().span(mn.STAGE_HUBBLE_CONSUME, rows=n):
+            with self._lock:
+                self._write(n, records[-self._cap:])
 
     def consume_flows(self, flows: list[dict]) -> None:
         """Write already-decoded flow dicts (relay peer ingestion)."""

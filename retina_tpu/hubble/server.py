@@ -78,7 +78,9 @@ class HubbleServer:
         self._stop = threading.Event()
         self._init_self_metrics()
         self._server = grpc.server(
-            futures.ThreadPoolExecutor(max_workers=max_workers)
+            futures.ThreadPoolExecutor(
+                max_workers=max_workers, thread_name_prefix="hubble-grpc"
+            )
         )
         self._server.add_generic_rpc_handlers(
             [self._make_handlers(), self._make_pb_handlers()]

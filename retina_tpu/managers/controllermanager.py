@@ -21,6 +21,7 @@ from retina_tpu.managers.filtermanager import FilterManager
 from retina_tpu.managers.pluginmanager import PluginManager
 from retina_tpu.managers.watchermanager import WatcherManager
 from retina_tpu.metrics import initialize_metrics
+from retina_tpu.obs.cpuaccount import CpuAccount
 from retina_tpu.pubsub import PubSub
 from retina_tpu.runtime import faults
 from retina_tpu.runtime.supervisor import Supervisor, policy_from_config
@@ -43,6 +44,9 @@ class ControllerManager:
             deadline_s=cfg.watchdog_deadline_s,
             interval_s=cfg.watchdog_interval_s,
         )
+        # Whose the host's CPU is, by the role of the thread: on
+        # whenever the agent is (obs/cpuaccount.py).
+        self.cpu_account = CpuAccount()
         self.engine = SketchEngine(cfg, supervisor=self.supervisor)
         self.cache = Cache(self.pubsub, max_pods=cfg.n_pods)
         self.filtermanager = FilterManager(self.engine.update_filter_ips)
@@ -78,6 +82,7 @@ class ControllerManager:
         if self._ident_timer is not None:
             self._ident_timer.cancel()
         self._ident_timer = threading.Timer(0.05, self._rebuild_identity)
+        self._ident_timer.name = "identity-rebuild"
         self._ident_timer.daemon = True
         self._ident_timer.start()
 
@@ -117,6 +122,7 @@ class ControllerManager:
             "plugin_supervision", self.pluginmanager.supervision_stats
         )
         self.server.expose_var("faults", faults.stats)
+        self.server.expose_var("cpu", self.cpu_account.stats)
         self.server.expose_var(
             "heartbeat", lambda: self.telemetry.last_heartbeat
         )
@@ -170,6 +176,10 @@ class ControllerManager:
         self.server.start()
         self.supervisor.start()
         self.telemetry.start_heartbeat()
+        self.supervisor.spawn(
+            "cpu-account", lambda: self.cpu_account.run(stop), stop,
+            policy_from_config(self.cfg, seed_key="cpu-account"),
+        )
         self.watchermanager.start(stop)
         self._engine_thread = threading.Thread(
             target=self.engine.start, args=(stop,), name="engine", daemon=True

@@ -279,6 +279,13 @@ class Metrics:
             buckets=[1e-5, 3e-5, 1e-4, 3e-4, 1e-3, 3e-3, 1e-2, 3e-2,
                      0.1, 0.3, 1.0, 3.0, 10.0, 30.0],
         )
+        # Host-CPU account (obs/cpuaccount.py; obs/recorder.py): the
+        # process's CPU seconds, the same by the role of the thread
+        # that burnt them (the FIXED registry mn.THREAD_ROLES), and by
+        # stage inside a thread (mn.CPU_STAGES).
+        self.process_cpu_seconds = c(mn.TPU_PROCESS_CPU_SECONDS, [])
+        self.thread_cpu_seconds = c(mn.TPU_THREAD_CPU_SECONDS, [mn.L_ROLE])
+        self.stage_cpu_seconds = c(mn.TPU_STAGE_CPU_SECONDS, [mn.L_STAGE])
         # Device proxy (utils/device_proxy.py): wait in its FIFO and
         # run time per call, by kind (the FIXED registry
         # mn.PROXY_KINDS); queue depth; calls. publish_lag is observed

@@ -27,6 +27,8 @@ import numpy as np
 
 from retina_tpu.events.schema import NUM_FIELDS
 from retina_tpu.log import logger
+from retina_tpu.obs.cpuaccount import book_own_thread
+from retina_tpu.utils import metric_names as mn
 
 _log = logger("native")
 _dir = os.path.dirname(os.path.abspath(__file__))
@@ -417,8 +419,17 @@ def combine_native_blocks_striped(
             hint, s, n_stripes,
         )
 
+    def run_and_book(s: int) -> None:
+        # A stripe's thread lives for one combine, shorter than the CPU
+        # account's sample period: it books its own CPU as it ends.
+        try:
+            run(s)
+        finally:
+            book_own_thread(mn.ROLE_FEED)
+
     workers = [
-        threading.Thread(target=run, args=(s,), daemon=True)
+        threading.Thread(target=run_and_book, args=(s,),
+                         name=f"combine-stripe-{s}", daemon=True)
         for s in range(1, n_stripes)
     ]
     try:

@@ -7,7 +7,12 @@ ends. A span does two things:
 - it writes one slot of a preallocated ring (``time.perf_counter``
   pair, the **window epoch as the trace ID**, its own span id and the
   id of the span that caused it), which `/debug/trace`, the stage
-  report and ``tpu_stage_seconds{stage}`` read;
+  report and ``tpu_stage_seconds{stage}`` read; the stages a thread
+  runs from start to finish on itself (`metric_names.CPU_STAGES`) also
+  read ``time.thread_time()`` at both ends: the span's ``cpu_s``
+  argument and ``tpu_stage_cpu_seconds_counter{stage}``, what the
+  stage cost the host where its seconds include the waits (for the
+  interpreter lock, for the device);
 - it opens a ``jax.profiler.TraceAnnotation("retina:<stage>", ...)``
   with the same ids, so that whenever a profiler session is running
   (``POST /debug/profile``, a benchmark's traced run) the span lands in
@@ -51,6 +56,7 @@ from retina_tpu.utils import metric_names as mn
 DEFAULT_CAPACITY = 4096
 
 _PREFIX = "retina:"
+_CPU_STAGES = mn.CPU_STAGES
 _annotation_cls: Any = None
 
 
@@ -111,10 +117,11 @@ class Span:
     """One open span. ``id`` is what a child names as its ``parent``."""
 
     __slots__ = ("_rec", "stage", "t0", "trace_id", "id", "parent",
-                 "_shared", "_ann", "_prev", "_open", "_args", "_late")
+                 "_shared", "_ann", "_prev", "_open", "_args", "_late",
+                 "_cpu0")
 
     def __init__(self, rec, stage, trace_id, span_id, parent, shared,
-                 ann, args) -> None:
+                 ann, args, cpu0) -> None:
         self._rec = rec
         self.stage = stage
         self.trace_id = trace_id
@@ -126,6 +133,7 @@ class Span:
         self._open = True
         self._args = args  # known at the start: on the annotation already
         self._late = None  # learnt since: set() and end()
+        self._cpu0 = cpu0  # this thread's CPU clock; None: not a CPU stage
         self.t0 = time.perf_counter()
 
     def __enter__(self) -> "Span":
@@ -152,6 +160,9 @@ class Span:
         if not self._open:
             return 0.0
         self._open = False
+        cpu_s = None
+        if self._cpu0 is not None:
+            args["cpu_s"] = cpu_s = time.thread_time() - self._cpu0
         if self._late:
             args = {**self._late, **args}
         ann = self._ann
@@ -163,7 +174,7 @@ class Span:
             args = {**self._args, **args}
         self._rec._commit(
             self.stage, self.t0, t1, self.trace_id, self.id, self.parent,
-            args or None, self._shared,
+            args or None, self._shared, cpu_s,
         )
         return t1 - self.t0
 
@@ -209,7 +220,8 @@ class FlightRecorder:
         self._shared_lock = threading.Lock()
         self._rings: list[_Ring] = [self._shared]
         self._rings_lock = threading.Lock()  # ring creation only
-        self._hist: dict[str, Any] = {}  # stage -> histogram child
+        # stage -> (histogram child, CPU-seconds child or None)
+        self._hist: dict[str, Any] = {}
         self._hist_lock = threading.Lock()
         self._metrics_broken = False
 
@@ -252,13 +264,16 @@ class FlightRecorder:
             ann = cls(_PREFIX + stage, trace_id=trace_id, span=span_id,
                       parent=parent, **args)
             ann.__enter__()
+        cpu0 = time.thread_time() if stage in _CPU_STAGES else None
         return Span(self, stage, trace_id, span_id, parent, shared, ann,
-                    args or None)
+                    args or None, cpu0)
 
     def _commit(self, stage, t0, t1, trace_id, span_id, parent, args,
-                shared=False) -> None:
+                shared=False, cpu_s=None) -> None:
         """Write one finished span. The one writer of ring slots:
-        :meth:`Span.end` for live spans, tests for hand-made ones."""
+        :meth:`Span.end` for live spans, tests for hand-made ones.
+        ``cpu_s`` (a CPU stage's, also in ``args``) goes to
+        ``tpu_stage_cpu_seconds_counter``."""
         if shared:
             with self._shared_lock:
                 self._shared.write(
@@ -268,27 +283,32 @@ class FlightRecorder:
             self._ring().write(
                 stage, t0, t1, trace_id, span_id, parent, args
             )
-        self._observe(stage, t1 - t0)
+        self._observe(stage, t1 - t0, cpu_s)
 
-    def _observe(self, stage: str, dt: float) -> None:
-        child = self._hist.get(stage)
-        if child is None:
+    def _observe(self, stage: str, dt: float, cpu_s: float | None) -> None:
+        children = self._hist.get(stage)
+        if children is None:
             if self._metrics_broken:
                 return
             try:
                 from retina_tpu.metrics import get_metrics
 
                 with self._hist_lock:
-                    child = self._hist.get(stage)
-                    if child is None:
-                        child = get_metrics().stage_seconds.labels(
-                            stage=stage
+                    children = self._hist.get(stage)
+                    if children is None:
+                        m = get_metrics()
+                        children = (
+                            m.stage_seconds.labels(stage=stage),
+                            m.stage_cpu_seconds.labels(stage=stage)
+                            if stage in _CPU_STAGES else None,
                         )
-                        self._hist[stage] = child
+                        self._hist[stage] = children
             except Exception:  # noqa: RT101 — recorder must never take down a stage; drop exposition, keep spans
                 self._metrics_broken = True
                 return
-        child.observe(dt)
+        children[0].observe(dt)
+        if cpu_s is not None:
+            children[1].inc(cpu_s)
 
     # -- drain / report (diagnostic paths; racy-read tolerant) ---------
     def spans(
@@ -382,9 +402,10 @@ class FlightRecorder:
 
 
 # -- process singleton -------------------------------------------------
-# Always-on by default: a span costs two perf_counter calls, seven list
-# writes and an inactive annotation, and spans are per-flush/per-window
-# cadence, not per-event.
+# Always-on by default: a span costs two perf_counter calls (a CPU
+# stage's, two thread_time calls more), seven list writes and an
+# inactive annotation, and spans are per-flush/per-window cadence, not
+# per-event.
 _singleton = FlightRecorder()
 _singleton_lock = threading.Lock()
 

@@ -212,6 +212,7 @@ class Operator:
                            n + 1, self.max_defers, reason)
             t = threading.Timer(
                 5.0, lambda: self._on_capture("applied", cap))
+            t.name = f"capture-retry-{cap.name}"
             t.daemon = True
             t.start()
             return True

@@ -75,7 +75,8 @@ class ExternalEventsPlugin(Plugin):
             except OSError:
                 break
             t = threading.Thread(
-                target=self._serve_conn, args=(conn, stop), daemon=True
+                target=self._serve_conn, args=(conn, stop),
+                name="plugin-externalevents-conn", daemon=True,
             )
             t.start()
             workers.append(t)

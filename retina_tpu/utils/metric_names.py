@@ -376,6 +376,120 @@ PART_SERIES = "series"
 PART_RENDER = "render"
 PUBLISH_PARTS = (PART_SNAPSHOT, PART_SERIES, PART_RENDER)
 
+# The host-CPU account of the agent's process (obs/cpuaccount.py), two
+# readings of one quantity taken at the same instant every two seconds.
+# process_cpu_seconds is getrusage(RUSAGE_SELF) user + system.
+# thread_cpu_seconds{role} is the CPU seconds of the process's threads
+# by the role of the thread: every tid of /proc/self/task is mapped to
+# a Python thread by its native id and to a role by its name
+# (THREAD_ROLE_PREFIXES); a tid that is no Python thread (XLA's,
+# libtpu's and PJRT's pools) is ``runtime``; a Python thread whose name
+# the table does not hold (an embedding program's: its main thread, a
+# benchmark's load generator) is ``foreign``. Threads that live shorter
+# than a sample period add their own time.thread_time() as they end
+# (SELF_ACCOUNTING_PREFIXES; the sampler skips them). Summed over its
+# label the first is the second, less what threads that died unseen
+# had burnt. stage_cpu_seconds{stage} is the second level, inside a
+# role: time.thread_time() between the two ends of the spans of
+# CPU_STAGES, beside tpu_stage_seconds (whose seconds include waits:
+# for the interpreter lock, for the device).
+TPU_THREAD_CPU_SECONDS = PREFIX + "tpu_thread_cpu_seconds_counter"
+TPU_PROCESS_CPU_SECONDS = PREFIX + "tpu_process_cpu_seconds_counter"
+TPU_STAGE_CPU_SECONDS = PREFIX + "tpu_stage_cpu_seconds_counter"
+L_ROLE = "role"
+
+# Thread-role registry (the ONLY legal values of the `role` label).
+# RT226 holds these constants, the THREAD_ROLES tuple, the roles the
+# prefix tables name and the role table in docs/observability.md
+# together, as it does the stages and the proxy kinds; a tier-1 test
+# (tests/test_cpuaccount.py) holds every thread name the tree spawns
+# to a role other than ``foreign``.
+ROLE_FEED = "feed"
+ROLE_DISPATCH = "dispatch"
+ROLE_PROXY = "proxy"
+ROLE_HARVEST = "harvest"
+ROLE_PUBLISH = "publish"
+ROLE_SERVE = "serve"
+ROLE_CONTROL = "control"
+ROLE_HUBBLE = "hubble"
+ROLE_ACCOUNT = "account"
+ROLE_RUNTIME = "runtime"
+ROLE_FOREIGN = "foreign"
+
+THREAD_ROLES = (
+    ROLE_FEED,
+    ROLE_DISPATCH,
+    ROLE_PROXY,
+    ROLE_HARVEST,
+    ROLE_PUBLISH,
+    ROLE_SERVE,
+    ROLE_CONTROL,
+    ROLE_HUBBLE,
+    ROLE_ACCOUNT,
+    ROLE_RUNTIME,
+    ROLE_FOREIGN,
+)
+
+# Thread-name prefix -> role, for the names the program gives the
+# threads it spawns; the longest prefix that matches wins. ``Dummy-``
+# is what `threading` calls a thread it did not start (a runtime thread
+# that once ran a Python callback).
+THREAD_ROLE_PREFIXES = (
+    ("engine", ROLE_FEED),  # the distributor loop
+    ("feed-worker-", ROLE_FEED),
+    ("combine-stripe-", ROLE_FEED),
+    ("plugin-", ROLE_FEED),
+    ("engine-dispatch", ROLE_DISPATCH),
+    ("device-proxy", ROLE_PROXY),
+    ("device-completion", ROLE_PROXY),
+    ("window-harvest", ROLE_HARVEST),
+    ("checkpointer", ROLE_HARVEST),
+    ("engine-bucket-warm", ROLE_HARVEST),
+    ("tt-ring-", ROLE_HARVEST),
+    ("fleet-ship-", ROLE_HARVEST),
+    ("fleet-agg", ROLE_HARVEST),
+    ("metricsmodule", ROLE_PUBLISH),
+    ("http-server", ROLE_SERVE),
+    ("http-handler", ROLE_SERVE),
+    ("metrics-render", ROLE_SERVE),
+    ("fleetquery", ROLE_SERVE),
+    ("watchdog", ROLE_CONTROL),
+    ("engine-recover", ROLE_CONTROL),
+    ("watchermanager", ROLE_CONTROL),
+    ("identity-rebuild", ROLE_CONTROL),
+    ("telemetry-heartbeat", ROLE_CONTROL),
+    ("pubsub", ROLE_CONTROL),
+    ("autocapture", ROLE_CONTROL),
+    ("na-control", ROLE_CONTROL),
+    ("leaderelection", ROLE_CONTROL),
+    ("filebridge", ROLE_CONTROL),
+    ("kubewatch-", ROLE_CONTROL),
+    ("kubebridge-", ROLE_CONTROL),
+    ("ciliumwatch", ROLE_CONTROL),
+    ("adopt-", ROLE_CONTROL),
+    ("capture-", ROLE_CONTROL),
+    ("monitoragent", ROLE_HUBBLE),
+    ("hubble-grpc", ROLE_HUBBLE),
+    ("relay-", ROLE_HUBBLE),
+    ("cpu-account", ROLE_ACCOUNT),
+    ("Dummy-", ROLE_RUNTIME),
+)
+# Threads that add their own CPU seconds to their role as they end,
+# because they live shorter than a sample period: the sampler skips
+# them, so nothing is counted twice.
+SELF_ACCOUNTING_PREFIXES = ("http-handler", "combine-stripe-")
+
+
+def thread_role(name: str) -> str:
+    """The role of a Python thread of this name (``foreign`` where the
+    table does not hold it)."""
+    best, role = -1, ROLE_FOREIGN
+    for prefix, r in THREAD_ROLE_PREFIXES:
+        if len(prefix) > best and name.startswith(prefix):
+            best, role = len(prefix), r
+    return role
+
+
 # Pipeline stage-name registry (the ONLY legal values of the
 # tpu_stage_seconds `stage` label and of every recorder span). The
 # RT226 analyzer machine-checks three-way agreement between these
@@ -435,6 +549,29 @@ STAGES = (
     STAGE_AGG_MERGE,
     STAGE_HUBBLE_CONSUME,
 )
+
+# Stages whose spans also read time.thread_time() at their two ends
+# (span argument ``cpu_s``, tpu_stage_cpu_seconds_counter{stage}):
+# those a thread runs from start to finish on itself. Not device_step
+# (the completion thread closes it), nor the spans that only wrap
+# others (feed_fill, pod_publish, snapshot) or a wait
+# (staging_handoff).
+CPU_STAGES = frozenset((
+    STAGE_DISTRIBUTOR_DEAL,
+    STAGE_COMBINE,
+    STAGE_PARTITION,
+    STAGE_WIRE_BUILD,
+    STAGE_TRANSFER_ENQUEUE,
+    STAGE_PROXY_RUN,
+    STAGE_WINDOW_CLOSE,
+    STAGE_HARVEST,
+    STAGE_SNAPSHOT_DISPATCH,
+    STAGE_SNAPSHOT_FETCH,
+    STAGE_SNAPSHOT_FINISH,
+    STAGE_SERIES_PUBLISH,
+    STAGE_RENDER,
+    STAGE_HUBBLE_CONSUME,
+))
 
 # Device-proxy call-kind registry (the ONLY legal values of the `kind`
 # label of tpu_proxy_* and of the `kind` argument of every proxied

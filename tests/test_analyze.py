@@ -757,6 +757,76 @@ def test_rt226_missing_stage_table(tmp_path):
     assert [f.key for f in rep.findings] == ["RT226:doc:no-table"]
 
 
+ROLE_USAGE = """
+    from retina_tpu.utils import metric_names as mn
+
+    def work(rec, book):
+        rec.span(mn.STAGE_ALPHA).end()
+        rec.span(mn.STAGE_BETA).end()
+        book(mn.ROLE_RUNTIME)
+"""
+
+ROLE_TABLE_OK = STAGE_TABLE_OK + """
+<!-- role-table-begin -->
+| Role | Threads |
+|---|---|
+| `feed` | `engine` |
+| `runtime` | the rest |
+<!-- role-table-end -->
+"""
+
+
+@pytest.mark.parametrize("decls, doc, want", [
+    # Clean: one role booked by the prefix table, one by the program.
+    ("""
+    ROLE_FEED = "feed"
+    ROLE_RUNTIME = "runtime"
+
+    THREAD_ROLES = (
+        ROLE_FEED,
+        ROLE_RUNTIME,
+    )
+    THREAD_ROLE_PREFIXES = (("engine", ROLE_FEED),)
+""", ROLE_TABLE_OK, set()),
+    # Drift in every direction: a role outside the tuple that nothing
+    # books and the table lacks; a row for a role that is none.
+    ("""
+    ROLE_FEED = "feed"
+    ROLE_RUNTIME = "runtime"
+    ROLE_ORPHAN = "orphan"
+
+    THREAD_ROLES = (
+        ROLE_FEED,
+        ROLE_RUNTIME,
+    )
+    THREAD_ROLE_PREFIXES = (("engine", ROLE_FEED),)
+""", ROLE_TABLE_OK.replace("`runtime` | the rest",
+                           "`runtime` | the rest |\n| `phantom` | none"),
+     {"RT226:role-tuple:ROLE_ORPHAN", "RT226:role-unused:ROLE_ORPHAN",
+      "RT226:role-doc-missing:orphan", "RT226:role-doc-unknown:phantom"}),
+    # Listed in the tuple alone is not booked; no table at all.
+    ("""
+    ROLE_FEED = "feed"
+    ROLE_RUNTIME = "runtime"
+    ROLE_IDLE = "idle"
+
+    THREAD_ROLES = (
+        ROLE_FEED,
+        ROLE_RUNTIME,
+        ROLE_IDLE,
+    )
+    THREAD_ROLE_PREFIXES = (("engine", ROLE_FEED),)
+""", STAGE_TABLE_OK,
+     {"RT226:role-unused:ROLE_IDLE", "RT226:role-doc:no-table"}),
+])
+def test_rt226_thread_roles(tmp_path, decls, doc, want):
+    ctxs = _rt226_repo(tmp_path, metrics_src=STAGE_DECLS + decls,
+                       usage_src=ROLE_USAGE, doc_obs=doc)
+    rep = Reporter()
+    rt226.check_program(ctxs, rep, tmp_path)
+    assert {f.key for f in rep.findings} == want
+
+
 def test_rt230_family(tmp_path):
     ctxs = _mini_repo(
         tmp_path,

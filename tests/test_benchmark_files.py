@@ -33,10 +33,19 @@ MESH_READERS = ("partition_ms_per_s", "shard_skew_pct",
 CHURN_CELL = "advanced-pod-churn.churn8m-steady"  # ISSUE 35
 CHURN_READERS = ("dict_new_rows_pct", "dict_clears_per_s",
                  "ingest_device_ms_per_s")
+# ISSUE 36: the program's CPU account, a reader a role and its check.
+CPU_ROLE_READERS = {"host_cpu_feed_ms_per_s": "feed",
+                    "host_cpu_dispatch_ms_per_s": "dispatch",
+                    "host_cpu_proxy_ms_per_s": "proxy",
+                    "host_cpu_publish_ms_per_s": "publish",
+                    "host_cpu_serve_ms_per_s": "serve",
+                    "host_cpu_runtime_ms_per_s": "runtime",
+                    "host_cpu_generator_ms_per_s": "foreign"}
+CPU_READERS = (*CPU_ROLE_READERS, "host_cpu_unnamed_pct")
 NEW_READERS = ("steps_per_s", "step_fill_pct", "overload_pressure_p95",
                "hubble_mirror_ms_per_s", "feed_wakeups_per_s",
                "publish_cpu_ms_per_s", "publish_changed_pct",
-               *MESH_READERS, *CHURN_READERS)
+               *MESH_READERS, *CHURN_READERS, *CPU_READERS)
 
 
 def _config(name: str) -> dict:
@@ -191,7 +200,9 @@ def test_the_churn_configuration_is_the_configmap_with_nothing_sized():
     assert by_name["dict_clears_per_s"]["workloads"] == [CHURN_CELL]
     assert by_name["ingest_device_ms_per_s"]["workloads"] == [
         CHURN_CELL, "advanced-pod.zipf1m-steady"]
-    assert [m["name"] for m in DOC["per_layer"][-3:]] == list(CHURN_READERS)
+    names = [m["name"] for m in DOC["per_layer"]]
+    at = names.index(CHURN_READERS[0])
+    assert names[at:at + 3] == list(CHURN_READERS)
     for name in CHURN_READERS:
         assert by_name[name]["layer"] == "flow dict + wire"
 
@@ -218,6 +229,15 @@ def _shards(*rows):
     keys it: by the full sample name it was asked for."""
     series = harness.load_reader("shard_skew_pct").SERIES
     return {series % d: float(n) for d, n in enumerate(rows)}
+
+
+def _account(process, **roles):
+    """The CPU account's counters as the poller keys them: the process's
+    by its name, each role's by the full sample name it was asked for."""
+    import cpu_account
+
+    return {cpu_account.PROCESS: process,
+            **{cpu_account.SERIES % r: s for r, s in roles.items()}}
 
 
 def _traced(ops_by_chip, window_s=5.0, modules=()):
@@ -257,31 +277,58 @@ RECORDED = _load(
              tpu_feed_wakeups_counter=900.0,
              tpu_publish_cpu_seconds_counter=0.5,
              tpu_publish_rows_counter=70_000.0,
-             tpu_publish_rows_changed_counter=70_000.0),
+             tpu_publish_rows_changed_counter=70_000.0,
+             **_account(1.0, feed=0.9)),
      _scrape(10.0, tpu_steps_counter=110.0, tpu_overload_pressure=0.10,
              tpu_feed_wakeups_counter=1000.0,
              tpu_publish_cpu_seconds_counter=0.6,
              tpu_publish_rows_counter=105_000.0,
              tpu_publish_rows_changed_counter=90_000.0,
              tpu_flow_dict_clears_counter=7.0,
-             **_shards(1000, 1000, 1000, 1000)),
+             **_shards(1000, 1000, 1000, 1000),
+             **_account(30.0, feed=3.0, foreign=20.0)),
      _scrape(35.0, tpu_steps_counter=360.0, tpu_overload_pressure=0.30,
              tpu_feed_wakeups_counter=3500.0,
              tpu_publish_cpu_seconds_counter=2.0,
              tpu_publish_rows_counter=900_000.0,
-             tpu_publish_rows_changed_counter=500_000.0),
+             tpu_publish_rows_changed_counter=500_000.0,
+             # The account's first sample to land in the window.
+             **_account(36.0, feed=4.0, dispatch=1.0, proxy=1.0, harvest=0.5,
+                        publish=2.0, serve=3.0, control=0.25, hubble=0.0,
+                        account=0.25, runtime=2.0, foreign=21.0)),
+     _scrape(47.0, tpu_steps_counter=480.0, tpu_overload_pressure=0.10,
+             tpu_feed_wakeups_counter=4700.0,
+             tpu_publish_cpu_seconds_counter=3.0,
+             tpu_publish_rows_counter=1_300_000.0,
+             tpu_publish_rows_changed_counter=800_000.0,
+             **_account(42.5, feed=5.2, dispatch=1.6, proxy=1.48, harvest=0.6,
+                        publish=2.84, serve=4.2, control=0.3, hubble=0.09,
+                        account=0.26, runtime=3.2, foreign=21.6)),
+     # Scraped again before the next sample: nothing has moved.
+     _scrape(47.1, tpu_steps_counter=481.0, tpu_overload_pressure=0.10,
+             tpu_feed_wakeups_counter=4710.0,
+             tpu_publish_cpu_seconds_counter=3.0,
+             tpu_publish_rows_counter=1_300_000.0,
+             tpu_publish_rows_changed_counter=800_000.0,
+             **_account(42.5, feed=5.2, dispatch=1.6, proxy=1.48, harvest=0.6,
+                        publish=2.84, serve=4.2, control=0.3, hubble=0.09,
+                        account=0.26, runtime=3.2, foreign=21.6)),
      _scrape(59.0, tpu_steps_counter=600.0, tpu_overload_pressure=0.20,
              tpu_feed_wakeups_counter=5900.0,
              tpu_publish_cpu_seconds_counter=4.03,
              tpu_publish_rows_counter=1_785_000.0,
              tpu_publish_rows_changed_counter=1_098_000.0,
              tpu_flow_dict_clears_counter=42.0,
-             **_shards(3000, 4000, 7000, 6000)),
+             **_shards(3000, 4000, 7000, 6000),
+             **_account(42.5, feed=5.2, dispatch=1.6, proxy=1.48, harvest=0.6,
+                        publish=2.84, serve=4.2, control=0.3, hubble=0.09,
+                        account=0.26, runtime=3.2, foreign=21.6)),
      _scrape(61.0, tpu_steps_counter=999.0, tpu_overload_pressure=0.95,
              tpu_feed_wakeups_counter=9999.0,
              tpu_publish_cpu_seconds_counter=9.0,
              tpu_publish_rows_counter=9_999_999.0,
-             tpu_publish_rows_changed_counter=9_999_999.0)],
+             tpu_publish_rows_changed_counter=9_999_999.0,
+             **_account(99.0, feed=9.0, serve=9.0))],
     before={"tpu_steps_counter": 50.0, "tpu_step_rows_counter": 1000.0},
     after={"tpu_steps_counter": 650.0,
            "tpu_step_rows_counter": 1000.0 + 600 * 131072 * 0.25},
@@ -293,7 +340,11 @@ PARENT = _load(
              tpu_publish_cpu_seconds_counter=0.0,
              tpu_publish_rows_counter=0.0,
              tpu_publish_rows_changed_counter=0.0,
-             tpu_flow_dict_clears_counter=0.0, **_shards(0, 0, 0, 0))
+             tpu_flow_dict_clears_counter=0.0, **_shards(0, 0, 0, 0),
+             **_account(0.0, **dict.fromkeys(
+                 ("feed", "dispatch", "proxy", "harvest", "publish", "serve",
+                  "control", "hubble", "account", "runtime", "foreign"),
+                 0.0)))
      for t in (9.0, 10.0, 35.0, 59.0)],
     trace=_traced([STEP_OPS]))  # one chip: no collective to read
 SPANS = [{"stage": "hubble_consume", "t0": 12.0 + i, "t1": 12.004 + i,
@@ -314,7 +365,16 @@ WANT = {"steps_per_s": (600.0 - 110.0) / 49.0, "step_fill_pct": 25.0,
         "dict_new_rows_pct": 75.0,
         "dict_clears_per_s": (42.0 - 7.0) / 49.0,
         # 7.5 ms of ingest programs on each chip over 5 s.
-        "ingest_device_ms_per_s": 1.5}
+        "ingest_device_ms_per_s": 1.5,
+        # Between the account's samples scraped at 35 s and at 47 s (the
+        # process 36 -> 42.5 s): each role's increase over 12 s.
+        "host_cpu_feed_ms_per_s": 100.0, "host_cpu_dispatch_ms_per_s": 50.0,
+        "host_cpu_proxy_ms_per_s": 40.0, "host_cpu_publish_ms_per_s": 70.0,
+        "host_cpu_serve_ms_per_s": 100.0, "host_cpu_runtime_ms_per_s": 100.0,
+        "host_cpu_generator_ms_per_s": 50.0,
+        # 6.37 of the 6.5 s are in a role (harvest, control, Hubble and
+        # the sampler took 0.1, 0.05, 0.09 and 0.01).
+        "host_cpu_unnamed_pct": 2.0}
 # ``tpu_wire_rows_counter`` by kind, as the recorded process counted.
 WIRE_ROWS = {"new": 600, "tableless": 150, "known": 250}
 
@@ -484,3 +544,61 @@ def test_the_dictionary_metrics_read_the_programs_counters():
     by_name = {m["name"]: m for m in DOC["per_layer"]}
     assert by_name["dict_new_rows_pct"]["source"] == "program_counter"
     assert by_name["ingest_device_ms_per_s"]["source"] == "device_trace"
+
+
+def test_the_cpu_account_readers_read_the_programs_counters():
+    """The eight ``host_cpu_*`` metrics (ISSUE 36) name the host's CPU
+    and list no cells (every cell has every role); each asks the poller
+    for its role's sample of ``tpu_thread_cpu_seconds_counter`` by its
+    full name, never for the counter's sum, and for the process's
+    counter; the check asks for every role of the program's registry."""
+    import cpu_account
+    import poller
+    from retina_tpu.exporter import get_exporter
+    from retina_tpu.obs.cpuaccount import CpuAccount
+    from retina_tpu.utils import metric_names as mn
+
+    layers = {"host_cpu_feed_ms_per_s": "feed + combine",
+              "host_cpu_dispatch_ms_per_s": "flow dict + wire",
+              "host_cpu_proxy_ms_per_s": "device proxy",
+              "host_cpu_publish_ms_per_s": "snapshot + publish",
+              "host_cpu_serve_ms_per_s": "snapshot + publish",
+              "host_cpu_runtime_ms_per_s": "device",
+              "host_cpu_generator_ms_per_s": "benchmark generator",
+              "host_cpu_unnamed_pct": "device"}
+    assert [m for m in DOC["per_layer"] if m["name"] in CPU_READERS] == [
+        {"name": name, "unit": "%" if name.endswith("_pct") else "ms/s",
+         "better": "lower", "source": "program_counter", "layer": layer,
+         "moves": "host_cpu_us_per_event"}
+        for name, layer in layers.items()]
+    assert [m["name"] for m in DOC["per_layer"][-8:]] == list(CPU_READERS)
+    assert cpu_account.ROLES == mn.THREAD_ROLES
+    assert mn.TPU_PROCESS_CPU_SECONDS == (
+        "networkobservability_" + cpu_account.PROCESS)
+    assert cpu_account.SERIES.startswith(
+        mn.TPU_THREAD_CPU_SECONDS.removeprefix("networkobservability_")
+        + "_total{" + mn.L_ROLE + "=")
+    for name, role in CPU_ROLE_READERS.items():
+        reader = harness.load_reader(name)
+        assert reader.COUNTERS == (
+            cpu_account.PROCESS, cpu_account.SERIES % role), name
+    check = harness.load_reader("host_cpu_unnamed_pct")
+    assert check.COUNTERS == cpu_account.counters(*mn.THREAD_ROLES)
+    asked = {c for n in CPU_READERS
+             for c in harness.load_reader(n).COUNTERS}
+    assert all("," not in c for c in asked)  # --counters a,b,c
+    assert mn.TPU_THREAD_CPU_SECONDS.removeprefix(
+        "networkobservability_") not in asked
+    # A sample of this process's account, as the poller reads it: the
+    # process, and the roles by their full names (this thread's, the
+    # test runner's main thread, is nobody the program spawned).
+    CpuAccount().sample()
+    names = tuple(poller.PREFIX + c.encode()
+                  for c in cpu_account.counters(*mn.THREAD_ROLES))
+    sums = poller.series_sum(get_exporter().gather_text(), names)
+    foreign = poller.PREFIX + (cpu_account.SERIES % mn.ROLE_FOREIGN).encode()
+    assert sums[names[0]] >= sums[foreign] > 0
+    assert sum(sums[n] for n in names[1:]) <= sums[names[0]] * 1.05
+    # A role that burnt nothing reads 0.0, not nothing: the account is
+    # there, and the result line must hold the metric in every cell.
+    assert cpu_account.role_ms_per_s(RECORDED, "nobody") == 0.0

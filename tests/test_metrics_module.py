@@ -845,7 +845,9 @@ def _publish_args(mm) -> dict:
                    if s["stage"] == mn.STAGE_SERIES_PUBLISH]
     finally:
         initialize_recorder(capacity=old.capacity, enabled=old.enabled)
-    return span["args"]
+    args = dict(span["args"])
+    assert args.pop("cpu_s") >= 0.0  # the span's own clock, not the cycle's
+    return args
 
 
 def _counter(name: str, labels=None) -> float:

@@ -1,4 +1,5 @@
-"""RT226 — recorder span-name and proxy-kind drift (whole-program).
+"""RT226 — recorder span-name, proxy-kind and thread-role drift
+(whole-program).
 
 The contract (the RT220 analog for the flight recorder): the
 ``STAGE_*`` constants in ``utils/metric_names.py`` are the single
@@ -9,8 +10,11 @@ table in ``docs/observability.md`` (between the ``stage-table-begin``/
 that does not exist. The device proxy's call kinds are held the same
 way: ``KIND_*`` constants, the ``PROXY_KINDS`` tuple, the ``kind=`` of
 every ``run_on_device``/``submit_on_device`` call, and the kind table
-(``kind-table-begin``/``kind-table-end``). Drift in any direction is a
-finding:
+(``kind-table-begin``/``kind-table-end``). So are the roles of the
+host-CPU account: ``ROLE_*`` constants, the ``THREAD_ROLES`` tuple, the
+roles the program books (a prefix table's entry, a ``ROLE_*`` named
+under ``retina_tpu/``) and the role table (``role-table-begin``/
+``role-table-end``). Drift in any direction is a finding:
 
   RT226 span opened under a stage not declared in the registry
         (string literal, unknown STAGE_* reference, or a registry
@@ -19,7 +23,9 @@ finding:
         a proxied call whose kind is a literal or an undeclared
         KIND_* reference, a KIND_* constant missing from
         PROXY_KINDS, or a registry kind no proxied call names (a
-        kind is added with its first call site); or
+        kind is added with its first call site);
+        a ROLE_* constant missing from THREAD_ROLES, or one nothing
+        books; or
         a docs/observability.md table out of sync with its registry
         (either direction).
 
@@ -43,6 +49,8 @@ TABLE_BEGIN = "<!-- stage-table-begin -->"
 TABLE_END = "<!-- stage-table-end -->"
 KIND_TABLE_BEGIN = "<!-- kind-table-begin -->"
 KIND_TABLE_END = "<!-- kind-table-end -->"
+ROLE_TABLE_BEGIN = "<!-- role-table-begin -->"
+ROLE_TABLE_END = "<!-- role-table-end -->"
 DOC_STAGE_RE = re.compile(r"`([a-z0-9_]+)`")
 PROXY_FUNCS = {"run_on_device", "submit_on_device"}
 
@@ -97,6 +105,22 @@ def check_program(ctxs: list[FileCtx], rep: Reporter, root: Path) -> None:
                     f"proxy kind constant {name} (\"{value}\") is "
                     "missing from the PROXY_KINDS tuple",
                     key=f"RT226:kind-tuple:{name}")
+
+    roles = _registry(mn_ctx, "ROLE_")
+    roles_in_tuple = _names_tuple(mn_ctx, "THREAD_ROLES")
+    roles_booked = _role_references(ctxs)
+    for name, (value, lineno) in sorted(roles.items()):
+        if name not in roles_in_tuple:
+            rep.add(mn_ctx, lineno, "RT226",
+                    f"thread role constant {name} (\"{value}\") is "
+                    "missing from the THREAD_ROLES tuple",
+                    key=f"RT226:role-tuple:{name}")
+        if name not in roles_booked:
+            rep.add(mn_ctx, lineno, "RT226",
+                    f"thread role constant {name} (\"{value}\") is "
+                    "booked by nothing: no prefix table names it and no "
+                    "code under retina_tpu/ does",
+                    key=f"RT226:role-unused:{name}")
 
     # A declared constant absent from the ordered STAGES tuple never
     # gets its histogram child pre-ordered in stage_report — drift.
@@ -177,6 +201,35 @@ def check_program(ctxs: list[FileCtx], rep: Reporter, root: Path) -> None:
         _check_table(doc_ctx, rep, "proxy kind",
                      {v for v, _ in kinds.values()}, KIND_TABLE_BEGIN,
                      KIND_TABLE_END, "kind-doc")
+    if roles:
+        _check_table(doc_ctx, rep, "thread role",
+                     {v for v, _ in roles.values()}, ROLE_TABLE_BEGIN,
+                     ROLE_TABLE_END, "role-doc")
+
+
+def _role_references(ctxs: list[FileCtx]) -> set[str]:
+    """``ROLE_*`` names read anywhere under ``retina_tpu/`` but in the
+    ``THREAD_ROLES`` tuple itself (which lists them all)."""
+    out: set[str] = set()
+    for ctx in ctxs:
+        if not ctx.rel.startswith("retina_tpu/"):
+            continue
+        for stmt in ast.walk(ctx.tree):
+            if (isinstance(stmt, ast.Assign)
+                    and isinstance(stmt.targets[0], ast.Name)
+                    and stmt.targets[0].id == "THREAD_ROLES"):
+                skip = {id(n) for n in ast.walk(stmt)}
+                break
+        else:
+            skip = set()
+        for node in ast.walk(ctx.tree):
+            if id(node) in skip:
+                continue
+            name = _const_name(node)
+            if (name and name.startswith("ROLE_")
+                    and isinstance(getattr(node, "ctx", None), ast.Load)):
+                out.add(name)
+    return out
 
 
 def _check_kind(ctx: FileCtx, node: ast.Call, kinds: dict,
