@@ -252,6 +252,7 @@ class Daemon:
                 filtermanager=self.cm.filtermanager,
                 pubsub=self.cm.pubsub,
                 dns_resolver=(dns_plugin.resolve if dns_plugin else None),
+                supervisor=self.cm.supervisor,
             )
         # Per-flow trace sampling off the record stream (module/traces):
         # idle until a TracesConfiguration reconcile names targets,

@@ -60,6 +60,7 @@ from retina_tpu.runtime.supervisor import (
 )
 from retina_tpu.utils import metric_names as mnames
 from retina_tpu.utils.device_proxy import (
+    HEARTBEATS as PROXY_HEARTBEATS,
     fence, fetch_on_device, on_ready, run_on_device, submit_on_device,
 )
 
@@ -141,6 +142,11 @@ class SketchEngine:
         # shared watchdog; standalone engines (tests, bench) get
         # detached Heartbeat cells that nothing scans.
         self._supervisor = supervisor
+        if supervisor is not None:
+            # The device proxy's two threads belong to no engine: the
+            # engine that is supervised has their cells scanned.
+            for hb in PROXY_HEARTBEATS:
+                supervisor.adopt(hb)
         self.sink = QueueSink(max_blocks=1024)
         self.pcfg = pipeline_config_from(cfg)
         if (
@@ -292,6 +298,11 @@ class SketchEngine:
         # bucket size -> jitted pad-to-capacity kernel (device-side zero
         # extension of a small transfer to the step's static shape).
         self._pad_cache: dict[int, Any] = {}
+        # The ingest programs (by their _pad_cache key) this process has
+        # run on the device at least once: a dispatch's transfer_enqueue
+        # span says `first` where it is about to run one that is not
+        # here (proxy thread only: dispatches and _warm_run_ingest).
+        self._ran_keys: set = set()
         self._snap_lock = threading.Lock()
         self._snap_flight = threading.Lock()
         self._snap_cache: dict[str, Any] | None = None
@@ -967,6 +978,7 @@ class SketchEngine:
                 self._desc_table = table
         out = self._ingest_known_fn(bucket)(known_dev, meta_dev, table)
         jax.block_until_ready(out)
+        self._ran_keys.update((("new", bucket), ("known", bucket)))
 
     def start_background_warm(
         self, stop: threading.Event | None = None
@@ -1044,6 +1056,7 @@ class SketchEngine:
                     )
                     if sl <= 0:
                         continue
+                    hb.park()  # the yield is a wait, not work
                     if stop is not None:
                         stop.wait(sl)
                     else:
@@ -1718,8 +1731,13 @@ class SketchEngine:
                 m.flow_dict_entries.set(fd_entries)
                 m.flow_dict_generation.set(fd_generation)
                 m.flow_dict_clears.inc(fd_cleared)
+            keys = [("new", Bn)] * have_new + [("known", Bk)] * have_known
             sp_x = self._step_span(
-                mnames.STAGE_TRANSFER_ENQUEUE, tid, record_metrics
+                mnames.STAGE_TRANSFER_ENQUEUE, tid, record_metrics,
+                bucket=max(k[1] for k in keys),
+                first=self._first_run(keys),
+                bucket_new=Bn if have_new else 0,
+                bucket_known=Bk if have_known else 0,
             )
             t_x0 = time.perf_counter()
             c_x0 = self._clock()
@@ -1935,7 +1953,8 @@ class SketchEngine:
                 ident = self.ident
                 fmap = self.filter_map
             sp_x = self._step_span(
-                mnames.STAGE_TRANSFER_ENQUEUE, tid, record_metrics
+                mnames.STAGE_TRANSFER_ENQUEUE, tid, record_metrics,
+                bucket=bucket, first=self._first_run([bucket]),
             )
             t_x0 = time.perf_counter()
             c_x0 = self._clock()
@@ -2003,13 +2022,23 @@ class SketchEngine:
             safe_xfer_and_step, kind=mnames.KIND_STEP, parent=sp_build.id
         )
 
-    def _step_span(self, stage: str, tid: int, record_metrics: bool):
+    def _step_span(self, stage: str, tid: int, record_metrics: bool,
+                   **args):
         """A span of the dispatch path, or the null span for a warm-up
         dispatch (``compile()``): a one-shot 30-100 s cold compile
         would sit in the stage histogram's p99 forever."""
         if not record_metrics:
             return NULL_SPAN
-        return self._recorder.span(stage, tid)
+        return self._recorder.span(stage, tid, **args)
+
+    def _first_run(self, keys: list) -> bool:  # runs-on: device-proxy
+        """Whether any of these ingest programs (``_pad_cache`` keys)
+        is about to run on the device for the first time in this
+        process; they are then noted as run."""
+        first = not self._ran_keys.issuperset(keys)
+        if first:
+            self._ran_keys.update(keys)
+        return first
 
     def _note_dispatched(
         self, c_x0: float, shard_rows: np.ndarray, n_steps: int,

@@ -37,16 +37,18 @@ class ControllerManager:
         self.cfg = cfg
         self.pubsub = PubSub()
         self.metrics = initialize_metrics()
+        # Whose the host's CPU is, by the role of the thread: on
+        # whenever the agent is (obs/cpuaccount.py). The watchdog's
+        # scan asks it who ran, once a stall of cause `held`.
+        self.cpu_account = CpuAccount()
         # Root of the supervision tree: every long-lived thread (feed,
         # dispatch, harvest, warm, plugins, checkpointer) registers a
         # heartbeat; the watchdog escalates stalls past the deadline.
         self.supervisor = Supervisor(
             deadline_s=cfg.watchdog_deadline_s,
             interval_s=cfg.watchdog_interval_s,
+            cpu_account=self.cpu_account,
         )
-        # Whose the host's CPU is, by the role of the thread: on
-        # whenever the agent is (obs/cpuaccount.py).
-        self.cpu_account = CpuAccount()
         self.engine = SketchEngine(cfg, supervisor=self.supervisor)
         self.cache = Cache(self.pubsub, max_pods=cfg.n_pods)
         self.filtermanager = FilterManager(self.engine.update_filter_ips)

@@ -125,6 +125,10 @@ class Metrics:
         # see metric_names for semantics).
         self.engine_restarts = c(mn.ENGINE_RESTARTS, [])
         self.watchdog_stalls = c(mn.WATCHDOG_STALLS, [mn.L_THREAD])
+        # Stalls of seconds, recorded by the same scan (no escalation).
+        self.watchdog_scans = c(mn.TPU_WATCHDOG_SCANS, [])
+        self.wake_late_seconds = c(mn.TPU_WAKE_LATE_SECONDS, [])
+        self.stall_seconds = c(mn.TPU_STALL_SECONDS, [mn.L_CAUSE])
         self.plugin_restarts = c(mn.PLUGIN_RESTARTS, [mn.L_PLUGIN])
         self.thread_restarts = c(mn.THREAD_RESTARTS, [mn.L_THREAD])
         self.engine_errors = c(mn.ENGINE_ERRORS, [mn.L_SITE])

@@ -37,6 +37,7 @@ from retina_tpu.log import logger
 from retina_tpu.managers.filtermanager import FilterManager
 from retina_tpu.metrics import get_metrics
 from retina_tpu.obs.recorder import get_recorder
+from retina_tpu.runtime.supervisor import NEVER, Heartbeat, Supervisor
 from retina_tpu.utils import metric_names as mn
 from retina_tpu.module.metric_objects import (
     METRIC_CONSTRUCTORS,
@@ -58,8 +59,15 @@ class MetricsModule:
         exporter: Optional[Exporter] = None,
         pubsub: Any = None,
         dns_resolver: Any = None,
+        supervisor: Optional[Supervisor] = None,
     ):
         self._log = logger("metricsmodule")
+        # The publisher's liveness cell: observed by the watchdog's
+        # scan for stalls of seconds, never escalated (a cycle that
+        # waits for a cold compile on the proxy is no fault).
+        self._hb = (supervisor.register("metricsmodule", NEVER, parked=True)
+                    if supervisor is not None
+                    else Heartbeat("metricsmodule", NEVER, parked=True))
         self.cfg = cfg
         self.engine = engine
         self.cache = cache
@@ -231,11 +239,15 @@ class MetricsModule:
         # PR 32), so that the cycle cannot take the host from the
         # feed, but never beyond 5 s (unbounded backoff turned
         # pod-gauge staleness into 12-15 s).
+        hb = self._hb
         while not stop.is_set():
             t0 = time.perf_counter()
+            hb.beat(t0)
             try:
                 self.publish_once()
             except Exception:
                 self._log.exception("publish cycle failed")
-            cost = time.perf_counter() - t0
+            t1 = time.perf_counter()
+            hb.park(t1)
+            cost = t1 - t0
             stop.wait(max(PUBLISH_INTERVAL_S, min(4 * cost, 5.0)))

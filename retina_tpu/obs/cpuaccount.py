@@ -52,6 +52,9 @@ SCHEDSTAT, STAT = "schedstat", "stat"
 # A thread that has burnt under IDLE_CPU_S in all and whose reading
 # stood still for IDLE_AFTER samples is read every IDLE_STRIDE-th sample.
 IDLE_CPU_S, IDLE_AFTER, IDLE_STRIDE = 0.05, 4, 8
+# Two samples closer than this are one to sample_top(): the second
+# adds its increases to the first's.
+FRESH_S = 0.25
 
 
 def process_cpu_s() -> float:
@@ -82,6 +85,7 @@ class _Thread:
     role: str
     cpu_s: float
     still: int = 0  # samples in a row its reading has not moved
+    burnt: float = 0.0  # its increase at the last sample
 
 
 class CpuAccount:
@@ -103,6 +107,11 @@ class CpuAccount:
         self._fds: dict[int, int] = {}  # tid -> its file, kept open
         self._process_s = 0.0
         self.samples = 0
+        self._added: dict[str, float] = {}  # by role, at the last sample
+        self._sampled_at = float("-inf")
+        # Its own thread samples once a period; the watchdog's scan
+        # asks for one more at a stall (sample_top).
+        self._lock = threading.Lock()
 
     # -- reading /proc ---------------------------------------------------
     def _tids(self) -> list[int]:
@@ -153,6 +162,37 @@ class CpuAccount:
 
     # -- one sample ------------------------------------------------------
     def sample(self) -> None:
+        with self._lock:
+            self._sample()
+
+    def sample_top(self) -> dict[str, Any]:
+        """One sample now, of every thread, and who burnt the most
+        since the sample before it: the role and the thread (a Python
+        thread by its name, the runtime's by ``comm``) with their CPU
+        seconds. What the watchdog's scan asks at a ``held`` stall: the
+        thread that held the interpreter lock, or the runtime's that
+        would not let go. Where the account's own thread sampled under
+        FRESH_S ago (it woke from the same hole, and its sample took
+        most of the hole with it) the two samples count as one. The
+        sample before is up to a period old, so the seconds are over
+        the stall and up to PERIOD_S before it."""
+        with self._lock:
+            # Every thread, the quiet ones too: the one that held the
+            # lock may have slept until it did.
+            self._sample(every=True)
+            role = max(self._added, key=self._added.get, default="")
+            top = max(self._known.values(), key=lambda t: t.burnt,
+                      default=None)
+            return {
+                "top_role": role,
+                "top_role_cpu_s": round(self._added.get(role, 0.0), 4),
+                "top_thread": top.name if top is not None else "",
+                "top_thread_cpu_s": round(top.burnt, 4)
+                if top is not None else 0.0,
+            }
+
+    def _sample(self, every: bool = False) -> None:
+        merge = time.perf_counter() - self._sampled_at < FRESH_S
         process_s = self._process_cpu_s()
         tids = self._tids()
         if self.source is None:
@@ -163,9 +203,12 @@ class CpuAccount:
         added = dict.fromkeys(mn.THREAD_ROLES, 0.0)
         for tid in tids:
             known = self._known.get(tid)
-            if (known is not None and known.still >= IDLE_AFTER
+            if (not every and known is not None
+                    and known.still >= IDLE_AFTER
                     and known.cpu_s < IDLE_CPU_S
                     and (self.samples + tid) % IDLE_STRIDE):
+                if not merge:
+                    known.burnt = 0.0
                 continue
             name = names.get(tid)
             if name is not None and name.startswith(
@@ -182,6 +225,7 @@ class CpuAccount:
             elif name is not None and name != known.name:  # renamed
                 known.name, known.role = self._who(tid, name)
             burnt = cpu_s - known.cpu_s
+            known.burnt = burnt + known.burnt if merge else burnt
             added[known.role] += burnt
             known.cpu_s = cpu_s
             known.still = 0 if burnt else known.still + 1
@@ -192,11 +236,15 @@ class CpuAccount:
             m.thread_cpu_seconds.labels(role=role).inc(s)
         m.process_cpu_seconds.inc(process_s - self._process_s)
         self._process_s = process_s
+        self._added = {r: s + self._added.get(r, 0.0)
+                       for r, s in added.items()} if merge else added
+        self._sampled_at = time.perf_counter()
         self.samples += 1
 
     def close(self) -> None:
-        for tid in list(self._fds):
-            self._forget(tid)
+        with self._lock:
+            for tid in list(self._fds):
+                self._forget(tid)
 
     def run(self, stop: threading.Event) -> None:
         """(the ``cpu-account`` thread) A sample a period until stop."""

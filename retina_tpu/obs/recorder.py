@@ -26,8 +26,14 @@ then ``sp.end(...)`` at the true end. A span used as a context manager
 is the parent of spans opened on the same thread inside it; a span
 ended elsewhere (the engine's ``device_step``, closed by the completion
 thread when the device is done) names its parent explicitly. There is
-no post-hoc form and no sampling: every site is per flush, per window
-or per publish, never per event.
+no sampling: every site is per flush, per window or per publish, never
+per event. There is one post-hoc form, for one stage: a ``stall``
+(:meth:`FlightRecorder.post_hoc`) is written by the watchdog's scan
+once both its ends are known, because what it describes (the process
+taken off the CPU, the interpreter held, one thread stuck in a call)
+could open no span of its own. It carries no ``cpu_s`` and, being
+over when it is written, no annotation; the scan drops an instant
+``retina:stall`` annotation where the hole ended.
 
 Overhead contract (`tests/test_obs.py` gates it at <3% on the host-path
 probe): long-lived threads take **no locks and allocate no ring
@@ -268,10 +274,25 @@ class FlightRecorder:
         return Span(self, stage, trace_id, span_id, parent, shared, ann,
                     args or None, cpu0)
 
+    def post_hoc(self, stage: str, t0: float, t1: float,
+                 trace_id: int = -1, parent: int = 0, **args: Any) -> int:
+        """Write a span that is already over, from ``t0`` to ``t1`` on
+        ``time.perf_counter``, into the calling thread's ring; returns
+        its id (0 from a disabled recorder). For ``stall`` alone (module
+        docstring): every other stage opens its span where its work
+        starts."""
+        if not self.enabled:
+            return 0
+        span_id = next(self._ids)
+        self._commit(stage, t0, t1, trace_id, span_id, parent,
+                     args or None)
+        return span_id
+
     def _commit(self, stage, t0, t1, trace_id, span_id, parent, args,
                 shared=False, cpu_s=None) -> None:
         """Write one finished span. The one writer of ring slots:
-        :meth:`Span.end` for live spans, tests for hand-made ones.
+        :meth:`Span.end` for live spans, :meth:`post_hoc` for a stall,
+        tests for hand-made ones.
         ``cpu_s`` (a CPU stage's, also in ``args``) goes to
         ``tpu_stage_cpu_seconds_counter``."""
         if shared:

@@ -34,6 +34,16 @@ which kind of call it ran; every kind but ``poll`` also writes a ring
 span (``proxy_run``, with ``kind``, ``wait_s`` and the enqueuing span
 as parent). A readiness poll runs a hundred times a second and would
 wrap a ring in a run: polls are counted and annotated only.
+
+Both threads own a liveness cell (``HEARTBEATS``;
+``runtime/supervisor.py``) that whoever runs a watchdog adopts: the
+proxy beats at the start of every call, with the call's kind and the
+clock reading it has taken anyway, and parks at the call's end; the
+completion thread beats before ``block_until_ready`` and parks in its
+queue. A call that keeps the proxy for a second is then a ``stall``
+span of cause ``thread`` with its kind, exact at both ends. The cells
+are observed, never escalated: a cold compile keeps the proxy for
+minutes and is no fault.
 """
 
 from __future__ import annotations
@@ -46,6 +56,7 @@ from typing import Any, Callable, TypeVar
 import numpy as np
 
 from retina_tpu.obs.recorder import NULL_SPAN, annotate, get_recorder
+from retina_tpu.runtime.supervisor import NEVER, Heartbeat
 from retina_tpu.utils import metric_names as mn
 
 T = TypeVar("T")
@@ -57,6 +68,11 @@ _thread: threading.Thread | None = None
 # in _children_of; filled on the proxy thread only.
 _children: dict[str, tuple] = {}
 _children_of: Any = None
+# The two threads' liveness cells (module docstring), parked until the
+# first call.
+_hb = Heartbeat("device-proxy", NEVER, parked=True)
+_hb_ready = Heartbeat("device-completion", NEVER, parked=True)
+HEARTBEATS = (_hb, _hb_ready)
 
 
 def _set_depth(q: queue.Queue) -> None:
@@ -134,6 +150,7 @@ def _loop(q: queue.Queue) -> None:
                 _unmark(idle)
         fn, args, kwargs, box, done, kind, t_enq, parent = item
         t_start = time.perf_counter()
+        _hb.beat(t_start, kind)
         _set_depth(q)
         span = _mark(
             (lambda: annotate("proxy_run", kind=kind))
@@ -142,6 +159,7 @@ def _loop(q: queue.Queue) -> None:
                 mn.STAGE_PROXY_RUN, parent=parent, kind=kind,
                 wait_s=t_start - t_enq))
         )
+        _hb.span = getattr(span, "id", 0)
         try:
             box.append(fn(*args, **kwargs))
         except BaseException as e:  # noqa: BLE001 — delivered to caller
@@ -149,6 +167,7 @@ def _loop(q: queue.Queue) -> None:
             box.append(True)
         finally:
             t_end = time.perf_counter()
+            _hb.park(t_end)
             done.set()
             _unmark(span)
             _observe(kind, t_start - t_enq, t_end - t_start)
@@ -266,6 +285,7 @@ _ready_thread: threading.Thread | None = None
 def _ready_loop(q: queue.SimpleQueue) -> None:
     while True:
         arr, fn = q.get()
+        _hb_ready.beat()
         err = None
         try:
             arr.block_until_ready()
@@ -275,6 +295,7 @@ def _ready_loop(q: queue.SimpleQueue) -> None:
             fn(err)
         except BaseException:  # noqa: BLE001, RT101 — contract: fn self-handles errors
             pass
+        _hb_ready.park()
 
 
 def on_ready(arr: Any, fn: Callable[[BaseException | None], Any]) -> None:

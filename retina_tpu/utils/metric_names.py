@@ -131,6 +131,22 @@ THREAD_RESTARTS = PREFIX + "thread_restarts_counter"
 ENGINE_ERRORS = PREFIX + "engine_errors_counter"
 DEGRADED_MODE = PREFIX + "tpu_degraded_mode"
 RECOVERY_SECONDS = PREFIX + "tpu_recovery_seconds"
+# Stalls of seconds, far under the watchdog's deadline
+# (runtime/supervisor.py; docs/observability.md, "Stalls"). The scan
+# that wakes every watchdog_interval_s counts itself (watchdog_scans)
+# and how late it woke (wake_late_seconds: woke less due, summed; over
+# the scans it is what a Python thread of this process pays to get the
+# CPU and the interpreter back after a wait). stall_seconds{cause} sums
+# the gaps of the `stall` spans: `paused` (a scan woke late and the
+# process had burnt hardly any CPU: nobody ran), `held` (it woke late
+# and somebody had run), `thread` (one thread mid-work and silent).
+TPU_WAKE_LATE_SECONDS = PREFIX + "tpu_wake_late_seconds_counter"
+TPU_WATCHDOG_SCANS = PREFIX + "tpu_watchdog_scans_counter"
+TPU_STALL_SECONDS = PREFIX + "tpu_stall_seconds_counter"
+STALL_PAUSED = "paused"
+STALL_HELD = "held"
+STALL_THREAD = "thread"
+STALL_CAUSES = (STALL_PAUSED, STALL_HELD, STALL_THREAD)
 # Adaptive overload control (runtime/overload.py). overload_state is
 # the controller state as a number (0=NOMINAL 1=SAMPLING 2=SHEDDING
 # 3=DEGRADED); events_sampled counts raw (packet-weighted) events
@@ -520,6 +536,7 @@ STAGE_SHIP_ENCODE = "ship_encode"
 STAGE_SHIP_SEND = "ship_send"
 STAGE_AGG_MERGE = "aggregator_merge"
 STAGE_HUBBLE_CONSUME = "hubble_consume"
+STAGE_STALL = "stall"
 
 # Ordered registry (pipeline order); drives the fixed label space of
 # tpu_stage_seconds and the bench critical-path report.
@@ -548,6 +565,7 @@ STAGES = (
     STAGE_SHIP_SEND,
     STAGE_AGG_MERGE,
     STAGE_HUBBLE_CONSUME,
+    STAGE_STALL,
 )
 
 # Stages whose spans also read time.thread_time() at their two ends
@@ -555,7 +573,8 @@ STAGES = (
 # those a thread runs from start to finish on itself. Not device_step
 # (the completion thread closes it), nor the spans that only wrap
 # others (feed_fill, pod_publish, snapshot) or a wait
-# (staging_handoff).
+# (staging_handoff), nor stall (written after the fact by the watchdog,
+# about another thread or the whole process).
 CPU_STAGES = frozenset((
     STAGE_DISTRIBUTOR_DEAL,
     STAGE_COMBINE,

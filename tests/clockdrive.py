@@ -47,6 +47,22 @@ class FakeClock:
             hook()
 
 
+class FakeCpu:
+    """The process's CPU seconds as ``os.times()`` gives them (user
+    and system first), burnt only when the test says so: what a
+    ``Supervisor(cpu_times=...)`` classifies a late scan by."""
+
+    def __init__(self) -> None:
+        self.user = self.system = 0.0
+
+    def __call__(self) -> tuple:
+        return (self.user, self.system, 0.0, 0.0, 0.0)
+
+    def burn(self, user: float = 0.0, system: float = 0.0) -> None:
+        self.user += user
+        self.system += system
+
+
 def wakeups(thread: str | None = None, cause: str | None = None) -> float:
     """``tpu_feed_wakeups_counter`` summed over the labels not given:
     returns from a wait of the feed path so far in this test."""

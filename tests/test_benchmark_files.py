@@ -571,7 +571,9 @@ def test_the_cpu_account_readers_read_the_programs_counters():
          "better": "lower", "source": "program_counter", "layer": layer,
          "moves": "host_cpu_us_per_event"}
         for name, layer in layers.items()]
-    assert [m["name"] for m in DOC["per_layer"][-8:]] == list(CPU_READERS)
+    names = [m["name"] for m in DOC["per_layer"]]
+    at = names.index(CPU_READERS[0])
+    assert names[at:at + 8] == list(CPU_READERS)
     assert cpu_account.ROLES == mn.THREAD_ROLES
     assert mn.TPU_PROCESS_CPU_SECONDS == (
         "networkobservability_" + cpu_account.PROCESS)
@@ -602,3 +604,150 @@ def test_the_cpu_account_readers_read_the_programs_counters():
     # A role that burnt nothing reads 0.0, not nothing: the account is
     # there, and the result line must hold the metric in every cell.
     assert cpu_account.role_ms_per_s(RECORDED, "nobody") == 0.0
+
+
+# -- ISSUE 37: the stall readers --------------------------------------------
+STALL_READERS = {"process_stall_longest_ms": ("program_span", "device"),
+                 "thread_stall_longest_ms": ("program_span", "device proxy"),
+                 "wake_late_mean_ms": ("program_counter", "device")}
+
+
+def _stall(t0, t1, cause, **args):
+    return {"stage": "stall", "t0": t0, "t1": t1, "thread": "watchdog",
+            "args": {"cause": cause, "gap_s": round(t1 - t0, 4), **args}}
+
+
+def _stall_run(monkeypatch, spans, has_stage=True):
+    """The window [10, 60) of a run whose recorder holds ``spans``."""
+    import stalls
+
+    monkeypatch.setattr(stalls, "_spans", lambda: list(spans))
+    monkeypatch.setattr(stalls, "has_stage", lambda: has_stage)
+    return _load([])
+
+
+def test_the_stall_metrics_are_read_in_every_cell_and_end_the_list():
+    names = [m["name"] for m in DOC["per_layer"]]
+    assert names[-3:] == list(STALL_READERS)
+    by_name = {m["name"]: m for m in DOC["per_layer"]}
+    for name, (source, layer) in STALL_READERS.items():
+        m = by_name[name]
+        assert "workloads" not in m  # every cell has a watchdog
+        assert (m["source"], m["layer"], m["unit"], m["better"],
+                m["moves"]) == (source, layer, "ms", "lower",
+                                "scrape_p95_ms")
+        assert harness.load_reader(name).UNIT == "ms"
+    from retina_tpu.utils import metric_names as mn
+
+    late = harness.load_reader("wake_late_mean_ms")
+    assert [mn.PREFIX + c for c in late.COUNTERS] == [
+        mn.TPU_WAKE_LATE_SECONDS, mn.TPU_WATCHDOG_SCANS]
+
+
+@pytest.mark.parametrize("name", ["process_stall_longest_ms",
+                                  "thread_stall_longest_ms"])
+def test_a_sound_run_reads_zero_and_a_program_without_the_stage_nothing(
+        name, monkeypatch):
+    """0.0 is a reading: the program looked and found no stall. A
+    parent's program never looked."""
+    reader = harness.load_reader(name)
+    other = [{"stage": "proxy_run", "t0": 20.0, "t1": 24.0,
+              "args": {"kind": "step"}}]
+    assert reader.read(_stall_run(monkeypatch, other)) == 0.0
+    assert reader.read(_stall_run(monkeypatch, [])) == 0.0
+    assert reader.read(_stall_run(monkeypatch, other, False)) is None
+
+
+def test_the_stall_readers_take_the_longest_of_their_own_causes(
+        monkeypatch):
+    spans = [_stall(12.0, 14.5, "paused", cpu_user_s=0.01),
+             _stall(30.0, 33.2, "held", top_thread="spinner"),
+             _stall(40.0, 41.6, "thread", thread="device-proxy",
+                    kind="step"),
+             _stall(44.0, 48.25, "thread", thread="device-completion"),
+             # Outside the window: boot's compile, and one after it.
+             _stall(2.0, 9.5, "thread", thread="device-proxy",
+                    kind="other"),
+             _stall(60.0, 69.0, "paused")]
+    run = _stall_run(monkeypatch, spans)
+    assert harness.load_reader("process_stall_longest_ms").read(run) == \
+        pytest.approx(3200.0)
+    assert harness.load_reader("thread_stall_longest_ms").read(run) == \
+        pytest.approx(4250.0)
+
+
+def test_a_stall_that_began_before_the_window_and_ended_in_it_counts(
+        monkeypatch):
+    """``host_spans.window_spans`` takes the spans that began in the
+    window; a hole that the window opens inside is the window's too,
+    and so is one it closes inside."""
+    process = harness.load_reader("process_stall_longest_ms")
+    thread = harness.load_reader("thread_stall_longest_ms")
+    run = _stall_run(monkeypatch, [_stall(7.0, 11.5, "paused"),
+                                   _stall(58.0, 63.0, "thread")])
+    assert process.read(run) == pytest.approx(4500.0)
+    assert thread.read(run) == pytest.approx(5000.0)
+    run = _stall_run(monkeypatch, [_stall(7.0, 10.0, "held"),
+                                   _stall(60.0, 63.0, "thread")])
+    assert process.read(run) == 0.0 and thread.read(run) == 0.0
+
+
+def test_the_stalls_line_is_logged_once_a_run_with_what_straddles_each(
+        monkeypatch, capsys):
+    spans = [_stall(30.0, 32.4, "paused", unparked=["device-proxy:step"]),
+             {"stage": "proxy_run", "t0": 29.9, "t1": 32.5,
+              "thread": "device-proxy",
+              "args": {"kind": "step", "wait_s": 0.0}},
+             {"stage": "render", "t0": 31.0, "t1": 31.2, "thread": "shared",
+              "args": {}}]
+    run = _stall_run(monkeypatch, spans)
+    harness.load_reader("process_stall_longest_ms").read(run)
+    harness.load_reader("thread_stall_longest_ms").read(run)
+    lines = [json.loads(ln) for ln in capsys.readouterr().err.splitlines()
+             if '"stalls"' in ln]
+    (line,) = lines
+    assert line["phase"] == "stalls" and line["count"] == 1
+    (st,) = line["stalls"]
+    assert st["cause"] == "paused" and st["at_s"] == 20.0
+    assert st["unparked"] == ["device-proxy:step"]
+    (inside,) = st["straddling"]
+    assert inside["stage"] == "proxy_run"
+    assert inside["args"]["kind"] == "step"
+    assert inside["began_before_s"] == pytest.approx(0.1)
+    assert inside["ended_after_s"] == pytest.approx(0.1)
+
+
+def test_the_wake_lateness_is_the_counters_ratio_over_the_window():
+    late = harness.load_reader("wake_late_mean_ms")
+    run = _load([
+        _scrape(9.0, tpu_wake_late_seconds_counter=9.0,
+                tpu_watchdog_scans_counter=10.0),
+        _scrape(10.0, tpu_wake_late_seconds_counter=0.5,
+                tpu_watchdog_scans_counter=100.0),
+        _scrape(35.0, tpu_wake_late_seconds_counter=0.6,
+                tpu_watchdog_scans_counter=150.0),
+        _scrape(59.0, tpu_wake_late_seconds_counter=0.794,
+                tpu_watchdog_scans_counter=198.0),
+        _scrape(61.0, tpu_wake_late_seconds_counter=99.0,
+                tpu_watchdog_scans_counter=202.0)])
+    assert late.read(run) == pytest.approx(3.0)  # 0.294 s over 98 scans
+    # A parent's program has neither counter: the poller sums nothing.
+    assert late.read(PARENT) is None and late.read(_load([])) is None
+
+
+def test_the_stall_readers_read_the_programs_own_recorder():
+    """End to end on the real recorder: a stall written by the
+    supervisor is what the reader finds."""
+    import stalls
+    from retina_tpu.obs.recorder import initialize_recorder
+    from retina_tpu.utils import metric_names as mn
+
+    rec = initialize_recorder()
+    assert stalls.has_stage() and stalls.STAGE == mn.STAGE_STALL
+    assert set(stalls.PROCESS + stalls.THREAD) == set(mn.STALL_CAUSES)
+    rec.post_hoc(mn.STAGE_STALL, 20.0, 22.5, cause=mn.STALL_HELD,
+                 gap_s=2.5)
+    run = _load([])
+    assert harness.load_reader("process_stall_longest_ms").read(run) == \
+        pytest.approx(2500.0)
+    assert harness.load_reader("thread_stall_longest_ms").read(run) == 0.0

@@ -222,6 +222,87 @@ def test_two_samples_add_only_the_increase(proc):
     assert len(stats["threads"]) == 5
 
 
+def test_a_stall_asks_who_ran_since_the_sample_before(proc, monkeypatch):
+    """``sample_top``: what the watchdog's scan asks at a ``held``
+    stall. One sample more, and the role and the thread that burnt the
+    most since the one before; the runtime's thread by its ``comm``."""
+    proc.thread(100, 3.0, "MainThread")
+    proc.thread(101, 1.5, "engine")
+    proc.thread(104, 2.0, comm="tfrt_worker")
+    proc.process_s = 6.5
+    proc.account.sample()
+    monkeypatch.setattr(proc.account, "_sampled_at", float("-inf"))
+    proc.thread(100, 5.0, "MainThread")  # held the lock for 2 s
+    proc.thread(101, 1.75, "engine")
+    proc.process_s = 8.75
+    top = proc.account.sample_top()
+    assert top == {"top_role": mn.ROLE_FOREIGN, "top_role_cpu_s": 2.0,
+                   "top_thread": "MainThread", "top_thread_cpu_s": 2.0}
+    assert proc.account.samples == 2
+    assert role_s(mn.ROLE_FOREIGN) == pytest.approx(5.0)
+    # Asked again at once: the two samples count as one, so who ran
+    # over the hole still reads, with what has been burnt since.
+    proc.thread(104, 2.5, comm="tfrt_worker")
+    again = proc.account.sample_top()
+    assert again["top_thread"] == "MainThread"
+    assert again["top_thread_cpu_s"] == 2.0
+    assert again["top_role_cpu_s"] == 2.0
+    assert proc.account.samples == 3
+    assert role_s(mn.ROLE_RUNTIME) == pytest.approx(2.5)
+    monkeypatch.setattr(proc.account, "_sampled_at", float("-inf"))
+    proc.thread(104, 3.25, comm="tfrt_worker")
+    top = proc.account.sample_top()
+    assert (top["top_role"], top["top_thread"]) == (
+        mn.ROLE_RUNTIME, "tfrt_worker")
+    assert top["top_thread_cpu_s"] == pytest.approx(0.75)
+    assert proc.account.samples == 4
+
+
+def test_a_stall_reads_the_quiet_threads_too(proc, monkeypatch):
+    """A thread that has hardly ever run is read every eighth sample by
+    the account's own thread; the one that held the lock for seconds may
+    have slept until it did (a timer's thread: the chip's control did),
+    so a stall's sample reads every thread."""
+    proc.thread(100, 3.0, "MainThread")
+    proc.thread(301, 0.0, "stall-control")
+    proc.process_s = 3.0
+    for _ in range(cpuaccount.IDLE_AFTER + 2):
+        proc.account.sample()
+    assert (proc.account.samples + 301) % cpuaccount.IDLE_STRIDE
+    proc.thread(301, 2.0, "stall-control")
+    proc.process_s = 5.0
+    proc.thread(100, 3.25, "MainThread")
+    proc.process_s = 5.25
+    monkeypatch.setattr(proc.account, "_sampled_at", float("-inf"))
+    # The account's own thread wakes from the hole first: not the quiet
+    # thread's turn, so its sample misses who held (as on the chip).
+    proc.account.sample()
+    assert role_s(mn.ROLE_FOREIGN) == pytest.approx(3.25)
+    assert (proc.account.samples + 301) % cpuaccount.IDLE_STRIDE
+    top = proc.account.sample_top()  # a moment later: one with it
+    assert (top["top_thread"], top["top_thread_cpu_s"]) == (
+        "stall-control", 2.0)
+    assert (top["top_role"], top["top_role_cpu_s"]) == (
+        mn.ROLE_FOREIGN, 2.25)
+    assert role_s(mn.ROLE_FOREIGN) == pytest.approx(5.25)
+
+
+def test_the_sampler_and_the_scan_may_sample_at_once(proc):
+    """The account's own thread and the watchdog's scan both sample:
+    neither adds an increase twice."""
+    for tid in range(200, 240):
+        proc.thread(tid, 1.0, f"feed-worker-{tid}")
+    proc.process_s = 40.0
+    threads = [threading.Thread(target=fn) for fn in
+               (proc.account.sample, proc.account.sample_top) * 4]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    assert role_s(mn.ROLE_FEED) == pytest.approx(40.0)
+    assert process_s() == pytest.approx(40.0)
+
+
 def test_a_thread_that_accounts_for_itself_adds_nothing_through_the_sampler(
         proc):
     proc.thread(200, 0.5, "http-server")

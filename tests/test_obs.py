@@ -84,6 +84,38 @@ class TestFlightRecorder:
         assert by[mn.STAGE_RENDER]["parent"] == outer.id
         assert by[mn.STAGE_POD_PUBLISH]["parent"] == 0
 
+    def test_a_stall_is_written_after_the_fact(self):
+        """The one post-hoc form: a span that is over when it is
+        written lands where every other does: ``spans()``, the Chrome
+        trace, ``tpu_stage_seconds{stage="stall"}``."""
+        from retina_tpu.metrics import get_metrics
+
+        rec = FlightRecorder(capacity=64)
+        hist = get_metrics().stage_seconds.labels(stage=mn.STAGE_STALL)
+        n0, s0 = sum(b.get() for b in hist._buckets), hist._sum.get()
+        sid = rec.post_hoc(mn.STAGE_STALL, 100.0, 102.3, parent=5,
+                           cause=mn.STALL_PAUSED, gap_s=2.3)
+        (span,) = rec.spans()
+        assert span["id"] == sid > 0 and span["parent"] == 5
+        assert (span["t0"], span["t1"]) == (100.0, 102.3)
+        assert span["thread"] == threading.current_thread().name
+        assert span["args"] == {"cause": mn.STALL_PAUSED, "gap_s": 2.3}
+        (ev,) = [e for e in rec.chrome_trace()["traceEvents"]
+                 if e["ph"] == "X"]
+        assert ev["name"] == mn.STAGE_STALL
+        assert ev["dur"] == pytest.approx(2.3e6)
+        assert ev["args"]["cause"] == mn.STALL_PAUSED
+        assert sum(b.get() for b in hist._buckets) - n0 == 1
+        assert hist._sum.get() - s0 == pytest.approx(2.3)
+        assert list(rec.stage_report()) == [mn.STAGE_STALL]
+        # Nobody ran it, so it has no CPU of its own to read.
+        assert mn.STAGE_STALL in mn.STAGES
+        assert mn.STAGE_STALL not in mn.CPU_STAGES
+        assert "cpu_s" not in span["args"]
+        off = FlightRecorder(capacity=64, enabled=False)
+        assert off.post_hoc(mn.STAGE_STALL, 1.0, 2.0) == 0
+        assert off.spans() == []
+
     def test_span_ended_on_another_thread(self):
         """The engine's device_step: opened on the proxy thread, closed
         by the completion thread, parent named explicitly."""
@@ -953,6 +985,105 @@ class TestDeviceProxyVisible:
         assert seen["thread"] == "device-completion" == step["thread"]
         assert seen["err"] is None and seen["dt"] >= 0.05
         assert step["args"] == {"n_steps": 4}
+
+    def test_a_call_that_keeps_the_proxy_is_a_thread_stall_with_its_kind(
+        self, fresh_recorder
+    ):
+        """The proxy's cell beats at the start of a call with its kind
+        and parks at its end: a call that blocks (here on an event the
+        test holds) is found by the watchdog's scan a second in, and
+        its ``stall`` span ends where the call did, inside the call's
+        ``proxy_run`` span."""
+        from retina_tpu.runtime.supervisor import Supervisor
+        from retina_tpu.utils import device_proxy
+        from retina_tpu.utils.device_proxy import fence, submit_on_device
+
+        assert fence(5.0)
+        sup = Supervisor()
+        hb, hb_ready = (sup.adopt(c) for c in device_proxy.HEARTBEATS)
+        assert (hb.name, hb_ready.name) == ("device-proxy",
+                                            "device-completion")
+        gate, inside = threading.Event(), threading.Event()
+
+        def held():
+            inside.set()
+            gate.wait(10.0)
+
+        mine = 424242
+        submit_on_device(held, kind=mn.KIND_STEP, parent=mine)
+        assert inside.wait(5.0)
+        assert not hb.parked and hb.what == mn.KIND_STEP and hb.span > 0
+        began = hb._last
+        # The scan, a second and more into the call, by a clock reading
+        # handed in: nothing sleeps.
+        assert sup.scan_once(now=began + 0.9) == [] and not sup._open
+        sup.scan_once(now=began + 1.6)
+        assert list(sup._open) == ["device-proxy"]
+        gate.set()
+        assert fence(5.0)
+        assert hb.parked
+        sup.scan_once(now=began + 2.1)
+        (run,) = [s for s in fresh_recorder.spans()
+                  if s["stage"] == mn.STAGE_PROXY_RUN
+                  and s["parent"] == mine]
+        (st,) = [s for s in fresh_recorder.spans()
+                 if s["stage"] == mn.STAGE_STALL]
+        assert st["args"]["cause"] == mn.STALL_THREAD
+        assert st["args"]["thread"] == "device-proxy"
+        assert st["args"]["kind"] == mn.KIND_STEP
+        assert st["args"]["in_span"] == run["id"] == st["parent"]
+        # Its ends are the proxy's own readings: the start and the end
+        # of the call, which the span of the call holds too.
+        assert st["t0"] == began
+        assert st["t0"] == pytest.approx(run["t0"], abs=0.01)
+        assert st["t0"] < st["t1"] <= run["t1"]
+        # The 30 s deadline never applies to the proxy's cells.
+        assert sup.scan_once(now=began + 3600.0) == []
+
+    def test_the_cells_cost_the_proxy_no_clock_reading(
+        self, monkeypatch, fresh_recorder
+    ):
+        """A call through the proxy read ``perf_counter`` three times
+        before its thread had a liveness cell (enqueue, start, end) and
+        reads it three times now: the cell is handed the start and the
+        end. The cell's own clock is never read."""
+        import types
+
+        from retina_tpu.utils import device_proxy
+        from retina_tpu.utils.device_proxy import fence, run_on_device
+
+        assert fence(5.0)
+        reads, own = [], []
+        counting = types.SimpleNamespace(
+            perf_counter=lambda: (reads.append(1), time.perf_counter())[1])
+        monkeypatch.setattr(device_proxy, "time", counting)
+        monkeypatch.setattr(
+            device_proxy._hb, "_clock",
+            lambda: (own.append(1), time.perf_counter())[1])
+        for _ in range(20):
+            assert run_on_device(lambda: 5, kind=mn.KIND_TABLE) == 5
+            run_on_device(lambda: None, kind=mn.KIND_POLL)
+        assert len(reads) == 3 * 40 and own == []
+        assert device_proxy._hb.parked
+
+    def test_the_completion_thread_beats_while_it_waits_for_the_device(
+        self
+    ):
+        from retina_tpu.utils import device_proxy
+        from retina_tpu.utils.device_proxy import on_ready
+
+        out, done = _FakeArray(), threading.Event()
+        on_ready(out, lambda err: done.set())
+        hb = device_proxy._hb_ready
+        deadline = time.monotonic() + 5.0
+        while hb.parked and time.monotonic() < deadline:
+            time.sleep(0.001)
+        assert not hb.parked  # mid-work: in block_until_ready
+        out.gate.set()
+        assert done.wait(5.0)
+        while not hb.parked and time.monotonic() < deadline:
+            time.sleep(0.001)
+        assert hb.parked  # back in its queue
 
     def test_completion_hands_a_failed_wait_to_the_callback(self):
         from retina_tpu.utils.device_proxy import on_ready
