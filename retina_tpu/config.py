@@ -137,13 +137,6 @@ class Config:
     # metrics publish interval (1s) or scrapes lag.
     flush_max_age_s: float = 0.4
     mesh_devices: int = 0  # 0 = all local devices
-    # Worker threads for the native combiner (combine.cpp
-    # rt_combine_mt; host-side RLE combining before the host->device
-    # transfer, the eBPF map pre-aggregation analog, lossless):
-    # per-thread partial combines + one small merge.
-    # 0 = auto (RETINA_COMBINE_THREADS env, else cores-1 capped at 4 —
-    # 1 on single-core hosts, i.e. the single-threaded pass).
-    host_combine_threads: int = 0
     # Bound on dispatches in flight behind the dispatch thread
     # (engine.py): submitted, and their last step not yet finished on
     # the device (transfers queue back-to-back on the device proxy so

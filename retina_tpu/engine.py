@@ -213,12 +213,6 @@ class SketchEngine:
         self._dispatch_thread: threading.Thread | None = None  # guarded-by: self._reads
         self._reads_asked = 0  # guarded-by: self._reads
         self._reads_served = 0  # guarded-by: self._reads
-        # Combiner thread count (native rt_combine_mt; 0 keeps the
-        # cores-based default — 1 thread on single-core hosts).
-        if cfg.host_combine_threads > 0:
-            from retina_tpu.native import set_combine_threads
-
-            set_combine_threads(cfg.host_combine_threads)
         # Flow-descriptor dictionary (parallel/flowdict.py).
         # Host side assigns stable device-table slots; the device table
         # itself is created lazily ON device (zeros jit — a host-side

@@ -27,8 +27,6 @@ import numpy as np
 
 from retina_tpu.events.schema import NUM_FIELDS
 from retina_tpu.log import logger
-from retina_tpu.obs.cpuaccount import book_own_thread
-from retina_tpu.utils import metric_names as mn
 
 _log = logger("native")
 _dir = os.path.dirname(os.path.abspath(__file__))
@@ -37,12 +35,12 @@ _lib: Optional[ctypes.CDLL] = None
 _lock = threading.Lock()
 _build_failed = False
 
-# Expected ABI of libretina_native.so (ring.cpp rt_abi_version — the
+# Expected ABI of libretina_native.so (decoder.cpp rt_abi_version — the
 # single source of truth on the C++ side). The loader refuses a library
 # reporting anything else: a stale prebuilt .so (wrong checkout, wrong
-# arch cache) would otherwise misparse the dense wire bitstream or the
-# striped-combine arguments silently. Bump BOTH sides together.
-NATIVE_ABI_VERSION = 3
+# arch cache) would otherwise misparse the dense wire bitstream or call
+# an entry point it no longer has. Bump BOTH sides together.
+NATIVE_ABI_VERSION = 4
 
 
 def _build(force: bool = False) -> bool:
@@ -134,24 +132,11 @@ def get_lib() -> Optional[ctypes.CDLL]:
             ctypes.POINTER(ctypes.c_uint32), ctypes.c_size_t,
             ctypes.POINTER(ctypes.c_uint32), ctypes.c_size_t,
         ]
-        lib.rt_combine_mt.restype = ctypes.c_long
-        lib.rt_combine_mt.argtypes = [
-            ctypes.POINTER(ctypes.c_uint32), ctypes.c_size_t,
-            ctypes.POINTER(ctypes.c_uint32), ctypes.c_size_t,
-            ctypes.c_uint,
-        ]
         lib.rt_combine_multi.restype = ctypes.c_long
         lib.rt_combine_multi.argtypes = [
             ctypes.POINTER(ctypes.POINTER(ctypes.c_uint32)),
             ctypes.POINTER(ctypes.c_size_t), ctypes.c_size_t,
             ctypes.POINTER(ctypes.c_uint32), ctypes.c_size_t,
-        ]
-        lib.rt_combine_stripe.restype = ctypes.c_long
-        lib.rt_combine_stripe.argtypes = [
-            ctypes.POINTER(ctypes.POINTER(ctypes.c_uint32)),
-            ctypes.POINTER(ctypes.c_size_t), ctypes.c_size_t,
-            ctypes.POINTER(ctypes.c_uint32), ctypes.c_size_t,
-            ctypes.c_uint32, ctypes.c_uint32,
         ]
         lib.rt_flowdict_new.restype = ctypes.c_void_p
         lib.rt_flowdict_new.argtypes = [ctypes.c_uint32]
@@ -257,38 +242,9 @@ def decode_pcap_native(data: bytes, obs_point: int = 2) -> Optional[tuple]:
 # diversity is stable, so sizing the next probe table from it keeps the
 # table cache-resident (combine.cpp rt_combine_hint grows it when the
 # hint undershoots — identical results either way). Plain int store:
-# only the engine feed thread writes it, and a stale read only costs a
-# suboptimal table size.
+# the feed workers each write it after their own flush's combine, and a
+# stale or another worker's value only costs a suboptimal table size.
 _combine_hint_groups = 0
-
-
-def _default_combine_threads() -> int:
-    """RETINA_COMBINE_THREADS, else cores-1 capped at 4 (the combiner
-    shares the host with the agent's feed/proxy/server threads). On the
-    1-core bench host this resolves to 1 — the single-threaded pass."""
-    env = os.environ.get("RETINA_COMBINE_THREADS", "")
-    if env.isdigit():
-        return max(1, int(env))
-    return max(1, min(4, (os.cpu_count() or 1) - 1))
-
-
-_combine_threads = _default_combine_threads()
-
-
-def get_combine_threads() -> int:
-    """Current combiner thread count (combine_blocks routes multi-core
-    quanta through the MT concat path instead of the single-thread
-    multi-block pass)."""
-    return _combine_threads
-
-
-def set_combine_threads(n: int) -> None:
-    """Engine/config hook (host_combine_threads). PROCESS-WIDE: the
-    combiner is shared library state, so with several engines in one
-    process the last setter wins (the daemon runs one engine). 0
-    restores the auto default."""
-    global _combine_threads
-    _combine_threads = int(n) if n > 0 else _default_combine_threads()
 
 
 def combine_native(records: np.ndarray) -> Optional[np.ndarray]:
@@ -309,12 +265,11 @@ def combine_native(records: np.ndarray) -> Optional[np.ndarray]:
     out = np.empty_like(records)
     # Target load factor <= 0.25 at the remembered group count so the
     # common case never pays the grow-and-rehash.
-    g = lib.rt_combine_mt(
+    g = lib.rt_combine_hint(
         records.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)),
         n,
         out.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)),
         4 * _combine_hint_groups,
-        _combine_threads,
     )
     if g < 0:
         return None
@@ -371,88 +326,6 @@ def native_abi_version() -> Optional[int]:
     if lib is None:
         return None
     return _loaded_abi(lib)
-
-
-def combine_native_blocks_striped(
-    blocks: list, n_stripes: int,
-) -> Optional[np.ndarray]:
-    """Multi-consumer combine crew (combine.cpp rt_combine_stripe): T
-    Python threads each combine ONE key-hash stripe of the same block
-    list into a private output buffer — the ctypes calls release the
-    GIL, the key partition makes the flow sets disjoint, so there is no
-    merge pass and no shared mutable state (per-worker partitioned
-    combine). Output concatenates the stripes; row order therefore
-    differs from the single-pass combine (consumers treat order as
-    arbitrary), but the key -> (packets, bytes, latest-ts) map is
-    identical — cross-checked by tests/test_combine_scaling.py.
-    Returns None when the library is unavailable or any block isn't a
-    plain (N, 16) u32 array — callers fall back."""
-    global _combine_hint_groups
-    lib = get_lib()
-    if lib is None or not blocks or n_stripes < 2:
-        return None
-    total = 0
-    for b in blocks:
-        if (b.ndim != 2 or b.shape[1] != 16 or b.dtype != np.uint32
-                or not b.flags.c_contiguous):
-            return None
-        total += len(b)
-    if total == 0:
-        return blocks[0][:0]
-    n_stripes = min(int(n_stripes), 16)
-    ptrs = (ctypes.POINTER(ctypes.c_uint32) * len(blocks))(
-        *[b.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32))
-          for b in blocks]
-    )
-    ns = (ctypes.c_size_t * len(blocks))(*[len(b) for b in blocks])
-    # Per-stripe buffers sized for the worst case (all rows one stripe):
-    # np.empty is a virtual allocation, so untouched pages of the slack
-    # cost address space, not RAM.
-    outs = [np.empty((total, 16), np.uint32) for _ in range(n_stripes)]
-    counts = [0] * n_stripes
-    hint = (4 * _combine_hint_groups) // n_stripes
-
-    def run(s: int) -> None:
-        counts[s] = lib.rt_combine_stripe(
-            ptrs, ns, len(blocks),
-            outs[s].ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)),
-            hint, s, n_stripes,
-        )
-
-    def run_and_book(s: int) -> None:
-        # A stripe's thread lives for one combine, shorter than the CPU
-        # account's sample period: it books its own CPU as it ends.
-        try:
-            run(s)
-        finally:
-            book_own_thread(mn.ROLE_FEED)
-
-    workers = [
-        threading.Thread(target=run_and_book, args=(s,),
-                         name=f"combine-stripe-{s}", daemon=True)
-        for s in range(1, n_stripes)
-    ]
-    try:
-        for w in workers:
-            w.start()
-    except RuntimeError:  # noqa: RT101 — not swallowed: a stripe whose
-        # thread never spawned (pid pressure) is detected below by
-        # w.ident is None and re-run sequentially on this thread, so
-        # the result is identical either way; nothing to count.
-        pass
-    run(0)
-    for w in workers:
-        if w.ident is not None:
-            w.join()
-        else:
-            run(workers.index(w) + 1)
-    if any(c < 0 for c in counts):
-        return None
-    g = sum(int(c) for c in counts)
-    _combine_hint_groups = g
-    return np.concatenate(
-        [outs[s][: int(counts[s])] for s in range(n_stripes)], axis=0
-    )
 
 
 def flowwire_dense_native(

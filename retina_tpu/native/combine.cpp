@@ -14,7 +14,6 @@
 #include <cstdint>
 #include <cstdlib>
 #include <cstring>
-#include <thread>
 
 namespace {
 
@@ -57,14 +56,6 @@ inline uint32_t sat_add_u32(uint32_t a, uint32_t b) {
   return s > 0xFFFFFFFFull ? 0xFFFFFFFFu : (uint32_t)s;
 }
 
-// Which of n_stripes key-partitions a row belongs to. Uses the UPPER
-// hash bits (the table index burns the lower ones — reusing them would
-// collapse each stripe's slot distribution) via a multiply-shift range
-// map, so any n_stripes works without a per-row divide.
-inline uint32_t stripe_of(uint64_t h, uint32_t n_stripes) {
-  return (uint32_t)(((uint64_t)(uint32_t)(h >> 32) * n_stripes) >> 32);
-}
-
 }  // namespace
 
 extern "C" {
@@ -93,102 +84,21 @@ long rt_combine_hint(const uint32_t* rows, size_t n, uint32_t* out,
   return rt_combine_multi(blocks, ns, 1, out, hint_slots);
 }
 
-// Multi-threaded combine for multi-core hosts: T contiguous chunks
-// combined independently (each with its own table), then one
-// sequential merge pass over the concatenated partials (G_total rows,
-// ~n/ratio — cheap). Row order differs from the single-thread pass
-// (chunk-major first-appearance); consumers treat order as arbitrary
-// (see header). nthreads <= 1, tiny inputs, or any allocation failure
-// fall back to the single-threaded pass — results are equivalent
-// either way (cross-checked as key -> value maps by the test suite).
-long rt_combine_mt(const uint32_t* rows, size_t n, uint32_t* out,
-                   size_t hint_slots, unsigned nthreads) {
-  constexpr size_t kMinPerThread = 1 << 15;
-  if (nthreads > 16) nthreads = 16;
-  if (nthreads <= 1 || n < 2 * kMinPerThread)
-    return rt_combine_hint(rows, n, out, hint_slots);
-  if ((size_t)nthreads > n / kMinPerThread)
-    nthreads = (unsigned)(n / kMinPerThread);
-
-  uint32_t* scratch =
-      (uint32_t*)malloc(n * NUM_FIELDS * sizeof(uint32_t));
-  if (!scratch) return rt_combine_hint(rows, n, out, hint_slots);
-  long* counts = (long*)malloc(nthreads * sizeof(long));
-  if (!counts) {
-    free(scratch);
-    return rt_combine_hint(rows, n, out, hint_slots);
-  }
-
-  size_t chunk = n / nthreads;
-  size_t per_hint = hint_slots ? hint_slots / nthreads : 0;
-  // Spawn-per-call is fine at these sizes: threading only engages at
-  // >= 64k rows, where create+join (tens of us) is <0.1% of the pass.
-  // std::thread construction can throw (EAGAIN under pid-limit
-  // pressure) — that must become the single-threaded fallback, never
-  // an exception across the extern "C" boundary (std::terminate).
-  std::thread workers[16];
-  unsigned spawned = 0;
-  try {
-    for (unsigned t = 0; t < nthreads; t++) {
-      size_t lo = t * chunk;
-      size_t hi = (t == nthreads - 1) ? n : lo + chunk;
-      workers[t] = std::thread([=]() {
-        counts[t] = rt_combine_hint(rows + lo * NUM_FIELDS, hi - lo,
-                                    scratch + lo * NUM_FIELDS, per_hint);
-      });
-      spawned++;
-    }
-  } catch (...) {
-    for (unsigned t = 0; t < spawned; t++) workers[t].join();
-    free(counts);
-    free(scratch);
-    return rt_combine_hint(rows, n, out, hint_slots);
-  }
-  for (unsigned t = 0; t < nthreads; t++) workers[t].join();
-
-  bool failed = false;
-  size_t total = 0;
-  for (unsigned t = 0; t < nthreads; t++) {
-    if (counts[t] < 0) failed = true;
-    else total += (size_t)counts[t];
-  }
-  long g = -1;
-  if (!failed) {
-    // Compact the partials to one contiguous run, then merge. The
-    // compaction reuses scratch in place (partials are in ascending
-    // offsets, so memmove is safe front to back).
-    size_t off = 0;
-    for (unsigned t = 0; t < nthreads; t++) {
-      size_t lo = t * chunk;
-      size_t cnt = (size_t)counts[t];
-      if (off != lo && cnt)
-        memmove(scratch + off * NUM_FIELDS, scratch + lo * NUM_FIELDS,
-                cnt * NUM_FIELDS * sizeof(uint32_t));
-      off += cnt;
-    }
-    g = rt_combine_hint(scratch, total, out, hint_slots);
-  }
-  free(counts);
-  free(scratch);
-  if (g < 0) return rt_combine_hint(rows, n, out, hint_slots);
-  return g;
-}
-
 long rt_combine(const uint32_t* rows, size_t n, uint32_t* out) {
   return rt_combine_hint(rows, n, out, 0);
 }
 
-// The one table body behind every combine entry point (single-block,
-// multi-block, striped) — a fix can never diverge between them.
-// stripe/n_stripes: with n_stripes > 1, only rows whose key hashes into
-// the given stripe (stripe_of) are combined; the rest are skipped. Key
-// partitioning makes concurrent striped calls over the SAME blocks
-// write disjoint flow sets — the multi-consumer combine crew needs no
-// cross-worker merge pass and no locks (each worker owns its out
-// buffer; the input blocks are read-only).
-static long combine_core(const uint32_t* const* blocks, const size_t* ns,
-                         size_t nblocks, uint32_t* out, size_t hint_slots,
-                         uint32_t stripe, uint32_t n_stripes) {
+// Multi-block combine: the one table body behind every combine entry
+// point (single-block, multi-block) — a fix can never diverge between
+// them. The feed loop's flush quantum is a list of sink blocks, and
+// concatenating them first costs a full row-copy pass (~40% of the
+// combine stage at production quanta). First-appearance output order
+// matches exactly what rt_combine_hint would produce on the
+// concatenation, so results are bit-identical (cross-checked by the
+// test suite). Single-threaded by design: the caller's feed workers
+// each combine their own flush, which is where the parallelism is.
+long rt_combine_multi(const uint32_t* const* blocks, const size_t* ns,
+                      size_t nblocks, uint32_t* out, size_t hint_slots) {
   size_t n = 0;
   for (size_t b = 0; b < nblocks; b++) n += ns[b];
   if (n == 0) return 0;
@@ -225,7 +135,6 @@ static long combine_core(const uint32_t* const* blocks, const size_t* ns,
         next_hashes[(i + kAhead) % kAhead] = h;
         __builtin_prefetch(&table[h & mask]);
       }
-      if (n_stripes > 1 && stripe_of(h_i, n_stripes) != stripe) continue;
       if (2 * g >= slots && slots < worst) {
         size_t nslots = slots << 1;
         uint32_t* ntable = (uint32_t*)malloc(nslots * sizeof(uint32_t));
@@ -274,38 +183,6 @@ static long combine_core(const uint32_t* const* blocks, const size_t* ns,
   }
   free(table);
   return (long)g;
-}
-
-// Multi-block combine: same single-pass table as rt_combine_hint but
-// consuming a LIST of row blocks — the feed loop's flush quantum is a
-// list of sink blocks, and concatenating them first costs a full
-// row-copy pass (~40% of the combine stage at production quanta).
-// First-appearance output order matches exactly what rt_combine_hint
-// would produce on the concatenation, so results are bit-identical
-// (cross-checked by the test suite).
-long rt_combine_multi(const uint32_t* const* blocks, const size_t* ns,
-                      size_t nblocks, uint32_t* out, size_t hint_slots) {
-  return combine_core(blocks, ns, nblocks, out, hint_slots, 0, 1);
-}
-
-// Striped multi-consumer combine: combine ONLY the rows of one key
-// partition (stripe of n_stripes, see stripe_of). T concurrent callers
-// over the same blocks with stripes 0..T-1 produce disjoint flow sets
-// whose concatenation equals rt_combine_multi's output as a key->value
-// map (first-appearance order is per-stripe). This is the per-worker
-// partitioned combine of the feed pool's combine crew: unlike
-// rt_combine_mt's chunk+sequential-merge, there is NO merge pass and no
-// shared mutable state — each worker scans all rows but hashes/probes
-// only its own stripe's, so the expensive part (table writes, output
-// row copies) parallelizes perfectly.
-long rt_combine_stripe(const uint32_t* const* blocks, const size_t* ns,
-                       size_t nblocks, uint32_t* out, size_t hint_slots,
-                       uint32_t stripe, uint32_t n_stripes) {
-  if (n_stripes <= 1)
-    return combine_core(blocks, ns, nblocks, out, hint_slots, 0, 1);
-  if (stripe >= n_stripes) return 0;
-  return combine_core(blocks, ns, nblocks, out, hint_slots, stripe,
-                      n_stripes);
 }
 
 }  // extern "C"

@@ -219,10 +219,13 @@ long rt_decode_pcap(const uint8_t* data, size_t len, uint32_t obs_point,
 // NATIVE_ABI_VERSION) refuses a mismatched binary and rebuilds from
 // source, so a stale .so from another checkout can never silently
 // misparse the wire.
-//   v1: rt_combine/rt_combine_mt and the two-lane flow-wire builder
-//   v2: + rt_combine_stripe (striped multi-consumer combine) and
+//   v1: the combine entry points and the two-lane flow-wire builder
+//   v2: + a key-stripe combine for several threads, and
 //       rt_flowwire_dense (dense known-row bitstream)
 //   v3: - the two-lane flow-wire builder (known rows are dense only)
-uint32_t rt_abi_version(void) { return 3; }
+//   v4: - the two threaded combine entry points (chunk-and-merge and
+//       key-stripe): a flush is combined by one single-threaded pass
+//       on the feed worker that flushes it
+uint32_t rt_abi_version(void) { return 4; }
 
 }  // extern "C"

@@ -111,36 +111,17 @@ def combine_records(records: np.ndarray) -> np.ndarray:
 
 
 def combine_blocks(blocks: list[np.ndarray]) -> np.ndarray:
-    """Combine a LIST of record blocks (the feed loop's flush quantum)
-    without concatenating them first — the concat alone costs a full
-    row-copy pass at production quanta (~40% of the stage on a 1-core
-    host). The key -> (packets, bytes, latest-ts) map is identical to
-    ``combine_records(np.concatenate(blocks))`` in every regime (the
-    losslessness contract above); ROW ORDER matches it on the
-    single-thread paths and is arbitrary on the multi-consumer striped
-    path (consumers never depend on it — rows are partitioned and
-    re-bucketed immediately downstream). Falls back to concat +
-    combine when the native library is unavailable."""
-    from retina_tpu.native import (
-        combine_native_blocks, combine_native_blocks_striped,
-        get_combine_threads,
-    )
+    """Combine a LIST of record blocks (a feed worker's flush) without
+    concatenating them first — the concat alone costs a full row-copy
+    pass at production quanta (~40% of the stage on a 1-core host).
+    One single-threaded native pass on the calling thread, at every
+    size: the feed pool's workers are the parallelism, each combining
+    its own flush with the GIL released in the ctypes call. The output
+    is identical to ``combine_records(np.concatenate(blocks))``, row
+    order included (first appearance). Falls back to concat + combine
+    when the native library is unavailable."""
+    from retina_tpu.native import combine_native_blocks
 
-    total = sum(len(b) for b in blocks)
-    n_threads = get_combine_threads()
-    if n_threads > 1 and total >= 2 * (1 << 15):
-        # Multi-consumer territory: T stripe workers each combine ONE
-        # key-hash stripe of the block list into a private table and
-        # output buffer (combine.cpp rt_combine_stripe) — key-disjoint
-        # stripes need no merge pass, no locks, and no concat
-        # (rt_combine_mt paid a full row-copy concat + a serial merge
-        # of T partial tables; the stripes replace both). Works
-        # directly on a single oversized block too.
-        out = combine_native_blocks_striped(blocks, n_threads)
-        if out is not None:
-            return out
-        # Library unavailable: the old concat + chunk-parallel path.
-        return combine_records(np.concatenate(blocks, axis=0))
     if len(blocks) == 1:
         return combine_records(blocks[0])
     out = combine_native_blocks(blocks)

@@ -453,7 +453,6 @@ THREAD_ROLES = (
 THREAD_ROLE_PREFIXES = (
     ("engine", ROLE_FEED),  # the distributor loop
     ("feed-worker-", ROLE_FEED),
-    ("combine-stripe-", ROLE_FEED),
     ("plugin-", ROLE_FEED),
     ("engine-dispatch", ROLE_DISPATCH),
     ("device-proxy", ROLE_PROXY),
@@ -493,7 +492,7 @@ THREAD_ROLE_PREFIXES = (
 # Threads that add their own CPU seconds to their role as they end,
 # because they live shorter than a sample period: the sampler skips
 # them, so nothing is counted twice.
-SELF_ACCOUNTING_PREFIXES = ("http-handler", "combine-stripe-")
+SELF_ACCOUNTING_PREFIXES = ("http-handler",)
 
 
 def thread_role(name: str) -> str:

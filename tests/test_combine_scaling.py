@@ -1,11 +1,12 @@
-"""Multi-consumer (striped) combine + sharded-feed algebra.
+"""One combine pass per flush + sharded-feed algebra.
 
-The striped combiner (native/combine.cpp rt_combine_stripe via
-combine_native_blocks_striped) replaces the single-consumer drain: T
-stripe workers each own a key-hash stripe of the flush's block list —
-key-disjoint by construction, so no locks and no merge pass. Contract:
-the key -> (packets, bytes, latest-ts) map is IDENTICAL to the
-single-threaded combine; row order is explicitly arbitrary.
+A flush is combined by ONE single-threaded native pass on the thread
+that flushes it (a feed worker), at every size: the feed pool's workers
+are the parallelism. Contract: the key -> (packets, bytes, latest-ts)
+map equals the plain numpy combine, each key once, and no thread is
+started for it. (Until PR 39 flushes of 65,536 rows or more went to
+three extra stripe threads that each scanned every row: ≈ 4.5 times the
+CPU of the one pass at 65,536 rows, slower in wall time too.)
 
 The mesh-sharding half checks the algebra the multi-chip feed rests on
 ("Sketchy With a Chance of Adoption": mergeability makes per-device
@@ -14,6 +15,8 @@ combines union to exactly the unsharded combine.
 """
 
 from __future__ import annotations
+
+import threading
 
 import numpy as np
 import pytest
@@ -24,6 +27,7 @@ from retina_tpu.parallel.combine import (
     KEY_COLS,
     combine_blocks,
     combine_records,
+    combine_records_numpy,
 )
 
 native = pytest.importorskip("retina_tpu.native")
@@ -44,89 +48,52 @@ def _as_map(arr: np.ndarray) -> dict:
     }
 
 
-def _blocks(n_blocks=6, block=1 << 14, n_flows=2000, seed=41):
-    gen = TrafficGen(n_flows=n_flows, n_pods=64, seed=seed)
-    return [gen.batch(block) for _ in range(n_blocks)]
+def _folded(arr: np.ndarray) -> dict:
+    """The key map of a combine that may leave a key in several rows:
+    the numpy reference splits a group where two descriptors' 32-bit
+    hashes collide and interleave (combine_records_numpy), which a
+    million rows of 70,000 flows meet."""
+    out: dict = {}
+    for key, (p, b, ts) in (
+        (tuple(int(x) for x in r[list(KEY_COLS)]),
+         (int(r[F.PACKETS]), int(r[F.BYTES]),
+          (int(r[F.TS_HI]) << 32) | int(r[F.TS_LO])))
+        for r in arr
+    ):
+        p0, b0, ts0 = out.get(key, (0, 0, 0))
+        out[key] = (p0 + p, b0 + b, max(ts0, ts))
+    return out
 
 
-def test_striped_combine_map_identical():
-    """Every stripe count must aggregate to exactly the single-thread
-    result (order-insensitive comparison — stripe-major output order is
-    part of the contract)."""
-    blocks = _blocks()
-    ref = _as_map(combine_records(np.concatenate(blocks)))
-    for n_stripes in (2, 3, 4, 8):
-        out = native.combine_native_blocks_striped(blocks, n_stripes)
-        if out is None:
-            pytest.skip("native library unavailable")
-        got = _as_map(out)
-        assert got == ref, f"stripe count {n_stripes} diverged"
-        assert len(out) == len(ref)  # each key exactly once
+# Block lists shaped like the cells' flushes: a ring's hand-over on one
+# chip (16,384) and on four (65,536), one block a second (262,144), six
+# hand-overs held into one flush, a saturated feed's quantum (1 << 20),
+# and an empty block beside a full one.
+FLUSHES = {
+    "one-16384": [16384],
+    "one-65536": [65536],
+    "one-262144": [262144],
+    "six-16384": [16384] * 6,
+    "one-1048576": [1 << 20],
+    "empty-beside-full": [0, 65536],
+}
 
 
-def test_striped_combine_single_oversized_block():
-    """combine_blocks routes ONE oversized block through the stripes
-    too (a feed worker's common shape under a backlogged sink)."""
-    big = [TrafficGen(n_flows=500, n_pods=32, seed=5).batch(1 << 17)]
-    ref = _as_map(combine_records(big[0]))
-    prev = native.get_combine_threads()
-    try:
-        native.set_combine_threads(4)
-        assert _as_map(combine_blocks(big)) == ref
-    finally:
-        native.set_combine_threads(prev)
+@pytest.mark.parametrize("sizes", FLUSHES.values(), ids=FLUSHES.keys())
+def test_one_pass_on_the_calling_thread_equals_numpy(sizes, monkeypatch):
+    gen = TrafficGen(n_flows=50_000, n_pods=64, seed=39 + len(sizes))
+    blocks = [gen.batch(max(n, 1))[:n] for n in sizes]
+    ref = _folded(combine_records_numpy(np.concatenate(blocks)))
 
+    def no_thread(self):
+        raise AssertionError(f"combine_blocks started thread {self.name}")
 
-def test_combine_blocks_routes_striped_and_agrees():
-    """Above the multi-thread threshold combine_blocks must take the
-    striped path and still satisfy the losslessness contract."""
-    blocks = _blocks(n_blocks=8, seed=43)
-    ref = _as_map(combine_records(np.concatenate(blocks)))
-    prev = native.get_combine_threads()
-    try:
-        native.set_combine_threads(4)
-        assert _as_map(combine_blocks(blocks)) == ref
-    finally:
-        native.set_combine_threads(prev)
-
-
-def test_four_consumer_combine_2x_single_consumer():
-    """What the striped combiner promises, read off its own counts and
-    not off a wall clock: four stripe consumers over one block list
-    each take a share of the keys (no stripe more than half, none
-    empty), the shares are disjoint, and together they are exactly the
-    single consumer's output.
-
-    The wall-clock ratio this test used to assert (4 consumers >= 2x
-    one) needed four idle cores and did not hold on an idle host
-    either (0.30-0.53x in the 8-core sandbox, PR 31): a rate is read
-    on the machine that runs the agent, not gated here."""
-    import ctypes
-
-    blocks = _blocks(n_blocks=8, block=1 << 15, n_flows=4000, seed=47)
-    single = native.combine_native_blocks(blocks)
-    ref = _as_map(single)
-    lib = native.get_lib()
-    u32p = ctypes.POINTER(ctypes.c_uint32)
-    ptrs = (u32p * len(blocks))(*[b.ctypes.data_as(u32p) for b in blocks])
-    ns = (ctypes.c_size_t * len(blocks))(*[len(b) for b in blocks])
-    total = sum(len(b) for b in blocks)
-    shares = []
-    for stripe in range(4):
-        out = np.empty((total, 16), np.uint32)
-        n = lib.rt_combine_stripe(
-            ptrs, ns, len(blocks), out.ctypes.data_as(u32p), 0, stripe, 4
-        )
-        assert n > 0
-        shares.append(_as_map(out[:n]))
-    assert sum(len(m) for m in shares) == len(ref)  # key-disjoint
-    assert max(len(m) for m in shares) <= len(ref) // 2
-    union: dict = {}
-    for m in shares:
-        union.update(m)
-    assert union == ref
-    # And the threaded entry point is those four stripes.
-    assert _as_map(native.combine_native_blocks_striped(blocks, 4)) == ref
+    monkeypatch.setattr(threading.Thread, "start", no_thread)
+    out = combine_blocks(blocks)
+    monkeypatch.undo()
+    got = _as_map(out)
+    assert got == ref
+    assert len(out) == len(got)  # each key exactly once
 
 
 def test_mesh_shard_sums_equal_unsharded_combine():

@@ -307,8 +307,7 @@ def test_a_thread_that_accounts_for_itself_adds_nothing_through_the_sampler(
         proc):
     proc.thread(200, 0.5, "http-server")
     proc.thread(201, 0.75, "http-handler")
-    proc.thread(202, 1.0, "combine-stripe-2")
-    proc.process_s = 2.25
+    proc.process_s = 1.25
     proc.account.sample()
     proc.thread(201, 1.25, "http-handler")
     proc.account.sample()

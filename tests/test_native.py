@@ -336,33 +336,6 @@ def test_combine_blocks_bit_identical_to_concat():
     empty = gen.batch(1)[:0]
     assert len(combine_blocks([empty, empty.copy()])) == 0
 
-    # Multi-core regime: combine_blocks routes through the STRIPED
-    # multi-consumer path, whose row order is stripe-major and
-    # explicitly arbitrary — the contract there is the key ->
-    # (packets, bytes, latest-ts) map, not row order (the deeper
-    # order-insensitive coverage lives in test_combine_scaling.py).
-    from retina_tpu.events.schema import F
-    from retina_tpu.native import get_combine_threads, set_combine_threads
-    from retina_tpu.parallel.combine import KEY_COLS
-
-    def as_map(arr):
-        return {
-            tuple(int(x) for x in r[list(KEY_COLS)]):
-                (int(r[F.PACKETS]), int(r[F.BYTES]),
-                 int(r[F.TS_HI]) << 32 | int(r[F.TS_LO]))
-            for r in arr
-        }
-
-    prev = get_combine_threads()
-    try:
-        set_combine_threads(4)
-        big = [gen.batch(1 << 14) for _ in range(6)]  # >= MT threshold
-        assert as_map(combine_blocks(big)) == as_map(
-            combine_records(np.concatenate(big))
-        )
-    finally:
-        set_combine_threads(prev)
-
 
 def test_combine_hint_grow_path_identical():
     """rt_combine_hint must return identical groups for any hint —
@@ -396,59 +369,6 @@ def test_combine_hint_grow_path_identical():
     for o in outs[1:]:
         np.testing.assert_array_equal(outs[0], o)
     assert (outs[0][:, 7] == 2).all()  # every group accumulated 2 packets
-
-
-def test_combine_mt_equivalent_across_thread_counts():
-    """rt_combine_mt: per-thread partials + merge must aggregate to
-    exactly the single-threaded result for any thread count (order may
-    differ — compare as descriptor -> (packets, bytes, ts) maps)."""
-    import ctypes
-
-    from retina_tpu.events.schema import F
-    from retina_tpu.native import get_lib
-    from retina_tpu.parallel.combine import KEY_COLS
-
-    lib = get_lib()
-    if lib is None:
-        pytest.skip("native library unavailable")
-    rng = np.random.default_rng(23)
-    n = 1 << 18  # above the per-thread minimum so threads engage
-    rec = np.zeros((n, NUM_FIELDS), np.uint32)
-    # ~2k distinct descriptors, heavy repetition across the whole span.
-    picks = rng.integers(0, 2000, n)
-    proto = rng.integers(0, 2 ** 32, size=(2000, NUM_FIELDS), dtype=np.uint32)
-    rec[:] = proto[picks]
-    rec[:, F.PACKETS] = 1
-    rec[:, F.BYTES] = rng.integers(1, 1500, n)
-    rec[:, F.TS_LO] = rng.integers(1, 2 ** 31, n)
-    rec[:, F.TS_HI] = 0
-    rows = np.ascontiguousarray(rec)
-    p = rows.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32))
-
-    def run(threads, hint=0):
-        out = np.empty_like(rows)
-        g = lib.rt_combine_mt(
-            p, n, out.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)),
-            hint, threads,
-        )
-        assert g > 0
-        return out[:g]
-
-    def as_map(arr):
-        return {
-            tuple(int(x) for x in r[list(KEY_COLS)]):
-                (int(r[F.PACKETS]), int(r[F.BYTES]),
-                 int(r[F.TS_HI]) << 32 | int(r[F.TS_LO]))
-            for r in arr
-        }
-
-    ref = as_map(run(1))
-    assert len(ref) == 2000
-    for threads in (2, 3, 8):
-        got = as_map(run(threads))
-        assert got == ref, f"threads={threads}"
-    # Hinted + threaded compose.
-    assert as_map(run(4, hint=8192)) == ref
 
 
 def test_loaded_abi_version_matches_headers():
