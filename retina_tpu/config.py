@@ -121,20 +121,18 @@ class Config:
     compilation_cache_dir: str = ""
     batch_capacity: int = 1 << 15  # events per device batch
     window_seconds: float = 1.0  # entropy/anomaly window
-    # How long a feed worker stages blocks before it combines them and
-    # hands the flush to the dispatch thread, when the dispatch
-    # pipeline is IDLE.
-    flush_interval_s: float = 0.05
-    # The longest a row waits on the host for the device, in either
-    # place it can wait. Under load (dispatches in flight) a feed
-    # worker keeps accumulating past flush_interval_s — bigger quanta
-    # raise the combine ratio and amortize per-flush fixed costs — but
-    # never beyond this age. The dispatch thread holds the flushes it is
-    # handed (a step costs the device the same whatever it holds, and
-    # every dispatch costs the host) until a step's worth of rows is
-    # held, a window close or a snapshot is about to read the state, or
-    # the oldest flush has been held this long. Must stay below the
-    # metrics publish interval (1s) or scrapes lag.
+    # The longest a row waits on the host for the device, counted from
+    # when its block was dealt to a feed worker: its staging there and
+    # the dispatch thread's hold together. A feed worker holds the raw
+    # blocks dealt to it (one combine over more blocks merges more
+    # rows, and every flush costs the host) until its quantum is full,
+    # a window close or a snapshot is about to read the state, or the
+    # oldest has waited this long; the dispatch thread holds the
+    # flushes it is handed (a step costs the device the same whatever
+    # it holds, and every dispatch costs the host) until a step's worth
+    # of rows is held, a reader asks, or the oldest flush's first block
+    # is this old. Must stay below the metrics publish interval (1s) or
+    # scrapes lag.
     flush_max_age_s: float = 0.4
     mesh_devices: int = 0  # 0 = all local devices
     # Bound on dispatches in flight behind the dispatch thread
@@ -196,8 +194,8 @@ class Config:
     flow_dict_slots: int = 1 << 18
     # Under sustained load, accumulate up to this many events per
     # combine+flush quantum (bigger quanta raise the combine ratio — more
-    # duplicate descriptors per pass — at bounded added latency). The
-    # flush_interval_s timeout still bounds latency at low rates.
+    # duplicate descriptors per pass — at bounded added latency).
+    # flush_max_age_s and the readers still bound latency at low rates.
     flush_max_events: int = 1 << 21
     snapshot_dir: str = ""  # sketch-state checkpoint dir ("" = off)
     snapshot_interval_s: float = 0.0  # 0 = only on shutdown

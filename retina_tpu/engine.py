@@ -192,14 +192,13 @@ class SketchEngine:
         self._inflight = threading.Semaphore(cfg.feed_pipeline_depth)
         # Count of dispatches in flight: submitted, and their last step
         # not yet finished ON THE DEVICE (the completion thread says
-        # when, _dispatch_done). The feed workers flush at
-        # flush_interval_s only when this is 0; while dispatches are in
-        # flight they accumulate bigger quanta up to flush_max_age_s.
-        # The dispatch thread holds and folds the flushes whatever the
-        # count, until a step's worth is held, the oldest has waited
-        # flush_max_age_s, or a reader asks (_dispatch_loop;
-        # _held_flushes is how many it holds and _held_since when it
-        # took the oldest, for feed_stats).
+        # when, _dispatch_done). The dispatch thread dispatches only
+        # while it is under feed_pipeline_depth, and holds and folds the
+        # flushes whatever the count, until a step's worth is held, the
+        # oldest was staged flush_max_age_s ago, or a reader asks
+        # (_dispatch_loop; _held_flushes is how many it holds and
+        # _held_since when the oldest's first block was staged, for
+        # feed_stats).
         self._busy_lock = threading.Lock()
         self._inflight_busy = 0
         self._submitted_n = 0  # dispatches ever submitted; guarded-by: self._busy_lock
@@ -207,11 +206,13 @@ class SketchEngine:
         self._held_since: float | None = None  # the oldest's, on the clock
         # The dispatch thread (start() sets it, the thread clears it on
         # its way out) and the readers that wait for it to submit what
-        # it holds (_release_held_for_read): requests made, and the
-        # last one served.
+        # it holds (_release_held_for_read): requests made, the feed
+        # pool's flush request of the last one, and the last one
+        # served.
         self._reads = threading.Condition()
         self._dispatch_thread: threading.Thread | None = None  # guarded-by: self._reads
         self._reads_asked = 0  # guarded-by: self._reads
+        self._read_epoch = 0  # guarded-by: self._reads
         self._reads_served = 0  # guarded-by: self._reads
         # Flow-descriptor dictionary (parallel/flowdict.py).
         # Host side assigns stable device-table slots; the device table
@@ -828,14 +829,14 @@ class SketchEngine:
         # of the boot critical path (44.9s observed in BENCH r5 dry
         # run). A scrape or window tick arriving inside the background
         # warm window compiles inline, exactly as a cold key would.
-        # Warm the smallest plain bucket (idle/interval flushes); the
+        # Warm the smallest plain bucket (idle and small flushes); the
         # rest of the bucket ladder is start_background_warm's job.
         self._dispatch(
             np.zeros((0, NUM_FIELDS), np.uint32), now_s=1,
             record_metrics=False,
         )
         mark("min plain bucket")
-        # The min-bucket flow-dict pair (idle/interval-flush keys) is
+        # The min-bucket flow-dict pair (small-flush keys) is
         # NOT warmed here: it is the first grid entry in
         # start_background_warm (~12s of warm-cache load that would
         # otherwise sit on the ready path); a trickle flush arriving
@@ -898,7 +899,7 @@ class SketchEngine:
         head of the FIFO proxy queue — and _close_window_impl deferring
         ticks until it lands — the first real close always finds the
         program resident. Then the min-bucket dispatch pair (a trickle
-        feed needs it on its very first interval flush), the snapshot
+        feed needs it on its very first small flush), the snapshot
         programs (first scrape, in production 15-30s after boot), then
         the rest of the grid in ramp order. All moved off compile()'s
         critical path — together they were ~30s of the 45s boot
@@ -1881,7 +1882,7 @@ class SketchEngine:
                 self._count_unheld(n_raw)
             return
         # The dictionary pays off per ROW saved; a tiny flush (idle
-        # agent, interval flush) is cheaper as one plain transfer than
+        # agent, a trickle feed) is cheaper as one plain transfer than
         # as a new/known pair of dispatches. Plain and dict flushes
         # interleave soundly: a plain flush simply ships full rows and
         # leaves the dictionary untouched.
@@ -2506,9 +2507,9 @@ class SketchEngine:
             n = max(1, min(4, cores - 1))
         return n
 
-    def _busy_count(self) -> int:  # runs-on: feed-worker*
-        """In-flight dispatch count: gates the feed workers' interval
-        flushes and the dispatch thread's folding."""
+    def _busy_count(self) -> int:  # runs-on: engine-dispatch
+        """In-flight dispatch count: gates the dispatch thread's
+        releases (``_has_slot``)."""
         with self._busy_lock:
             return self._inflight_busy
 
@@ -2530,22 +2531,23 @@ class SketchEngine:
         with self._busy_lock:
             self._inflight_busy -= 1
         self._inflight.release()
-        # The count fell, then the wake: whoever waits for it (a
-        # worker's partial quantum for an idle pipeline; the dispatch
-        # thread's held rows that are due, for a slot; a readback for
-        # the dispatches ahead of it, _dispatches_landed) re-reads it.
+        # The count fell, then the wake: whoever waits for it (the
+        # dispatch thread's held rows that are due, for a slot; a
+        # readback for the dispatches ahead of it, _dispatches_landed)
+        # re-reads it. The feed workers hold what they stage whatever
+        # the pipeline does: none waits for it.
         with self._reads:
             self._reads.notify_all()
         pool = self._feed_pool
         if pool is not None:
-            pool.wake_pending()
+            pool.mux.wake()
 
     def wake(self) -> None:
         """Every thread of the feed path that sleeps to a deadline on
         the engine's clock re-reads it: the hook of a clock advanced by
         hand (tests/clockdrive): the feed loop for its ticks, the
-        workers for their flush ages, the dispatch thread for the age
-        of what it holds."""
+        workers for the ages of what they stage, the dispatch thread
+        for the age of what it holds."""
         self.sink.data.set()
         pool = self._feed_pool
         if pool is not None:
@@ -2623,10 +2625,11 @@ class SketchEngine:
     def _build_quantum(  # runs-on: feed-worker*  # hot-path: event
         self, blocks: list[np.ndarray], n_raw: int, now_s: int
     ) -> list[tuple]:
-        """Combine + partition one flush quantum into dispatchable step
-        items. Pure host work that the feed workers (parallel/feed.py)
-        run concurrently — the native combiner releases the GIL and
-        partition is numpy."""
+        """Combine + partition one flush (every raw block a feed worker
+        held, up to its quantum) into dispatchable step items: ONE
+        combine over all of them. Pure host work that the feed workers
+        (parallel/feed.py) run concurrently — the native combiner
+        releases the GIL and partition is numpy."""
         cap = self.cfg.batch_capacity * self.n_devices
         coal = cap * max(1, self.cfg.feed_coalesce_windows)
         coal_per_dev = self.cfg.batch_capacity * max(
@@ -2704,29 +2707,36 @@ class SketchEngine:
         **Folding.** A fused step costs the device the same whatever
         it holds and every dispatch costs the host, so steps follow the
         rows offered and the reads of the state, not the hand-overs
-        and not the device falling idle. A flush taken off the mux is
-        HELD, and what is held (across workers, up to one coalesced
-        transfer) is folded into one batch (``fold_batches``) and
-        dispatched when the pipeline has a slot
+        and not the device falling idle. The feed workers hold what is
+        dealt to them raw and flush it combined when the same causes
+        hold for it (``FeedWorker._loop``); a flush taken off the mux is
+        HELD here too, and what is held (across workers, up to one
+        coalesced transfer) is folded into one batch
+        (``fold_batches``) and dispatched when the pipeline has a slot
         (``feed_pipeline_depth``) and one of these holds
         (``tpu_dispatches_counter{cause}``):
 
         * ``full``: the fullest device's held rows reach
           ``batch_capacity`` (holding longer could not save a step);
-        * ``age``: the oldest held flush has been held for
-          ``flush_max_age_s`` of the engine's clock;
+        * ``age``: the first block of the oldest held flush was staged
+          ``flush_max_age_s`` of the engine's clock ago: the bound
+          counts the worker's staging and this hold together;
         * ``read``: something is about to read the state the rows
-          belong in. A window tick dispatches what is held before its
-          close is submitted, so rows taken before the tick land in the
-          window they were taken in (with the pipeline full the close
-          overtakes what is held, as it overtakes what is staged in the
-          workers: no close waits). A snapshot about to submit its
-          readback asks (``_release_held_for_read``) and is served once
-          the mux is empty and what was held has been submitted. A
-          readback that released rows onto an idle device waits for
-          their step to finish before it is submitted
-          (``_dispatches_landed``: the tick here, for some 75 ms, the
-          snapshot on its own thread);
+          belong in, and has asked the workers to flush what they
+          stage (``FeedWorkerPool.request_flush``). A window tick
+          carries that request: the thread takes what the workers hand
+          off until every live one has answered (``_take_flushed``),
+          then dispatches what is held before the close is submitted,
+          so rows dealt before the tick land in its window (with the
+          pipeline full the close overtakes what is held: no close
+          waits). A snapshot about to submit its readback asks
+          (``_release_held_for_read``) and is served once every live
+          worker has answered, the mux holds no step item and what was
+          held has been submitted (``_serve_reads``). A readback that
+          released rows onto an idle device waits for their step to
+          finish before it is submitted (``_dispatches_landed``: the
+          tick here, for some 250 ms at most, the snapshot on its own
+          thread);
         * ``drain``: shutdown.
 
         ``get`` blocks until an item, a wake (a completion gave a slot
@@ -2739,8 +2749,8 @@ class SketchEngine:
         coal = self.cfg.batch_capacity * max(
             1, self.cfg.feed_coalesce_windows
         )
-        # Step items off the mux, not dispatched: each with the clock's
-        # reading when it was taken.
+        # Step items off the mux, not dispatched: each ends with the
+        # clock's reading when its flush's first block was staged.
         held: list[tuple] = []
         try:
             while True:
@@ -2765,32 +2775,26 @@ class SketchEngine:
                         self._dispatch_held(
                             held, coal, mnames.DISPATCH_DRAIN)
                     return
-                if not item:
-                    # The mux read empty: what was handed off before a
-                    # reader asked (its wake came after) is held.
-                    asked = self._reads_asked
-                    if asked > self._reads_served \
-                            and self._dispatch_for_read(held, coal):
-                        with self._reads:
-                            self._reads_served = asked
-                            self._reads.notify_all()
-                elif item[0] == "step":
-                    held.append(item + (self._clock(),))
+                if item and item[0] == "step":
+                    held.append(item)
                     self._note_held(held)
-                else:
+                elif item:
+                    # Never for a window's length: ticks behind this
+                    # one find what arrived meanwhile held.
+                    bound = min(self.cfg.flush_max_age_s,
+                                self.cfg.window_seconds / 4)
+                    if item[1] is not None:
+                        self._take_flushed(q, held, coal, item[1], bound)
                     onto_idle = bool(held) and self._busy_count() == 0
                     self._dispatch_for_read(held, coal)
                     if onto_idle:
-                        # Never for a window's length: ticks behind
-                        # this one find what arrived meanwhile held.
-                        self._dispatches_landed(min(
-                            self.cfg.flush_max_age_s,
-                            self.cfg.window_seconds / 4))
+                        self._dispatches_landed(bound)
                     try:
                         self._submit_close_window()
                     except Exception:
                         if self._count_error("dispatch"):
                             self.log.exception("window dispatch failed")
+                self._serve_reads(q, held, coal)
                 while held and (cause := self._release_cause(held)):
                     self._dispatch_held(held, coal, cause)
         finally:
@@ -2808,9 +2812,14 @@ class SketchEngine:
             it[1].n_valid.astype(np.int64) for it in held
         ).max())
 
+    @staticmethod
+    def _held_since_of(held: list[tuple]) -> float:
+        """When the first block of the oldest held flush was staged."""
+        return min(it[4] for it in held)
+
     def _held_due(self, held: list[tuple]) -> float:
         """When the oldest held flush reaches ``flush_max_age_s``."""
-        return held[0][4] + self.cfg.flush_max_age_s
+        return self._held_since_of(held) + self.cfg.flush_max_age_s
 
     def _has_slot(self) -> bool:
         return self._busy_count() < self.cfg.feed_pipeline_depth
@@ -2819,7 +2828,8 @@ class SketchEngine:
         """Why what is held goes to the device now, or "": a full
         pipeline takes nothing; otherwise a step's worth of rows goes
         at once (folding more could not save a step) and anything goes
-        once its oldest flush has waited ``flush_max_age_s``. An idle
+        once its oldest flush's first block was staged
+        ``flush_max_age_s`` ago. An idle
         pipeline alone is no reason: nobody reads what an early step
         wrote before the next window close or snapshot, and those ask
         (``_dispatch_for_read``)."""
@@ -2839,21 +2849,71 @@ class SketchEngine:
             self._dispatch_held(held, coal, mnames.DISPATCH_READ)
         return not held
 
+    def _serve_reads(self, q, held: list[tuple], coal: int) -> None:
+        """(the dispatch thread, after each item or wake) Serve the
+        readers that asked: once every live feed worker has answered
+        the last one's flush request and no step item waits on the mux
+        (what they handed off for it is held: an answer comes after its
+        hand-off), what is held is submitted and they are released."""
+        with self._reads:
+            asked, epoch = self._reads_asked, self._read_epoch
+        if asked <= self._reads_served:
+            return
+        pool = self._feed_pool
+        if pool is not None and not pool.flushed(epoch):
+            return
+        if q.has_steps() or not self._dispatch_for_read(held, coal):
+            return
+        with self._reads:
+            self._reads_served = asked
+            self._reads.notify_all()
+
+    def _take_flushed(
+        self, q, held: list[tuple], coal: int, epoch: int, bound: float
+    ) -> None:
+        """(the dispatch thread, on a window tick) Take what the feed
+        workers hand off for the tick's flush request ``epoch`` until
+        every live worker has answered it and no step item is left on
+        the mux, for ``bound`` seconds at most. Holding a transfer's
+        worth, it dispatches while the pipeline has a slot and stops
+        where it has none: a worker may then wait on its hand-off, and
+        the tick waits for no answer it refuses the items of; its close
+        overtakes what is still staged, as it overtakes what is held."""
+        pool = self._feed_pool
+        t_end = time.monotonic() + bound
+        while not (pool.flushed(epoch) and not q.has_steps()):
+            left = t_end - time.monotonic()
+            if left <= 0:
+                return
+            if self._held_rows(held) >= coal:
+                if not self._has_slot():
+                    return
+                self._dispatch_held(held, coal, mnames.DISPATCH_READ)
+                continue
+            try:
+                item = q.get(timeout=left, ctl=False)
+            except queue_mod.Empty:
+                continue
+            held.append(item)
+            self._note_held(held)
+
     def _release_held_for_read(self) -> None:
         """(a reader's thread, before it submits a readback) Have the
-        dispatch thread submit what it holds, and wait until it has
-        and the device has finished it (``_dispatches_landed``): the
-        proxy is FIFO, so the readback then holds every event flushed
-        before this call. Bounded by ``flush_max_age_s`` (past it the
-        rows have gone by age; 1 s at most), and a dispatch thread
-        that is not running, has died or is on its way out is not
-        waited for."""
+        feed workers flush what they stage and the dispatch thread
+        submit it with what it holds, and wait until it has and the
+        device has finished it (``_dispatches_landed``): the proxy is
+        FIFO, so the readback then holds every event dealt to the
+        workers before this call. Bounded by ``flush_max_age_s`` (past
+        it the rows have gone by age; 1 s at most), and a dispatch
+        thread that is not running, has died or is on its way out is
+        not waited for."""
         pool, t = self._feed_pool, self._dispatch_thread
         if pool is None or t is None or not t.is_alive():
             return
         bound = min(self.cfg.flush_max_age_s, PARK_MAX_S)
         t_end = time.monotonic() + bound
         with self._reads:
+            self._read_epoch = pool.request_flush()
             self._reads_asked += 1
             want = self._reads_asked
             pool.mux.wake()
@@ -2921,7 +2981,7 @@ class SketchEngine:
 
     def _note_held(self, held: list[tuple]) -> None:
         self._held_flushes = len(held)
-        self._held_since = held[0][4] if held else None
+        self._held_since = self._held_since_of(held) if held else None
 
     def start(self, stop: threading.Event) -> None:
         """Feed loop: drain sink → combine → partition → device; close
@@ -2944,11 +3004,11 @@ class SketchEngine:
             self._tt_ring.start()
         cap = self.cfg.batch_capacity * self.n_devices
         # Flush threshold: accumulating beyond one device batch raises the
-        # combine ratio (more duplicate descriptors per pass); the
-        # interval timeout still bounds latency. Coalescing into device
-        # batches happens inside _build_quantum. Per-worker quantum
-        # splits the configured flush quantum so total staged latency
-        # stays put as workers scale.
+        # combine ratio (more duplicate descriptors per pass);
+        # flush_max_age_s and the readers bound latency. Coalescing into
+        # device batches happens inside _build_quantum. Per-worker
+        # quantum splits the configured flush quantum so total staged
+        # latency stays put as workers scale.
         quantum = max(cap, self.cfg.flush_max_events)
         n_workers = self._resolve_feed_workers()
 
@@ -2971,11 +3031,9 @@ class SketchEngine:
             n_workers=n_workers,
             quantum=max(cap, quantum // n_workers),
             staging_blocks=self.cfg.feed_staging_blocks,
-            flush_interval_s=self.cfg.flush_interval_s,
             flush_max_age_s=self.cfg.flush_max_age_s,
             build_steps=self._build_quantum,
             drop=drop_item,
-            busy=self._busy_count,
             alive=lambda: worker.is_alive(),
             register_hb=self._register_hb,
             deregister_hb=self._deregister_hb,
@@ -3047,11 +3105,13 @@ class SketchEngine:
                     # Window ticks ride the mux control lane: closes
                     # overtake the step backlog and stay on cadence
                     # under overload. Never into a queue nobody drains.
-                    win = ("window", None, 0, 0)
+                    # A tick is a read: the workers flush what was
+                    # dealt before it, and the tick carries the
+                    # request, which the dispatch thread waits on.
                     if worker.is_alive():
-                        q.put_ctl(win)
+                        q.put_ctl(("window", pool.request_flush(), 0, 0))
                     else:
-                        drop_item(win)
+                        drop_item(("window", None, 0, 0))
                     # Batched tick: one close per catch-up, however many
                     # boundaries a stall skipped. Advancing by the missed
                     # count keeps the cadence phase-locked to the start

@@ -109,6 +109,7 @@ class Metrics:
             mn.FEED_BLOCKS_DROPPED, [mn.L_WORKER]
         )
         self.feed_wakeups = c(mn.FEED_WAKEUPS, [mn.L_THREAD, mn.L_CAUSE])
+        self.feed_flushes = c(mn.FEED_FLUSHES, [mn.L_CAUSE])
         # events-in / rows-transferred of the host combiner (the kernel-map
         # aggregation factor; parallel/combine.py). 1.0 = nothing merged.
         self.combine_ratio = g(mn.COMBINE_RATIO, [])

@@ -4,16 +4,19 @@ and the double-buffered transfer handoff to the dispatch thread.
 The reference agent parallelizes its ingest the same way the kernel
 does — per-CPU perf rings drained by independent readers
 (packetparser_linux.go:556-652). Here the engine's feed loop (the
-*distributor*) drains the plugin sink and deals raw record blocks
-round-robin across N :class:`FeedWorker` threads. Each worker owns a
-private staging deque, accumulates a flush quantum, and runs the
-CPU-heavy half of a flush — combine + partition — off the distributor
-thread (the native combiner releases the GIL, so workers overlap on
-real cores). Finished :class:`~retina_tpu.parallel.partition.ShardedBatch`
-items hand off to the single dispatch thread through a
-:class:`TransferQueue`: a depth-2 (double-buffered) SPSC deque — one
-batch in flight on the dispatch side while the next is fully built —
-with no lock on the hot path (CPython deque append/popleft are atomic;
+*distributor*) drains the plugin sink and deals raw record blocks to
+N :class:`FeedWorker` threads: to one worker until its staged rows
+reach its quantum, then to the next. Each worker owns a private staging
+deque and HOLDS what is dealt to it, raw, until something would release
+it: a full quantum, the age bound, a reader (a window tick, a snapshot:
+``FeedWorkerPool.request_flush``) or the stop. Then it runs the
+CPU-heavy half of a flush — ONE combine over everything it held +
+partition — off the distributor thread (the native combiner releases
+the GIL, so workers overlap on real cores). Finished
+:class:`~retina_tpu.parallel.partition.ShardedBatch` items hand off
+to the single dispatch thread through a :class:`TransferQueue`: a
+depth-2 (double-buffered) SPSC deque — one batch in flight on the
+dispatch side while the next is fully built — with no lock on the hot path (CPython deque append/popleft are atomic;
 events only park a side that has nothing to do).
 
 Every wait of the feed path is one idiom, :func:`park`: block on an
@@ -184,8 +187,9 @@ class TransferMux:
     every producer sets it after its append (``TransferQueue.put``,
     ``put_ctl``), and ``wake`` sets it for a condition the consumer
     waits on beside the items (the pipeline has room again:
-    ``_dispatch_done``; a snapshot is about to read the state; the
-    clock was advanced by hand)."""
+    ``_dispatch_done``; a snapshot is about to read the state; every
+    worker has answered a flush request; the clock was advanced by
+    hand)."""
 
     def __init__(self, queues: list[TransferQueue], data: threading.Event,
                  clock: Callable[[], float] = time.monotonic):
@@ -217,21 +221,27 @@ class TransferMux:
                 out.append(tq.q.popleft())
         return out
 
+    def has_steps(self) -> bool:
+        """A step item waits in some worker's queue."""
+        return any(tq.q for tq in self._qs)
+
     def get(self, timeout: float | None = None, steps: bool = True,
-            due: float | None = None) -> Any:
+            due: float | None = None, ctl: bool = True) -> Any:
         """The next item: control lane first, then the workers' queues
         round-robin. With ``steps`` false only the control lane is
         served (the consumer holds all it may and takes no more step
         items, but window ticks stay on cadence); the shutdown sentinel
-        then waits, as it does behind any undrained queue. With nothing
-        to return it parks until a producer or ``wake`` sets the event,
-        for ``timeout`` seconds at most or until the mux's clock reads
-        ``due`` (``queue.Empty``)."""
+        then waits, as it does behind any undrained queue. With ``ctl``
+        false only step items are served (the consumer is inside a
+        window tick, taking what the workers flushed for it). With
+        nothing to return it parks until a producer or ``wake`` sets
+        the event, for ``timeout`` seconds at most or until the mux's
+        clock reads ``due`` (``queue.Empty``)."""
         t_end = None if timeout is None else time.monotonic() + timeout
         while True:
-            if self._ctl and self._ctl[0] is not None:
+            if ctl and self._ctl and self._ctl[0] is not None:
                 return self._ctl.popleft()
-            draining = bool(self._ctl)  # head is the None sentinel
+            draining = ctl and bool(self._ctl)  # head: the None sentinel
             n = len(self._qs) if steps else 0
             for k in range(n):
                 tq = self._qs[(self._rr + k) % n]
@@ -255,32 +265,30 @@ class TransferMux:
 
 
 class FeedWorker(threading.Thread):
-    """One ingest shard: staging deque -> quantum flush -> handoff.
+    """One ingest shard: staging deque -> flush -> handoff.
 
     Counter discipline (lock-free accounting): ``*_in`` fields are
-    written only by the distributor, ``*_out`` only by this worker —
-    both monotonic, so ``pending = in - out`` is always consistent
-    without a lock (a torn read can only be momentarily stale)."""
+    written only by the distributor, ``*_out`` and ``acked`` only by
+    this worker — all monotonic, so ``pending = in - out`` is always
+    consistent without a lock (a torn read can only be momentarily
+    stale). Each staged block carries the pool clock's reading when it
+    was dealt: the oldest one's is the staging's age."""
 
     def __init__(self, idx: int, pool: "FeedWorkerPool",
                  data: threading.Event):
         super().__init__(name=f"feed-worker-{idx}", daemon=True)
         self.idx = idx
         self.pool = pool
-        self.staging: deque = deque()
+        self.staging: deque = deque()  # (block, clock when dealt)
         self.outq = TransferQueue(pool.depth, data, pool.clock)
         self.wake = threading.Event()
         self.events_in = 0       # distributor-only
         self.blocks_in = 0       # distributor-only
         self.events_out = 0      # worker-only
         self.blocks_out = 0      # worker-only
-        # Stamp of the oldest staged block. Written by BOTH the
-        # distributor (push, on empty->nonempty) and the worker
-        # (_flush restamp) without a lock: the race is bounded —
-        # a lost store skews ONE flush-age decision by at most one
-        # block interval, and a lock here would put the distributor's
-        # hot path behind every worker flush.
-        self.first_t = 0.0  # noqa: RT200 — benign bounded race, see above
+        # The last flush request answered (FeedWorkerPool.request_flush):
+        # everything dealt before it has been handed off.
+        self.acked = 0           # worker-only
         self.fill = 0.0          # last flush's quantum fill ratio
         self.batches = 0
         self.handoff_dropped = 0  # worker-only: items the consumer lost
@@ -293,12 +301,16 @@ class FeedWorker(threading.Thread):
         return self.events_in - self.events_out
 
     def push(self, block) -> None:  # hot-path: event
-        if self.pending_events() == 0:
-            self.first_t = self.pool.clock()
-        self.staging.append(block)
+        """Stage a block. The worker is woken only where it has to act:
+        the first block staged sets its age deadline, the block that
+        fills its quantum makes a flush due; a block between changes
+        neither."""
+        first = self.pending_events() == 0
+        self.staging.append((block, self.pool.clock()))
         self.blocks_in += 1
         self.events_in += len(block)
-        self.wake.set()
+        if first or self.pending_events() >= self.pool.quantum:
+            self.wake.set()
 
     # -- worker side --------------------------------------------------
     def run(self) -> None:
@@ -351,60 +363,70 @@ class FeedWorker(threading.Thread):
                 self.pool.deregister_hb(self.name)
 
     def _loop(self, hb) -> None:  # hot-path: event
-        """Flush when a quantum is due; otherwise park (heartbeat
-        parked too) until what would make one due: a block
-        (``push`` sets ``wake``), the stop (``FeedWorkerPool.stop``),
-        the pipeline going idle (``FeedWorkerPool.wake_pending``, from
-        the engine's ``_dispatch_done``), or the staged quantum's next
-        age deadline on the pool's clock. Nothing staged: no
-        deadline."""
+        """Hold what is staged, raw, and flush it when something would
+        release it (``tpu_feed_flushes_counter{cause}``): a flush
+        request (``read``: every block dealt before it leaves, then the
+        request is acknowledged), the stop (``drain``: the same), a
+        full quantum (``full``) or the oldest staged block reaching
+        ``flush_max_age_s`` on the pool's clock (``age``). An idle
+        dispatch pipeline is no reason: the dispatch thread would only
+        hold the rows until one of these. Otherwise park (heartbeat
+        parked too) until a block that makes a flush due (``push``), a
+        request or the stop (both set ``wake``), or the oldest block's
+        age deadline. Nothing staged: no deadline."""
         pool = self.pool
         while True:
             stopping = pool.stop_evt.is_set()
+            asked = pool.flush_epoch
+            if asked > self.acked or stopping:
+                cause = mn.FLUSH_DRAIN if stopping else mn.FLUSH_READ
+                target = self.blocks_in
+                while self.blocks_out < target:
+                    if hb is not None:
+                        hb.beat()
+                    self._flush(cause)
+                if asked > self.acked:
+                    # Handed off first, then answered; the last answer
+                    # wakes the dispatch thread, which then finds every
+                    # item the request released on the mux.
+                    self.acked = asked
+                    if pool.flushed(asked):
+                        pool.mux.wake()
+                if stopping and not self.pending_events():
+                    return
+                continue
             pend = self.pending_events()
             deadline = None
             if pend:
-                # Flush policy: a full quantum, or the hard age bound,
-                # or an interval flush when the dispatch pipeline is
-                # idle. Interval flushes serve LATENCY; with work in
-                # flight, keep accumulating (bigger quanta combine
-                # harder and amortize per-flush fixed costs) up to the
-                # age bound — without this gate the async pipeline
-                # settles into many tiny flushes whose fixed costs cap
-                # throughput. The two ages are read as the
-                # deadlines the wait below sleeps to, so that a clock
-                # that has reached one has reached the other.
-                first_t = self.first_t
-                now = pool.clock()
-                interval_due = first_t + pool.flush_interval_s
-                age_due = first_t + pool.flush_max_age_s
-                if (
-                    pend >= pool.quantum
-                    or stopping
-                    or now >= age_due
-                    or (now >= interval_due and pool.busy() == 0)
-                ):
+                # Read as the deadline the wait below sleeps to, so
+                # that a clock that has reached it flushes.
+                age_due = self.staging[0][1] + pool.flush_max_age_s
+                if pend >= pool.quantum or pool.clock() >= age_due:
                     if hb is not None:
                         hb.beat()
-                    self._flush()
+                    self._flush(mn.FLUSH_FULL if pend >= pool.quantum
+                                else mn.FLUSH_AGE)
                     continue
-                # Past the interval the pipeline is busy: its going
-                # idle is signalled, the age bound is the deadline.
-                deadline = interval_due if now < interval_due else age_due
-            elif stopping:
-                return
+                deadline = age_due
             if hb is not None:
                 hb.park()
             park(self.wake, mn.WAKE_WORKER, pool.clock, deadline)
 
-    def _flush(self) -> None:
+    def _flush(self, cause: str) -> None:
+        """Combine and partition up to a quantum of the staged blocks,
+        oldest first, in one ``build_steps`` call, and hand the items
+        off, each carrying when its first block was staged (the
+        dispatch thread's hold counts its age from there)."""
         blocks = []
         n_raw = 0
+        t_first = None
         while n_raw < self.pool.quantum:
             try:
-                b = self.staging.popleft()
+                b, t = self.staging.popleft()
             except IndexError:
                 break
+            if t_first is None:
+                t_first = t
             blocks.append(b)
             n_raw += len(b)
         if not blocks:
@@ -414,15 +436,17 @@ class FeedWorker(threading.Thread):
         # crunched.
         self.blocks_out += len(blocks)
         self.events_out += n_raw
-        self.first_t = self.pool.clock()
         self.fill = n_raw / max(self.pool.quantum, 1)
+        from retina_tpu.metrics import get_metrics
         from retina_tpu.obs.recorder import get_recorder
 
+        get_metrics().feed_flushes.labels(cause=cause).inc()
         rec = get_recorder()
         with rec.span(mn.STAGE_FEED_FILL):
             items = self.pool.build_steps(blocks, n_raw, int(time.time()))
         with rec.span(mn.STAGE_STAGING_HANDOFF):
             for it in items:
+                it = it + (t_first,)
                 if not self.outq.put(it, alive=self.pool.alive):
                     self.handoff_dropped += 1
                     self.pool.drop(it)
@@ -459,23 +483,21 @@ class FeedWorkerPool:
     """N feed workers + the mux the dispatch thread consumes.
 
     ``build_steps(blocks, n_raw, now_s) -> list[item]`` is the engine's
-    combine+partition stage (pure host work, safe concurrently);
-    ``drop(item)`` is called for any finished item the dispatch side
-    will never consume (dead consumer) so losses are counted, never
-    silent; ``busy()`` returns the in-flight dispatch count (interval
-    flush gating: whoever lowers it calls ``wake_pending``);
-    ``alive()`` reports dispatch-thread liveness."""
+    combine+partition stage (pure host work, safe concurrently); each
+    item reaches the mux with one more field, the pool clock's reading
+    when the flush's first block was staged. ``drop(item)`` is called
+    for any finished item the dispatch side will never consume (dead
+    consumer) so losses are counted, never silent; ``alive()`` reports
+    dispatch-thread liveness."""
 
     def __init__(
         self,
         n_workers: int,
         quantum: int,
         staging_blocks: int,
-        flush_interval_s: float,
         flush_max_age_s: float,
         build_steps: Callable[[list, int, int], list],
         drop: Callable[[Any], None],
-        busy: Callable[[], int] = lambda: 0,
         alive: Callable[[], bool] = lambda: True,
         depth: int = TRANSFER_DEPTH,
         register_hb: Optional[Callable[[str], Any]] = None,
@@ -488,11 +510,9 @@ class FeedWorkerPool:
         self.clock = clock
         self.quantum = max(1, int(quantum))
         self.staging_blocks = max(1, int(staging_blocks))
-        self.flush_interval_s = flush_interval_s
         self.flush_max_age_s = flush_max_age_s
         self.build_steps = build_steps
         self.drop = drop
-        self.busy = busy
         self.alive = alive
         self.depth = max(1, int(depth))
         # Supervision seams (engine passes its heartbeat registrar and
@@ -507,7 +527,10 @@ class FeedWorkerPool:
             FeedWorker(i, self, data) for i in range(max(1, n_workers))
         ]
         self.mux = TransferMux([w.outq for w in self.workers], data, clock)
-        self._rr = 0
+        self._cur = 0  # distributor-only: the worker being dealt to
+        # Flush requests made (request_flush); the workers answer each.
+        self._epoch_lock = threading.Lock()
+        self.flush_epoch = 0  # guarded-by: self._epoch_lock (writes)
         # Distributor-only counters: blocks no worker could take.
         self.staging_dropped_blocks = 0
         self.staging_dropped_events = 0
@@ -517,30 +540,44 @@ class FeedWorkerPool:
             w.start()
 
     def stage(self, block) -> bool:
-        """Deal one raw block to a worker (round-robin, skipping full
-        or dead shards). Returns False — caller drops + counts — only
-        when EVERY worker is saturated or gone."""
+        """Deal one raw block to the worker being dealt to, or, where
+        that one is dead or its staging full, to the next live one with
+        room; once the worker's staged rows reach its quantum the next
+        block goes to the next worker. At a ring's cadence what one
+        release will read is then held, and combined, in one worker; at
+        saturation the workers fill and flush in turn, in parallel.
+        Returns False — caller drops + counts — only when EVERY worker
+        is saturated or gone."""
         n = len(self.workers)
         for k in range(n):
-            w = self.workers[(self._rr + k) % n]
+            i = (self._cur + k) % n
+            w = self.workers[i]
             if w.is_alive() and w.pending_blocks() < self.staging_blocks:
-                self._rr = (self._rr + k + 1) % n
                 w.push(block)
+                if w.pending_events() >= self.quantum:
+                    i = (i + 1) % n
+                self._cur = i
                 return True
         return False
 
-    def wake_pending(self) -> None:
-        """The dispatch pipeline has gone idle (or has room): wake the
-        dispatch thread, which may hold flushes for it, and every
-        worker that holds a partial quantum for it (past
-        ``flush_interval_s`` it would otherwise sleep on to
-        ``flush_max_age_s``). The caller has already changed what
-        ``busy()`` returns. A worker with nothing staged needs no
-        wake: the block that changes that brings its own."""
-        self.mux.wake()
+    def request_flush(self) -> int:
+        """Ask every worker to flush all it has staged, hand it off,
+        and then acknowledge; returns the request's epoch, which
+        ``flushed`` answers. A reader asks before it reads the state
+        (the distributor before a window tick, a snapshot through the
+        engine), so that what was dealt before it is in what it reads."""
+        with self._epoch_lock:
+            self.flush_epoch += 1
+            epoch = self.flush_epoch
         for w in self.workers:
-            if w.pending_events():
-                w.wake.set()
+            w.wake.set()
+        return epoch
+
+    def flushed(self, epoch: int) -> bool:
+        """Every live worker has handed off what was dealt to it before
+        request ``epoch`` (a dead worker holds nothing it could hand
+        off)."""
+        return all(w.acked >= epoch for w in self.workers if w.is_alive())
 
     def wake_all(self) -> None:
         """Every worker re-reads its clock (it was advanced by hand)."""
@@ -555,7 +592,7 @@ class FeedWorkerPool:
         self.staging_dropped_blocks += 1
         self.staging_dropped_events += n_events
         get_metrics().feed_blocks_dropped.labels(
-            worker=str(self._rr % len(self.workers))
+            worker=str(self._cur)
         ).inc()
 
     def stop(self, timeout: float = 30.0) -> None:

@@ -111,6 +111,17 @@ WAKE_WORKER = "worker"
 WAKE_DISPATCH = "dispatch"
 CAUSE_DATA = "data"
 CAUSE_DEADLINE = "deadline"
+# Flushes of the feed workers (parallel/feed.FeedWorker._flush), by
+# what released the raw blocks a worker held: its staged rows reached
+# its quantum (``full``), its oldest staged block reached
+# flush_max_age_s (``age``), a reader asked (``read``: a window tick or
+# a snapshot, FeedWorkerPool.request_flush), shutdown (``drain``). Each
+# flush is one combine and one partition.
+FEED_FLUSHES = PREFIX + "tpu_feed_flushes_counter"
+FLUSH_FULL = "full"
+FLUSH_AGE = "age"
+FLUSH_READ = "read"
+FLUSH_DRAIN = "drain"
 # Window ticks deferred because the close program was still queued in
 # the background warm (engine._close_window_impl): the window stays
 # open instead of cold-compiling end_window inline mid-feed.

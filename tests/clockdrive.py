@@ -96,25 +96,35 @@ class Drive:
 
     def tick(self, dt: float) -> None:
         """Let ``dt`` seconds pass, once the pipeline has followed: it
-        is idle, or has been busy (a dispatch in flight, flushes held
-        past ``flush_max_age_s``, or flushes handed off that the
-        dispatch thread has not taken yet) for less than KEEPS_UP_S of
-        the clock, and at most one closed window awaits its readback.
-        Flushes the dispatch thread holds that are not yet due are no
-        backlog: it holds them by design, for a step's worth, their
-        age or a reader. Without the third, a dispatch thread starved
-        of the machine's CPU reads as idle while the workers' hand-off
-        queues fill behind it, and the seconds then let pass are a
-        hand-off wait of 1.0 to the controller."""
+        is idle, or has been busy (a dispatch in flight, blocks staged
+        or flushes held past ``flush_max_age_s`` from when they were
+        dealt, or flushes handed off that the dispatch thread has not
+        taken yet) for less than KEEPS_UP_S of the clock, and at most
+        one closed window awaits its readback. Blocks the workers stage
+        and flushes the dispatch thread holds that are not yet due are
+        no backlog: they are held by design, for a quantum or a step's
+        worth, their age or a reader. Without the third, a dispatch
+        thread starved of the machine's CPU reads as idle while the
+        workers' hand-off queues fill behind it, and the seconds then
+        let pass are a hand-off wait of 1.0 to the controller."""
         eng = self.eng
+        age = eng.cfg.flush_max_age_s
+
+        def staged_since(w) -> float | None:
+            try:
+                return w.staging[0][1]
+            except IndexError:
+                return None
 
         def followed() -> bool:
             pool = eng._feed_pool
             handed_off = pool is not None and any(
                 w.outq.q for w in pool.workers)
-            since = eng._held_since
-            overdue = since is not None and \
-                self.clock() >= since + eng.cfg.flush_max_age_s
+            since = [eng._held_since] + ([
+                staged_since(w) for w in pool.workers]
+                if pool is not None else [])
+            overdue = any(t is not None and self.clock() >= t + age
+                          for t in since)
             if not overdue and eng._busy_count() == 0 and not handed_off:
                 self._busy_since = None
             elif self._busy_since is None:
@@ -166,8 +176,10 @@ class Drive:
         for _ in range(200):
             self.tick(ws / 4)
             time.sleep(0.002)
-            if closed._value.get() > n0 \
-                    and not eng._harvest_q.unfinished_tasks:
+            if closed._value.get() > n0:
+                # The clock stands: no later window closes behind it.
+                wait_until(lambda: not eng._harvest_q.unfinished_tasks,
+                           "the closed window is read back")
                 return
         raise AssertionError("no window closed")
 

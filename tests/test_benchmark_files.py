@@ -45,7 +45,8 @@ CPU_READERS = (*CPU_ROLE_READERS, "host_cpu_unnamed_pct")
 NEW_READERS = ("steps_per_s", "step_fill_pct", "overload_pressure_p95",
                "hubble_mirror_ms_per_s", "feed_wakeups_per_s",
                "publish_cpu_ms_per_s", "publish_changed_pct",
-               *MESH_READERS, *CHURN_READERS, *CPU_READERS)
+               *MESH_READERS, *CHURN_READERS, *CPU_READERS,
+               "feed_flushes_per_s")
 
 
 def _config(name: str) -> dict:
@@ -271,16 +272,19 @@ MESH_TRACE = _traced(modules=PROGRAMS, ops_by_chip=[
 
 
 # tpu_feed_wakeups_counter as the poller sums it over {thread, cause},
+# tpu_feed_flushes_counter over {cause},
 # tpu_publish_cpu_seconds_counter over {part}.
 RECORDED = _load(
     [_scrape(9.0, tpu_steps_counter=100.0, tpu_overload_pressure=0.9,
              tpu_feed_wakeups_counter=900.0,
+             tpu_feed_flushes_counter=5.0,
              tpu_publish_cpu_seconds_counter=0.5,
              tpu_publish_rows_counter=70_000.0,
              tpu_publish_rows_changed_counter=70_000.0,
              **_account(1.0, feed=0.9)),
      _scrape(10.0, tpu_steps_counter=110.0, tpu_overload_pressure=0.10,
              tpu_feed_wakeups_counter=1000.0,
+             tpu_feed_flushes_counter=10.0,
              tpu_publish_cpu_seconds_counter=0.6,
              tpu_publish_rows_counter=105_000.0,
              tpu_publish_rows_changed_counter=90_000.0,
@@ -289,6 +293,7 @@ RECORDED = _load(
              **_account(30.0, feed=3.0, foreign=20.0)),
      _scrape(35.0, tpu_steps_counter=360.0, tpu_overload_pressure=0.30,
              tpu_feed_wakeups_counter=3500.0,
+             tpu_feed_flushes_counter=90.0,
              tpu_publish_cpu_seconds_counter=2.0,
              tpu_publish_rows_counter=900_000.0,
              tpu_publish_rows_changed_counter=500_000.0,
@@ -298,6 +303,7 @@ RECORDED = _load(
                         account=0.25, runtime=2.0, foreign=21.0)),
      _scrape(47.0, tpu_steps_counter=480.0, tpu_overload_pressure=0.10,
              tpu_feed_wakeups_counter=4700.0,
+             tpu_feed_flushes_counter=130.0,
              tpu_publish_cpu_seconds_counter=3.0,
              tpu_publish_rows_counter=1_300_000.0,
              tpu_publish_rows_changed_counter=800_000.0,
@@ -307,6 +313,7 @@ RECORDED = _load(
      # Scraped again before the next sample: nothing has moved.
      _scrape(47.1, tpu_steps_counter=481.0, tpu_overload_pressure=0.10,
              tpu_feed_wakeups_counter=4710.0,
+             tpu_feed_flushes_counter=130.0,
              tpu_publish_cpu_seconds_counter=3.0,
              tpu_publish_rows_counter=1_300_000.0,
              tpu_publish_rows_changed_counter=800_000.0,
@@ -315,6 +322,7 @@ RECORDED = _load(
                         account=0.26, runtime=3.2, foreign=21.6)),
      _scrape(59.0, tpu_steps_counter=600.0, tpu_overload_pressure=0.20,
              tpu_feed_wakeups_counter=5900.0,
+             tpu_feed_flushes_counter=157.0,
              tpu_publish_cpu_seconds_counter=4.03,
              tpu_publish_rows_counter=1_785_000.0,
              tpu_publish_rows_changed_counter=1_098_000.0,
@@ -325,6 +333,7 @@ RECORDED = _load(
                         account=0.26, runtime=3.2, foreign=21.6)),
      _scrape(61.0, tpu_steps_counter=999.0, tpu_overload_pressure=0.95,
              tpu_feed_wakeups_counter=9999.0,
+             tpu_feed_flushes_counter=999.0,
              tpu_publish_cpu_seconds_counter=9.0,
              tpu_publish_rows_counter=9_999_999.0,
              tpu_publish_rows_changed_counter=9_999_999.0,
@@ -336,7 +345,7 @@ RECORDED = _load(
 # A parent's run: the poller sums nothing for a series that is not there.
 PARENT = _load(
     [_scrape(t, tpu_steps_counter=0.0, tpu_overload_pressure=0.0,
-             tpu_feed_wakeups_counter=0.0,
+             tpu_feed_wakeups_counter=0.0, tpu_feed_flushes_counter=0.0,
              tpu_publish_cpu_seconds_counter=0.0,
              tpu_publish_rows_counter=0.0,
              tpu_publish_rows_changed_counter=0.0,
@@ -354,6 +363,7 @@ SPANS = [{"stage": "hubble_consume", "t0": 12.0 + i, "t1": 12.004 + i,
 WANT = {"steps_per_s": (600.0 - 110.0) / 49.0, "step_fill_pct": 25.0,
         "overload_pressure_p95": 0.30, "hubble_mirror_ms_per_s": 2.0,
         "feed_wakeups_per_s": (5900.0 - 1000.0) / 49.0,
+        "feed_flushes_per_s": (157.0 - 10.0) / 49.0,
         "publish_cpu_ms_per_s": 1e3 * (4.03 - 0.6) / 49.0,
         "publish_changed_pct": 100.0 * 1_008_000 / 1_680_000,
         "partition_ms_per_s": 40 * 6.0 / 50.0,
@@ -429,6 +439,37 @@ def test_the_wakeup_metric_is_read_in_every_cell_off_the_programs_counter():
     body = get_exporter().gather_text()
     name = mn.FEED_WAKEUPS.encode()
     assert poller.series_sum(body, (name,))[name] == 7.0
+
+
+def test_the_flush_metric_is_read_in_every_cell_off_the_programs_counter():
+    """``feed_flushes_per_s`` is the last per-layer entry, names the
+    feed's layer and the host's CPU, lists no cells (every cell runs the
+    feed), and the counter it asks the poller for is the one the
+    program registers, summed over every cause."""
+    from retina_tpu.metrics import get_metrics
+    from retina_tpu.utils import metric_names as mn
+
+    entry = DOC["per_layer"][-1]
+    assert entry == {"name": "feed_flushes_per_s", "unit": "flushes/s",
+                     "better": "lower", "source": "program_counter",
+                     "layer": "feed + combine",
+                     "moves": "host_cpu_us_per_event"}
+    for w in DOC["workloads"]:
+        names = [m["name"]
+                 for m in harness.metrics_of(DOC, "per_layer", w["name"])]
+        assert "feed_flushes_per_s" in names
+    reader = harness.load_reader("feed_flushes_per_s")
+    assert mn.FEED_FLUSHES == "networkobservability_" + reader.FLUSHES
+    assert reader.COUNTERS == (reader.FLUSHES,)
+    for n, cause in enumerate((mn.FLUSH_FULL, mn.FLUSH_AGE, mn.FLUSH_READ,
+                               mn.FLUSH_DRAIN), 1):
+        get_metrics().feed_flushes.labels(cause=cause).inc(n)
+    import poller
+    from retina_tpu.exporter import get_exporter
+
+    body = get_exporter().gather_text()
+    name = mn.FEED_FLUSHES.encode()
+    assert poller.series_sum(body, (name,))[name] == 10.0
 
 
 def test_the_publish_metrics_are_read_in_every_cell_off_the_programs_counters():
@@ -627,8 +668,12 @@ def _stall_run(monkeypatch, spans, has_stage=True):
 
 
 def test_the_stall_metrics_are_read_in_every_cell_and_end_the_list():
+    """Appended together, after every entry that was there before
+    them; only entries added since follow them."""
     names = [m["name"] for m in DOC["per_layer"]]
-    assert names[-3:] == list(STALL_READERS)
+    i = names.index(next(iter(STALL_READERS)))
+    assert names[i:i + 3] == list(STALL_READERS)
+    assert names[i + 3:] == ["feed_flushes_per_s"]
     by_name = {m["name"]: m for m in DOC["per_layer"]}
     for name, (source, layer) in STALL_READERS.items():
         m = by_name[name]

@@ -98,6 +98,14 @@ def test_the_churn_deployment_agrees_with_the_plain_reference(
     pool = traffic.make_pool(mix, SEED)
     cfg = bench_agent.build_config(config, str(tmp_path), "", "", True)
     cfg.flow_dict_slots = DICT_SLOTS
+    # A feed worker holds what it is dealt up to its quantum or
+    # flush_max_age_s. In the cell a flush (0.4 s of hand-overs, about
+    # 91,000 rows) is under a step (131,072) and under the dictionary
+    # (262,144); at rehearsal shapes 0.4 s of hand-overs is three steps
+    # and twice this dictionary. A step's worth of quantum keeps the
+    # cell's proportions, so that flows recur across flushes inside a
+    # generation.
+    cfg.flush_max_events = cfg.batch_capacity
     cfg.feed_workers = 2  # the pool, whatever the machine's cores
     cfg.mesh_devices = 1  # one chip, of the test session's eight virtual
     agent = bench_agent.Agent(cfg, mix.n_endpoints, ready_deadline_s=300.0,

@@ -35,7 +35,6 @@ def small_cfg(**kw) -> Config:
     cfg.entropy_buckets = 1 << 8
     cfg.conntrack_slots = 1 << 10
     cfg.identity_slots = 1 << 10
-    cfg.flush_interval_s = 0.01
     cfg.window_seconds = 0.2
     for k, v in kw.items():
         setattr(cfg, k, v)
@@ -483,7 +482,7 @@ def test_dead_dispatch_worker_drops_and_counts(monkeypatch):
         raise RuntimeError("injected fatal dispatch error")
 
     monkeypatch.setattr(Eng, "_dispatch_loop", fatal_loop)
-    cfg = small_cfg(feed_pipeline_depth=2, flush_interval_s=0.01)
+    cfg = small_cfg(feed_pipeline_depth=2)
     eng = SketchEngine(cfg)
     eng.update_identities({POD_NET + i: i for i in range(1, 20)})
     eng.compile()

@@ -38,7 +38,6 @@ def small_cfg(**kw) -> Config:
     cfg.entropy_buckets = 1 << 8
     cfg.conntrack_slots = 1 << 10
     cfg.identity_slots = 1 << 10
-    cfg.flush_interval_s = 0.01
     cfg.window_seconds = 0.2
     for k, v in kw.items():
         setattr(cfg, k, v)
@@ -146,7 +145,7 @@ def test_mux_get_times_out_empty():
 def _mk_pool(**kw):
     defaults = dict(
         n_workers=2, quantum=100, staging_blocks=8,
-        flush_interval_s=0.01, flush_max_age_s=0.05,
+        flush_max_age_s=0.05,
         build_steps=lambda blocks, n_raw, now_s: [
             ("step", np.concatenate(blocks), now_s, n_raw)
         ],
@@ -180,8 +179,7 @@ def test_pool_end_to_end_delivers_every_event():
 
 
 def test_pool_stop_flushes_staged_remainder():
-    pool = _mk_pool(quantum=10_000, flush_interval_s=60.0,
-                    flush_max_age_s=60.0)
+    pool = _mk_pool(quantum=10_000, flush_max_age_s=60.0)
     pool.start()
     assert pool.stage(np.zeros((7, 2), np.uint32))
     stopper = threading.Thread(target=pool.stop, daemon=True)
@@ -194,7 +192,7 @@ def test_pool_stop_flushes_staged_remainder():
 
 def test_stage_refuses_when_every_worker_saturated():
     pool = _mk_pool(n_workers=1, staging_blocks=2, quantum=10_000,
-                    flush_interval_s=60.0, flush_max_age_s=60.0)
+                    flush_max_age_s=60.0)
     pool.start()
     assert pool.stage(np.zeros((5, 2), np.uint32))
     assert pool.stage(np.zeros((5, 2), np.uint32))
@@ -230,8 +228,9 @@ def test_dead_consumer_drops_are_counted_not_wedged():
 # -- engine integration ----------------------------------------------
 
 
-def _run_feed(cfg, n_events=1600):
-    """One feed of ``n_events`` by an injected clock (clockdrive): on
+def _run_feed(cfg, n_events=1600, dt=0.03):
+    """One feed of ``n_events``, a block of 400 every ``dt`` seconds,
+    by an injected clock (clockdrive): on
     the wall clock a loaded machine reads as a late device (with
     ``feed_pipeline_depth=2`` one cold ingest key compiling inline
     used to be the whole in-flight budget), the controller samples,
@@ -249,7 +248,7 @@ def _run_feed(cfg, n_events=1600):
     drive = Drive(eng, clock)
     gen = TrafficGen(n_flows=50, n_pods=16, seed=3)
     for _ in range(n_events // 400):
-        drive.hand_over(gen.batch(400), 0.03)
+        drive.hand_over(gen.batch(400), dt)
     drive.settle()
     assert eng.overload.stats()["transitions"] == 0
     snap = eng.snapshot(max_age_s=0)
@@ -329,7 +328,9 @@ def test_yaml_with_removed_feed_and_wire_keys_runs_the_one_path(tmp_path):
             setattr(cfg, k, v)
     m = get_metrics()
     known0 = m.wire_rows.labels(kind="known")._value.get()
-    eng, snap, st = _run_feed(cfg)
+    # A block a window (0.2 s): each window's tick releases its own
+    # flush, so the flows of the first come back as known rows.
+    eng, snap, st = _run_feed(cfg, dt=0.25)
     assert int(snap["totals"][0]) == 1600
     # Combined (50 flows in 400-event blocks) and carried as dense
     # known rows: the dictionary's ingest pair is what compiled.
@@ -452,62 +453,198 @@ def test_mux_wake_returns_the_consumer_with_no_item(long_parks):
 
 def test_a_parked_worker_flushes_at_the_interval_with_no_poll_between(
         long_parks):
-    """(b) A block pushed to a parked worker wakes it once; it then
-    sleeps to ``first_t + flush_interval_s`` on the injected clock, is
-    woken when that clock is advanced, and flushes when the interval
-    has passed: three wake-ups, however long the wall clock runs."""
+    """(b) The first block pushed to a parked worker wakes it once; it
+    then sleeps to that block's age deadline (the clock when it was
+    dealt + ``flush_max_age_s``) on the injected clock. A second block
+    short of the quantum wakes nobody. The worker is woken when that
+    clock is advanced, and flushes both blocks as one when the age has
+    passed: three wake-ups, however long the wall clock runs."""
     from clockdrive import FakeClock, wait_until
 
     clock = FakeClock()
     pool = _clocked_pool(clock, n_workers=1, quantum=10_000,
-                         flush_interval_s=30.0, flush_max_age_s=3600.0)
+                         flush_max_age_s=30.0)
     pool.start()
     w = pool.workers[0]
     w0 = wakeups("worker")
+    t_dealt = clock()
     assert pool.stage(np.zeros((7, 2), np.uint32))
     wait_until(lambda: wakeups("worker") - w0 == 1, "the push wakes it")
+    assert pool.stage(np.ones((5, 2), np.uint32))
     time.sleep(0.3)  # 150 polls of 2 ms
     assert wakeups("worker") - w0 == 1 and w.batches == 0
     clock.advance(29.9)
     wait_until(lambda: wakeups("worker") - w0 == 2, "the clock wakes it")
     time.sleep(0.05)
-    assert w.batches == 0 and w.pending_events() == 7
+    assert w.batches == 0 and w.pending_events() == 12
     clock.advance(0.2)
     item = _take(pool)
-    assert len(item[1]) == 7 and wakeups("worker") - w0 == 3
+    assert len(item[1]) == 12 and wakeups("worker") - w0 == 3
+    # The flush carries when its first block was dealt.
+    assert item[4] == t_dealt
     pool.stop()
 
 
 def test_a_partial_quantum_behind_a_busy_pipeline_leaves_when_it_goes_idle(
         long_parks):
-    """(c) Past ``flush_interval_s`` with a dispatch in flight, a
-    worker sleeps on to ``flush_max_age_s``. ``busy()`` falling to 0 is
-    signalled (``wake_pending``, which the engine's ``_dispatch_done``
-    calls): the quantum leaves then, on a clock that never reaches the
-    age bound. A worker with nothing staged is left asleep."""
+    """(c) A partial quantum is held by its worker whatever the
+    pipeline does: the pipeline going idle (the engine's
+    ``_dispatch_done`` wakes the mux) wakes the dispatch thread and no
+    worker. The quantum leaves when a reader asks
+    (``request_flush``), on a clock that never reaches the age bound;
+    every worker answers the request, the holder once its flush is on
+    the mux, and the last answer wakes the mux."""
     from clockdrive import FakeClock, wait_until
 
     clock = FakeClock()
-    busy = [1]
     pool = _clocked_pool(clock, n_workers=2, quantum=10_000,
-                         flush_interval_s=0.05, flush_max_age_s=3600.0,
-                         busy=lambda: busy[0])
+                         flush_max_age_s=3600.0)
     pool.start()
     w0 = wakeups("worker")
     assert pool.stage(np.zeros((7, 2), np.uint32))
     wait_until(lambda: wakeups("worker") - w0 == 1, "the push wakes it")
-    clock.advance(0.06)  # past the interval; both workers re-read it
-    wait_until(lambda: wakeups("worker") - w0 == 3, "the clock wakes them")
-    time.sleep(0.05)
     holder = pool.workers[0]
+    pool.mux.wake()  # a completion: the mux is woken, no worker
+    with pytest.raises(queue_mod.Empty):
+        pool.mux.get(timeout=30.0)
+    time.sleep(0.05)
+    assert wakeups("worker") - w0 == 1
     assert holder.batches == 0 and holder.pending_events() == 7
-    busy[0] = 0  # the state first, then the wake
-    pool.wake_pending()
+    epoch = pool.request_flush()
     item = _take(pool)
     assert len(item[1]) == 7
+    wait_until(lambda: pool.flushed(epoch), "every worker answers")
+    assert [w.acked for w in pool.workers] == [epoch, epoch]
+    # The last answer woke the mux: its consumer comes back empty.
+    with pytest.raises(queue_mod.Empty):
+        pool.mux.get(timeout=30.0)
     assert clock() - 1000.0 < 0.1  # nowhere near the age bound
-    # The holder's wake and no other worker's.
-    assert wakeups("worker") - w0 == 4
+    # The request's wake of each worker, and no other.
+    assert wakeups("worker") - w0 == 3
+    pool.stop()
+
+
+def _flush_causes() -> dict[str, float]:
+    """``tpu_feed_flushes_counter`` by cause, so far in this test."""
+    from retina_tpu.metrics import get_metrics
+
+    return {s.labels["cause"]: s.value
+            for mf in get_metrics().feed_flushes.collect()
+            for s in mf.samples if s.name.endswith("_total")}
+
+
+def test_a_ring_cadence_is_held_by_one_worker_and_flushed_once():
+    """Blocks short of a quantum are all dealt to one worker, which
+    holds them raw; a reader's request then makes ONE flush of them, in
+    the order they were dealt, however many workers the pool has."""
+    from clockdrive import FakeClock, wait_until
+
+    clock = FakeClock()
+    pool = _clocked_pool(clock, n_workers=4, quantum=100,
+                         flush_max_age_s=3600.0)
+    pool.start()
+    for i in range(5):
+        assert pool.stage(np.full((10, 2), i, np.uint32))
+    assert [w.pending_blocks() for w in pool.workers] == [5, 0, 0, 0]
+    epoch = pool.request_flush()
+    item = _take(pool)
+    assert item[1][:, 0].tolist() == [i for i in range(5) for _ in range(10)]
+    wait_until(lambda: pool.flushed(epoch), "every worker answers")
+    assert [w.batches for w in pool.workers] == [1, 0, 0, 0]
+    pool.stop()
+
+
+def test_at_saturation_the_deal_spills_to_the_next_worker_and_all_four_flush(
+        long_parks):
+    """The deal stays with a worker until its staged rows reach its
+    quantum and then moves on, so a saturated feed fills the four
+    workers in turn and each flushes a full quantum (``full``), in
+    parallel, as round-robin dealing did."""
+    from clockdrive import FakeClock
+
+    clock = FakeClock()
+    pool = _clocked_pool(clock, n_workers=4, quantum=100,
+                         flush_max_age_s=3600.0)
+    c0 = _flush_causes()
+    pool.start()
+    for i in range(8):
+        assert pool.stage(np.full((50, 2), i, np.uint32))
+    items = [_take(pool) for _ in range(4)]
+    got = sorted(sorted(set(it[1][:, 0].tolist())) for it in items)
+    assert got == [[0, 1], [2, 3], [4, 5], [6, 7]]
+    assert [w.batches for w in pool.workers] == [1, 1, 1, 1]
+    assert all(w.pending_events() == 0 for w in pool.workers)
+    assert _flush_causes().get("full", 0) - c0.get("full", 0) == 4
+    # A worker whose staging is full is passed over however few rows
+    # it holds.
+    w = pool.workers[pool._cur]
+    w.blocks_in += pool.staging_blocks  # as if that many were staged
+    assert pool.stage(np.zeros((1, 2), np.uint32))
+    w.blocks_in -= pool.staging_blocks
+    assert w.pending_blocks() == 0
+    pool.stop()
+
+
+def test_the_flush_counter_counts_each_cause(long_parks):
+    """``tpu_feed_flushes_counter{cause}``: a full quantum, the age
+    bound, a reader's request and the stop each make one flush of
+    their own cause."""
+    from clockdrive import FakeClock, wait_until
+
+    clock = FakeClock()
+    pool = _clocked_pool(clock, n_workers=1, quantum=100,
+                         flush_max_age_s=10.0)
+    c0 = _flush_causes()
+
+    def since() -> dict[str, float]:
+        now = _flush_causes()
+        return {k: v - c0.get(k, 0) for k, v in now.items()
+                if v != c0.get(k, 0)}
+
+    pool.start()
+    assert pool.stage(np.zeros((100, 2), np.uint32))
+    assert len(_take(pool)[1]) == 100
+    assert since() == {"full": 1}
+    assert pool.stage(np.zeros((3, 2), np.uint32))
+    clock.advance(10.0)
+    assert len(_take(pool)[1]) == 3
+    assert since() == {"full": 1, "age": 1}
+    assert pool.stage(np.zeros((4, 2), np.uint32))
+    epoch = pool.request_flush()
+    assert len(_take(pool)[1]) == 4
+    wait_until(lambda: pool.flushed(epoch), "the worker answers")
+    assert since() == {"full": 1, "age": 1, "read": 1}
+    assert pool.stage(np.zeros((5, 2), np.uint32))
+    pool.stop()
+    assert len(_take(pool)[1]) == 5
+    assert since() == {"full": 1, "age": 1, "read": 1, "drain": 1}
+
+
+def test_a_request_behind_a_full_hand_off_is_answered_once_it_drains(
+        long_parks):
+    """A worker whose hand-off queue is full waits on it and cannot
+    answer a flush request: the request stays open, nothing is lost,
+    and once the consumer takes the items every block dealt before the
+    request has reached the mux and the request is answered."""
+    from clockdrive import FakeClock, wait_until
+
+    clock = FakeClock()
+    pool = _clocked_pool(clock, n_workers=1, quantum=10,
+                         flush_max_age_s=3600.0)
+    pool.start()
+    w = pool.workers[0]
+    for i in range(3):
+        assert pool.stage(np.full((10, 2), i, np.uint32))
+    wait_until(lambda: w.outq.waiting_since is not None,
+               "the third flush waits for room")
+    assert pool.stage(np.full((4, 2), 3, np.uint32))
+    epoch = pool.request_flush()
+    time.sleep(0.1)
+    assert not pool.flushed(epoch) and len(w.outq.q) == TRANSFER_DEPTH
+    got = [_take(pool)[1][0, 0] for _ in range(4)]
+    assert got == [0, 1, 2, 3]
+    wait_until(lambda: pool.flushed(epoch), "the worker answers")
+    assert w.pending_events() == 0 and w.handoff_dropped == 0
     pool.stop()
 
 
@@ -529,7 +666,7 @@ def test_many_producers_and_a_stop_at_any_moment_lose_no_block_and_no_wake(
     rng = random.Random(seed)
     sink = QueueSink(max_blocks=64)
     pool = _mk_pool(n_workers=3, quantum=40, staging_blocks=4,
-                    flush_interval_s=0.002, flush_max_age_s=0.01)
+                    flush_max_age_s=0.01)
     stop = threading.Event()
     consumed = [0]
     accepted = [0] * 6
@@ -555,7 +692,9 @@ def test_many_producers_and_a_stop_at_any_moment_lose_no_block_and_no_wake(
                 return
             consumed[0] += len(item[1])
             if rng.random() < 0.3:
-                pool.wake_pending()  # completions come at any moment
+                pool.mux.wake()  # completions come at any moment
+            if rng.random() < 0.2:
+                pool.request_flush()  # and readers
 
     def producer(k, n, gaps):
         for i in range(n):

@@ -3,10 +3,11 @@
 At rehearsal sizes, on the CPU, by an injected clock (clockdrive): the
 same seeded events handed over as 1, 16 and 32 blocks a window give the
 per-pod counters of the benchmark's plain reference with the overload
-controller NOMINAL throughout, with Hubble's mirror on; the dispatch
-thread holds and folds the flushes until a step's worth is held, the
-oldest has aged out or a reader asks (ISSUE 34), never because the
-device fell idle; a device that cannot keep up still takes the
+controller NOMINAL throughout, with Hubble's mirror on; the feed
+workers hold the blocks dealt to them raw and the dispatch thread
+holds and folds the flushes until a step's worth is held, the oldest
+has aged out (counted from when it was dealt) or a reader asks, never
+because the device fell idle; and a device that cannot keep up still takes the
 controller to DEGRADED. The v5e-4 host's layout (ISSUE 33): the same
 events over a four-device mesh give the reference's counters and the
 one-device mesh's sketches.
@@ -131,15 +132,16 @@ class Rig:
 
         wait_until(followed, "the close lane follows the clock")
 
+    def staged(self) -> int:
+        """Blocks the feed workers hold, raw."""
+        return sum(w.pending_blocks() for w in self.eng._feed_pool.workers)
+
     def hold(self, block) -> None:
-        """Hand a block over and let its worker's interval pass: the
-        dispatch thread holds one flush more (the pipeline idle)."""
-        eng = self.eng
-        n = eng._held_flushes
+        """Hand a block over: a feed worker holds it, raw, until its
+        age, a reader or the stop releases it."""
+        n = self.staged()
         self.drive.stage(block)
-        self.clock.advance(eng.cfg.flush_interval_s)
-        wait_until(lambda: eng._held_flushes == n + 1,
-                   "the dispatch thread holds the flush")
+        assert self.staged() == n + 1
 
     def to_before_a_window_tick(self, before: float) -> None:
         """Let the clock run to ``before`` seconds short of a window
@@ -210,6 +212,18 @@ def _dispatches() -> dict[str, float]:
 
 def _since(d0: dict[str, float]) -> dict[str, float]:
     return {k: v - d0[k] for k, v in _dispatches().items() if v != d0[k]}
+
+
+def _flushes() -> dict[str, float]:
+    """``tpu_feed_flushes_counter`` by cause."""
+    m = get_metrics()
+    return {c: m.feed_flushes.labels(cause=c)._value.get()
+            for c in (mn.FLUSH_FULL, mn.FLUSH_AGE, mn.FLUSH_READ,
+                      mn.FLUSH_DRAIN)}
+
+
+def _flushes_since(f0: dict[str, float]) -> dict[str, float]:
+    return {k: v - f0[k] for k, v in _flushes().items() if v != f0[k]}
 
 
 @pytest.mark.parametrize("handovers", [1, 16, 32])
@@ -348,59 +362,58 @@ def test_a_four_device_mesh_gives_the_reference_and_one_devices_answers(
 
 def test_the_dispatch_thread_folds_what_accumulates_behind_a_busy_device(
         rig):
-    """One dispatch hangs on the proxy; the flushes that arrive behind
-    it are held, its completion alone releases none of them (an idle
-    device is no reason to step), and they leave as ONE dispatch when a
-    reader asks."""
+    """One dispatch hangs on the proxy; the blocks dealt behind it are
+    held raw by ONE worker (the deal stays with a worker short of its
+    quantum), its completion releases none of them (an idle device is
+    no reason to flush or to step), and they leave as ONE flush,
+    combined once, and ONE dispatch when a reader asks."""
     eng, mix, drive, clock = rig.eng, rig.mix, rig.drive, rig.clock
     drive.settle()
     rig.to_before_a_window_tick(ROOM)
     pool = traffic.make_pool(mix, seed=2902)
     m = get_metrics()
     fwd0, drop0 = rig.counters()
-    d0 = _dispatches()
+    d0, fl0 = _dispatches(), _flushes()
     f0 = m.dispatch_flushes._value.get()
     faults.configure("transfer:hang@1")
     per = 512
-    # The first block is held, goes by its age and hangs.
+    # The first block goes by its age and hangs.
     rig.hold(pool[:per].copy())
     clock.advance(eng.cfg.flush_max_age_s)
     wait_until(lambda: eng._busy_count() == 1, "the first dispatch hangs")
-    # Six more, three to each worker: the workers keep them to the
-    # feed's max age (a dispatch is in flight), then hand each its one
-    # flush to the dispatch thread, which holds them: they have not
-    # aged there.
+    assert _flushes_since(fl0) == {mn.FLUSH_AGE: 1}
+    # Six more, short of their age: one worker holds them all.
     for k in range(1, 7):
-        drive.stage(pool[k * per:(k + 1) * per].copy())
-    clock.advance(eng.cfg.flush_max_age_s)
-    held = len(eng._feed_pool.workers)
-    wait_until(lambda: eng._held_flushes == held
-               and not any(w.pending_events()
-                           for w in eng._feed_pool.workers),
-               "the flushes behind it are held")
-    assert eng._busy_count() == 1
+        rig.hold(pool[k * per:(k + 1) * per].copy())
+    holders = [w for w in eng._feed_pool.workers if w.pending_events()]
+    assert len(holders) == 1 and holders[0].pending_blocks() == 6
+    b0 = holders[0].batches
+    assert eng._held_flushes == 0
     assert _since(d0) == {}  # the one in flight has not finished
     assert eng._dispatches_landed(0.05) is False
     w0 = wakeups("dispatch", "data")
     faults.release_hangs()
     # The completion wakes the dispatch thread, which finds nothing
-    # due: the device is idle and the flushes stay held.
+    # due: the device is idle and the blocks stay in their worker.
     wait_until(lambda: eng._busy_count() == 0
                and wakeups("dispatch", "data") > w0,
                "the completion wakes the dispatch thread")
-    assert eng._held_flushes == held == 2
     assert eng._dispatches_landed(0.0) is True
+    assert holders[0].pending_blocks() == 6 and holders[0].batches == b0
     assert _since(d0) == {mn.DISPATCH_AGE: 1, "all": 1}
-    st = eng.feed_stats()["dispatch"]
-    assert st["in_flight"] == 0 and st["held_flushes"] == held
-    assert 0 <= st["held_age_s"] < eng.cfg.flush_max_age_s
+    st = eng.feed_stats()
+    assert st["dispatch"] == {"in_flight": 0, "held_flushes": 0,
+                              "held_age_s": 0.0}
+    assert sum(w["staged_blocks"] for w in st["per_worker"]) == 6
     want = reference.Counts(mix.n_endpoints).add(pool[:7 * per])
     fwd1, drop1 = rig.counters()  # a snapshot: a reader
     assert np.array_equal(fwd1 - fwd0, want.fwd)
     drive.settle()
     assert _since(d0) == {mn.DISPATCH_AGE: 1, mn.DISPATCH_READ: 1, "all": 2}
-    assert m.dispatch_flushes._value.get() - f0 == 1 + held
-    # A stall of 0.8 s of the engine's time with nothing piling up
+    assert m.dispatch_flushes._value.get() - f0 == 2
+    assert _flushes_since(fl0) == {mn.FLUSH_AGE: 1, mn.FLUSH_READ: 1}
+    assert holders[0].batches == b0 + 1
+    # A stall of 0.4 s of the engine's time with nothing piling up
     # behind it is not pressure: no transition.
     assert eng.feed_stats()["dispatch"] == {
         "in_flight": 0, "held_flushes": 0, "held_age_s": 0.0}
@@ -410,13 +423,13 @@ def test_the_dispatch_thread_folds_what_accumulates_behind_a_busy_device(
 def test_a_completion_wakes_the_holders_of_rows_not_the_clock(
         rig1, long_parks):
     """(c), (d) With the pipeline's one slot taken by a dispatch hung
-    on the proxy, one worker holds a partial quantum past
-    ``flush_interval_s`` (for an idle pipeline) and the dispatch thread
-    holds a flush past ``flush_max_age_s`` (for a slot): neither waits
-    by the clock (their idle bound is out of reach and the clock stands
-    still). The completion (``_dispatch_done``) wakes both: the overdue
-    flush is dispatched and the partial quantum flushed, with no
-    further tick."""
+    on the proxy, the dispatch thread holds a flush whose first block
+    was dealt ``flush_max_age_s`` ago (for a slot), and a worker holds
+    a block short of its age: neither waits by the clock (their idle
+    bound is out of reach and the clock stands still). The completion
+    (``_dispatch_done``) wakes the dispatch thread, which dispatches
+    the overdue flush with no further tick; it wakes no worker, and the
+    block stays held until a reader asks."""
     rig = rig1
     eng, mix, drive, clock = rig.eng, rig.mix, rig.drive, rig.clock
     drive.settle()
@@ -431,20 +444,19 @@ def test_a_completion_wakes_the_holders_of_rows_not_the_clock(
     rig.hold(pool[:per].copy())
     clock.advance(eng.cfg.flush_max_age_s)
     wait_until(lambda: eng._busy_count() == 1, "the first dispatch hangs")
-    # A flush for the dispatch thread to hold past its age: old enough
-    # to leave its worker by age, then as old again.
-    drive.stage(pool[per:2 * per].copy())
+    # A flush for the dispatch thread to hold past its age: it leaves
+    # its worker by age, with no slot to go to.
+    rig.hold(pool[per:2 * per].copy())
     clock.advance(eng.cfg.flush_max_age_s)
     wait_until(lambda: eng._held_flushes == 1, "a flush is held")
-    clock.advance(eng.cfg.flush_max_age_s)
-    # A partial quantum for a worker to hold: past the interval only.
-    drive.stage(pool[2 * per:3 * per].copy())
-    clock.advance(eng.cfg.flush_interval_s + 0.01)
+    # A block for a worker to hold, short of its age.
+    rig.hold(pool[2 * per:3 * per].copy())
     holders = [w for w in eng._feed_pool.workers if w.pending_events()]
     assert len(holders) == 1
-    w0, p0, t0 = wakeups("worker"), wakeups("dispatch"), clock()
-    wait_until(lambda: wakeups("worker") > w0 and wakeups("dispatch") > p0,
-               "the clock wakes them")
+    p0 = wakeups("dispatch")
+    clock.advance(0.01)
+    wait_until(lambda: wakeups("dispatch") > p0, "the clock wakes it")
+    t0 = clock()
     assert holders[0].pending_events() == per
     assert eng._held_flushes == 1 and eng._busy_count() == 1
     assert eng.feed_stats()["dispatch"]["held_age_s"] \
@@ -452,63 +464,70 @@ def test_a_completion_wakes_the_holders_of_rows_not_the_clock(
     assert _since(d0) == {}
     b0 = holders[0].batches
     faults.release_hangs()
-    # The overdue flush goes by its age; the partial quantum is flushed
-    # for the idle pipeline and goes with it, or is held for the read
-    # below, as the two holders happen to wake.
+    # The overdue flush goes by its age as the slot comes back.
     wait_until(lambda: _since(d0) == {mn.DISPATCH_AGE: 2, "all": 2}
-               and eng._busy_count() == 0
-               and holders[0].batches == b0 + 1
-               and not holders[0].outq.q,
-               "the completion wakes the holders")
+               and eng._busy_count() == 0,
+               "the completion wakes the dispatch thread")
     assert clock() == t0  # no tick did it
+    assert holders[0].batches == b0 and holders[0].pending_events() == per
     want = reference.Counts(mix.n_endpoints).add(pool[:3 * per])
-    fwd1, _ = rig.counters()
+    fwd1, _ = rig.counters()  # a reader releases the held block
     assert np.array_equal(fwd1 - fwd0, want.fwd)
+    assert holders[0].batches == b0 + 1
     assert clock() == t0
+    wait_until(lambda: _since(d0) == {mn.DISPATCH_AGE: 2,
+                                      mn.DISPATCH_READ: 1, "all": 3},
+               "the read's dispatch is seen finished")
     assert eng.overload.stats()["transitions"] == 0
 
 
 def test_an_idle_pipeline_holds_a_small_flush_until_it_ages_out(
         rig, long_parks):
-    """Nothing in flight and a flush of a few hundred rows: the
-    dispatch thread holds it. It goes when the engine's clock reaches
-    ``flush_max_age_s`` from the moment it was taken, and not an
-    instant before; the wait for that moment is a ``deadline`` wake-up
-    of the dispatch thread (its idle bound is out of reach here)."""
+    """Nothing in flight and a block of a few hundred rows: its worker
+    holds it, raw. It is flushed, and dispatched at once, when the
+    engine's clock reaches ``flush_max_age_s`` from the moment it was
+    dealt, and not an instant before; the wait for that moment is a
+    ``deadline`` wake-up of the worker (its idle bound is out of reach
+    here)."""
     eng, mix, drive, clock = rig.eng, rig.mix, rig.drive, rig.clock
     drive.settle()
     rig.to_before_a_window_tick(ROOM)
     pool = traffic.make_pool(mix, seed=3401)
     fwd0, _ = rig.counters()
-    d0, e0 = _dispatches(), eng._events_in
+    d0, fl0, e0 = _dispatches(), _flushes(), eng._events_in
+    dealt = clock()
     rig.hold(pool[:512].copy())
     assert eng._busy_count() == 0
-    since = eng._held_since
-    assert since == clock()
+    holder, = [w for w in eng._feed_pool.workers if w.pending_events()]
+    assert holder.staging[0][1] == dealt
     # Just short of the bound: woken (the clock was advanced) and the
     # rows stay.
-    w0 = wakeups("dispatch")
+    w0 = wakeups("worker")
     clock.advance(eng.cfg.flush_max_age_s - 0.002)
-    wait_until(lambda: wakeups("dispatch") > w0, "the advance wakes it")
-    p0 = wakeups("dispatch", "deadline")
-    wait_until(lambda: wakeups("dispatch", "deadline") > p0,
+    wait_until(lambda: wakeups("worker") > w0, "the advance wakes it")
+    p0 = wakeups("worker", "deadline")
+    wait_until(lambda: wakeups("worker", "deadline") > p0,
                "it sleeps to the age bound, two milliseconds away")
-    assert eng._held_flushes == 1 and eng._events_in == e0
-    assert _since(d0) == {}
+    assert holder.pending_events() == 512 and eng._events_in == e0
+    assert _since(d0) == {} and _flushes_since(fl0) == {}
     clock.advance(0.004)  # past the bound, whatever the sums round to
     wait_until(lambda: eng._events_in == e0 + 512 and eng._busy_count() == 0,
-               "the flush goes at its age")
-    assert clock() >= since + eng.cfg.flush_max_age_s
+               "the block goes at its age")
+    assert clock() >= dealt + eng.cfg.flush_max_age_s
+    assert eng._held_flushes == 0
     assert _since(d0) == {mn.DISPATCH_AGE: 1, "all": 1}
+    assert _flushes_since(fl0) == {mn.FLUSH_AGE: 1}
     want = reference.Counts(mix.n_endpoints).add(pool[:512])
     assert np.array_equal(rig.counters()[0] - fwd0, want.fwd)
     assert _since(d0) == {mn.DISPATCH_AGE: 1, "all": 1}  # nothing held
 
 
-def test_a_steps_worth_of_held_rows_goes_at_once(rig):
-    """Flushes are held while the fullest device's rows are short of
-    ``batch_capacity``; the flush that makes a step's worth releases
-    them all as one dispatch, the clock far from their age."""
+def test_a_steps_worth_of_held_rows_goes_at_once(rig, monkeypatch):
+    """Flushes are held by the dispatch thread while the fullest
+    device's rows are short of ``batch_capacity``; the flush that makes
+    a step's worth releases them all as one dispatch, the clock far
+    from their age. (Each block here fills its worker's quantum, so it
+    leaves its worker at once, ``full``: a saturated feed's flushes.)"""
     from retina_tpu.parallel.combine import combine_blocks
     from retina_tpu.parallel.partition import partition_events
 
@@ -517,7 +536,7 @@ def test_a_steps_worth_of_held_rows_goes_at_once(rig):
     rig.to_before_a_window_tick(ROOM)
     pool = traffic.make_pool(mix, seed=3402)
     fwd0, _ = rig.counters()
-    d0, e0, t0 = _dispatches(), eng._events_in, clock()
+    d0, fl0, e0, t0 = _dispatches(), _flushes(), eng._events_in, clock()
     # A block of 8,192 events combines to about 670 rows a device:
     # which one makes a step's worth on the fullest.
     per, n, rows = 8192, 0, np.zeros((eng.n_devices,), np.int64)
@@ -527,18 +546,90 @@ def test_a_steps_worth_of_held_rows_goes_at_once(rig):
             eng.n_devices, eng.cfg.batch_capacity).n_valid
         n += 1
     assert 2 <= n <= 6
+    monkeypatch.setattr(eng._feed_pool, "quantum", per)
     for k in range(n - 1):
-        rig.hold(pool[k * per:(k + 1) * per].copy())
-    assert eng._held_flushes == n - 1 and _since(d0) == {}
+        drive.stage(pool[k * per:(k + 1) * per].copy())
+        wait_until(lambda: eng._held_flushes == k + 1,
+                   "the dispatch thread holds the flush")
+    assert _since(d0) == {}
     drive.stage(pool[(n - 1) * per:n * per].copy())
-    clock.advance(eng.cfg.flush_interval_s)
     wait_until(lambda: eng._events_in == e0 + n * per
                and eng._busy_count() == 0, "they go together")
-    assert clock() - t0 < eng.cfg.flush_max_age_s
+    assert clock() == t0
     assert eng._held_flushes == 0
     assert _since(d0) == {mn.DISPATCH_FULL: 1, "all": 1}
+    assert _flushes_since(fl0) == {mn.FLUSH_FULL: n}
     want = reference.Counts(mix.n_endpoints).add(pool[:n * per])
     assert np.array_equal(rig.counters()[0] - fwd0, want.fwd)
+
+
+def test_no_row_waits_longer_than_the_age_bound_in_staging_and_hold_together(
+        rig, monkeypatch, long_parks):
+    """A flush that leaves its worker before its age (here a full
+    quantum) and is held by the dispatch thread short of a step's
+    worth goes when its first block was dealt ``flush_max_age_s`` ago:
+    the bound counts the staging and the hold together, and does not
+    start again at the hand-off."""
+    eng, mix, drive, clock = rig.eng, rig.mix, rig.drive, rig.clock
+    drive.settle()
+    rig.to_before_a_window_tick(ROOM)
+    pool = traffic.make_pool(mix, seed=3407)
+    fwd0, _ = rig.counters()
+    age = eng.cfg.flush_max_age_s
+    d0, e0, dealt = _dispatches(), eng._events_in, clock()
+    monkeypatch.setattr(eng._feed_pool, "quantum", 512)
+    drive.stage(pool[:512].copy())
+    wait_until(lambda: eng._held_flushes == 1,
+               "the dispatch thread holds the flush")
+    assert eng._held_since == dealt
+    clock.advance(age / 2)  # its worker's part of the bound
+    p0 = wakeups("dispatch", "deadline")
+    clock.advance(age / 2 - 0.002)
+    wait_until(lambda: wakeups("dispatch", "deadline") > p0,
+               "it sleeps to the age bound, two milliseconds away")
+    assert eng._held_flushes == 1 and eng._events_in == e0
+    assert _since(d0) == {}
+    clock.advance(0.004)
+    wait_until(lambda: eng._events_in == e0 + 512 and eng._busy_count() == 0,
+               "the flush goes at the age of its first block")
+    assert clock() - dealt < age + 0.01
+    assert _since(d0) == {mn.DISPATCH_AGE: 1, "all": 1}
+    want = reference.Counts(mix.n_endpoints).add(pool[:512])
+    assert np.array_equal(rig.counters()[0] - fwd0, want.fwd)
+
+
+def test_sixteen_hand_overs_a_second_make_one_flush_a_release(rig):
+    """At a ring's cadence on an idle pipeline the blocks one release
+    will read are held raw by one worker and combined once: two windows
+    of 16 hand-overs make a flush for each release by the oldest
+    block's age or by the window's tick, not one a hand-over, and every
+    flush reaches a dispatch (two, where a tick finds an aged flush
+    still on the mux, fold into one); the counters are the plain
+    reference's."""
+    eng, mix, drive = rig.eng, rig.mix, rig.drive
+    drive.settle()
+    pool = traffic.make_pool(mix, seed=4301)
+    m = get_metrics()
+    rows = int(mix.rate_events_per_s * eng.cfg.window_seconds)
+    per, total = rows // 16, 2 * rows
+    fwd0, _ = rig.counters()
+    d0, fl0 = _dispatches(), _flushes()
+    f0 = m.dispatch_flushes._value.get()
+    for a in range(0, total, per):
+        drive.hand_over(pool[a:a + per].copy(), eng.cfg.window_seconds / 16)
+    drive.settle(eng.cfg.window_seconds / 16)
+    flushes = _flushes_since(fl0)
+    n = sum(flushes.values())
+    # A block's age (0.4 s) or a tick releases it: about three flushes
+    # a window, against 16 hand-overs.
+    assert 4 <= n <= 8, flushes
+    assert set(flushes) <= {mn.FLUSH_AGE, mn.FLUSH_READ}, flushes
+    assert m.dispatch_flushes._value.get() - f0 == n
+    by = _since(d0)
+    assert by.pop("all") == sum(by.values()) <= n, by
+    want = reference.offered(pool, total, mix.n_endpoints)
+    assert np.array_equal(rig.counters()[0] - fwd0, want.fwd)
+    assert eng.overload.stats()["transitions"] == 0
 
 
 def _closed_window_events(eng) -> int:
@@ -546,37 +637,43 @@ def _closed_window_events(eng) -> int:
 
 
 def test_a_window_tick_dispatches_what_is_held_before_its_close(rig):
-    """A flush held when the window's tick comes off the mux goes to
-    the device before the close is submitted: its rows land in the
-    window they were taken in (the close's annotation counts them)."""
+    """Blocks dealt before a window's tick are flushed for it (the tick
+    carries the feed's flush request, which the dispatch thread waits
+    on) and go to the device before its close is submitted: their rows
+    land in the window they were dealt in (the close's annotation
+    counts them), short of their age."""
     eng, mix, drive, clock = rig.eng, rig.mix, rig.drive, rig.clock
     drive.settle()
     pool = traffic.make_pool(mix, seed=3403)
     rig.to_before_a_window_tick(0.2)
-    d0, e0 = _dispatches(), eng._events_in
+    d0, fl0, e0 = _dispatches(), _flushes(), eng._events_in
     open0 = e0 - eng._closed_events_in  # earlier tests'
     rig.hold(pool[:512].copy())
+    rig.hold(pool[512:1024].copy())
     assert _since(d0) == {}
-    clock.advance(0.2 - eng.cfg.flush_interval_s + 0.01)  # the boundary
-    wait_until(lambda: eng._closed_events_in == e0 + 512
+    clock.advance(0.2 + 0.01)  # the boundary
+    assert 0.21 < eng.cfg.flush_max_age_s
+    wait_until(lambda: eng._closed_events_in == e0 + 1024
                and not eng._harvest_q.unfinished_tasks
                and eng._busy_count() == 0, "the window closes")
     assert _since(d0) == {mn.DISPATCH_READ: 1, "all": 1}
-    assert _closed_window_events(eng) == open0 + 512
-    assert eng._held_flushes == 0
+    assert _flushes_since(fl0) == {mn.FLUSH_READ: 1}
+    assert _closed_window_events(eng) == open0 + 1024
+    assert eng._held_flushes == 0 and rig.staged() == 0
 
 
 def test_a_close_overtakes_what_is_held_when_the_pipeline_is_full(rig1):
     """The pipeline's one slot is taken by a dispatch hung on the
     proxy and a flush is held for it: the window's tick does not wait,
     its close is submitted ahead of the held rows, which land in the
-    next window."""
+    next window (overdue, they go by their age as soon as the slot is
+    free)."""
     rig = rig1
     eng, mix, drive, clock = rig.eng, rig.mix, rig.drive, rig.clock
     drive.settle()
     pool = traffic.make_pool(mix, seed=3404)
     age, gap = eng.cfg.flush_max_age_s, 0.1
-    before = eng.cfg.flush_interval_s + 2 * age + gap
+    before = 2 * age + gap
     assert before < eng.cfg.window_seconds
     rig.to_before_a_window_tick(before)
     d0, e0 = _dispatches(), eng._events_in
@@ -585,7 +682,7 @@ def test_a_close_overtakes_what_is_held_when_the_pipeline_is_full(rig1):
     rig.hold(pool[:512].copy())
     clock.advance(age)
     wait_until(lambda: eng._busy_count() == 1, "the first dispatch hangs")
-    drive.stage(pool[512:1280].copy())
+    rig.hold(pool[512:1280].copy())
     clock.advance(age)
     wait_until(lambda: eng._held_flushes == 1, "a flush is held")
     # The boundary: the tick comes off the mux with no slot free.
@@ -598,17 +695,13 @@ def test_a_close_overtakes_what_is_held_when_the_pipeline_is_full(rig1):
     # those of an engine some earlier test left running.)
     wait_until(lambda: eng._closed_events_in == e0 + 512
                and not eng._harvest_q.unfinished_tasks
+               and eng._events_in == e0 + 1280
                and eng._busy_count() == 0, "the window closes")
     assert _closed_window_events(eng) == open0 + 512
-    # The slot is free again and the held flush not yet of age.
-    assert eng._held_flushes == 1 and eng._events_in == e0 + 512
-    assert _since(d0) == {mn.DISPATCH_AGE: 1, "all": 1}
-    # Half a window on it goes by its age; the other half, and the
-    # next tick closes the window it landed in.
-    clock.advance(eng.cfg.window_seconds / 2)
-    wait_until(lambda: eng._events_in == e0 + 1280
-               and eng._busy_count() == 0, "the held flush goes by age")
-    clock.advance(eng.cfg.window_seconds / 2)
+    assert eng._held_flushes == 0
+    assert _since(d0) == {mn.DISPATCH_AGE: 2, "all": 2}
+    # The next tick closes the window the held rows landed in.
+    clock.advance(eng.cfg.window_seconds)
     wait_until(lambda: eng._closed_events_in == e0 + 1280
                and not eng._harvest_q.unfinished_tasks,
                "the next window closes")
@@ -618,12 +711,13 @@ def test_a_close_overtakes_what_is_held_when_the_pipeline_is_full(rig1):
 
 
 def test_a_snapshot_holds_every_event_flushed_before_it(rig):
-    """``engine.snapshot()`` about to submit a readback has the
-    dispatch thread submit what it holds first: the snapshot holds
-    every event accepted and flushed before the call, and nothing the
-    sink accepted lags it; its readback is submitted once the device
-    has finished what was released, not on its heels. A snapshot served
-    from the cache asks for nothing and releases nothing."""
+    """``engine.snapshot()`` about to submit a readback has the feed
+    workers flush what they hold and the dispatch thread submit it:
+    the snapshot holds every event dealt before the call, and nothing
+    the sink accepted lags it; its readback is submitted once the
+    device has finished what was released, not on its heels. A
+    snapshot served from the cache asks for nothing and releases
+    nothing."""
     eng, mix, drive, clock = rig.eng, rig.mix, rig.drive, rig.clock
     drive.settle()
     rig.to_before_a_window_tick(ROOM)
@@ -633,7 +727,7 @@ def test_a_snapshot_holds_every_event_flushed_before_it(rig):
     rig.hold(pool[2560:3072].copy())
     fwd0, _ = rig.counters()
     wait_until(lambda: eng._busy_count() == 0, "the device finishes")
-    d0, e0 = _dispatches(), eng._events_in
+    d0, fl0, e0 = _dispatches(), _flushes(), eng._events_in
     rig.hold(pool[:512].copy())
     rig.hold(pool[512:1024].copy())
     asked = eng._reads_asked
@@ -641,7 +735,7 @@ def test_a_snapshot_holds_every_event_flushed_before_it(rig):
     assert snap["events_in"] == e0 + 1024 == eng._events_in
     assert eng.publish_lag_s(snap) == (0.0, e0 + 1024)
     assert eng._reads_asked == asked + 1 == eng._reads_served
-    assert eng._held_flushes == 0
+    assert eng._held_flushes == 0 and rig.staged() == 0
     # The readback was submitted behind a dispatch that had landed.
     assert eng._busy_count() == 0
     want = reference.Counts(mix.n_endpoints).add(pool[:1024])
@@ -651,10 +745,11 @@ def test_a_snapshot_holds_every_event_flushed_before_it(rig):
         want.fwd)
     wait_until(lambda: eng._busy_count() == 0, "the device finishes")
     assert _since(d0) == {mn.DISPATCH_READ: 1, "all": 1}
-    # From the cache: the same snapshot, and the flush stays held.
+    assert _flushes_since(fl0) == {mn.FLUSH_READ: 1}  # one worker's two
+    # From the cache: the same snapshot, and the block stays held.
     rig.hold(pool[1024:1536].copy())
     assert eng.snapshot(max_age_s=3600.0) is snap
-    assert eng._reads_asked == asked + 1 and eng._held_flushes == 1
+    assert eng._reads_asked == asked + 1 and rig.staged() == 1
     assert eng.publish_lag_s(snap)[0] > 0.0
     assert _since(d0) == {mn.DISPATCH_READ: 1, "all": 1}
     drive.settle()
@@ -662,8 +757,9 @@ def test_a_snapshot_holds_every_event_flushed_before_it(rig):
 
 def test_shutdown_drains_what_is_held_and_no_snapshot_waits_for_the_dead(
         tmp_path):
-    """The stop finds a flush held: it is dispatched (``drain``) before
-    the dispatch thread ends, and the engine's totals hold it. With the
+    """The stop finds a block held: its worker flushes it (``drain``)
+    and it is dispatched (``drain``) before the dispatch thread ends,
+    and the engine's totals hold it. With the
     thread gone, by the sentinel or by a fault that killed it, a
     snapshot asks nobody and returns. Over the rig's life every
     dispatch had exactly one cause."""
@@ -679,6 +775,7 @@ def test_shutdown_drains_what_is_held_and_no_snapshot_waits_for_the_dead(
         r.close()
     assert r.clock() == clock_t  # no age: the drain did it
     assert eng._events_in == 512 and eng._held_flushes == 0
+    assert r.staged() == 0
     wait_until(lambda: _since(d0) == {mn.DISPATCH_DRAIN: 1, "all": 1},
                "the completion thread has seen the drained dispatch")
     assert eng._dispatch_thread is None
@@ -694,6 +791,71 @@ def test_shutdown_drains_what_is_held_and_no_snapshot_waits_for_the_dead(
     assert eng.snapshot(max_age_s=0)["events_in"] == 512
     assert eng._reads_asked == asked
     assert time.monotonic() - t0 < 30.0  # two readbacks, no wait
+
+
+def test_a_read_while_a_transfers_worth_is_held_neither_deadlocks_nor_loses(
+        tmp_path, monkeypatch):
+    """The pipeline's one slot is taken by a dispatch hung on the proxy;
+    the dispatch thread holds a transfer's worth and takes no more step
+    items, so the workers' hand-off queues fill and a worker waits on
+    its own. A snapshot asks the workers to flush: the waiting worker
+    cannot answer, the snapshot's wait is bounded, and the dispatch
+    thread goes on. Once the device returns, everything drains: the
+    request is answered, every block dealt reaches the device exactly
+    once, and nothing is lost or sampled."""
+    r = Rig(str(tmp_path), feed_pipeline_depth=1, feed_coalesce_windows=1)
+    try:
+        eng, mix, drive, clock = r.eng, r.mix, r.drive, r.clock
+        pool = traffic.make_pool(mix, seed=3408)
+        lost0 = _lost_events()
+        per = 8192
+        blocks = [pool[:512].copy()]
+        faults.configure("transfer:hang@1")
+        r.hold(blocks[0])
+        clock.advance(eng.cfg.flush_max_age_s)
+        wait_until(lambda: eng._busy_count() == 1, "the first dispatch hangs")
+        # Each block fills its worker's quantum and leaves it at once.
+        monkeypatch.setattr(eng._feed_pool, "quantum", per)
+        workers = eng._feed_pool.workers
+        for k in range(40):
+            if any(w.outq.waiting_since is not None for w in workers):
+                break
+            blocks.append(pool[(k % 8) * per:(k % 8 + 1) * per].copy())
+            drive.stage(blocks[-1])
+            time.sleep(0.05)
+        wait_until(lambda: any(w.outq.waiting_since is not None
+                               for w in workers), "a worker waits")
+        assert eng._held_flushes >= 2 and eng._busy_count() == 1
+        reader = threading.Thread(
+            target=eng.snapshot, kwargs={"max_age_s": 0}, daemon=True)
+        asked = eng._reads_asked
+        reader.start()
+        wait_until(lambda: eng._reads_asked == asked + 1, "the reader asks")
+        epoch = eng._read_epoch
+        time.sleep(2 * eng.cfg.flush_max_age_s)  # past the reader's bound
+        assert not eng._feed_pool.flushed(epoch)
+        assert eng._dispatch_thread.is_alive()
+        held = eng._held_flushes
+        assert held >= 2 and eng.feed_stats()["dispatch"]["in_flight"] == 1
+        faults.release_hangs()
+        reader.join(60.0)
+        assert not reader.is_alive()
+        wait_until(lambda: eng._feed_pool.flushed(epoch),
+                   "the request is answered")
+        drive.settle()
+        want = reference.Counts(mix.n_endpoints)
+        for b in blocks:
+            want.add(b)
+        fwd, drop = r.counters()
+        assert np.array_equal(fwd, want.fwd)
+        assert np.array_equal(drop[:, :reference.N_REASONS], want.drop)
+        assert eng._events_in == sum(len(b) for b in blocks)
+        assert _lost_events() == lost0
+        st = eng.overload.stats()
+        assert st["state"] == "NOMINAL" and st["transitions"] == 0, st
+    finally:
+        faults.clear()
+        r.close()
 
 
 def test_fold_of_two_side_windows_is_their_valid_rows_in_order():
@@ -724,14 +886,15 @@ def test_a_device_that_cannot_keep_up_takes_the_controller_to_degraded(
     ``feed.backpressure`` alone pins SHEDDING (0.95, as documented).
     With ``transfer`` hung on the proxy the one dispatch in flight
     never ends, the feed holds what arrives (here for as long as it
-    likes: the age bound is set out of the way so that the pile is
-    the workers' staging, which fills a block at a time), and the arc
-    NOMINAL -> SAMPLING -> SHEDDING -> DEGRADED is walked, then walked
-    back once the device returns."""
+    likes: the age bound is set out of the way, and the window's tick,
+    a read that flushes the staging, out of the steps' reach, so that
+    the pile is the workers' staging, which fills a block at a time),
+    and the arc NOMINAL -> SAMPLING -> SHEDDING -> DEGRADED is walked,
+    then walked back once the device returns."""
     # The controller's cadence is made finer than the steps this test
     # takes and the staging smaller; no threshold is touched.
     r = Rig(str(tmp_path), overload_tick_s=0.01, feed_staging_blocks=64,
-            flush_max_age_s=3600.0)
+            flush_max_age_s=3600.0, window_seconds=10.0)
     try:
         eng, clock, drive = r.eng, r.clock, r.drive
         pool = traffic.make_pool(r.mix, seed=2903)
@@ -762,10 +925,10 @@ def test_a_device_that_cannot_keep_up_takes_the_controller_to_degraded(
         assert m.overload_signal.labels(
             signal="staging")._value.get() >= 0.98
         assert eng.feed_stats()["dispatch"]["in_flight"] == 1
-        # The device returns: the pile drains (the workers flush for
-        # the idle pipeline, and a reader, as the publisher is once a
-        # second, releases what the dispatch thread then holds), one
-        # level down a dwell.
+        # The device returns: the pile drains (a reader, as the
+        # publisher is once a second, has the workers flush what they
+        # stage and the dispatch thread submit it), one level down a
+        # dwell.
         faults.release_hangs()
         faults.clear()
         def drained() -> bool:

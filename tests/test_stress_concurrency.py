@@ -24,7 +24,6 @@ def small_cfg() -> Config:
     cfg.entropy_buckets = 1 << 8
     cfg.conntrack_slots = 1 << 10
     cfg.identity_slots = 1 << 10
-    cfg.flush_interval_s = 0.01
     cfg.window_seconds = 0.1  # force frequent window closes
     cfg.bypass_lookup_ip_of_interest = True
     return cfg
